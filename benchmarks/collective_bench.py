@@ -73,8 +73,8 @@ import numpy as np
 
 
 def _fence(x) -> None:
-    # device->host readback: block_until_ready can be a no-op on tunneled
-    # backends (see bench.py), so fetch one element to fence.
+    # device->host readback of one element: timing waits for the
+    # computation, not for its enqueue.
     np.asarray(jax_device_get_first(x))
 
 

@@ -49,7 +49,7 @@ def bench_np(np_: int, *, steps: int, reps: int, B: int, S: int,
     from jax.sharding import Mesh, PartitionSpec as P
 
     import horovod_tpu as hvd
-    from horovod_tpu.jaxcompat import shard_map
+    from jax import shard_map
     from horovod_tpu.models import llama
     from horovod_tpu.optim import partition as PP
 

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from functools import partial
-from horovod_tpu.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd

@@ -33,14 +33,14 @@ def main() -> None:
     ap.add_argument("--num-blocks", type=int, default=128)
     ap.add_argument("--stream", action="store_true",
                     help="print tokens as they are generated")
-    ap.add_argument("--platform", default="cpu",
-                    help="jax platform to pin before init (cpu/tpu)")
+    ap.add_argument("--platform", default=None,
+                    help="jax platform to pin (cpu/tpu); default: "
+                         "whatever JAX finds")
     args = ap.parse_args()
 
-    if args.platform == "cpu":
-        from horovod_tpu.utils.cpurig import force_cpu_platform
-        force_cpu_platform(1)
     import jax
+    if args.platform:
+        jax.config.update("jax_platforms", args.platform)
 
     from horovod_tpu import serving
     from horovod_tpu.models import llama
@@ -71,15 +71,25 @@ def main() -> None:
                   f"budget {m}")
         session.drain()
 
-        print("\nper-request results:")
+        print(f"\ndecode attention path: {session.engine.attention_path}"
+              f" on {jax.devices()[0].device_kind}")
+        print("per-request results:")
+        failed = 0
         for fut in futs:
             r = fut.result()
             m = r.metrics
+            if "error" in m:
+                failed += 1
+                print(f"  req{r.req_id}: FAILED after {m['new_tokens']} "
+                      f"tokens: {m['error']}")
+                continue
             print(f"  req{r.req_id}: {m['prompt_len']:3d} prompt + "
                   f"{m['new_tokens']:2d} new | queue "
                   f"{m['queue_wait_s'] * 1e3:6.1f} ms | ttft "
                   f"{m['ttft_s']:.3f}s | {m['decode_tokens_per_s'] or 0:.0f}"
                   f" tok/s | preemptions {m['preemptions']}")
+    if failed:
+        sys.exit(f"{failed} of {len(futs)} requests failed")
 
 
 if __name__ == "__main__":

@@ -516,9 +516,10 @@ def broadcast_parameters(params: Any, root_rank: int = 0) -> Any:
     if not state.initialized:
         raise NotInitializedError()
     if jax.process_count() > 1:
-        # Per-leaf negotiated broadcast verb, NOT
-        # multihost_utils.broadcast_one_to_all — the latter silently
-        # returns local zeros on the CPU-gloo rig (jax 0.4.x).
+        # Per-leaf negotiated broadcast verb, not
+        # multihost_utils.broadcast_one_to_all: that issues its own
+        # cross-process computation from the calling thread, unordered
+        # against the engine's negotiated collectives.
         params = jax.tree.map(
             lambda a: _C.to_numpy(broadcast(
                 _C.replicate_local(np.asarray(a)), root_rank)),
@@ -536,7 +537,8 @@ def broadcast_object(obj: Any, root_rank: int = 0) -> Any:
     riding the negotiated broadcast verb, since buffer shapes must agree on
     every host; non-source hosts contribute zero-filled placeholders.
     (``multihost_utils.broadcast_one_to_all`` is deliberately not used: it
-    silently returns local zeros on the CPU-gloo rig, jax 0.4.x.)
+    issues its own cross-process computation from the calling thread,
+    unordered against the engine's negotiated collectives.)
     """
     import jax
     if jax.process_count() > 1:

@@ -1,8 +1,9 @@
 """ctypes bindings to the native core (``native/libhvdtpu_core.so``).
 
 † ``horovod/common/basics.py`` loads the built extension via ctypes the same
-way.  The library is built on demand with ``make -C native`` if missing
-(dev convenience; packaged builds ship the .so).
+way.  In a source tree the library is built from ``native/hvdtpu_core.cc``
+with ``make -C native`` on first load (it is never committed); packaged
+builds ship the .so next to this module.
 """
 
 from __future__ import annotations
@@ -63,33 +64,24 @@ def _so_path() -> str:
     editable installs), else a wheel-shipped copy next to this package
     († ``basics.py`` loading the built extension).  make runs on every
     source-tree load — a no-op when the .so is newer than the sources —
-    so editing ``hvdtpu_core.cc`` never silently loads a stale binary.
+    so what loads is always what ``hvdtpu_core.cc`` says; a failed build
+    raises.
     """
     if os.path.exists(os.path.join(_NATIVE_DIR, "Makefile")):
-        src_so = os.path.join(_NATIVE_DIR, "libhvdtpu_core.so")
         # Serialize the (possible) rebuild: hvdrun starts N workers that
         # import concurrently, and N unlocked makes would write the .so
-        # while siblings dlopen it mid-write.  A failed rebuild (no
-        # toolchain, read-only checkout) falls back to the committed .so
-        # when one exists — only a missing binary is fatal.
-        try:
-            import fcntl
-            with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lockf:
-                fcntl.flock(lockf, fcntl.LOCK_EX)
+        # while siblings dlopen it mid-write.
+        import fcntl
+        with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lockf:
+            fcntl.flock(lockf, fcntl.LOCK_EX)
+            try:
                 subprocess.run(["make", "-C", _NATIVE_DIR],
                                check=True, capture_output=True, text=True)
-        except (OSError, subprocess.CalledProcessError) as err:
-            if not os.path.exists(src_so):
-                detail = getattr(err, "stderr", "") or str(err)
+            except subprocess.CalledProcessError as err:
                 raise OSError(
-                    f"native core build failed and no prebuilt "
-                    f"libhvdtpu_core.so exists: {detail}") from err
-            import warnings
-            warnings.warn(
-                f"could not rebuild native core ({err.__class__.__name__}); "
-                "using the existing libhvdtpu_core.so, which may be stale "
-                "relative to hvdtpu_core.cc", RuntimeWarning)
-        return src_so
+                    f"native core build failed (make -C {_NATIVE_DIR}): "
+                    f"{err.stderr or err.stdout}") from err
+        return os.path.join(_NATIVE_DIR, "libhvdtpu_core.so")
     wheel_so = os.path.join(_PKG_DIR, "libhvdtpu_core.so")
     if os.path.exists(wheel_so):
         return wheel_so

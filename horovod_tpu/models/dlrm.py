@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ..jaxcompat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 

@@ -19,14 +19,13 @@ story would be "bring your own torch model"), so this is built TPU-first:
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from ..jaxcompat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel import sharding as shd
@@ -35,6 +34,9 @@ from ..parallel.ring_attention import (
     ring_attention_local,
     ulysses_attention_local,
 )
+from ..utils import logging as hvd_logging
+
+log = hvd_logging.get_logger()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -352,6 +354,14 @@ def _flash_backend() -> bool:
     return jax.default_backend() == "tpu" or _FORCE_FLASH_INTERPRET
 
 
+@lru_cache(maxsize=None)
+def _log_attention_path(path: str, q_shape: tuple, mesh_shape) -> None:
+    """INFO line naming the attention implementation a traced step uses,
+    once per distinct (path, local shape, mesh)."""
+    log.info("llama attention path: %s (local q %s, mesh %s)", path,
+             q_shape, dict(mesh_shape) if mesh_shape else None)
+
+
 def _sp_local_attention(sp_mode: str):
     """The mapped-context sequence-parallel attention for ``sp_mode``."""
     if sp_mode == "ulysses":
@@ -362,19 +372,40 @@ def _sp_local_attention(sp_mode: str):
                      "(expected 'ring' or 'ulysses')")
 
 
+def attention_path(q_shape: tuple, itemsize: int, mesh: Optional[Mesh],
+                   sp_mode: str = "ring") -> str:
+    """Which implementation :func:`_attention` runs for a global
+    ``[B, S, H, D]`` query on ``mesh``: ``"ring"``/``"ulysses"`` when the
+    sequence is sp-sharded, ``"flash"`` (the Pallas kernels) on TPU when
+    the per-chip shard divides evenly and :func:`FA.supported` accepts
+    it, ``"dense"`` (XLA) otherwise."""
+    from ..ops import flash_attention as FA
+    shape = dict(mesh.shape) if mesh is not None else {}
+    if shape.get("sp", 1) > 1:
+        _sp_local_attention(sp_mode)
+        return sp_mode
+    B, S, H, D = q_shape
+    dpf = shape.get("dp", 1) * shape.get("fsdp", 1)
+    tp = shape.get("tp", 1)
+    if (_flash_backend() and B % dpf == 0 and H % tp == 0
+            and FA.supported((B // dpf, S, H // tp, D), itemsize)):
+        return "flash"
+    return "dense"
+
+
 def _attention(q, k, v, mesh: Optional[Mesh], causal: bool,
                sp_mode: str = "ring") -> jax.Array:
-    """Dispatch: ring/Ulysses attention when the sequence is sp-sharded;
-    the Pallas flash kernel on TPU for supported shapes (shard_mapped over
-    the mesh so each chip runs the kernel on its own batch/head shard — a
-    bare pallas_call has no GSPMD partitioning rule and would be
-    replicated); dense XLA otherwise."""
-    sp = mesh.shape.get("sp", 1) if mesh is not None else 1
-    if sp > 1:
+    """Dispatch per :func:`attention_path`.  Under a mesh the sp paths and
+    the flash kernel are shard_mapped so each chip works on its own
+    batch/head shard (a bare pallas_call has no GSPMD partitioning rule
+    and would be replicated)."""
+    path = attention_path(q.shape, q.dtype.itemsize, mesh, sp_mode)
+    _log_attention_path(path, q.shape,
+                        tuple(mesh.shape.items()) if mesh is not None
+                        else None)
+    if path in ("ring", "ulysses"):
         k, v = _gqa_expand(q, k, v)   # ring/Ulysses rotate full head sets
-        # FULL-manual over every mesh axis (partial-auto shard_map lowers
-        # axis_index to PartitionId on 0.4.x jaxlib and the SPMD
-        # partitioner rejects it): the batch/head dims are explicitly
+        # Manual over every mesh axis: the batch/head dims are explicitly
         # dp·fsdp / tp sliced instead of left to GSPMD, and the body only
         # communicates over sp.
         spec = P(("dp", "fsdp"), "sp", "tp", None)
@@ -386,33 +417,21 @@ def _attention(q, k, v, mesh: Optional[Mesh], causal: bool,
             out_specs=spec,
             check_vma=False)
         return fn(q, k, v)
-    if _flash_backend():
+    if path == "flash":
         from ..ops import flash_attention as FA
-        B, S, H, D = q.shape
-        KV = k.shape[2]
-        if mesh is not None:
-            dpf = mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
-            tp = mesh.shape.get("tp", 1)
-            local = (B // max(dpf, 1), S, H // max(tp, 1), D)
-            if (B % dpf == 0 and H % tp == 0
-                    and FA.supported(local, q.dtype.itemsize)):
-                if KV % tp:
-                    # tp divides H but not KV: the grouped cache cannot
-                    # shard over tp — expand K/V and keep the flash
-                    # kernel (losing it entirely would be a 2-5x
-                    # regression for the sake of the GQA memory win).
-                    k, v = _gqa_expand(q, k, v)
-                spec = P(("dp", "fsdp"), None, "tp", None)
-                fn = shard_map(
-                    lambda q_, k_, v_: FA.flash_attention(
-                        q_, k_, v_, None, causal, None, None,
-                        _FORCE_FLASH_INTERPRET),
-                    mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                    check_vma=False)
-                return fn(q, k, v)
-        elif FA.supported(q.shape, q.dtype.itemsize):
-            return FA.flash_attention(q, k, v, None, causal, None, None,
-                                      _FORCE_FLASH_INTERPRET)
+        flash = lambda q_, k_, v_: FA.flash_attention(
+            q_, k_, v_, None, causal, None, None, _FORCE_FLASH_INTERPRET)
+        if mesh is None:
+            return flash(q, k, v)
+        if k.shape[2] % mesh.shape.get("tp", 1):
+            # tp divides H but not KV: the grouped cache cannot shard
+            # over tp — expand K/V and keep the flash kernel (losing it
+            # entirely would be a 2-5x regression for the sake of the
+            # GQA memory win).
+            k, v = _gqa_expand(q, k, v)
+        spec = P(("dp", "fsdp"), None, "tp", None)
+        return shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
     from ..ops.flash_attention import dense_attention
     return dense_attention(q, k, v, 1.0 / np.sqrt(q.shape[-1]), causal)
 
@@ -432,13 +451,10 @@ def _moe_mlp(h2, lp, cfg: LlamaConfig, mesh: Optional[Mesh]):
                "w_down": lp["w_down"]}
     ep = mesh.shape.get("ep", 1) if mesh is not None else 1
     if ep > 1:
-        # FULL-manual over every mesh axis (partial-auto shard_map is
-        # rejected by the SPMD partitioner on 0.4.x jaxlib): dp/fsdp/ep
-        # all count as token axes so each ep rank dispatches distinct
-        # local tokens (mirroring the pp path), the expert hidden dim is
-        # Megatron-sliced over tp with an explicit row-parallel psum, and
-        # aux rides out as shape [1] (rank-0 outputs of differentiated
-        # shard_maps trip a spec error on 0.4.x).
+        # Manual over every mesh axis: dp/fsdp/ep all count as token
+        # axes so each ep rank dispatches distinct local tokens
+        # (mirroring the pp path), and the expert hidden dim is
+        # Megatron-sliced over tp with an explicit row-parallel psum.
         all_axes = tuple(mesh.axis_names)
 
         def expert_fn_tp(w, x):
@@ -453,7 +469,7 @@ def _moe_mlp(h2, lp, cfg: LlamaConfig, mesh: Optional[Mesh]):
             # pmean over every axis: data axes average the per-shard aux
             # into the global mean; replicated axes (tp/pp) are forward
             # no-ops that keep the transpose psum correctly 1/n-scaled.
-            return out, lax.pmean(aux, all_axes).reshape(1)
+            return out, lax.pmean(aux, all_axes)
 
         espec = {"w_gate": P("ep", None, "tp"),
                  "w_up": P("ep", None, "tp"),
@@ -474,7 +490,6 @@ def _moe_mlp(h2, lp, cfg: LlamaConfig, mesh: Optional[Mesh]):
             check_vma=False)
         out, aux = fn(flat, lp["router"].astype(jnp.float32), eparams)
         out = jax.lax.with_sharding_constraint(out, token_pin)
-        aux = aux[0]
     else:
         # Single expert group: same math without the exchange.
         from ..parallel.moe import switch_route
@@ -552,15 +567,19 @@ def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
         return out
 
     def attention(q, k, v):
+        # q is this rank's shard already (the region is manual over
+        # every axis), so the mesh-free dispatch applies to it as is.
+        path = (cfg.sp_attention if sp > 1 else
+                attention_path(q.shape, q.dtype.itemsize, None))
+        _log_attention_path(path, q.shape, tuple(mesh.shape.items()))
         if sp > 1:
             k, v = _gqa_expand(q, k, v)
             return _sp_local_attention(cfg.sp_attention)(
                 q, k, v, axis_name="sp", causal=causal)
-        if _flash_backend() and FA.supported(q.shape, q.dtype.itemsize):
+        if path == "flash":
             return FA.flash_attention(q, k, v, None, causal, None, None,
                                       _FORCE_FLASH_INTERPRET)
-        from ..ops.flash_attention import dense_attention
-        return dense_attention(q, k, v, scale, causal)
+        return FA.dense_attention(q, k, v, scale, causal)
 
     def moe_mlp_local(x2, lp):
         Bq, Sq, Dq = x2.shape
@@ -690,15 +709,12 @@ def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
         rope = _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
         out, aux = pipeline_apply_local(make_stage_fn(rope), local_layers,
                                         mbs, axis_name="pp", with_aux=True)
-        # aux rides out as shape [1]: rank-0 outputs of differentiated
-        # shard_maps trip a spec error on 0.4.x jaxlib.
-        return out.reshape(B_loc, S_loc, D), aux.reshape(1)
+        return out.reshape(B_loc, S_loc, D), aux
 
     layer_specs, act_spec = parts["layer_specs"], parts["act_spec"]
     fn = shard_map(local, mesh=mesh, in_specs=(layer_specs, act_spec),
                    out_specs=(act_spec, P()), check_vma=False)
     h, aux = fn(params["layers"], h)
-    aux = aux[0]
     h = shd.constrain(h, ("batch", "seq", None), mesh)
     h = _rmsnorm(h, params["final_norm"])
     logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
@@ -1172,6 +1188,48 @@ def prefill_step(params, tokens: jax.Array, cfg: LlamaConfig, *,
     return logits, ks, vs
 
 
+# Logical dims of the serving page pool [L, NB, BS, KV, Dh].
+_POOL_DIMS = (None, None, None, "kv_heads", None)
+
+
+def paged_kernel_ok(cfg: LlamaConfig, mesh: Optional[Mesh],
+                    block_size: int) -> bool:
+    """Whether :func:`decode_step_paged` can take ``use_flash=True`` for
+    this model, mesh and page size: tp must split q and kv heads alike
+    (each chip runs the kernel on its own ``kv_heads`` shard), and the
+    per-chip page must fit the kernel's VMEM budget."""
+    from ..ops import flash_attention as FA
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    if cfg.n_heads % tp or cfg.n_kv_heads % tp:
+        return False
+    return FA.paged_supported(block_size, cfg.head_dim,
+                              cfg.n_kv_heads // tp,
+                              jnp.dtype(cfg.dtype).itemsize)
+
+
+def _paged_kernel_attend(q, kp, vp, layer, tables, lengths, scale, mesh,
+                         rules, interpret):
+    """The Pallas paged-decode kernel on q [B, H, Dh] against layer
+    ``layer`` of the whole pools.  Under a mesh it is shard_mapped —
+    batch rows over dp·fsdp, q heads and the pool's kv_heads over tp —
+    for the same reason as the training kernel in :func:`_attention`: a
+    bare pallas_call has no GSPMD partitioning rule and would gather the
+    pool onto every chip."""
+    from ..ops import flash_attention as FA
+    kernel = partial(FA.paged_attention, scale=scale, interpret=interpret)
+    if mesh is None:
+        return kernel(q, kp, vp, layer, tables, lengths)
+    q_spec = shd.spec_for(("batch", "heads", None), rules)
+    pool_spec = shd.spec_for(_POOL_DIMS, rules)
+    return shard_map(
+        kernel, mesh=mesh,
+        in_specs=(q_spec, pool_spec, pool_spec, P(),
+                  shd.spec_for(("batch", None), rules),
+                  shd.spec_for(("batch",), rules)),
+        out_specs=q_spec, check_vma=False)(
+            q, kp, vp, layer, tables, lengths)
+
+
 def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
                       k_pool: jax.Array, v_pool: jax.Array,
                       tables: jax.Array, cfg: LlamaConfig, *,
@@ -1188,8 +1246,9 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
     window with a per-request ``<= position`` mask (stale slots masked).
     The attention reads the pool either through a contiguous gather (XLA
     path, GSPMD-shardable) or the Pallas paged kernel's scalar-prefetch
-    block routing (``use_flash``).  Returns (logits [B, V] fp32, k_pool,
-    v_pool) — pass the pools donated so the writes land in place."""
+    block routing (``use_flash``; callers check :func:`paged_kernel_ok`).
+    Returns (logits [B, V] fp32, k_pool, v_pool) — pass the pools donated
+    so the writes land in place."""
     from ..serving.kv_pager import gather_blocks
 
     B = tok.shape[0]
@@ -1209,8 +1268,7 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
     def constrain_pool(p):
         if mesh is None:
             return p
-        return shd.constrain(p, (None, None, None, "kv_heads", None),
-                             mesh, rules)
+        return shd.constrain(p, _POOL_DIMS, mesh, rules)
 
     def layer(carry, xs):
         h, kp, vp = carry
@@ -1221,10 +1279,9 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
         kp = constrain_pool(kp.at[li, blk, off].set(k1[:, 0]))
         vp = constrain_pool(vp.at[li, blk, off].set(v1[:, 0]))
         if use_flash:
-            from ..ops import flash_attention as FA
-            attn = FA.paged_attention(
-                q[:, 0], kp[li], vp[li], tables, positions + 1,
-                scale=scale, interpret=interpret)[:, None]
+            attn = _paged_kernel_attend(
+                q[:, 0], kp, vp, li, tables, positions + 1, scale,
+                mesh, rules, interpret)[:, None]
         else:
             keys = gather_blocks(kp[li], tables)           # [B, T, KV, Dh]
             vals = gather_blocks(vp[li], tables)
@@ -1293,8 +1350,7 @@ def extend_step_paged(params, tok: jax.Array, positions: jax.Array,
     def constrain_pool(p):
         if mesh is None:
             return p
-        return shd.constrain(p, (None, None, None, "kv_heads", None),
-                             mesh, rules)
+        return shd.constrain(p, _POOL_DIMS, mesh, rules)
 
     def layer(carry, xs):
         h, kp, vp = carry

@@ -364,23 +364,32 @@ def _flash_backward(q, k, v, out, lse, g, *, scale, causal, block_q,
 # paged decode kernel (serving: block-paged KV cache)
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_scr, l_scr, acc_scr, *, block_size: int,
-                         scale: float):
-    """One (request, kv-head-group, table-column) grid step of paged
-    decode attention: online-softmax accumulate this physical block's
-    contribution for the group's ``rep`` query heads.
+def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_ref,
+                         v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                         block_size: int, scale: float):
+    """One (request, table-column) grid step of paged decode attention:
+    online-softmax accumulate this physical page's contribution for every
+    kv head at once.
 
-    The block table never touches the kernel body's data path — it rides
-    the scalar-prefetch channel and the K/V BlockSpec index maps below
-    route each grid step straight to its physical page, the same
-    grouped-KV index-map routing ``_kv_row_map`` gives the training
-    kernel (GQA-native: K/V pages stay at kv_heads width)."""
+    Neither the layer index nor the block table touches the kernel
+    body's data path — they ride the scalar-prefetch channel and the K/V
+    BlockSpec index maps below route each grid step straight to its
+    physical page (GQA-native: K/V pages stay at kv_heads width).  Pages
+    wholly past the request's length are fetched (the grid is
+    rectangular) but not computed.
+
+    A page arrives as the pool stores it, ``[BS, KV, Dh]`` — kv heads on
+    sublanes, head_dim on lanes — and is used in that form: scores are a
+    lane reduction of ``k * q`` kept as ``[BS, KV, 1]``, which is also
+    the shape that lane-broadcasts against ``v``, and every reduction
+    over the page's tokens is over the leading (untiled) dim.  One query
+    token per request leaves the MXU nothing to batch, and this way no
+    in-kernel transpose or relayout is asked of Mosaic."""
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    n_cols = pl.num_programs(2)
+    j = pl.program_id(1)
+    length = lengths_ref[b]
 
     @pl.when(j == 0)
     def _init():
@@ -388,94 +397,112 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0]                                   # [rep, Dh]
-    k = k_ref[0, :, 0, :]                             # [BS, Dh]
-    v = v_ref[0, :, 0, :]
-    # bf16 operands on the MXU, fp32 accumulation (see _fwd_kernel).
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    rep = q.shape[0]
-    pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (rep, block_size), 1)
-    s = jnp.where(pos < lengths_ref[b], s, _NEG_INF)
-    m_prev = m_scr[...]                               # [rep, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                            # [rep, BS]
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    @pl.when(j * block_size < length)
+    def _page():
+        k = k_ref[...].astype(jnp.float32)            # [BS, KV, Dh]
+        v = v_ref[...].astype(jnp.float32)
+        live = j * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (*k.shape[:2], 1), 0) < length
+        for r in range(q_ref.shape[0]):               # q heads per kv head
+            q = q_ref[r].astype(jnp.float32)          # [KV, Dh]
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            s = jnp.where(live, s, _NEG_INF)          # [BS, KV, 1]
+            m_prev = m_scr[r]                         # [KV, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[None])              # [BS, KV, 1]
+            l_scr[r] = l_scr[r] * alpha + jnp.sum(p, axis=0)
+            acc_scr[r] = acc_scr[r] * alpha + jnp.sum(p * v, axis=0)
+            m_scr[r] = m_new
 
-    @pl.when(j == n_cols - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
-def paged_supported(block_size: int, head_dim: int) -> bool:
-    """Pool geometries the paged decode kernel handles: sublane-aligned
-    pages and a lane-bounded head dim (mirrors :func:`supported`)."""
-    return block_size % 8 == 0 and head_dim <= 256
+# What a kernel may keep resident in VMEM at once, by the estimates in
+# :func:`supported` and :func:`paged_supported`.  Checked against the
+# compiler, not the data sheet: ahead-of-time compiles for a TPU v5 lite
+# target (jax 0.9.0, libtpu 0.0.34) accepted every probed shape whose
+# estimate was <= 12.5 MiB (e.g. S=16384 D=128 bf16, S=8192 D=256 bf16,
+# S=1024 D=512 fp32) and refused the smallest at 14.1 MiB with "ran out
+# of memory in memory space vmem".
+_VMEM_BUDGET = 12 << 20
 
 
-def paged_attention(q, k_pool, v_pool, tables, lengths, *,
+def paged_supported(block_size: int, head_dim: int, kv_heads: int,
+                    itemsize: int) -> bool:
+    """Pool geometries the paged decode kernel compiles for.  A K/V block
+    is one whole page ``[BS, KV, Dh]`` — its trailing dims equal the
+    array's, which Mosaic accepts at any size (probed from KV=1, Dh=16
+    to 32-head pages of 256 tokens) — so the only bound is the resident
+    set: K and V pages double-buffered, plus their fp32 copies."""
+    page = block_size * kv_heads * head_dim
+    return 4 * page * itemsize + 2 * page * 4 <= _VMEM_BUDGET
+
+
+def paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
                     scale: Optional[float] = None, interpret: bool = False):
     """Decode-step attention over a block-paged KV pool, GQA-native.
 
-    q [B, H, Dh] (one token per request); k_pool/v_pool
-    [num_blocks, block_size, KV, Dh]; tables [B, n_cols] int32 physical
-    block ids (rows padded with the scratch block 0); lengths [B] —
-    logical positions ``< lengths[b]`` are live, the rest masked.
+    q [B, H, Dh] (one token per request); k_pool/v_pool the WHOLE pools
+    [L, num_blocks, block_size, KV, Dh] and ``layer`` the int32 scalar
+    index of the layer to read — the kernel addresses pages inside the
+    pool, where slicing a layer out first would hand the custom call a
+    copy of that layer's pages every call; tables [B, n_cols] int32
+    physical block ids (rows padded with the scratch block 0); lengths
+    [B] — logical positions ``< lengths[b]`` are live, the rest masked.
 
-    The table rides ``PrefetchScalarGridSpec``'s scalar-prefetch channel
-    so the K/V BlockSpec index maps dereference it per grid step — no
-    gathered ``[B, T, KV, Dh]`` copy ever lands in HBM (the XLA fallback
-    in the serving engine materializes exactly that copy).  Returns
-    [B, H, Dh].
+    Layer and table ride ``PrefetchScalarGridSpec``'s scalar-prefetch
+    channel so the K/V BlockSpec index maps dereference them per grid
+    step — no gathered ``[B, T, KV, Dh]`` copy ever lands in HBM (the XLA
+    fallback in the serving engine materializes exactly that copy).
+    Returns [B, H, Dh].
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Dh = q.shape
-    NB, BS, KV, _ = k_pool.shape
+    L, NB, BS, KV, _ = k_pool.shape
     if H % KV:
         raise ValueError(f"kv heads {KV} must divide q heads {H}")
     rep = H // KV
     if scale is None:
         scale = 1.0 / float(np.sqrt(Dh))
     n_cols = tables.shape[1]
-    qg = q.reshape(B, KV, rep, Dh)      # group-major, as _cached_attend
+    # [B, rep, KV, Dh]: q head g*rep + r sits at [r, g], so each r is a
+    # [KV, Dh] tile aligned with a page's trailing dims.
+    qg = q.reshape(B, KV, rep, Dh).swapaxes(1, 2)
 
     kernel = functools.partial(_paged_decode_kernel, block_size=BS,
                                scale=scale)
+    q_spec = pl.BlockSpec((None, rep, KV, Dh),
+                          lambda b, j, li, tbl, ln: (b, 0, 0, 0))
     kv_spec = pl.BlockSpec(
-        (1, BS, 1, Dh),
-        lambda b, g, j, tbl, ln: (tbl[b, j], 0, g, 0))
+        (None, None, BS, KV, Dh),
+        lambda b, j, li, tbl, ln: (li[0], tbl[b, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, n_cols),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, Dh),
-                         lambda b, g, j, tbl, ln: (b, g, 0, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, Dh),
-                               lambda b, g, j, tbl, ln: (b, g, 0, 0)),
+        num_scalar_prefetch=3,
+        grid=(B, n_cols),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, Dh), jnp.float32),
+            pltpu.VMEM((rep, KV, 1), jnp.float32),
+            pltpu.VMEM((rep, KV, 1), jnp.float32),
+            pltpu.VMEM((rep, KV, Dh), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, rep, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, rep, KV, Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qg,
-      k_pool, v_pool)
-    return out.reshape(B, H, Dh)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
+      lengths.astype(jnp.int32), qg, k_pool, v_pool)
+    return out.swapaxes(1, 2).reshape(B, H, Dh)
 
 
 # ---------------------------------------------------------------------------
@@ -501,7 +528,7 @@ def default_blocks(seq_len: int) -> tuple[int, int]:
         # Kept on the flash path at parity (≥1x) rather than gated to
         # dense: one uniform code path across lengths, and the smaller
         # resident set leaves VMEM headroom.  See "S=512 flash decision"
-        # in BASELINE.md (round-6 close of VERDICT ask #5).
+        # in BASELINE.md (round 6).
         return 256, 256
     if seq_len % 512 == 0:
         return 512, 512
@@ -510,13 +537,11 @@ def default_blocks(seq_len: int) -> tuple[int, int]:
 
 
 def supported(q_shape: tuple, itemsize: int = 4) -> bool:
-    """Shapes the kernel handles: seq divisible by a block size, D ≤ 256,
-    and the heaviest kernel's resident set fitting VMEM (measured fwd+bwd
-    speedup over dense is ≥1x at every supported length — see module
-    docstring).  The budget counts what actually sits in VMEM at once:
-    two full-sequence operands (K/V in the forward, Q/dO in the dkv
-    backward), the lse/delta rows, and the double-buffered fp32 block
-    operands/accumulators."""
+    """Shapes the kernels compile for: seq divisible by a block size and
+    the heaviest kernel's resident set within :data:`_VMEM_BUDGET`.  The
+    estimate counts two full-sequence operands (K/V in the forward, Q/dO
+    in the dkv backward), the lse/delta rows, and the double-buffered
+    fp32 block operands/accumulators."""
     B, S, H, D = q_shape
     bq, bk = default_blocks(S)
     blk = max(bq, bk)
@@ -524,7 +549,7 @@ def supported(q_shape: tuple, itemsize: int = 4) -> bool:
                 + 2 * 8 * S * 4           # lse + delta, 8 sublanes fp32
                 + 2 * 4 * blk * D * 4)    # double-buffered fp32 blocks
     return (S % bq == 0 and S % bk == 0 and S >= bq
-            and D <= 256 and resident <= (8 << 20))
+            and resident <= _VMEM_BUDGET)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

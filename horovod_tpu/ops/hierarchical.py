@@ -47,7 +47,7 @@ from functools import lru_cache
 
 import jax
 from jax import lax
-from ..jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -65,7 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs import REGISTRY as _obs
@@ -523,7 +523,7 @@ def in_context_allreduce(x: jax.Array, axis_name: str, mode: str,
     usually small per-layer gradients where the extra collective's
     latency dominates).  Wire: 2B/elem + 4B/block vs fp32's 4B.
     """
-    from ..jaxcompat import axis_size
+    from jax.lax import axis_size
     n = axis_size(axis_name)
     alg = algebra_for(mode)
     if mode in QUANT_MODES and n > (256 if mode == "int8" else 146):

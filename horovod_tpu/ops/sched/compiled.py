@@ -6,7 +6,7 @@ by unit — per chunk a reduce-scatter program, a combine program, an
 allgather program — and relies on JAX's async dispatch to overlap them.
 That buys host-visible overlap windows but pays one host dispatch (and
 one XLA executable launch) per unit: on dispatch-bound payloads the walk
-itself is the bottleneck (BENCH_r07's 0.06–1.1× decomposed ratios on the
+itself is the bottleneck (round 7's 0.06–1.1× decomposed ratios on the
 CPU rig).  This module lowers the SAME schedule — same
 :func:`~.lower.chunk_layout` boundaries, same per-chunk arithmetic, same
 encode/decode algebra — into one ``jax.jit`` program over the
@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ...jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...obs import REGISTRY as _obs

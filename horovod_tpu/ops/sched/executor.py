@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ...jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...obs import REGISTRY as _obs

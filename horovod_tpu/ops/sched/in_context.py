@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ...jaxcompat import axis_size
+from jax.lax import axis_size
 from .. import reduction as R
 from .ir import Schedule
 from .lower import chunk_layout

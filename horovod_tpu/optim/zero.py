@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from ..jaxcompat import axis_size
+from jax.lax import axis_size
 from ..obs import REGISTRY as _obs
 from ..ops import collectives as C
 from ..ops.compression import Compression, Compressor, routes_engine_side

@@ -32,6 +32,10 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
+from ..utils import logging as hvd_logging
+
+log = hvd_logging.get_logger()
+
 AXES = ("pp", "dp", "fsdp", "ep", "sp", "tp")
 
 
@@ -97,7 +101,11 @@ def build_mesh(config: MeshConfig,
     if devices is None and len(devs) > 1:
         try:
             arr = mesh_utils.create_device_mesh(shape)
-        except (ValueError, AssertionError):
+        except (ValueError, AssertionError) as e:
+            log.warning(
+                "create_device_mesh%s failed (%s); falling back to device "
+                "enumeration order, which ignores the ICI topology", shape,
+                e)
             arr = np.array(devs).reshape(shape)
     else:
         arr = np.array(devs).reshape(shape)

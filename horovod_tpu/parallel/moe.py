@@ -22,7 +22,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..jaxcompat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs import REGISTRY as _obs

@@ -23,7 +23,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..jaxcompat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -63,10 +64,8 @@ def pipeline_apply_local(stage_fn: Callable[[Any, jax.Array], jax.Array],
         y, aux = res if with_aux else (res, None)
         if with_aux:
             # This stage processes real data at tick t iff 0 <= t-idx < M.
-            # Both the mask and aux ride as shape [1]: rank-0 residuals of
-            # a differentiated shard_map trip a spec error on 0.4.x.
-            live = ((t - idx >= 0) & (t - idx < M)).reshape(1)
-            aux_acc = aux_acc + jnp.where(live, aux.reshape(1), 0.0)
+            live = (t - idx >= 0) & (t - idx < M)
+            aux_acc = aux_acc + jnp.where(live, aux, 0.0)
         # The last stage records its result for microbatch t - (n-1).
         out_idx = jnp.clip(t - (n - 1), 0, M - 1)
         is_valid = (t - (n - 1) >= 0) & (t - (n - 1) < M)
@@ -81,15 +80,13 @@ def pipeline_apply_local(stage_fn: Callable[[Any, jax.Array], jax.Array],
     out0 = jnp.zeros(microbatches.shape[:1] + _out_shape(
         stage_fn, stage_params, microbatches[0], with_aux),
         microbatches.dtype)
-    carry0 = (buf0, out0, jnp.zeros((1,), jnp.float32))
+    carry0 = (buf0, out0, jnp.zeros((), jnp.float32))
     (_, outputs, aux_acc), _ = lax.scan(tick, carry0, jnp.arange(T))
     # Broadcast final outputs from the last stage to all pp ranks so the
     # caller sees replicated results (one psum, masked).
     outputs = lax.psum(
         jnp.where(idx == n - 1, outputs, jnp.zeros_like(outputs)), axis_name)
     if with_aux:
-        # aux stays shape [1] (see tick) — callers index [0] outside the
-        # differentiated region.
         return outputs, lax.psum(aux_acc, axis_name) / M
     return outputs
 

@@ -190,6 +190,17 @@ def _pickle_module_by_value(mod) -> bool:
     return not path.startswith(stdlib + os.sep)
 
 
+def _holds_tpu() -> bool:
+    """Has this process already opened the TPU?  (Never opens it.)"""
+    import sys
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() == "tpu")
+
+
 def run_func(func, args: Sequence[Any] = (), kwargs: Optional[dict] = None,
              np: int = 1, *, hosts: Optional[str] = None,
              extra_env: Optional[dict] = None, ssh_port: int = 22,
@@ -206,6 +217,17 @@ def run_func(func, args: Sequence[Any] = (), kwargs: Optional[dict] = None,
 
     from .._native import KvClient
     from .launch import launch_workers
+
+    platform = {**os.environ, **(extra_env or {})}.get("HVDTPU_PLATFORM")
+    if platform != "cpu" and hosts is None and _holds_tpu():
+        # A chip belongs to one process at a time: the workers would
+        # fail to open it, or hang waiting for it.
+        raise RuntimeError(
+            "run_func: this process has already initialized the TPU "
+            "backend and holds the local chips, so workers spawned from "
+            "it could not open them.  Call run_func before any JAX "
+            "computation, or use the in-process mode (hvd.init() over "
+            "all local devices)")
 
     # Ship the function BY VALUE when its module is plausibly not
     # importable on the workers (a notebook cell, a pytest-loaded test

@@ -27,6 +27,7 @@ import subprocess
 import sys
 import threading
 import time
+from collections import Counter
 from typing import List, Optional, Sequence
 
 from .hosts import assign_ranks, parse_hosts
@@ -43,6 +44,36 @@ def _free_port() -> int:
 
 
 from .cluster import local_ip as _local_ip  # noqa: E402  (shared probe)
+
+# One chip a process: how libtpu lays N single-chip processes of one host
+# onto that host's chips (TPU_PROCESS_BOUNDS), for the host sizes known.
+_TPU_PROCESS_BOUNDS = {4: "2,2,1"}
+
+
+def tpu_chip_binding(n_local: int) -> List[dict]:
+    """Per-local-rank env that gives each of ``n_local`` workers on this
+    host its own chip and joins them into one slice.  A chip belongs to
+    one process: workers that all inherit the launcher's environment
+    would each try to open every chip, and the first would win.  Raises
+    ValueError when the layout for ``n_local`` is not known — the caller
+    refuses to spawn rather than let the workers fight over the chips."""
+    bounds = _TPU_PROCESS_BOUNDS.get(n_local)
+    if bounds is None:
+        raise ValueError(
+            f"--platform tpu with {n_local} workers on one host: no chip "
+            f"binding known for that count (known: "
+            f"{sorted(_TPU_PROCESS_BOUNDS)}).  Run one process over all "
+            "local chips instead (hvd.init() in-process mode, -np 1)")
+    ports = [_free_port() for _ in range(n_local)]
+    addresses = ",".join(f"localhost:{p}" for p in ports)
+    return [{
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_ADDRESSES": addresses,
+        "TPU_PROCESS_PORT": str(ports[i]),
+        "TPU_VISIBLE_DEVICES": str(i),
+        "CLOUD_TPU_TASK_ID": str(i),
+    } for i in range(n_local)]
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -77,9 +108,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "available host; enables elastic mode")
     p.add_argument("--slots", type=int, default=None,
                    help="default slots per discovered host (elastic "
-                        "discovery scripts printing bare hostnames; with "
-                        "--tpu-pod only for setups partitioning chips "
-                        "per-process themselves via TPU_VISIBLE_DEVICES)")
+                        "discovery scripts printing bare hostnames)")
     p.add_argument("--autoscale", action="store_true", default=False,
                    help="close the loop between /cluster signals and "
                         "elastic rendezvous: the driver grows the job on "
@@ -278,6 +307,22 @@ def launch_workers(command: Sequence[str], *, np_total: int,
                       f"coordinator={coord_host} nics={routing['nics']}",
                       file=sys.stderr)
 
+    chip_env: List[dict] = [{}] * np_total
+    if (extra_env or {}).get("HVDTPU_PLATFORM") == "tpu":
+        per_host = Counter(host for _, host, _ in assignment)
+        if max(per_host.values()) > 1:
+            try:
+                if not is_local_job:
+                    raise ValueError(
+                        "--platform tpu with several workers per host is "
+                        "only bound to chips on a single-host job; give "
+                        "each host one slot (-H host1:1,host2:1)")
+                chip_env = tpu_chip_binding(np_total)
+            except ValueError as e:
+                services.close()
+                print(f"[launcher] {e}", file=sys.stderr)
+                return 2
+
     workers: List[_Worker] = []
     failed = threading.Event()
     exit_codes: dict[int, int] = {}
@@ -293,6 +338,7 @@ def launch_workers(command: Sequence[str], *, np_total: int,
             rank, local_rank,
             coordinator_addr=f"{coord_host}:{coord_port}",
             extra_env=extra_env))
+        env.update(chip_env[local_rank])
         if timeline_dir:
             # One Timeline v2 file per rank; merged after the run into
             # a single multi-lane Perfetto trace.

@@ -39,6 +39,14 @@ from .scheduler import Request
 
 log = hvd_logging.get_logger()
 
+#: Engine-step failures a session recovers from: collective aborts (the
+#: elastic re-rendezvous case) and transport errors (what the chaos
+#: harness injects).  Anything else — a kernel the compiler refuses, a
+#: shape error, a device fault — is the program's own failure: in-flight
+#: futures carry the exception and it propagates to whoever drives the
+#: loop, so a broken decode step can never read as served requests.
+RECOVERABLE_ERRORS = (HorovodInternalError, ConnectionError)
+
 # Request-level latency series (horovod_tpu.obs).  TTFT and ITL are the
 # two serving SLO primitives; queue-wait isolates the admission share of
 # TTFT so "slow prefill" and "full pool" are distinguishable in one scrape.
@@ -96,10 +104,10 @@ class ServingSession:
         self._lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
-        # Graceful degradation: an engine-step failure aborts in-flight
-        # requests (error finish_reason), holds /healthz at 503 through
-        # the drain window, rejoins (elastic re-rendezvous for
-        # collective failures), and resumes — instead of dying.
+        # Graceful degradation: a collective/transport failure of an
+        # engine step aborts in-flight requests (error finish_reason),
+        # holds /healthz at 503 through the drain window, rejoins
+        # (elastic re-rendezvous for collective failures), and resumes.
         self._recover = recover
         self._max_recoveries = max_recoveries
         self._recovery_pause_s = recovery_pause_s
@@ -283,19 +291,23 @@ class ServingSession:
 
     # -- graceful degradation --------------------------------------------
     def _handle_engine_failure(self, exc: BaseException) -> None:
-        """One engine-step failure, survived: abort in-flight requests
-        with an ``error`` finish_reason (futures resolve to their
-        partial results — streamed tokens are already delivered, not
-        lied about), hold ``/healthz`` at 503 through the drain window,
-        rejoin through elastic re-rendezvous when the failure was a
-        collective abort, then resume serving.  Past
-        ``max_recoveries`` the failure is re-raised (a permanently sick
-        engine should die loudly, not flap)."""
+        """One engine-step failure.  Every in-flight request is aborted
+        and ``/healthz`` goes 503.  A :data:`RECOVERABLE_ERRORS` failure
+        is survived: futures resolve to their partial results with an
+        ``error`` finish_reason (streamed tokens are already delivered,
+        not lied about), the session rejoins through elastic
+        re-rendezvous when the failure was a collective abort, and
+        serving resumes; past ``max_recoveries`` it is re-raised (a
+        permanently sick engine should die loudly, not flap).  Any other
+        failure is set on the futures and re-raised at once."""
         from ..context import is_initialized, set_component_health
-        self.recoveries += 1
-        log.error("serving: engine step failed (%s); aborting in-flight "
-                  "requests and degrading (recovery %d/%d)",
-                  exc, self.recoveries, self._max_recoveries)
+        recoverable = isinstance(exc, RECOVERABLE_ERRORS)
+        if recoverable:
+            self.recoveries += 1
+        log.error("serving: engine step failed (%s: %s); aborting "
+                  "in-flight requests (%s)", type(exc).__name__, exc,
+                  f"recovery {self.recoveries}/{self._max_recoveries}"
+                  if recoverable else "not a recoverable kind")
         set_component_health("serving", False,
                              reason=f"engine step failed: {exc}")
         _frec.RECORDER.record("serving_abort", error=repr(exc),
@@ -307,13 +319,18 @@ class ServingSession:
         for req, fut in futs:
             self._t_last_emit.pop(req.req_id, None)
             _m_requests.labels(outcome="aborted").inc()
-            if fut is not None and not fut.done():
-                m = req.metrics()
-                m["error"] = str(exc)
-                fut.set_result(RequestResult(
-                    req_id=req.req_id, prompt=req.prompt,
-                    tokens=list(req.generated), metrics=m))
-        if self.recoveries > self._max_recoveries or not self._recover:
+            if fut is None or fut.done():
+                continue
+            if not recoverable:
+                fut.set_exception(exc)
+                continue
+            m = req.metrics()
+            m["error"] = str(exc)
+            fut.set_result(RequestResult(
+                req_id=req.req_id, prompt=req.prompt,
+                tokens=list(req.generated), metrics=m))
+        if (not recoverable or not self._recover
+                or self.recoveries > self._max_recoveries):
             _frec.RECORDER.maybe_dump("serving_abort",
                                       extra={"error": repr(exc)})
             raise exc
@@ -357,11 +374,13 @@ def serve(params: Any, cfg, *, mesh=None,
         print(fut.result().tokens)
 
     ``recover``/``max_recoveries``/``recovery_pause_s`` configure the
-    graceful-degradation loop: on an engine-step failure the session
-    aborts in-flight requests with an ``error`` finish_reason, answers
-    503 on ``/healthz`` through the drain window (``recovery_pause_s``),
-    re-rendezvouses when the failure was a collective abort, and
-    resumes — see :meth:`ServingSession._handle_engine_failure`.
+    graceful-degradation loop: on a collective or transport failure of an
+    engine step (:data:`RECOVERABLE_ERRORS`) the session aborts in-flight
+    requests with an ``error`` finish_reason, answers 503 on ``/healthz``
+    through the drain window (``recovery_pause_s``), re-rendezvouses when
+    the failure was a collective abort, and resumes; any other step
+    failure fails the futures and propagates — see
+    :meth:`ServingSession._handle_engine_failure`.
 
     ``prefix_cache=True`` turns on the radix prefix cache (shared prompt
     prefixes skip prefill); ``spec_k=k`` with ``draft_params`` /
@@ -369,6 +388,8 @@ def serve(params: Any, cfg, *, mesh=None,
     :mod:`horovod_tpu.serving.frontdoor`, both token-identical to plain
     greedy decoding.
     """
+    from ..utils.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     base = engine_cfg or EngineConfig()
     if engine_kw:
         base = dataclasses.replace(base, **engine_kw)

@@ -170,12 +170,16 @@ class ServingEngine:
         self._steps = 0
 
         flash = engine_cfg.use_flash
-        from ..ops import flash_attention as FA
-        kernel_ok = FA.paged_supported(engine_cfg.block_size, cfg.head_dim)
         self._interpret = flash == "interpret"
-        self._use_flash = kernel_ok and (
-            flash == "interpret"
-            or (flash == "auto" and jax.default_backend() == "tpu"))
+        wanted = flash == "interpret" or (
+            flash == "auto" and jax.default_backend() == "tpu")
+        self._use_flash = wanted and llama.paged_kernel_ok(
+            cfg, mesh, engine_cfg.block_size)
+        log.info("serving decode attention path: %s (use_flash=%s, "
+                 "backend %s, block_size %d, head_dim %d, mesh %s)",
+                 self.attention_path, flash, jax.default_backend(),
+                 engine_cfg.block_size, cfg.head_dim,
+                 dict(mesh.shape) if mesh is not None else None)
 
         # One jit per step kind; bucketing keeps the traced shape set
         # small and jax's cache does the rest.
@@ -237,6 +241,26 @@ class ServingEngine:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), kp, vp
 
     # -- public surface --------------------------------------------------
+    @property
+    def attention_path(self) -> str:
+        """What the decode tick reads the pool through: ``"pallas"`` (the
+        paged kernel, compiled), ``"pallas-interpret"`` or ``"gather"``
+        (XLA)."""
+        if not self._use_flash:
+            return "gather"
+        return "pallas-interpret" if self._interpret else "pallas"
+
+    def lower_decode(self, n_cols: int):
+        """The decode tick, lowered (``jax.stages.Lowered``) at a block
+        table ``n_cols`` wide — what :meth:`step` runs at that width,
+        for inspection of the compiled module (``chip_smoke.py`` looks
+        for the paged kernel's custom call in it)."""
+        jax, jnp = self._jax, self._jnp
+        R = self.ecfg.max_active
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        return self._decode.lower(self.params, self.k_pool, self.v_pool,
+                                  i32(R), i32(R), i32(R, n_cols))
+
     def submit(self, prompt, max_new_tokens: int, *, eos_token=None,
                stream_cb=None, migrate_cb=None, trace_ctx=None) -> Request:
         # Chaos site: admission.  err rejects the request before it
@@ -291,8 +315,9 @@ class ServingEngine:
         A raise out of here (device failure, collective abort, injected
         fault) leaves the scheduler/pager bookkeeping consistent enough
         for :meth:`abort_inflight` — the session layer catches, aborts
-        the in-flight set with an ``error`` finish_reason, flips
-        ``/healthz``, and drains-and-rejoins instead of dying."""
+        the in-flight set, flips ``/healthz``, and either
+        drains-and-rejoins (collective/transport failures) or re-raises
+        (everything else)."""
         # Chaos site: one traversal per serving round (decode step).
         chaos.fire("serving_step")
         emitted: list[tuple[Request, int]] = []

@@ -10,11 +10,11 @@ process → synchronize.
 import os
 import sys
 
-# One CPU device per process = one rank per process (the reference's model).
+# One device per process = one rank per process (the reference's model).
+# The platform is the launcher's (`hvdrun --platform`, read by hvd.init);
+# on the CPU rig each process gets exactly one host device.
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
     " --xla_force_host_platform_device_count=1"
-import jax  # noqa: E402
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 import horovod_tpu as hvd  # noqa: E402

@@ -115,4 +115,4 @@ def test_default_blocks_divisibility():
 def test_supported_gating():
     assert supported((1, 1024, 8, 64))
     assert not supported((1, 100, 8, 64))     # not block-divisible
-    assert not supported((1, 1024, 8, 512))   # head dim too large
+    assert not supported((1, 2048, 8, 512))   # resident set over budget

@@ -6,7 +6,7 @@ padding for non-divisible payloads.
 import jax
 import numpy as np
 import pytest
-from horovod_tpu.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.ops.hierarchical import (

@@ -13,7 +13,7 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
-from horovod_tpu.jaxcompat import leaves_with_path
+from jax.tree import leaves_with_path
 
 from horovod_tpu.models import llama
 from horovod_tpu.parallel import MeshConfig, build_mesh
@@ -162,6 +162,7 @@ def test_pp_pipeline_no_per_layer_param_gather():
 
 
 @pytest.mark.integration
+@pytest.mark.slow  # tier-1 budget (~22s); `make ci` runs the same dry run
 def test_multichip_dryrun_no_involuntary_remat():
     """The full dp/tp/pp, sp/tp/dp and ep/fsdp/dp dryrun compiles must
     emit zero SPMD 'Involuntary full rematerialization' warnings — each

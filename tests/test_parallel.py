@@ -115,7 +115,7 @@ def test_ulysses_matches_dense(causal):
     mesh = Mesh(np.array(jax.devices()), ("sp",))
     sh = NamedSharding(mesh, P(None, "sp"))
     from functools import partial
-    from horovod_tpu.jaxcompat import shard_map
+    from jax import shard_map
     fn = jax.jit(shard_map(
         partial(ulysses_attention_local, causal=causal),
         mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp"),
@@ -348,7 +348,7 @@ def test_moe_layer_hvd_parity_with_drops():
 def test_pipeline_1f1b_matches_autodiff_oracle():
     """1F1B schedule (pipeline_train_local): loss and every gradient must
     equal plain autodiff through the sequential stage composition."""
-    from horovod_tpu.jaxcompat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel.pipeline import pipeline_train_local
 
     n_stage, M, mb, d = 8, 8, 2, 4

@@ -164,7 +164,7 @@ def test_zero_block_rank_does_not_poison_shared_scale():
 def test_in_context_zero_block_rank():
     import jax
     from jax.sharding import PartitionSpec as P
-    from horovod_tpu.jaxcompat import shard_map
+    from jax import shard_map
     state = hvd.global_state()
     mesh, axis = state.mesh, state.config.dp_axis_name
 
@@ -250,7 +250,7 @@ def test_adasum_never_quantizes():
 def test_wire_cost_model_meets_bandwidth_target():
     """int8 wire must save >= 1.5x interconnect bytes vs the fp32 ring at
     >= 4 MB payloads (the EQuARX-style effective-bandwidth claim; the
-    measured-wall-clock companion lives in collective_bench/BENCH_r06 —
+    measured-wall-clock companion lives in collective_bench —
     byte-width-insensitive CPU collectives cannot show it, a real
     interconnect does)."""
     for nbytes in (1 << 22, 1 << 24, 1 << 26):
@@ -315,7 +315,7 @@ def test_in_context_quantized_allreduce():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from horovod_tpu.jaxcompat import shard_map
+    from jax import shard_map
     state = hvd.global_state()
     mesh, axis = state.mesh, state.config.dp_axis_name
 

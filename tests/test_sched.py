@@ -492,7 +492,7 @@ def test_in_context_overlap_allreduce_parity():
     import jax
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
-    from horovod_tpu.jaxcompat import shard_map
+    from jax import shard_map
     mesh = hvd.mesh()
     axis = hvd.global_state().config.dp_axis_name
     x = np.random.RandomState(11).randn(N, 3000).astype(np.float32)
@@ -525,7 +525,7 @@ def test_matmul_reducescatter_parity():
     import jax
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
-    from horovod_tpu.jaxcompat import shard_map
+    from jax import shard_map
     mesh = hvd.mesh()
     axis = hvd.global_state().config.dp_axis_name
     rng = np.random.RandomState(13)

@@ -257,14 +257,15 @@ def test_paged_attention_kernel_vs_gather_oracle():
     rng = np.random.RandomState(0)
     B, H, KV, Dh, NB, BS, C = 3, 8, 2, 64, 16, 8, 4
     q = jnp.asarray(rng.randn(B, H, Dh), jnp.float32)
-    kp = jnp.asarray(rng.randn(NB, BS, KV, Dh), jnp.float32)
-    vp = jnp.asarray(rng.randn(NB, BS, KV, Dh), jnp.float32)
+    L, li = 3, 1
+    kp = jnp.asarray(rng.randn(L, NB, BS, KV, Dh), jnp.float32)
+    vp = jnp.asarray(rng.randn(L, NB, BS, KV, Dh), jnp.float32)
     tables = jnp.asarray(
         rng.choice(np.arange(1, NB), size=(B * C,),
                    replace=False).reshape(B, C), jnp.int32)
     lengths = jnp.asarray([5, 17, 32], jnp.int32)
-    out = FA.paged_attention(q, kp, vp, tables, lengths, interpret=True)
-    keys, vals = gather_blocks(kp, tables), gather_blocks(vp, tables)
+    out = FA.paged_attention(q, kp, vp, li, tables, lengths, interpret=True)
+    keys, vals = gather_blocks(kp[li], tables), gather_blocks(vp[li], tables)
     mask = (jnp.arange(C * BS)[None, :] < lengths[:, None])[:, None, :]
     ref = _cached_attend(q[:, None], keys, vals, mask,
                          1.0 / np.sqrt(Dh))[:, 0]

@@ -21,7 +21,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.jaxcompat import shard_map
+from jax import shard_map
 from horovod_tpu.ops.compression import Compression
 from horovod_tpu.optim import partition as PP
 from horovod_tpu.optim import zero as zero_mod
