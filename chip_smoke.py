@@ -18,10 +18,15 @@ entry points a user calls, on every device JAX finds, at the widths of
   its logits against the XLA gather path.
 
 It fails (non-zero exit, no result line) on the first phase that fails
-and at once when JAX's first device is not a TPU.  The last line of
-standard output is one JSON object: device, versions, compile cache, and
+and at once when JAX's first device is not a TPU.  The last two lines of
+standard output are one JSON object each.  The last is the verdict the
+driver reads, with exactly these keys:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The line before it is the report: device, versions, compile cache, and
 per phase wall seconds, compile seconds and the attention path that ran.
-It reports no throughput, utilization or latency: those are the
+Neither reports a throughput, a utilization or a latency: those are the
 benchmark's.
 
 ``--cpu-tiny N`` (with ``JAX_PLATFORMS=cpu``) runs the same phases and
@@ -388,11 +393,10 @@ def main(argv=None) -> int:
         except importlib.metadata.PackageNotFoundError:
             return None
 
+    device = {"platform": platform, "kind": devices[0].device_kind,
+              "count": len(devices)}
     print(json.dumps({
-        "ok": True,
-        "device": {"platform": platform,
-                   "kind": devices[0].device_kind,
-                   "count": len(devices)},
+        "device": device,
         "mode": "cpu-tiny" if tiny else "full",
         "versions": {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
                      "libtpu": version("libtpu")},
@@ -405,6 +409,8 @@ def main(argv=None) -> int:
                           "entries_after": _cache_entries(cache_dir)},
         "phases": phases,
     }))
+    # The verdict: these keys and no others, last on standard output.
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 
