@@ -285,10 +285,15 @@ def phase_serve(out: dict, devices, cfg, serve_kw: dict, lens, budgets,
 
         n_cols = 64
         if not interpret:
-            calls = _pallas_custom_calls(
-                engine.lower_decode(n_cols).compile())
+            tick = engine.lower_decode(n_cols).compile()
+            calls = _pallas_custom_calls(tick)
             assert calls["forward"] >= 1, \
                 "paged kernel missing from the compiled decode tick"
+            assert any("hvd_paged_decode" in ln
+                       for ln in tick.as_text().splitlines()
+                       if 'custom_call_target="tpu_custom_call"' in ln), \
+                "the decode tick's Mosaic call does not carry the name " \
+                "hvd_paged_decode that the benchmark finds it by"
             out["pallas_custom_calls"] = calls
 
         # Kernel check outside the engine: one decode tick on the pools

@@ -840,7 +840,6 @@ class CollectiveEngine:
             # captures show which collective a compiled program belongs
             # to, complementing the host-side Chrome timeline
             # († SURVEY aux: timeline + per-collective profiler spans).
-            from jax.profiler import TraceAnnotation
             label = (group[0].name if len(group) == 1
                      else f"hvd.fused[{len(group)}].{group[0].name}")
             # Chaos site: one traversal per fused dispatch.  err lands
@@ -849,7 +848,7 @@ class CollectiveEngine:
             # injected rank death the chaos CI scenario rides.
             chaos.fire("dispatch")
             t_disp = time.monotonic()
-            with TraceAnnotation(f"hvd.{group[0].verb}:{label}"):
+            with _trace.profiler_span(f"hvd.{group[0].verb}:{label}"):
                 results = self._dispatch(group)
             t_disp = time.monotonic() - t_disp
             if tl is not None and tl.enabled:

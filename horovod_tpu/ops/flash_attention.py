@@ -174,6 +174,7 @@ def _flash_forward(q, k, v, *, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((B * H, 8, S), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_flash_fwd",
     )(qt, kt, vt)
     return _from_bhsd(out, B, H), lse[:, 0, :]
 
@@ -317,6 +318,7 @@ def _flash_backward(q, k, v, out, lse, g, *, scale, causal, block_q,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         interpret=interpret,
+        name="hvd_flash_bwd_dq",
     )(*common_in)
 
     # dk/dv: one program per (kv row, k block, q-head-in-group), r
@@ -354,6 +356,7 @@ def _flash_backward(q, k, v, out, lse, g, *, scale, causal, block_q,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_flash_bwd_dkv",
     )(*common_in)
 
     return (_from_bhsd(dq, B, H), _from_bhsd(dk, B, KV),
@@ -500,6 +503,7 @@ def paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="hvd_paged_decode",
     )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
       lengths.astype(jnp.int32), qg, k_pool, v_pool)
     return out.swapaxes(1, 2).reshape(B, H, Dh)

@@ -31,6 +31,7 @@ import numpy as np
 
 from ..obs import REGISTRY as _obs
 from ..obs import flightrec as _frec
+from ..obs import trace as _trace
 from ..ops.engine import HorovodInternalError
 from ..utils import logging as hvd_logging
 from ..utils.timeline import Timeline
@@ -58,9 +59,6 @@ _m_itl = _obs.histogram(
     "inter-token latency between consecutive emissions of one request")
 _m_queue_wait = _obs.histogram(
     "hvd_serving_queue_wait_seconds", "submit -> admission")
-_m_decode_rate = _obs.gauge(
-    "hvd_serving_decode_tokens_per_s",
-    "steady-state decode rate of the most recently finished request")
 _m_requests = _obs.counter(
     "hvd_serving_requests_total", "requests by terminal outcome",
     ("outcome",))
@@ -144,7 +142,6 @@ class ServingSession:
                 # Bounded like the tracer's finished-trace table: once a
                 # trace would be evicted there, its id here is dead
                 # weight — don't leak one entry per request forever.
-                from ..obs import trace as _trace
                 while len(self._trace_ids) > _trace.TRACER.keep:
                     self._trace_ids.pop(next(iter(self._trace_ids)))
         return fut
@@ -173,7 +170,6 @@ class ServingSession:
         """The finished request's trace as a JSON-ready dict (span chain
         with shared trace id), or None when the request was unsampled or
         its trace already evicted from the tracer's bounded table."""
-        from ..obs import trace as _trace
         with self._lock:
             tid = self._trace_ids.get(req_id)
         return _trace.TRACER.export(tid) if tid else None
@@ -248,20 +244,21 @@ class ServingSession:
             fut = self._futures.pop(req.req_id, None)
             if fut is not None and not fut.done():
                 fut.set_exception(exc)
-        now = time.monotonic()
-        for req, token in emissions:
-            if req.t_first_token is None:
-                req.t_first_token = now
-                _m_ttft.observe(now - req.t_submit)
-            else:
-                last = self._t_last_emit.get(req.req_id)
-                if last is not None:
-                    _m_itl.observe(now - last)
-            self._t_last_emit[req.req_id] = now
-            if req.stream_cb is not None:
-                req.stream_cb(req.req_id, token)
-            if req.state.value == "finished":
-                self._resolve(req)
+        with _trace.profiler_span("hvd.serve.deliver"):
+            now = time.monotonic()
+            for req, token in emissions:
+                if req.t_first_token is None:
+                    req.t_first_token = now
+                    _m_ttft.observe(now - req.t_submit)
+                else:
+                    last = self._t_last_emit.get(req.req_id)
+                    if last is not None:
+                        _m_itl.observe(now - last)
+                self._t_last_emit[req.req_id] = now
+                if req.stream_cb is not None:
+                    req.stream_cb(req.req_id, token)
+                if req.state.value == "finished":
+                    self._resolve(req)
 
     def _resolve(self, req: Request) -> None:
         self._t_last_emit.pop(req.req_id, None)
@@ -269,12 +266,10 @@ class ServingSession:
         # Registry routing of the per-request metrics dict (the log line
         # below stays — grep-ability is a feature, it is just no longer
         # the only consumer).  TTFT/ITL were observed at emission time;
-        # the submit->admission share and the decode rate land here.
+        # the submit->admission share lands here.
         _m_requests.labels(outcome="finished").inc()
         _m_tokens.inc(m["new_tokens"])
         _m_queue_wait.observe(m["queue_wait_s"])
-        if m["decode_tokens_per_s"]:
-            _m_decode_rate.set(m["decode_tokens_per_s"])
         log.info(
             "serving req=%d prompt=%d new=%d queue_wait=%.4fs ttft=%.4fs "
             "decode_tok_s=%s preemptions=%d trace=%s",

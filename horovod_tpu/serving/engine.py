@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
 from typing import Any, Optional
 
 import numpy as np
@@ -59,6 +58,27 @@ _m_decode_tokens = _obs.counter(
 _m_prefill_skipped = _obs.counter(
     "hvd_serving_prefill_skipped_tokens_total",
     "prompt tokens NOT prefilled because a cached prefix covered them")
+# What the decode step's block table holds, summed over ticks: the step
+# walks max_active x n_cols table slots whatever they hold, so
+# blocks_total / slots_total is the share of that walk that reads a page.
+_m_table_slots = _obs.counter(
+    "hvd_serving_decode_table_slots_total",
+    "block-table entries handed to decode steps (max_active x n_cols)")
+_m_table_blocks = _obs.counter(
+    "hvd_serving_decode_table_blocks_total",
+    "of those, entries that name a real (non-scratch) block")
+
+_span = _trace.profiler_span
+
+
+def _named(name: str, fn):
+    """``fn`` under the name its jitted program carries in a profile
+    (``jit_<name>`` on the trace's XLA Modules line); a bound method or a
+    partial would trace as ``jit__unknown`` or under a private name."""
+    def call(*args):
+        return fn(*args)
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 def _bucket_pow2(n: int, floor: int = 1) -> int:
@@ -182,14 +202,16 @@ class ServingEngine:
                  dict(mesh.shape) if mesh is not None else None)
 
         # One jit per step kind; bucketing keeps the traced shape set
-        # small and jax's cache does the rest.
-        self._prefill = jax.jit(partial(self._prefill_impl))
-        self._scatter = jax.jit(partial(self._scatter_impl),
-                                donate_argnums=(0, 1))
-        self._decode = jax.jit(partial(self._decode_impl),
-                               donate_argnums=(1, 2))
-        self._extend = jax.jit(partial(self._extend_impl),
-                               donate_argnums=(1, 2))
+        # small and jax's cache does the rest.  A profile shows each as
+        # the program jit_<name>.
+        self._prefill = jax.jit(_named(
+            "hvd_serve_prefill", self._prefill_impl))
+        self._scatter = jax.jit(_named(
+            "hvd_serve_scatter", self._scatter_impl), donate_argnums=(0, 1))
+        self._decode = jax.jit(_named(
+            "hvd_serve_decode", self._decode_impl), donate_argnums=(1, 2))
+        self._extend = jax.jit(_named(
+            "hvd_serve_extend", self._extend_impl), donate_argnums=(1, 2))
 
         self.spec = None
         if engine_cfg.spec_k:
@@ -209,17 +231,20 @@ class ServingEngine:
 
     def _scatter_impl(self, kp, vp, ks, vs, blocks):
         """Write one request's prefill K/V ([L, 1, P, KV, Dh]) into its
-        pool blocks.  P is padded up to a whole number of blocks; the
-        tail slots hold pad-token K/V, masked by position until decode
-        overwrites them one at a time."""
+        ``nb`` pool blocks.  P is the prefill bucket: it is cut to the
+        blocks' positions where it is longer (here and not by the caller,
+        whose eager slices would be two more programs, each a copy of K
+        or V) and padded up to them where it is shorter; the tail slots
+        hold pad-token K/V, masked by position until decode overwrites
+        them one at a time."""
         jnp = self._jnp
         L = ks.shape[0]
-        P = ks.shape[2]
         BS = self.cache.block_size
         nb = blocks.shape[0]
+        P = min(ks.shape[2], nb * BS)
         pad = nb * BS - P
-        ks = jnp.pad(ks[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vs = jnp.pad(vs[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
+        ks = jnp.pad(ks[:, 0, :P], ((0, 0), (0, pad), (0, 0), (0, 0)))
+        vs = jnp.pad(vs[:, 0, :P], ((0, 0), (0, pad), (0, 0), (0, 0)))
         ks = ks.reshape(L, nb, BS, *ks.shape[2:])
         vs = vs.reshape(L, nb, BS, *vs.shape[2:])
         return kp.at[:, blocks].set(ks), vp.at[:, blocks].set(vs)
@@ -323,24 +348,28 @@ class ServingEngine:
         emitted: list[tuple[Request, int]] = []
         self._steps += 1
         _m_steps.inc()
-        for req in self.scheduler.admit():
-            self._assign_slot(req)
-            _m_prefill_tokens.inc(
-                int(req.prefill_tokens.shape[0]) - req.cached_tokens)
-            emitted.append((req, self._prefill_one(req)))
-            if req.migrate_cb is not None \
-                    and req.state == RequestState.RUNNING:
-                # Disaggregated handoff: this replica's job ends at the
-                # prefill emission — export the KV blocks while the
-                # pager table is still held and let a decode replica
-                # continue the request (serving/disagg).
-                self._migrate_out(req)
-        if self.scheduler.running:
-            ticked = (self.spec.tick() if self.spec is not None
-                      else self._decode_tick())
-            _m_decode_tokens.inc(len(ticked))
-            emitted.extend(ticked)
-        self._sample_gauges()
+        with _span("hvd.serve.step", step=self._steps):
+            with _span("hvd.serve.admit"):
+                admitted = self.scheduler.admit()
+            for req in admitted:
+                self._assign_slot(req)
+                _m_prefill_tokens.inc(
+                    int(req.prefill_tokens.shape[0]) - req.cached_tokens)
+                emitted.append((req, self._prefill_one(req)))
+                if req.migrate_cb is not None \
+                        and req.state == RequestState.RUNNING:
+                    # Disaggregated handoff: this replica's job ends at
+                    # the prefill emission — export the KV blocks while
+                    # the pager table is still held and let a decode
+                    # replica continue the request (serving/disagg).
+                    self._migrate_out(req)
+            if self.scheduler.running:
+                with _span("hvd.serve.decode") as tick:
+                    ticked = (self.spec.tick(tick) if self.spec is not None
+                              else self._decode_tick(tick))
+                _m_decode_tokens.inc(len(ticked))
+                emitted.extend(ticked)
+            self._sample_gauges()
         return emitted
 
     def _sample_gauges(self) -> None:
@@ -394,34 +423,39 @@ class ServingEngine:
         P = int(toks.shape[0])
         Pb = self._bucket_len(P)
         sp = req.open_phase("prefill", tokens=P, bucket=Pb)
-        # The span is the context's current span while the prefill
-        # dispatches, so nested layers (collectives the model enqueues)
-        # attach their events to this request's chain.
-        with sp.use():
-            padded = np.zeros((1, Pb), np.int32)
-            padded[0, :P] = toks
-            tok, ks, vs = self._prefill(
-                self.params, jnp.asarray(padded),
-                jnp.asarray([P - 1], jnp.int32))
-            blocks = self.pager.table(req.req_id)
-            nb = self.cache.blocks_for(P)
-            # Only the blocks the P real positions span are written; the
-            # +1 slot block (for the emitted token) is untouched here.
-            lim = min(Pb, nb * self.cache.block_size)
-            ks, vs = ks[:, :, :lim], vs[:, :, :lim]
-            self.k_pool, self.v_pool = self._scatter(
-                self.k_pool, self.v_pool, ks, vs,
-                jnp.asarray(blocks[:nb], jnp.int32))
-            if self.spec is not None:
-                self.spec.mirror_prefill(req, padded, P)
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(toks, self.pager.table(req.req_id))
-        req.close_phase("prefill")
-        token = self._emit(req, int(tok[0]))
-        if req.state == RequestState.RUNNING:
-            # The decode phase opens once and spans every tick until the
-            # terminal state (scheduler.finish/preempt closes it).
-            req.open_phase("decode")
+        with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=0):
+            # The span is the context's current span while the prefill
+            # dispatches, so nested layers (collectives the model
+            # enqueues) attach their events to this request's chain.
+            with sp.use(), _span("hvd.serve.prefill.dispatch"):
+                padded = np.zeros((1, Pb), np.int32)
+                padded[0, :P] = toks
+                # Small integer inputs go in as numpy arrays: jnp.asarray
+                # of a list, like an index into a device array, is an
+                # eager program of its own (jit_convert_element_type).
+                tok, ks, vs = self._prefill(
+                    self.params, jnp.asarray(padded),
+                    np.asarray([P - 1], np.int32))
+                blocks = self.pager.table(req.req_id)
+                # Only the blocks the P real positions span are written;
+                # the +1 slot block (for the emitted token) is untouched.
+                nb = self.cache.blocks_for(P)
+                self.k_pool, self.v_pool = self._scatter(
+                    self.k_pool, self.v_pool, ks, vs,
+                    np.asarray(blocks[:nb], np.int32))
+                if self.spec is not None:
+                    self.spec.mirror_prefill(req, padded, P)
+            if self.prefix_cache is not None:
+                self.prefix_cache.insert(toks,
+                                         self.pager.table(req.req_id))
+            req.close_phase("prefill")
+            with _span("hvd.serve.prefill.fetch"):
+                first = int(np.asarray(tok)[0])
+            token = self._emit(req, first)
+            if req.state == RequestState.RUNNING:
+                # The decode phase opens once and spans every tick until
+                # the terminal state (scheduler.finish/preempt closes it).
+                req.open_phase("decode")
         return token
 
     def _prefill_cached(self, req: Request) -> int:
@@ -436,79 +470,102 @@ class ServingEngine:
         S = P - C
         Sb = _bucket_pow2(S)
         sp = req.open_phase("prefill", tokens=P, cached=C, bucket=Sb)
-        with sp.use():
-            req.trace.event("prefill_skip", cached_tokens=C)
-            tok2 = np.zeros((1, Sb), np.int32)
-            tok2[0, :S] = toks[C:]
-            # Padded slots repeat a valid position but carry valid=False,
-            # so their writes land in scratch block 0 and their logits
-            # are never read.
-            pos2 = np.full((1, Sb), P - 1, np.int32)
-            pos2[0, :S] = np.arange(C, P, dtype=np.int32)
-            val2 = np.zeros((1, Sb), bool)
-            val2[0, :S] = True
-            n_cols = min(_bucket_pow2(self.cache.blocks_for(P)),
-                         self.cache.num_blocks)
-            tables = self.pager.table_matrix([req.req_id], n_cols)
-            nxt, self.k_pool, self.v_pool = self._extend(
-                self.params, self.k_pool, self.v_pool,
-                jnp.asarray(tok2), jnp.asarray(pos2),
-                jnp.asarray(val2), jnp.asarray(tables))
-            if self.spec is not None:
-                self.spec.mirror_extend(tok2, pos2, val2, tables)
-        if self.prefix_cache is not None:
-            # The tail may complete further full blocks; share them too.
-            self.prefix_cache.insert(toks, self.pager.table(req.req_id))
-        _m_prefill_skipped.inc(C)
-        req.close_phase("prefill")
-        token = self._emit(req, int(nxt[0, S - 1]))
-        if req.state == RequestState.RUNNING:
-            req.open_phase("decode")
+        with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=C):
+            with sp.use(), _span("hvd.serve.prefill.dispatch"):
+                req.trace.event("prefill_skip", cached_tokens=C)
+                tok2 = np.zeros((1, Sb), np.int32)
+                tok2[0, :S] = toks[C:]
+                # Padded slots repeat a valid position but carry
+                # valid=False, so their writes land in scratch block 0
+                # and their logits are never read.
+                pos2 = np.full((1, Sb), P - 1, np.int32)
+                pos2[0, :S] = np.arange(C, P, dtype=np.int32)
+                val2 = np.zeros((1, Sb), bool)
+                val2[0, :S] = True
+                n_cols = min(_bucket_pow2(self.cache.blocks_for(P)),
+                             self.cache.num_blocks)
+                tables = self.pager.table_matrix([req.req_id], n_cols)
+                nxt, self.k_pool, self.v_pool = self._extend(
+                    self.params, self.k_pool, self.v_pool,
+                    jnp.asarray(tok2), jnp.asarray(pos2),
+                    jnp.asarray(val2), jnp.asarray(tables))
+                if self.spec is not None:
+                    self.spec.mirror_extend(tok2, pos2, val2, tables)
+            if self.prefix_cache is not None:
+                # The tail may complete further full blocks; share them
+                # too.
+                self.prefix_cache.insert(toks,
+                                         self.pager.table(req.req_id))
+            _m_prefill_skipped.inc(C)
+            req.close_phase("prefill")
+            with _span("hvd.serve.prefill.fetch"):
+                first = int(np.asarray(nxt)[0, S - 1])
+            token = self._emit(req, first)
+            if req.state == RequestState.RUNNING:
+                req.open_phase("decode")
         return token
 
-    def _decode_tick(self) -> list[tuple[Request, int]]:
+    def _count_table(self, tick, tables: np.ndarray) -> None:
+        """What one decode step's block table holds, onto the step's
+        profiler span and the cumulative counters.  Block 0 is scratch
+        and never in a request's table, so the non-zero entries are the
+        real pages."""
+        blocks = int(np.count_nonzero(tables))
+        _m_table_slots.inc(tables.size)
+        _m_table_blocks.inc(blocks)
+        tick.set_metadata(n_cols=tables.shape[1], blocks=blocks)
+
+    def _decode_tick(self, tick) -> list[tuple[Request, int]]:
+        """One decode step for the running set, under ``tick``, the
+        round's ``hvd.serve.decode`` profiler span."""
         jnp = self._jnp
-        # Reserve the write position for every running request first —
-        # growth can preempt, shrinking the running set.
-        for req in list(self.scheduler.running):
-            if req in self.scheduler.running:
-                try:
-                    self.scheduler.grow(req)
-                except OutOfBlocks as e:
-                    # Only reachable when req cannot fit even alone
-                    # (grow preempts every other victim first): fail
-                    # THIS request and keep the batch serving — a
-                    # per-request capacity problem must not abort the
-                    # engine.
-                    self.scheduler.fail_running(req, e)
-        self._sync_slots()
+        with _span("hvd.serve.decode.grow"):
+            # Reserve the write position for every running request
+            # first — growth can preempt, shrinking the running set.
+            for req in list(self.scheduler.running):
+                if req in self.scheduler.running:
+                    try:
+                        self.scheduler.grow(req)
+                    except OutOfBlocks as e:
+                        # Only reachable when req cannot fit even alone
+                        # (grow preempts every other victim first): fail
+                        # THIS request and keep the batch serving — a
+                        # per-request capacity problem must not abort
+                        # the engine.
+                        self.scheduler.fail_running(req, e)
+            self._sync_slots()
         active = [r for r in self._slots if r is not None]
         if not active:
             return []
-        R = self.ecfg.max_active
-        need_cols = max(
-            self.cache.blocks_for(r.context_len + 1) for r in active)
-        n_cols = min(_bucket_pow2(need_cols), self.cache.num_blocks)
-        tok = np.zeros((R,), np.int32)
-        pos = np.zeros((R,), np.int32)
-        ids = [-1] * R
-        for i, r in enumerate(self._slots):
-            if r is None:
-                continue
-            tok[i] = r.generated[-1]
-            pos[i] = r.context_len
-            ids[i] = r.req_id
-        tables = self.pager.table_matrix(ids, n_cols)
-        nxt, self.k_pool, self.v_pool = self._decode(
-            self.params, self.k_pool, self.v_pool,
-            jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables))
-        nxt = np.asarray(nxt)
+        with _span("hvd.serve.decode.tables"):
+            R = self.ecfg.max_active
+            need_cols = max(
+                self.cache.blocks_for(r.context_len + 1) for r in active)
+            n_cols = min(_bucket_pow2(need_cols), self.cache.num_blocks)
+            tok = np.zeros((R,), np.int32)
+            pos = np.zeros((R,), np.int32)
+            ids = [-1] * R
+            for i, r in enumerate(self._slots):
+                if r is None:
+                    continue
+                tok[i] = r.generated[-1]
+                pos[i] = r.context_len
+                ids[i] = r.req_id
+            tables = self.pager.table_matrix(ids, n_cols)
+            self._count_table(tick, tables)
+            args = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables))
+        with _span("hvd.serve.decode.dispatch"):
+            nxt, self.k_pool, self.v_pool = self._decode(
+                self.params, self.k_pool, self.v_pool, *args)
+        with _span("hvd.serve.decode.fetch"):
+            nxt = np.asarray(nxt)
         emitted = []
-        for i, r in enumerate(list(self._slots)):
-            if r is None:
-                continue
-            r.context_len += 1          # this tick wrote pos[i]
-            emitted.append((r, self._emit(r, int(nxt[i]))))
+        with _span("hvd.serve.decode.emit"):
+            for i, r in enumerate(list(self._slots)):
+                if r is None:
+                    continue
+                r.context_len += 1          # this tick wrote pos[i]
+                emitted.append((r, self._emit(r, int(nxt[i]))))
         return emitted
 
     def _emit(self, req: Request, token: int) -> int:

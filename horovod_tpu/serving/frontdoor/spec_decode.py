@@ -31,8 +31,6 @@ stay valid for every request that matches the prefix.
 
 from __future__ import annotations
 
-from functools import partial
-
 import numpy as np
 
 from ...models import llama
@@ -87,11 +85,15 @@ class SpecDecoder:
         self._drafted_total = 0
         self._accepted_total = 0
 
-        self._prefill = jax.jit(partial(self._prefill_impl))
-        self._decode = jax.jit(partial(self._decode_impl),
-                               donate_argnums=(1, 2))
-        self._extend = jax.jit(partial(self._extend_impl),
-                               donate_argnums=(1, 2))
+        from ..engine import _named
+        self._prefill = jax.jit(_named(
+            "hvd_serve_draft_prefill", self._prefill_impl))
+        self._decode = jax.jit(_named(
+            "hvd_serve_draft_decode", self._decode_impl),
+            donate_argnums=(1, 2))
+        self._extend = jax.jit(_named(
+            "hvd_serve_draft_extend", self._extend_impl),
+            donate_argnums=(1, 2))
 
     # -- draft-model jitted bodies (target mesh rules do not apply) ------
     def _prefill_impl(self, params, tokens, last_pos):
@@ -122,13 +124,12 @@ class SpecDecoder:
         eng = self.eng
         ks, vs = self._prefill(
             self.draft_params, jnp.asarray(padded),
-            jnp.asarray([n_tokens - 1], jnp.int32))
+            np.asarray([n_tokens - 1], np.int32))
         blocks = eng.pager.table(req.req_id)
         nb = self.cache.blocks_for(n_tokens)
-        lim = min(padded.shape[1], nb * self.cache.block_size)
         self.dk_pool, self.dv_pool = eng._scatter(
-            self.dk_pool, self.dv_pool, ks[:, :, :lim], vs[:, :, :lim],
-            jnp.asarray(blocks[:nb], jnp.int32))
+            self.dk_pool, self.dv_pool, ks, vs,
+            np.asarray(blocks[:nb], np.int32))
 
     def mirror_extend(self, tok2, pos2, val2, tables) -> None:
         """Mirror a prefix-hit tail prefill into the draft pools (the
@@ -141,9 +142,15 @@ class SpecDecoder:
             jnp.asarray(tables))
 
     # -- the round -------------------------------------------------------
-    def tick(self) -> list:
-        """One speculative round for the whole running set; returns the
-        (request, token) emissions like ``ServingEngine._decode_tick``."""
+    def tick(self, span) -> list:
+        """One speculative round for the whole running set, under the
+        engine's ``hvd.serve.decode`` profiler span; returns the
+        (request, token) emissions like ``ServingEngine._decode_tick``.
+        The span and the table counters read once a round, not once a
+        decode call: the round's k + 1 draft steps and its verify step
+        all walk the one table counted here, which already holds the
+        blocks reserved for the round's k + 1 positions, and the round
+        has no child spans."""
         eng = self.eng
         jnp = self._jnp
         sched = eng.scheduler
@@ -177,7 +184,9 @@ class SpecDecoder:
             pos[i] = r.context_len
             act[i] = True
             ids[i] = r.req_id
-        tables = jnp.asarray(eng.pager.table_matrix(ids, n_cols))
+        tables = eng.pager.table_matrix(ids, n_cols)
+        eng._count_table(span, tables)
+        tables = jnp.asarray(tables)
 
         # 1. draft k tokens sequentially with the small model.
         drafts = np.zeros((R, k), np.int32)
