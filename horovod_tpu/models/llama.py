@@ -1193,18 +1193,19 @@ _POOL_DIMS = (None, None, None, "kv_heads", None)
 
 
 def paged_kernel_ok(cfg: LlamaConfig, mesh: Optional[Mesh],
-                    block_size: int) -> bool:
+                    block_size: int, interpret: bool = False) -> bool:
     """Whether :func:`decode_step_paged` can take ``use_flash=True`` for
     this model, mesh and page size: tp must split q and kv heads alike
     (each chip runs the kernel on its own ``kv_heads`` shard), and the
-    per-chip page must fit the kernel's VMEM budget."""
+    per-chip pool geometry must be one the kernel compiles for (any is,
+    for the interpreter)."""
     from ..ops import flash_attention as FA
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if cfg.n_heads % tp or cfg.n_kv_heads % tp:
         return False
-    return FA.paged_supported(block_size, cfg.head_dim,
-                              cfg.n_kv_heads // tp,
-                              jnp.dtype(cfg.dtype).itemsize)
+    return interpret or FA.paged_supported(
+        block_size, cfg.head_dim, cfg.n_kv_heads // tp, cfg.n_heads // tp,
+        jnp.dtype(cfg.dtype).itemsize)
 
 
 def _paged_kernel_attend(q, kp, vp, layer, tables, lengths, scale, mesh,
@@ -1245,8 +1246,9 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
     offset ``positions[b] % BS`` and attends over the table's logical
     window with a per-request ``<= position`` mask (stale slots masked).
     The attention reads the pool either through a contiguous gather (XLA
-    path, GSPMD-shardable) or the Pallas paged kernel's scalar-prefetch
-    block routing (``use_flash``; callers check :func:`paged_kernel_ok`).
+    path, GSPMD-shardable) or in the Pallas paged kernel, which copies
+    each stream's live pages in by the scalar-prefetched table
+    (``use_flash``; callers check :func:`paged_kernel_ok`).
     Returns (logits [B, V] fp32, k_pool, v_pool) — pass the pools donated
     so the writes land in place."""
     from ..serving.kv_pager import gather_blocks

@@ -367,61 +367,128 @@ def _flash_backward(q, k, v, out, lse, g, *, scale, causal, block_q,
 # paged decode kernel (serving: block-paged KV cache)
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_ref,
-                         v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                         block_size: int, scale: float):
-    """One (request, table-column) grid step of paged decode attention:
-    online-softmax accumulate this physical page's contribution for every
-    kv head at once.
+def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm,
+                         v_hbm, o_ref, kbuf, vbuf, sem, first_slot, *,
+                         scale: float):
+    """One stream of paged decode attention: walk the stream's LIVE pages
+    in groups of ``P``, online-softmax accumulating all q heads at once.
 
-    Neither the layer index nor the block table touches the kernel
-    body's data path — they ride the scalar-prefetch channel and the K/V
-    BlockSpec index maps below route each grid step straight to its
-    physical page (GQA-native: K/V pages stay at kv_heads width).  Pages
-    wholly past the request's length are fetched (the grid is
-    rectangular) but not computed.
+    The pools stay in HBM and the kernel fetches a group's ``P`` pages
+    itself (``make_async_copy``) into one of two VMEM slots, starting
+    group ``g+1`` before it computes group ``g``; a stream's last group
+    starts the next stream's first (the grid runs in order, and
+    ``first_slot`` hands the slot on), so only the call's very first
+    copies are waited for in the open.  The loop's trip count is
+    ``ceil(length / (P*BS))``: a padded table slot is never visited and
+    the table's width costs nothing; a group's page indices past the
+    stream's last live page are clamped to it and masked.
 
-    A page arrives as the pool stores it, ``[BS, KV, Dh]`` — kv heads on
-    sublanes, head_dim on lanes — and is used in that form: scores are a
-    lane reduction of ``k * q`` kept as ``[BS, KV, 1]``, which is also
-    the shape that lane-broadcasts against ``v``, and every reduction
-    over the page's tokens is over the leading (untiled) dim.  One query
-    token per request leaves the MXU nothing to batch, and this way no
-    in-kernel transpose or relayout is asked of Mosaic."""
+    A group lands as ``[P*BS*KV, Dh]``: rows are (token, kv head), the
+    pool's own order, so no transpose or relayout of a page is asked of
+    Mosaic.  Both products run on the MXU with operands in the pool's
+    dtype and float32 accumulation (the contract of :func:`_fwd_kernel`):
+    ``q [H, Dh] . K^T -> [H, P*BS*KV]``, every column whose kv head is
+    not the row's masked together with the columns past ``length``, and
+    ``p . V -> [H, Dh]`` with ``p`` cast to V's dtype.  Masked entries
+    are exact zeros, so the result is the GQA attention; the KV-fold
+    surplus of MXU work and ``exp``s is on units that otherwise idle
+    while the pages arrive (on v5e the copies alone take 1.7 to 2.6
+    times the compute alone: PERF.md, PR 28).
+
+    Pools narrower than 32 bits move as uint32 words: a word holds the
+    same column of two adjacent rows, the sublane packing of both the
+    HBM tile and a vreg, so the group is loaded at full vreg width and
+    reinterpreted, where a load in the pool's dtype would arrive in
+    half-filled vregs and be repacked (2 ops a vreg, on every byte)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    H, Dh = q_ref.shape
+    BS, KV = k_hbm.shape[2:4]
+    rep = H // KV
+    if kbuf.dtype != k_hbm.dtype:
+        k_hbm, v_hbm = k_hbm.bitcast(kbuf.dtype), v_hbm.bitcast(kbuf.dtype)
+    page_rows = k_hbm.shape[2] * k_hbm.shape[3]       # buffer rows a page
+    P = kbuf.shape[1] // page_rows
+    G = P * BS                                        # tokens a group
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    li = layer_ref[0]
     length = lengths_ref[b]
+    n_groups = jnp.maximum((length + G - 1) // G, 1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def start(stream, g, slot):
+        # A run-time loop over the pages, not 2P descriptors written out:
+        # the kernel is traced again at every table width, and written
+        # out its three start sites cost a decode compile more host time
+        # than the rest of the step (2 s of the backlog cell's set-up),
+        # for no device time.
+        last_page = jnp.maximum(
+            (lengths_ref[stream] + BS - 1) // BS - 1, 0)
 
-    @pl.when(j * block_size < length)
-    def _page():
-        k = k_ref[...].astype(jnp.float32)            # [BS, KV, Dh]
-        v = v_ref[...].astype(jnp.float32)
-        live = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (*k.shape[:2], 1), 0) < length
-        for r in range(q_ref.shape[0]):               # q heads per kv head
-            q = q_ref[r].astype(jnp.float32)          # [KV, Dh]
-            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
-            s = jnp.where(live, s, _NEG_INF)          # [BS, KV, 1]
-            m_prev = m_scr[r]                         # [KV, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[None])              # [BS, KV, 1]
-            l_scr[r] = l_scr[r] * alpha + jnp.sum(p, axis=0)
-            acc_scr[r] = acc_scr[r] * alpha + jnp.sum(p * v, axis=0)
-            m_scr[r] = m_new
+        def page(p, _):
+            blk = tables_ref[stream, jnp.minimum(g * P + p, last_page)]
+            rows = pl.ds(pl.multiple_of(p * page_rows, page_rows),
+                         page_rows)
+            for i, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                pltpu.make_async_copy(
+                    hbm.at[li, blk].reshape(page_rows, Dh),
+                    buf.at[slot, rows], sem.at[i, slot]).start()
+        jax.lax.fori_loop(0, P, page, None)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _flush():
-        l_safe = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+    def wait(slot):
+        # A slot's P copies of K (of V) signal one semaphore, which
+        # counts bytes: one wait for the whole slot's worth takes all P.
+        for i, buf in enumerate((kbuf, vbuf)):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sem.at[i, slot]).wait()
+
+    @pl.when(b == 0)
+    def _first():
+        first_slot[0] = 0
+        start(0, 0, 0)
+
+    slot0 = first_slot[0]
+    q = q_ref[...]
+    # Column c of a group is token c // KV of it, for kv head c % KV: the
+    # token where that head is the row's own, else one no length reaches.
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, G * KV), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (H, G * KV), 0)
+    tok = jnp.where(col % KV == row // rep, col // KV,
+                    jnp.iinfo(jnp.int32).max)
+
+    def load(buf, slot):
+        x = buf[slot]
+        return x if x.dtype == q.dtype else pltpu.bitcast(x, q.dtype)
+
+    def body(g, carry):
+        m, l, acc = carry
+        slot = (slot0 + g) % 2
+        more = g + 1 < n_groups
+        pl.when(more)(lambda: start(b, g + 1, 1 - slot))
+        pl.when(jnp.logical_not(more) & (b + 1 < pl.num_programs(0)))(
+            lambda: start(b + 1, 0, 1 - slot))
+        wait(slot)
+        k, v = load(kbuf, slot), load(vbuf, slot)     # [G*KV, Dh]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(tok < length - g * G, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
+
+    m0 = jnp.full((H, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((H, 1), jnp.float32)
+    acc0 = jnp.zeros((H, Dh), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_groups, body, (m0, l0, acc0))
+    first_slot[0] = (slot0 + n_groups) % 2
+    # length >= 1 leaves every row a live column in every group it
+    # visits, so l > 0; a length of 0 reads as zeros.
+    o_ref[...] = jnp.where(length > 0, acc / l, 0.0).astype(o_ref.dtype)
 
 
 # What a kernel may keep resident in VMEM at once, by the estimates in
@@ -433,16 +500,50 @@ def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_ref,
 # of memory in memory space vmem".
 _VMEM_BUDGET = 12 << 20
 
+# Tokens the paged decode kernel takes in one group where VMEM allows:
+# of 64, 128, 256 and 512 the fastest at the backlog cell's shape on v5e
+# (the sweep is in PERF.md, PR 28).  Larger groups make fewer loop turns;
+# a stream's last group is fetched whole, so they also fetch more dead
+# tokens (half a group a stream).
+_PAGED_GROUP_TOKENS = 256
+
+
+def _paged_resident(pages: int, block_size: int, head_dim: int,
+                    kv_heads: int, heads: int, itemsize: int) -> int:
+    """VMEM bytes of the paged decode kernel at ``pages`` pages a group:
+    K and V groups in two slots each, and the float32 score block
+    ``[H, P*BS*KV]`` three times over (scores, ``p``, the token map)."""
+    rows = pages * block_size * kv_heads
+    return 4 * rows * head_dim * itemsize + 3 * heads * rows * 4
+
+
+def paged_group_pages(block_size: int, head_dim: int, kv_heads: int,
+                      heads: int, itemsize: int, n_cols: int) -> int:
+    """Pages the paged decode kernel fetches and multiplies at once, from
+    the shapes alone: :data:`_PAGED_GROUP_TOKENS` tokens' worth, no more
+    than the table is wide, halved until the resident set fits."""
+    pages = max(1, min(_PAGED_GROUP_TOKENS // block_size, n_cols))
+    while pages > 1 and _paged_resident(
+            pages, block_size, head_dim, kv_heads, heads,
+            itemsize) > _VMEM_BUDGET:
+        pages //= 2
+    return pages
+
 
 def paged_supported(block_size: int, head_dim: int, kv_heads: int,
-                    itemsize: int) -> bool:
-    """Pool geometries the paged decode kernel compiles for.  A K/V block
-    is one whole page ``[BS, KV, Dh]`` — its trailing dims equal the
-    array's, which Mosaic accepts at any size (probed from KV=1, Dh=16
-    to 32-head pages of 256 tokens) — so the only bound is the resident
-    set: K and V pages double-buffered, plus their fp32 copies."""
-    page = block_size * kv_heads * head_dim
-    return 4 * page * itemsize + 2 * page * 4 <= _VMEM_BUDGET
+                    heads: int, itemsize: int) -> bool:
+    """Pool geometries the paged decode kernel compiles for (interpret
+    mode runs any).  The kernel slices single pages out of the pool in
+    HBM, which Mosaic takes only in whole tiles: ``head_dim`` a multiple
+    of the 128 lanes, and a token's kv heads filling whole 32-bit
+    sublane rows (an odd count of bf16 heads does not).  Ahead-of-time
+    compiles for v5e accepted KV 2 to 32, H up to 64, BS 4 to 256, Dh
+    128 and 256 in bf16, and KV 8 in float32; refused Dh 16 and 64 and
+    bf16 at KV 1 and 3.  Then one page a group has to fit VMEM; larger
+    groups are :func:`paged_group_pages`'s to choose."""
+    return (head_dim % 128 == 0 and (kv_heads * itemsize) % 4 == 0
+            and _paged_resident(1, block_size, head_dim, kv_heads, heads,
+                                itemsize) <= _VMEM_BUDGET)
 
 
 def paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
@@ -457,11 +558,13 @@ def paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     physical block ids (rows padded with the scratch block 0); lengths
     [B] — logical positions ``< lengths[b]`` are live, the rest masked.
 
-    Layer and table ride ``PrefetchScalarGridSpec``'s scalar-prefetch
-    channel so the K/V BlockSpec index maps dereference them per grid
-    step — no gathered ``[B, T, KV, Dh]`` copy ever lands in HBM (the XLA
-    fallback in the serving engine materializes exactly that copy).
-    Returns [B, H, Dh].
+    Layer, table and lengths ride ``PrefetchScalarGridSpec``'s
+    scalar-prefetch channel; the pools are handed over in HBM
+    (``memory_space=pl.ANY``) and each of the ``B`` grid steps copies its
+    stream's live pages in itself — no gathered ``[B, T, KV, Dh]`` copy
+    ever lands in HBM (the XLA fallback in the serving engine
+    materializes exactly that copy), and the time follows the live KV
+    bytes, not the table's width.  Returns [B, H, Dh].
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -470,43 +573,36 @@ def paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     L, NB, BS, KV, _ = k_pool.shape
     if H % KV:
         raise ValueError(f"kv heads {KV} must divide q heads {H}")
-    rep = H // KV
     if scale is None:
         scale = 1.0 / float(np.sqrt(Dh))
-    n_cols = tables.shape[1]
-    # [B, rep, KV, Dh]: q head g*rep + r sits at [r, g], so each r is a
-    # [KV, Dh] tile aligned with a page's trailing dims.
-    qg = q.reshape(B, KV, rep, Dh).swapaxes(1, 2)
+    itemsize = k_pool.dtype.itemsize
+    P = paged_group_pages(BS, Dh, KV, H, itemsize, tables.shape[1])
+    # Sub-32-bit pools travel as uint32 words (see the kernel) wherever
+    # the kv heads pair up into them.
+    pack = 4 // itemsize if KV % (4 // itemsize) == 0 else 1
+    buf = pltpu.VMEM((2, P * BS * KV // pack, Dh),
+                     jnp.uint32 if pack > 1 else k_pool.dtype)
 
-    kernel = functools.partial(_paged_decode_kernel, block_size=BS,
-                               scale=scale)
-    q_spec = pl.BlockSpec((None, rep, KV, Dh),
-                          lambda b, j, li, tbl, ln: (b, 0, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (None, None, BS, KV, Dh),
-        lambda b, j, li, tbl, ln: (li[0], tbl[b, j], 0, 0, 0))
+    q_spec = pl.BlockSpec((None, H, Dh), lambda b, li, tbl, ln: (b, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, n_cols),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        grid=(B,),
+        in_specs=[q_spec, pool_spec, pool_spec],
         out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((rep, KV, 1), jnp.float32),
-            pltpu.VMEM((rep, KV, 1), jnp.float32),
-            pltpu.VMEM((rep, KV, Dh), jnp.float32),
-        ],
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
-    out = pl.pallas_call(
-        kernel,
+    return pl.pallas_call(
+        functools.partial(_paged_decode_kernel, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, rep, KV, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="hvd_paged_decode",
     )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
-      lengths.astype(jnp.int32), qg, k_pool, v_pool)
-    return out.swapaxes(1, 2).reshape(B, H, Dh)
+      lengths.astype(jnp.int32), q, k_pool, v_pool)
 
 
 # ---------------------------------------------------------------------------

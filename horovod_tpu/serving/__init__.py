@@ -11,7 +11,7 @@ with request-level scheduling:
 - :mod:`~horovod_tpu.serving.kv_pager` — block-paged KV cache over the
   grouped ``[B, S, KV, D]`` layout: a free-list allocator, per-request
   block tables, and paged-attention dispatch (gather-by-block-table under
-  XLA, scalar-prefetch BlockSpec routing in the Pallas kernel).
+  XLA, the Pallas kernel's own copies of each stream's live pages).
 - :mod:`~horovod_tpu.serving.scheduler` — continuous batching: admission
   queue, prefill/decode phase split, per-step join/evict, and a prefill
   token budget that bounds decode latency.

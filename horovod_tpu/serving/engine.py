@@ -58,9 +58,10 @@ _m_decode_tokens = _obs.counter(
 _m_prefill_skipped = _obs.counter(
     "hvd_serving_prefill_skipped_tokens_total",
     "prompt tokens NOT prefilled because a cached prefix covered them")
-# What the decode step's block table holds, summed over ticks: the step
-# walks max_active x n_cols table slots whatever they hold, so
-# blocks_total / slots_total is the share of that walk that reads a page.
+# What the decode step's block table holds, summed over ticks: the host
+# builds and ships max_active x n_cols table slots whatever they hold, so
+# blocks_total / slots_total is the share of them that names a page (the
+# gather path reads them all; the Pallas kernel visits live pages only).
 _m_table_slots = _obs.counter(
     "hvd_serving_decode_table_slots_total",
     "block-table entries handed to decode steps (max_active x n_cols)")
@@ -194,7 +195,7 @@ class ServingEngine:
         wanted = flash == "interpret" or (
             flash == "auto" and jax.default_backend() == "tpu")
         self._use_flash = wanted and llama.paged_kernel_ok(
-            cfg, mesh, engine_cfg.block_size)
+            cfg, mesh, engine_cfg.block_size, self._interpret)
         log.info("serving decode attention path: %s (use_flash=%s, "
                  "backend %s, block_size %d, head_dim %d, mesh %s)",
                  self.attention_path, flash, jax.default_backend(),

@@ -13,10 +13,10 @@ vLLM's central idea):
   block ``table[p // block_size]``;
 - attention reads the pool either by gathering a request's blocks into a
   contiguous ``[B, T_pad, KV, D]`` view (XLA path — a plain take, which
-  GSPMD shards like any other gather) or directly via the Pallas decode
-  kernel's scalar-prefetch BlockSpec routing
-  (:func:`horovod_tpu.ops.flash_attention.paged_attention`), the same
-  grouped-KV index-map trick the training flash kernel uses for GQA.
+  GSPMD shards like any other gather) or directly in the Pallas decode
+  kernel (:func:`horovod_tpu.ops.flash_attention.paged_attention`),
+  which takes the table by scalar prefetch and copies each stream's
+  live pages out of the pool itself, at kv-head width.
 
 Block 0 is RESERVED as a scratch target: inactive decode slots in the
 fixed-shape step function point their table rows at it, so their masked

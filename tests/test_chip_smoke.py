@@ -145,7 +145,7 @@ def test_kernels_compile_for_v5e(monkeypatch):
     assert custom_calls(grads, qkv, qkv, qkv) == 3      # fwd, dq, dkv
 
     R, L, NB, BS, n_cols = 4, 4, 2048, 16, 64
-    assert FA.paged_supported(BS, D, H, 2)
+    assert FA.paged_supported(BS, D, H, H, 2)
     pool = spec((L, NB, BS, H, D))
     assert custom_calls(
         FA.paged_attention, spec((R, H, D)), pool, pool,

@@ -237,7 +237,7 @@ def test_engine_bucketed_prefill_matches_exact(tiny):
 
 def test_engine_paged_flash_kernel_mode(tiny):
     """use_flash="interpret" routes decode attention through the Pallas
-    paged kernel (scalar-prefetch block tables); tokens must match the
+    paged kernel (block tables by scalar prefetch); tokens must match the
     XLA gather path bit for bit."""
     cfg, params = tiny
     rng = np.random.RandomState(5)
@@ -271,6 +271,115 @@ def test_paged_attention_kernel_vs_gather_oracle():
                          1.0 / np.sqrt(Dh))[:, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
+
+
+# (id, KV, rep, dtype, BS, n_cols, group tokens or None for the kernel's
+# own, lengths; None in lengths is an inactive row: length 1, table all
+# scratch block 0).  With G = the group's tokens: lengths of 1, one short
+# of a page, exactly a group, one past a group, the whole table.
+_PAGED_CASES = [
+    ("kv2-rep4-f32", 2, 4, "float32", 4, 12, 16, [1, 3, 16, 17, 48]),
+    ("kv8-rep1-f32", 8, 1, "float32", 4, 12, 16, [1, 3, 16, 17, 48]),
+    ("kv8-rep4-f32", 8, 4, "float32", 8, 6, 16, [7, 16, 17, 33, 48]),
+    ("kv2-rep1-f32", 2, 1, "float32", 8, 6, 16, [1, 15, 32, 48]),
+    ("kv2-rep4-bf16", 2, 4, "bfloat16", 4, 12, 16, [1, 3, 16, 17, 48]),
+    ("kv8-rep4-bf16", 8, 4, "bfloat16", 8, 6, 16, [7, 16, 17, 33, 48]),
+    ("kv8-rep1-bf16", 8, 1, "bfloat16", 4, 12, 16, [1, 3, 16, 17, 48]),
+    ("inactive-rows", 2, 4, "float32", 4, 12, 16, [None, 17, None, 48]),
+    ("inactive-rows-bf16", 2, 4, "bfloat16", 4, 12, 16, [None, 17, None]),
+    # the table narrower than a group's P = 8 pages: P becomes n_cols
+    ("table-narrower-than-group", 2, 4, "float32", 4, 3, 32, [1, 5, 12]),
+    # ... and not a multiple of P = 2: the last group's second page is
+    # past the table, clamped onto its last live page
+    ("table-not-a-multiple", 2, 4, "float32", 8, 5, 16, [8, 31, 33, 40]),
+    ("table-not-a-multiple-bf16", 8, 4, "bfloat16", 8, 5, 16, [8, 33, 40]),
+    # the kernel's own group size (256 tokens, P = 32) on a table 2.5
+    # groups wide
+    ("default-group", 2, 4, "float32", 8, 80, None, [1, 255, 256, 257, 640]),
+    ("default-group-bf16", 2, 4, "bfloat16", 8, 80, None, [256, 257, 640]),
+]
+
+
+@pytest.mark.parametrize("poison", [None, "nan", "1e30"])
+@pytest.mark.parametrize(
+    "KV,rep,dtype,BS,C,group,lens",
+    [pytest.param(*c[1:], id=c[0]) for c in _PAGED_CASES])
+def test_paged_attention_kernel_cases(monkeypatch, KV, rep, dtype, BS, C,
+                                      group, lens, poison):
+    """The paged kernel against the gather oracle over its geometry and
+    the lengths at which its walk changes shape.  ``poison`` fills every
+    page of the pool that lies wholly past its stream's length (and
+    every page no table names) with NaN or 1e30 for the kernel alone:
+    the kernel never reads them, so the result is the clean pool's.
+
+    bf16 pools: both sides round ``p`` (at most 1) to bf16, the kernel
+    before the row sum divides it and the oracle after, and round the
+    result; each of the four roundings is within 2**-9 relative, on a
+    convex combination of V's rows, so the gap is under 4 * 2**-9 *
+    max|V|.  float32 pools round nothing: only the summation order
+    differs."""
+    from horovod_tpu.models.llama import _cached_attend
+    from horovod_tpu.ops import flash_attention as FA
+    if group is not None:
+        monkeypatch.setattr(FA, "_PAGED_GROUP_TOKENS", group)
+    rng = np.random.RandomState(len(lens) * 131 + KV * 7 + rep + BS + C)
+    dtype = jnp.dtype(dtype)
+    B, H, Dh, L, li = len(lens), KV * rep, 32, 2, 1
+    NB = 1 + B * C
+    q = jnp.asarray(rng.randn(B, H, Dh), dtype)
+    kp = rng.randn(L, NB, BS, KV, Dh).astype(np.float32)
+    vp = rng.randn(L, NB, BS, KV, Dh).astype(np.float32)
+    tables = (1 + rng.permutation(B * C)).reshape(B, C).astype(np.int32)
+    lengths = np.asarray([1 if n is None else n for n in lens], np.int32)
+    live = np.zeros(NB, bool)                 # blocks holding a live token
+    for b, n in enumerate(lens):
+        if n is None:
+            tables[b] = 0
+        live[tables[b, :-(-int(lengths[b]) // BS)]] = True
+    clean_k, clean_v = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
+    if poison is not None:                    # copies: jnp may alias numpy
+        kp, vp = kp.copy(), vp.copy()
+        kp[:, ~live] = vp[:, ~live] = float(poison)
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+    out = FA.paged_attention(q, jnp.asarray(kp, dtype),
+                             jnp.asarray(vp, dtype), li, tables, lengths,
+                             interpret=True)
+    keys = gather_blocks(clean_k[li], tables)
+    vals = gather_blocks(clean_v[li], tables)
+    mask = (jnp.arange(C * BS)[None, :] < lengths[:, None])[:, None, :]
+    ref = _cached_attend(q[:, None], keys, vals, mask,
+                         1.0 / np.sqrt(Dh))[:, 0]
+    assert out.dtype == q.dtype and out.shape == q.shape
+    if dtype == jnp.float32:
+        tol = dict(rtol=1e-5, atol=2e-6)
+    else:
+        tol = dict(rtol=0, atol=4 * 2.0 ** -9 * float(
+            np.abs(vp[li, live]).max()))
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol)
+
+
+def test_paged_group_pages_follow_the_shapes():
+    """The group size comes from the shapes alone: the kernel's token
+    target over the page size, no wider than the table, halved until the
+    resident set fits VMEM; geometries Mosaic refuses are gated."""
+    from horovod_tpu.ops import flash_attention as FA
+    tokens = FA._PAGED_GROUP_TOKENS
+    # the backlog cell: Mistral 7B, 16-token pages, bf16
+    assert FA.paged_group_pages(16, 128, 8, 32, 2, 256) == tokens // 16
+    assert FA.paged_group_pages(16, 128, 8, 32, 2, 5) == 5
+    assert FA.paged_group_pages(512, 128, 8, 32, 2, 64) == 1
+    # 64 q and kv heads of 256: a 256-token group would be 32 MiB
+    wide = FA.paged_group_pages(16, 256, 64, 64, 2, 256)
+    assert 1 <= wide < tokens // 16
+    assert FA._paged_resident(wide, 16, 256, 64, 64, 2) <= FA._VMEM_BUDGET
+    assert FA._paged_resident(2 * wide, 16, 256, 64, 64, 2) > FA._VMEM_BUDGET
+    assert FA.paged_supported(16, 128, 8, 32, 2)
+    assert FA.paged_supported(16, 128, 2, 8, 2)         # tp=4 share of it
+    assert FA.paged_supported(16, 128, 8, 32, 4)
+    assert not FA.paged_supported(16, 64, 8, 32, 2)     # Dh under a lane row
+    assert not FA.paged_supported(16, 128, 1, 8, 2)     # odd bf16 kv heads
+    assert not FA.paged_supported(4096, 256, 64, 64, 2)  # one page over VMEM
 
 
 def test_engine_on_mesh_matches_generate(tiny):

@@ -207,11 +207,9 @@ def test_pallas_kernels_are_named():
     assert "hvd_paged_decode" in text
 
 
-def test_kernel_names_reach_the_compiled_tpu_module(monkeypatch):
-    """Compiled for a v5e (no chip needed), each Mosaic custom call is an
-    instruction whose own name holds the kernel's (autodiff wraps it:
-    %transpose_jvp_hvd_flash_bwd_dq__.1): the left side of the line that a
-    device trace shows the op by."""
+def _v5e_spec(monkeypatch):
+    """``spec(shape, dtype)``: a ShapeDtypeStruct on one chip of a
+    compile-only v5e:2x2 (no chip needed); skips without libtpu."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
@@ -222,13 +220,25 @@ def test_kernel_names_reach_the_compiled_tpu_module(monkeypatch):
     except Exception as e:  # no libtpu on this machine
         pytest.skip(f"no compile-only TPU topology: {e}")
     chip = SingleDeviceSharding(topo.devices[0])
-    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+    return lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dtype, sharding=chip)
 
+
+def _mosaic_lines(compiled):
+    return [ln.split(" = ")[0].strip()
+            for ln in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def test_kernel_names_reach_the_compiled_tpu_module(monkeypatch):
+    """Compiled for a v5e (no chip needed), each Mosaic custom call is an
+    instruction whose own name holds the kernel's (autodiff wraps it:
+    %transpose_jvp_hvd_flash_bwd_dq__.1): the left side of the line that a
+    device trace shows the op by."""
+    spec = _v5e_spec(monkeypatch)
+
     def mosaic_lines(fn, *args):
-        text = jax.jit(fn).lower(*args).compile().as_text()
-        return [ln.split(" = ")[0].strip() for ln in text.splitlines()
-                if 'custom_call_target="tpu_custom_call"' in ln]
+        return _mosaic_lines(jax.jit(fn).lower(*args).compile())
 
     qkv = spec((1, 512, 4, 128))
     grads = jax.grad(lambda q, k, v: FA.flash_attention(q, k, v).astype(
@@ -242,6 +252,41 @@ def test_kernel_names_reach_the_compiled_tpu_module(monkeypatch):
         FA.paged_attention, spec((4, 32, 128)), pool, pool,
         spec((), jnp.int32), spec((4, 8), jnp.int32), spec((4,), jnp.int32))
     assert len(names) == 1 and "hvd_paged_decode" in names[0], names
+
+
+def test_decode_step_compiles_to_one_paged_kernel_and_no_pool_copy(
+        monkeypatch):
+    """The decode tick at the backlog cell's configuration (Mistral 7B
+    widths, 16 layers, 32 slots, a 4 GiB pool of 16-token pages, the
+    widest table), compiled for a v5e: one Mosaic call, named
+    ``hvd_paged_decode`` (what the benchmark's reducers find the decode
+    program and the kernel by), the donated pools updated in place, and
+    temporaries far under one layer's pages (128 MiB), so neither pool
+    nor a layer of one is copied for the kernel."""
+    from horovod_tpu.models import llama
+    spec = _v5e_spec(monkeypatch)
+    L, NB, BS, R, n_cols = 16, 4096, 16, 32, 256
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, d_model=4096, n_layers=L, n_heads=32,
+        n_kv_heads=8, d_ff=14336, rope_theta=1e6, dtype=jnp.bfloat16)
+    assert llama.paged_kernel_ok(cfg, None, BS)
+    params = jax.tree.map(
+        lambda s: spec(s.shape, s.dtype), jax.eval_shape(
+            lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = spec((L, NB, BS, cfg.n_kv_heads, cfg.head_dim))
+    i32 = lambda *shape: spec(shape, jnp.int32)
+    step = jax.jit(
+        lambda p, tok, pos, kp, vp, tables: llama.decode_step_paged(
+            p, tok, pos, kp, vp, tables, cfg, use_flash=True),
+        donate_argnums=(3, 4))
+    compiled = step.lower(params, i32(R), i32(R), pool, pool,
+                          i32(R, n_cols)).compile()
+    names = _mosaic_lines(compiled)
+    assert len(names) == 1 and "hvd_paged_decode" in names[0], names
+    mem = compiled.memory_analysis()
+    pool_bytes = L * NB * BS * cfg.n_kv_heads * cfg.head_dim * 2
+    assert mem.alias_size_in_bytes == 2 * pool_bytes
+    assert mem.temp_size_in_bytes < (pool_bytes // L) // 8, mem
 
 
 def test_profiler_span_is_a_noop_context_without_the_profiler():
