@@ -19,6 +19,7 @@ story would be "bring your own torch model"), so this is built TPU-first:
 from __future__ import annotations
 
 import dataclasses
+import sys
 from functools import lru_cache, partial
 from typing import Any, Optional
 
@@ -37,6 +38,7 @@ from ..parallel.ring_attention import (
 from ..utils import logging as hvd_logging
 
 log = hvd_logging.get_logger()
+_THIS = sys.modules[__name__]     # make_train_step's default model
 
 
 @dataclasses.dataclass(frozen=True)
@@ -373,12 +375,14 @@ def _sp_local_attention(sp_mode: str):
 
 
 def attention_path(q_shape: tuple, itemsize: int, mesh: Optional[Mesh],
-                   sp_mode: str = "ring") -> str:
+                   sp_mode: str = "ring", v_dim: Optional[int] = None
+                   ) -> str:
     """Which implementation :func:`_attention` runs for a global
     ``[B, S, H, D]`` query on ``mesh``: ``"ring"``/``"ulysses"`` when the
     sequence is sp-sharded, ``"flash"`` (the Pallas kernels) on TPU when
     the per-chip shard divides evenly and :func:`FA.supported` accepts
-    it, ``"dense"`` (XLA) otherwise."""
+    it, ``"dense"`` (XLA) otherwise.  ``v_dim`` is the value width where
+    it differs from the key width ``D``."""
     from ..ops import flash_attention as FA
     shape = dict(mesh.shape) if mesh is not None else {}
     if shape.get("sp", 1) > 1:
@@ -388,7 +392,7 @@ def attention_path(q_shape: tuple, itemsize: int, mesh: Optional[Mesh],
     dpf = shape.get("dp", 1) * shape.get("fsdp", 1)
     tp = shape.get("tp", 1)
     if (_flash_backend() and B % dpf == 0 and H % tp == 0
-            and FA.supported((B // dpf, S, H // tp, D), itemsize)):
+            and FA.supported((B // dpf, S, H // tp, D), itemsize, v_dim)):
         return "flash"
     return "dense"
 
@@ -399,7 +403,8 @@ def _attention(q, k, v, mesh: Optional[Mesh], causal: bool,
     the flash kernel are shard_mapped so each chip works on its own
     batch/head shard (a bare pallas_call has no GSPMD partitioning rule
     and would be replicated)."""
-    path = attention_path(q.shape, q.dtype.itemsize, mesh, sp_mode)
+    path = attention_path(q.shape, q.dtype.itemsize, mesh, sp_mode,
+                          v.shape[-1])
     _log_attention_path(path, q.shape,
                         tuple(mesh.shape.items()) if mesh is not None
                         else None)
@@ -1416,7 +1421,7 @@ def loss_fn(params: dict, batch: dict, cfg: LlamaConfig, *,
     return (lse - picked).mean() + cfg.moe_aux_weight * aux
 
 
-def _opt_shardings(tx, cfg: LlamaConfig, mesh: Mesh):
+def _opt_shardings(tx, cfg, mesh: Mesh, model=None):
     """Explicit shardings for the optimizer state: every param-shaped
     subtree (adam mu/nu, momentum, ...) mirrors the parameter shardings,
     anything else (step counters) replicates.
@@ -1425,9 +1430,10 @@ def _opt_shardings(tx, cfg: LlamaConfig, mesh: Mesh):
     state's shardings to inference lets the propagator pick layouts that
     disagree with the donated inputs on tp/sp meshes, and XLA aliasing
     fails at runtime with a sub-shape size mismatch."""
-    pshard = param_shardings(cfg, mesh)
+    model = model or _THIS
+    pshard = model.param_shardings(cfg, mesh)
     repl = NamedSharding(mesh, P())
-    params_aval = jax.eval_shape(partial(init_params, cfg),
+    params_aval = jax.eval_shape(partial(model.init_params, cfg),
                                  jax.random.PRNGKey(0))
     ptree = jax.tree.structure(params_aval)
     state_aval = jax.eval_shape(tx.init, params_aval)
@@ -1594,19 +1600,33 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
                    donate_argnums=(0, 1))
 
 
-def make_train_step(cfg: LlamaConfig, mesh: Mesh, tx, *,
-                    pipeline_schedule: str = "1f1b"):
+def make_train_step(cfg, mesh: Mesh, tx, *,
+                    pipeline_schedule: str = "1f1b", model=None):
     """Jitted full training step over the mesh (GSPMD collectives for
     dp/fsdp/tp, explicit shard_map blocks for sp/ep; layer stack over pp).
 
+    ``model`` is the module the loss and the layout come from: it gives
+    ``loss_fn(params, batch, cfg, mesh=)``, ``param_shardings(cfg, mesh)``
+    and ``init_params(cfg, key)`` (for the optimizer state's shapes).
+    The default is this module with a :class:`LlamaConfig`;
+    :mod:`horovod_tpu.models.kimi_linear` is the other.  Where the model
+    sets ``LOSS_HAS_AUX`` its loss returns ``(loss, aux)`` and so does
+    the step, as its third output.
+
     On pp>1 meshes ``pipeline_schedule`` selects "1f1b" (default: explicit
     interleaved fwd/bwd schedule, activation memory bounded by 2*(pp-1)
-    microbatches) or "gpipe" (autodiff through the fill-drain forward)."""
+    microbatches) or "gpipe" (autodiff through the fill-drain forward);
+    both are the Llama stack's."""
+    model = model or _THIS
+    if mesh.shape.get("pp", 1) > 1 and model is not _THIS:
+        raise NotImplementedError(
+            f"{model.__name__} has no pipelined forward; use a pp=1 mesh")
     if mesh.shape.get("pp", 1) > 1 and pipeline_schedule == "1f1b":
         if cfg.blockwise_ce:
             raise NotImplementedError("blockwise CE requires a pp=1 mesh")
         return _make_train_step_1f1b(cfg, mesh, tx)
-    pshard = param_shardings(cfg, mesh)
+    has_aux = getattr(model, "LOSS_HAS_AUX", False)
+    pshard = model.param_shardings(cfg, mesh)
     repl = NamedSharding(mesh, P())
     batch_shard = NamedSharding(mesh, P(("dp", "fsdp")))
 
@@ -1614,7 +1634,8 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, tx, *,
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(p, batch, cfg, mesh=mesh))(params)
+            lambda p: model.loss_fn(p, batch, cfg, mesh=mesh),
+            has_aux=has_aux)(params)
         # Pin gradients to the parameter shardings: the backward scan's
         # per-layer dynamic-update-slice accumulators otherwise get
         # propagation-derived shardings that force involuntary full
@@ -1627,7 +1648,7 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, tx, *,
         params = jax.tree.map(jnp.add, params, updates)
         return params, opt_state, loss
 
-    opt_shard = _opt_shardings(tx, cfg, mesh)
+    opt_shard = _opt_shardings(tx, cfg, mesh, model)
     return jax.jit(
         step,
         in_shardings=(pshard, opt_shard, batch_shard),

@@ -117,7 +117,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
 
     m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, q_ref.shape[-1]), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     if causal:
         # last needed K block covers query row (qi+1)*block_q - 1
         upper = jax.lax.min(
@@ -131,6 +131,46 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     # broadcast across 8 sublanes: [BH, 8, S].
     lse_ref[0] = jnp.broadcast_to((m + jnp.log(l_safe))[None, :],
                                   (8, lse_ref.shape[-1]))
+
+
+# Scoped VMEM a Mosaic kernel gets on the v5e without asking; a kernel
+# that needs more asks for it (``vmem_limit_bytes``) out of the chip's
+# 128 MiB.  :data:`_FLASH_VMEM_BUDGET` is what :func:`supported` lets the
+# flash kernels keep resident, by :func:`_flash_resident`.
+_SCOPED_VMEM = 16 << 20
+_FLASH_VMEM_BUDGET = 24 << 20
+
+
+def _flash_resident(S: int, D: int, Dv: int, itemsize: int, blk: int) -> int:
+    """VMEM bytes of the heaviest of the three kernels, the one estimate
+    that :func:`supported` and :func:`_compiler_params` both go by: the
+    two full-sequence operands a kernel keeps resident (K/V in the
+    forward and dq, Q/dO in dk/dv: one of the key width ``D``, one of the
+    value width ``Dv``), each padded to whole 128-lane tiles and held in
+    two buffers, the lse and delta rows likewise, and the float32 block
+    operands and accumulators.  Checked against the compiler:
+    ahead-of-time compiles for a v5e (jax 0.9.0, libtpu 0.0.34, 32
+    heads) name 16.02 MiB for S=8192 D=192 Dv=128 bf16 (17.0 here) and
+    17.75 MiB for S=16384 D=128 bf16 (20.0 here)."""
+    lanes = lambda d: -(-d // 128) * 128
+    return (2 * S * (lanes(D) + lanes(Dv)) * itemsize
+            + 2 * 2 * 8 * S * 4
+            + 2 * 4 * blk * lanes(D) * 4)
+
+
+def _compiler_params(S: int, D: int, Dv: int, itemsize: int,
+                     blk: int) -> dict:
+    """``compiler_params`` for the three kernels: nothing while
+    :func:`_flash_resident` stays an eighth under the default scoped
+    VMEM, which those shapes fit; above that (latent attention at 8k:
+    keys 192 wide pad to 256 lanes) the limit is raised to the estimate
+    and half as much again."""
+    from jax.experimental.pallas import tpu as pltpu
+    resident = _flash_resident(S, D, Dv, itemsize, blk)
+    if resident <= _SCOPED_VMEM * 7 // 8:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=resident * 3 // 2)}
 
 
 def _kv_row_map(H: int, KV: int):
@@ -148,7 +188,7 @@ def _flash_forward(q, k, v, *, scale, causal, block_q, block_k, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, D = q.shape
-    KV = k.shape[2]
+    KV, Dv = k.shape[2], v.shape[3]
     qt, kt, vt = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     kernel = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, seq_len=S,
@@ -161,20 +201,22 @@ def _flash_forward(q, k, v, *, scale, causal, block_q, block_k, interpret):
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, S, D), kv_map, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, S, D), kv_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, S, Dv), kv_map, memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, qi: (bh, qi, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 8, block_q), lambda bh, qi: (bh, 0, qi),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 8, S), jnp.float32),
         ],
         interpret=interpret,
         name="hvd_flash_fwd",
+        **_compiler_params(S, D, Dv, q.dtype.itemsize,
+                           max(block_q, block_k)),
     )(qt, kt, vt)
     return _from_bhsd(out, B, H), lse[:, 0, :]
 
@@ -284,7 +326,7 @@ def _flash_backward(q, k, v, out, lse, g, *, scale, causal, block_q,
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, D = q.shape
-    KV = k.shape[2]
+    KV, Dv = k.shape[2], v.shape[3]
     rep = H // KV
     qt, kt, vt = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     dot = _to_bhsd(g)
@@ -306,8 +348,8 @@ def _flash_backward(q, k, v, out, lse, g, *, scale, causal, block_q,
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, S, D), kv_map, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, S, D), kv_map, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0),
+            pl.BlockSpec((1, S, Dv), kv_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, qi: (bh, qi, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 8, block_q), lambda bh, qi: (bh, 0, qi),
                          memory_space=pltpu.VMEM),
@@ -319,6 +361,8 @@ def _flash_backward(q, k, v, out, lse, g, *, scale, causal, block_q,
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         interpret=interpret,
         name="hvd_flash_bwd_dq",
+        **_compiler_params(S, D, Dv, q.dtype.itemsize,
+                           max(block_q, block_k)),
     )(*common_in)
 
     # dk/dv: one program per (kv row, k block, q-head-in-group), r
@@ -335,28 +379,30 @@ def _flash_backward(q, k, v, out, lse, g, *, scale, causal, block_q,
             pl.BlockSpec((1, S, D), grp, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, D), lambda kb, ki, r: (kb, ki, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, D), lambda kb, ki, r: (kb, ki, 0),
+            pl.BlockSpec((1, block_k, Dv), lambda kb, ki, r: (kb, ki, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, S, D), grp, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, S, Dv), grp, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 8, S), grp, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 8, S), grp, memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda kb, ki, r: (kb, ki, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, D), lambda kb, ki, r: (kb, ki, 0),
+            pl.BlockSpec((1, block_k, Dv), lambda kb, ki, r: (kb, ki, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * KV, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * KV, S, D), v.dtype),
+            jax.ShapeDtypeStruct((B * KV, S, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="hvd_flash_bwd_dkv",
+        **_compiler_params(S, D, Dv, q.dtype.itemsize,
+                           max(block_q, block_k)),
     )(*common_in)
 
     return (_from_bhsd(dq, B, H), _from_bhsd(dk, B, KV),
@@ -491,13 +537,8 @@ def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm,
     o_ref[...] = jnp.where(length > 0, acc / l, 0.0).astype(o_ref.dtype)
 
 
-# What a kernel may keep resident in VMEM at once, by the estimates in
-# :func:`supported` and :func:`paged_supported`.  Checked against the
-# compiler, not the data sheet: ahead-of-time compiles for a TPU v5 lite
-# target (jax 0.9.0, libtpu 0.0.34) accepted every probed shape whose
-# estimate was <= 12.5 MiB (e.g. S=16384 D=128 bf16, S=8192 D=256 bf16,
-# S=1024 D=512 fp32) and refused the smallest at 14.1 MiB with "ran out
-# of memory in memory space vmem".
+# What the paged decode kernel may keep resident in VMEM at once, by
+# :func:`_paged_resident`, under the default scoped limit.
 _VMEM_BUDGET = 12 << 20
 
 # Tokens the paged decode kernel takes in one group where VMEM allows:
@@ -636,27 +677,29 @@ def default_blocks(seq_len: int) -> tuple[int, int]:
     return b, b  # two-tuple API: callers may still override bq/bk apart
 
 
-def supported(q_shape: tuple, itemsize: int = 4) -> bool:
+def supported(q_shape: tuple, itemsize: int = 4,
+              v_dim: Optional[int] = None) -> bool:
     """Shapes the kernels compile for: seq divisible by a block size and
-    the heaviest kernel's resident set within :data:`_VMEM_BUDGET`.  The
-    estimate counts two full-sequence operands (K/V in the forward, Q/dO
-    in the dkv backward), the lse/delta rows, and the double-buffered
-    fp32 block operands/accumulators."""
+    the heaviest kernel's resident set (:func:`_flash_resident`; the
+    value width ``v_dim`` is ``D`` unless given) within
+    :data:`_FLASH_VMEM_BUDGET`."""
     B, S, H, D = q_shape
     bq, bk = default_blocks(S)
-    blk = max(bq, bk)
-    resident = (2 * S * D * itemsize      # two full-seq operands
-                + 2 * 8 * S * 4           # lse + delta, 8 sublanes fp32
-                + 2 * 4 * blk * D * 4)    # double-buffered fp32 blocks
     return (S % bq == 0 and S % bk == 0 and S >= bq
-            and resident <= _VMEM_BUDGET)
+            and _flash_resident(S, D, v_dim or D, itemsize, max(bq, bk))
+            <= _FLASH_VMEM_BUDGET)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, scale: Optional[float] = None,
                     causal: bool = True, block_q: Optional[int] = None,
                     block_k: Optional[int] = None, interpret: bool = False):
-    """Exact attention, flash-style.  q: [B, S, H, D] → [B, S, H, D].
+    """Exact attention, flash-style.  q: [B, S, H, D] → [B, S, H, Dv].
+
+    The value width may differ from the key width (q, k ``[.., D]``, v
+    and the output ``[.., Dv]``: latent attention trains with keys of
+    nope + rope width and narrower values); the default scale is
+    ``D ** -0.5``.
 
     GQA-native: k/v may carry ``KV = H / rep`` heads ([B, S, KV, D]) and
     are indexed per-group inside the kernels — K/V HBM arrays, traffic

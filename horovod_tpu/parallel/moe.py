@@ -293,3 +293,192 @@ def moe_layer_hvd(tokens, router_kernel, expert_fn, expert_params, *,
         outs.append(out)
     record_dropped_tokens(dropped, layer)
     return outs, float(np.mean(auxes)) if auxes else 0.0, dropped
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k routing over the experts held here
+# ---------------------------------------------------------------------------
+
+_m_held = _obs.counter(
+    "hvd_moe_held_pairs_total",
+    "(token, expert) pairs routed to an expert this chip holds, summed "
+    "over steps (the work of its grouped products: over tokens x k it is "
+    "the share of the routed traffic that lands here)", ("layer",))
+_m_load = _obs.gauge(
+    "hvd_moe_expert_load_max_over_mean",
+    "pairs of the fullest held expert over the mean of the held experts "
+    "in the last recorded step (1.0 = even; the straggler that an "
+    "expert-parallel step waits for)", ("layer",))
+
+
+def record_held_pairs(expert_counts, layer: str = "0") -> None:
+    """Count one step's routed load into the per-layer metrics.
+
+    ``expert_counts``: pairs per held expert, ``[E_held]``.  Host-side,
+    after the step, as :func:`record_dropped_tokens`."""
+    import numpy as np
+    c = np.asarray(expert_counts, np.float64)
+    _m_held.labels(layer=str(layer)).inc(float(c.sum()))
+    if c.sum() > 0:
+        _m_load.labels(layer=str(layer)).set(float(c.max() / c.mean()))
+
+
+def topk_route(scores: jax.Array, bias: jax.Array, k: int, *,
+               renormalize: bool = True, scale: float = 1.0
+               ) -> tuple[jax.Array, jax.Array]:
+    """Top-k routing with a selection bias (DeepSeek-V3 style,
+    arXiv:2412.19437 section 2.1.2).
+
+    ``scores [T, E]`` are the gate's activations (sigmoid or softmax
+    already applied), ``bias [E]`` moves the selection only: the ``k``
+    experts a token takes are the top k of ``scores + bias``; their
+    weights are ``scores`` at those experts, divided by their sum under
+    ``renormalize``, times ``scale``.  Returns ``(experts [T, k] int32,
+    weights [T, k])``; the weights carry the gradient, the choice none.
+    """
+    _, experts = lax.top_k(lax.stop_gradient(scores + bias), k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if renormalize:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scale
+
+
+def _swiglu_tile(x, wg, wu, wd, wt):
+    """One tile's rows through one expert, weighted: ``[tile, D]``."""
+    hidden = jax.nn.silu(x @ wg) * (x @ wu)
+    return (hidden @ wd) * wt[:, None].astype(x.dtype)
+
+
+def _tile_operands(i, tile, tokens, rows, weights, tile_expert, experts):
+    idx = lax.dynamic_slice(rows, (i * tile,), (tile,))
+    wt = lax.dynamic_slice(weights, (i * tile,), (tile,))
+    # a padding row names token T: it reads as zeros and is dropped
+    # on the way back
+    x = jnp.take(tokens, idx, axis=0, mode="fill", fill_value=0)
+    w = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+        a, tile_expert[i], 0, keepdims=False), experts)
+    return idx, x, wt, w
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _grouped_experts(tokens, rows, weights, tile_expert, n_tiles, experts,
+                     tile: int):
+    """Sum over the listed (row, expert) pairs of weight x SwiGLU_expert.
+
+    The pairs lie sorted by expert, each expert's run padded to whole
+    tiles of ``tile`` rows, so a tile belongs to one expert: ``rows [M]``
+    names each slot's token (``T`` for padding), ``weights [M]`` its
+    routing weight, ``tile_expert [M / tile]`` each tile's expert and
+    ``n_tiles`` how many tiles are in use.  A loop over the tiles in use
+    gathers a tile's rows, runs them through that expert's three
+    matrices and adds the result back to the tokens' rows: the work is
+    that of the pairs held (to a tile), whatever the static bound ``M``,
+    and no buffer of ``M`` rows of activations exists."""
+    def body(i, out):
+        idx, x, wt, w = _tile_operands(i, tile, tokens, rows, weights,
+                                       tile_expert, experts)
+        y = _swiglu_tile(x, w["gate"], w["up"], w["down"], wt)
+        return out.at[idx].add(y, mode="drop", unique_indices=True)
+    return lax.fori_loop(0, n_tiles, body, jnp.zeros_like(tokens))
+
+
+def _grouped_fwd(tokens, rows, weights, tile_expert, n_tiles, experts, tile):
+    out = _grouped_experts(tokens, rows, weights, tile_expert, n_tiles,
+                           experts, tile)
+    return out, (tokens, rows, weights, tile_expert, n_tiles, experts)
+
+
+def _grouped_bwd(tile, res, d_out):
+    tokens, rows, weights, tile_expert, n_tiles, experts = res
+
+    def body(i, carry):
+        d_tok, d_wt, d_exp = carry
+        idx, x, wt, w = _tile_operands(i, tile, tokens, rows, weights,
+                                       tile_expert, experts)
+        dy = jnp.take(d_out, idx, axis=0, mode="fill", fill_value=0)
+        _, vjp = jax.vjp(_swiglu_tile, x, w["gate"], w["up"], w["down"], wt)
+        dx, dg, du, dd, dwt = vjp(dy)
+        d_tok = d_tok.at[idx].add(dx, mode="drop", unique_indices=True)
+        d_wt = lax.dynamic_update_slice(d_wt, dwt.astype(d_wt.dtype),
+                                        (i * tile,))
+        e = tile_expert[i]
+        d_exp = {k: d_exp[k].at[e].add(d.astype(d_exp[k].dtype))
+                 for k, d in (("gate", dg), ("up", du), ("down", dd))}
+        return d_tok, d_wt, d_exp
+
+    zeros = (jnp.zeros_like(tokens), jnp.zeros_like(weights),
+             jax.tree.map(jnp.zeros_like, experts))
+    d_tok, d_wt, d_exp = lax.fori_loop(0, n_tiles, body, zeros)
+    return d_tok, None, d_wt, None, None, d_exp
+
+
+_grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
+                   experts_held: dict, held_range: tuple[int, int],
+                   shared: Any = None, *, k: int, renormalize: bool = True,
+                   scale: float = 1.0, tile: int = 512):
+    """This chip's part of a dropless top-k expert layer.
+
+    ``tokens [T, D]``; ``router [D, E]`` keeps every expert's column and
+    ``bias [E]`` its selection bias (:func:`topk_route`, sigmoid gate in
+    float32); ``experts_held`` = ``{"gate", "up" [E_held, D, F], "down"
+    [E_held, F, D]}`` are experts ``held_range = (first, last + 1)`` of
+    the ``E``.  A token's pairs whose expert lies elsewhere contribute
+    nothing here: on the expert-parallel job they are computed by the
+    chips that hold them and summed by the exchange, which a single
+    chip's share runs without.  ``shared`` (``{"w_gate", "w_up",
+    "w_down"}``) is the shared expert, which every chip computes alike.
+
+    No capacity and no dropped token: the pairs held are sorted by
+    expert and run as grouped products (:func:`_grouped_experts`) under
+    the static bound ``T * min(k, E_held)`` that routing cannot pass.
+
+    Returns ``(out [T, D], stats)`` with ``stats = {"pairs_held": int32
+    scalar, "expert_counts": int32 [E_held]}``, traced values for
+    :func:`record_held_pairs`.
+    """
+    T, D = tokens.shape
+    first, last = held_range
+    E_held = last - first
+    assert experts_held["gate"].shape[0] == E_held, (held_range,
+                                                     experts_held["gate"].shape)
+    logits = jnp.einsum("td,de->te", tokens.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    experts, weights = topk_route(jax.nn.sigmoid(logits), bias, k,
+                                  renormalize=renormalize, scale=scale)
+
+    # Sort the pairs by held expert; a pair held elsewhere sorts last.
+    local = experts.reshape(-1) - first
+    held = (local >= 0) & (local < E_held)
+    key = jnp.where(held, local, E_held)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros((E_held + 1,), jnp.int32).at[key].add(1)[:E_held]
+    # Expert e's run starts at a tile boundary of the padded list.
+    padded = -(-counts // tile) * tile
+    starts = jnp.cumsum(padded) - padded
+    begins = jnp.cumsum(counts) - counts               # in the sorted list
+    M = -(-T * min(k, E_held) // tile) * tile + E_held * tile
+    sorted_key = key[order]
+    rank = jnp.arange(order.shape[0]) - begins[jnp.minimum(sorted_key,
+                                                           E_held - 1)]
+    slot = jnp.where(sorted_key < E_held,
+                     starts[jnp.minimum(sorted_key, E_held - 1)] + rank, M)
+    rows = jnp.full((M,), T, jnp.int32).at[slot].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    pair_w = jnp.zeros((M,), weights.dtype).at[slot].set(
+        weights.reshape(-1)[order], mode="drop")
+    n_tiles = jnp.sum(padded) // tile
+    tile_expert = jnp.clip(jnp.searchsorted(
+        jnp.cumsum(padded), jnp.arange(M // tile) * tile, side="right"),
+        0, E_held - 1).astype(jnp.int32)
+
+    out = _grouped_experts(tokens, rows, pair_w, tile_expert, n_tiles,
+                           experts_held, tile)
+    if shared is not None:
+        hidden = jax.nn.silu(tokens @ shared["w_gate"]) * \
+            (tokens @ shared["w_up"])
+        out = out + hidden @ shared["w_down"]
+    return out, {"pairs_held": jnp.sum(counts), "expert_counts": counts}

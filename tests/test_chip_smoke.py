@@ -151,3 +151,75 @@ def test_kernels_compile_for_v5e(monkeypatch):
         FA.paged_attention, spec((R, H, D)), pool, pool,
         spec((), jnp.int32), spec((R, n_cols), jnp.int32),
         spec((R,), jnp.int32)) == 1
+
+
+def test_kimi_kernels_compile_for_v5e(monkeypatch):
+    """The KDA forward kernel through Mosaic at the Kimi-Linear cell's
+    shape (B4 x S8192, 32 heads of 128)."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from horovod_tpu.ops import kda
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this machine
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    sharding = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def compiled_text(fn, *args):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    B, S, H, K = 4, 8192, 32, 128
+    text = compiled_text(
+        lambda q, k, v, g, b: kda.chunk_kda(q, k, v, g, b, kda.CHUNK, False),
+        spec((B, S, H, K)), spec((B, S, H, K)), spec((B, S, H, K)),
+        spec((B, S, H, K), jnp.float32), spec((B, S, H), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "hvd_kda_fwd" in text
+
+
+# (S, heads, kv heads, key width, value width, asks for VMEM).  Latent
+# attention at the Kimi-Linear cell's shape; a Llama block at 8k, which
+# stays under the default scoped VMEM and hands the compiler no limit;
+# and at 16k, which needs more than the default.
+@pytest.mark.parametrize("S,H,KV,D,Dv,asks", [
+    (8192, 32, 32, 192, 128, True),
+    (8192, 32, 8, 128, 128, False),
+    (16384, 32, 8, 128, 128, True),
+], ids=["mla-8k", "llama-8k", "llama-16k"])
+def test_flash_kernels_compile_for_v5e_at_long_sequences(
+        monkeypatch, S, H, KV, D, Dv, asks):
+    """The three flash kernels through Mosaic where their resident set
+    nears or passes the default scoped VMEM: :func:`FA.supported` and the
+    limit the kernels ask for go by one estimate."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from horovod_tpu.ops import flash_attention as FA
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this machine
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    sharding = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+
+    B = 1
+    assert FA.supported((B, S, H, D), 2, Dv)
+    assert bool(FA._compiler_params(S, D, Dv, 2, 512)) == asks
+    assert not FA._compiler_params(2048, 128, 128, 2, 512)
+    grads = jax.grad(
+        lambda q, k, v: FA.flash_attention(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+    text = jax.jit(grads).lower(
+        spec((B, S, H, D)), spec((B, S, KV, D)), spec((B, S, KV, Dv))
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
