@@ -37,13 +37,46 @@ uses the same tree: ``T <- T - T (N * mask_m) T`` merges the inverses of
 two blocks of ``m`` into that of their block of ``2m`` (block forward
 substitution, products only).
 
-The forward is a Pallas kernel (``hvd_kda_fwd``): one grid row per
-(sequence, head), the chunks on an ``arbitrary`` axis, the float32 state
-in VMEM scratch all the way, so that it never goes to HBM between chunks.
-The backward is the autodiff of :func:`chunk_kda_jnp`, the same
-arithmetic as a ``lax.scan`` over chunks in plain ``jnp``, under the same
-``custom_vjp`` (a reverse kernel is future work; the kernel therefore
-writes no per-chunk states).  Off the TPU the kernel runs interpreted.
+Both directions are Pallas kernels under one ``custom_vjp``, around one
+function of values, :func:`_chunk`: ``(S0, q, k, v, g, beta) -> (S1, O)``
+for one chunk of one head.  The forward (``hvd_kda_fwd``) has one grid
+row per (sequence, head), the chunks on an ``arbitrary`` axis and the
+float32 state in VMEM scratch all the way, so that it never goes to HBM
+between chunks.  It comes in two calls of one kernel.  :func:`chunk_kda`
+itself is the stateless one.  The ``custom_vjp``'s forward rule is the
+state-writing one: it also stores the state each chunk starts from,
+``[B, H, S / chunk, K, V]`` float32 (``S0`` before the update; the last
+chunk's ``S1`` is read by nothing), as the residual the backward walks
+over: 1.07 GB a layer at B4 x S8192 x H32 x K128 x V128, alive from that
+layer's second forward to the end of its backward.  The rule is given to
+``defvjp`` with ``optimize_remat``, so under a ``jax.checkpoint`` around
+the layer the first forward, whose residuals nobody keeps, is the
+stateless call and only remat's second forward writes.  Both calls
+return the same ``o`` bit for bit.
+
+The backward (``hvd_kda_bwd``) has a grid over (sequence, block of
+heads, chunk) and walks the chunks last to first by its index maps.  The
+state's cotangent, ``[heads in block, K, V]`` float32, lives in VMEM
+scratch from the last chunk (zero there) to the first.  A grid step
+loads a chunk's operands, its ``S0`` and its ``dO``, and takes
+``jax.vjp`` of :func:`_chunk` inside the kernel body with cotangents
+``(dS1, dO)``: the chunk's intermediates are made again and transposed,
+float32 at ``highest`` throughout, and ``dq, dk, dv, dg`` (float32),
+``dbeta`` and the new state cotangent are written.  Four pieces of the
+chunk carry a transpose of their own (:func:`_inverse`, :func:`_pairs`,
+:func:`_diagonal`, :func:`_decayed`) where autodiff's would spend
+products the derivative does not need.  ``dbeta`` has a layout of its
+own, ``[B, H / block, S, block]``: heads lie on a ``parallel`` axis, and
+a block of ``beta``'s ``[C, H]`` rows would be written by every block of
+heads.  The block of heads (:func:`_head_block`) is the most heads that
+divide ``H``, keep a block's columns whole lane tiles and fit the
+default scoped VMEM by :func:`_bwd_resident` (8 of the published 32).  A
+grid step runs them one after another in a loop, each on its own 128-lane
+columns of the blocks, so the chunk is traced and compiled once however
+many they are.  Off the TPU both kernels run interpreted.
+:func:`chunk_kda_jnp` is the same arithmetic as a ``lax.scan`` in plain
+``jnp``: what the tests compare the kernels' gradients with, and in no
+training step.
 """
 
 from __future__ import annotations
@@ -116,24 +149,16 @@ def _chunk_step(S0, q, k, v, g, beta):
     return S1, O
 
 
-def chunk_kda_jnp(q, k, v, g, beta, chunk: int = CHUNK, group: int = 16):
+def chunk_kda_jnp(q, k, v, g, beta, chunk: int = CHUNK):
     """The chunked form in plain ``jnp``: a ``lax.scan`` over chunks,
     every (sequence, head) batched inside a step.  ``q, k, g [B, S, H,
     K]``, ``v [B, S, H, V]``, ``beta [B, S, H]``; ``g`` is the log decay
-    (at most 0).  Returns ``o [B, S, H, V]`` in ``v``'s dtype.
-
-    Written for its gradient, which is the backward of :func:`chunk_kda`:
-    a step slices its chunk out of the operands where they lie (no
-    chunk-major float32 copy of them is made), and the scan is
-    checkpointed at two levels, ``group`` chunks inside a checkpointed
-    outer step, so that the backward keeps ``n / group + group`` states
-    instead of ``n`` (at B4 x S8192 x H32: 0.2 GB, not 1.07) for one more
-    run of the forward."""
+    (at most 0).  Returns ``o [B, S, H, V]`` in ``v``'s dtype.  The plain
+    form that the kernels' results and gradients are tested against."""
     B, S, H, K = q.shape
     if S % chunk:
         raise ValueError(f"sequence {S} is not a multiple of chunk {chunk}")
     n = S // chunk
-    group = next(c for c in range(min(group, n), 0, -1) if n % c == 0)
 
     def one(S0, i):
         part = lambda x: jnp.swapaxes(lax.dynamic_slice_in_dim(
@@ -142,14 +167,20 @@ def chunk_kda_jnp(q, k, v, g, beta, chunk: int = CHUNK, group: int = 16):
                             part(beta[..., None])[..., 0])
         return S1, jnp.swapaxes(O, 1, 2).astype(v.dtype)        # [B, C, H, V]
 
-    def some(S0, ids):
-        return lax.scan(jax.checkpoint(one), S0, ids)
-
     S0 = jnp.zeros((B, H, K, v.shape[-1]), F32)
-    _, o = lax.scan(jax.checkpoint(some), S0,
-                    jnp.arange(n).reshape(n // group, group))
-    # o [n / group, group, B, C, H, V]
-    return jnp.moveaxis(o.reshape(n, B, chunk, H, -1), 0, 1).reshape(v.shape)
+    _, o = lax.scan(one, S0, jnp.arange(n))                # [n, B, C, H, V]
+    return jnp.moveaxis(o, 0, 1).reshape(v.shape)
+
+
+def _token_step(St, x):
+    """One token of the recurrence: ``St [B, H, K, V]`` and the token's
+    ``(q, k, v, g, beta)`` -> the next state and ``o_t``."""
+    qt, kt, vt, gt, bt = x                         # [B, H, .]
+    St = jnp.exp(gt)[..., None] * St
+    u = bt[..., None] * (vt - jnp.einsum("bhk,bhkv->bhv", kt, St,
+                                         precision=_HI))
+    St = St + kt[..., None] * u[..., None, :]
+    return St, jnp.einsum("bhk,bhkv->bhv", qt, St, precision=_HI)
 
 
 def recurrent_kda(q, k, v, g, beta):
@@ -157,17 +188,8 @@ def recurrent_kda(q, k, v, g, beta):
     forms are tested against."""
     B, S, H, K = q.shape
     f = lambda x: jnp.moveaxis(x.astype(F32), 1, 0)
-
-    def step(St, x):
-        qt, kt, vt, gt, bt = x                     # [B, H, .]
-        St = jnp.exp(gt)[..., None] * St
-        u = bt[..., None] * (vt - jnp.einsum("bhk,bhkv->bhv", kt, St,
-                                             precision=_HI))
-        St = St + kt[..., None] * u[..., None, :]
-        return St, jnp.einsum("bhk,bhkv->bhv", qt, St, precision=_HI)
-
     S0 = jnp.zeros((B, H, K, v.shape[-1]), F32)
-    _, o = lax.scan(step, S0, (f(q), f(k), f(v), f(g), f(beta)))
+    _, o = lax.scan(_token_step, S0, (f(q), f(k), f(v), f(g), f(beta)))
     return jnp.moveaxis(o, 0, 1).astype(v.dtype)
 
 
@@ -185,60 +207,179 @@ _NT = (((1,), (1,)), ((), ()))     # a @ b.T
 _TN = (((0,), (0,)), ((), ()))     # a.T @ b
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state, *,
-                chunk: int):
-    from jax.experimental import pallas as pl
+def _own_transpose(fwd, bwd):
+    """``custom_vjp`` for a piece of the chunk whose transpose is cheaper
+    written out than derived: ``fwd`` returns the result and what ``bwd``
+    keeps."""
+    def attach(primal):
+        f = jax.custom_vjp(primal)
+        f.defvjp(fwd, bwd)
+        return f
+    return attach
 
-    C = chunk
-    h = pl.program_id(1)
 
-    @pl.when(pl.program_id(2) == 0)
-    def _reset():
-        state[...] = jnp.zeros_like(state)
+def _iotas(n: int):
+    return (lax.broadcasted_iota(jnp.int32, (n, n), 0),
+            lax.broadcasted_iota(jnp.int32, (n, n), 1))
 
-    q = q_ref[...].astype(F32)                      # [C, K]
-    k = k_ref[...].astype(F32)
-    v = v_ref[...].astype(F32)                      # [C, V]
-    g = g_ref[...].astype(F32)
-    # beta arrives as the chunk's [C, H] rows; keep this head's column.
-    col = lax.broadcasted_iota(jnp.int32, beta_ref.shape, 1)
-    beta = jnp.sum(jnp.where(col == h, beta_ref[...].astype(F32), 0.0),
-                   axis=1, keepdims=True)           # [C, 1]
-    S0 = state[...]
 
-    t = lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    s = lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    eye = (s == t).astype(F32)
+# The kernels are bound by the matrix unit's instructions, one a cycle: a
+# product at ``highest`` is six passes, and a pass of ``[M, K] x [K, N]``
+# (N to 128) is M/8 pushes and K/8 latches whatever of the unit it fills.
+# So the pieces below count products, not FLOPs: what autodiff would
+# transpose product by product is here written with fewer, or with none.
+
+def _diagonal_fwd(q, k):
+    return _diagonal(q, k), (q, k)
+
+
+def _diagonal_bwd(res, d):
+    q, k = res
+    t, s = _iotas(q.shape[0])
+    d = jnp.sum(jnp.where(s == t, d, 0.0), axis=1, keepdims=True)
+    return d * k, d * q
+
+
+@_own_transpose(_diagonal_fwd, _diagonal_bwd)
+def _diagonal(q, k):
+    """``Diag(q k^T)``, the pairs ``s == t`` of ``B``."""
+    t, s = _iotas(q.shape[0])
+    return _dot(q, k, _NT) * (s == t).astype(F32)
+
+
+def _pairs_fwd(ku, qu, kl, mask):
+    # one product for both: they share ``kl``
+    both = jnp.concatenate([ku, qu], axis=0)
+    P = _dot(both, kl, _NT)
+    C = ku.shape[0]
+    return (mask * P[:C], mask * P[C:]), (both, kl, mask)
+
+
+def _pairs_bwd(res, d):
+    both, kl, mask = res
+    D = jnp.concatenate([mask * d[0], mask * d[1]], axis=0)     # [2C, C]
+    dboth = _dot(D, kl)
+    C = kl.shape[0]
+    return dboth[:C], dboth[C:], _dot(D, both, _TN), None
+
+
+@_own_transpose(_pairs_fwd, _pairs_bwd)
+def _pairs(ku, qu, kl, mask):
+    """One level's part of ``A`` and of ``B``."""
+    return mask * _dot(ku, kl, _NT), mask * _dot(qu, kl, _NT)
+
+
+def _inverse_fwd(N):
+    T = _inverse(N)
+    return T, T
+
+
+def _inverse_bwd(T, dT):
+    # the inverse's own transpose: two products, not the tree's 24
+    return (-_dot(_dot(T, dT, _TN), T, _NT),)
+
+
+@_own_transpose(_inverse_fwd, _inverse_bwd)
+def _inverse(N):
+    """``(I + N)^-1`` for a strictly lower triangular ``N [C, C]``, by
+    the tree of the module's text: twelve products, each waiting for the
+    last."""
+    t, s = _iotas(N.shape[0])
+    T = (s == t).astype(F32)
+    for m in _levels(N.shape[0]):
+        T = T - _dot(T, _dot(N * _level_mask(t, s, m).astype(F32), T))
+    return T
+
+
+def _diag_of(e):
+    """``Diag(e)`` of a row ``e [1, K]``."""
+    K = e.shape[1]
+    r, c = _iotas(K)
+    return jnp.where(r == c, jnp.broadcast_to(e, (K, K)), 0.0)
+
+
+def _decayed_fwd(e, S0):
+    return _decayed(e, S0), (e, S0)
+
+
+def _decayed_bwd(res, dS1):
+    e, S0 = res
+    K = e.shape[1]
+    r, c = _iotas(K)
+    column = jnp.sum(_diag_of(e), axis=1, keepdims=True)            # [K, 1]
+    de = jnp.sum(dS1 * S0, axis=1, keepdims=True)                   # [K, 1]
+    de = jnp.sum(jnp.where(r == c, jnp.broadcast_to(de, (K, K)), 0.0),
+                 axis=0, keepdims=True)                             # [1, K]
+    return de, column * dS1
+
+
+@_own_transpose(_decayed_fwd, _decayed_bwd)
+def _decayed(e, S0):
+    """``Diag(e) S0``: row ``c`` of ``S0 [K, V]`` scaled by ``e[0, c]``;
+    as a product with the diagonal matrix, so that no ``[1, K] -> [K,
+    1]`` relayout is asked for."""
+    return _dot(_diag_of(e), S0)
+
+
+def _chunk(S0, q, k, v, g, beta):
+    """One chunk of one head on values, as the kernels run it: ``S0 [K,
+    V]``; ``q, k, g [C, K]``; ``v [C, V]``; ``beta [C, 1]``; all float32.
+    Returns ``(S1, O)``.  :func:`_chunk_step`'s arithmetic in the forms
+    Mosaic lowers: two-dimensional products only, ``R`` picked by a
+    one-hot product."""
+    C = q.shape[0]
+    t, s = _iotas(C)
     G = _dot((s <= t).astype(F32), g)
     A = jnp.zeros((C, C), F32)
-    Bm = _dot(q, k, _NT) * eye
-    masks = {m: _level_mask(t, s, m).astype(F32) for m in _levels(C)}
+    Bm = _diagonal(q, k)
     for m in _levels(C):
         # R: G at row (t // 2m) * 2m + m, picked by a one-hot product.
         R = _dot((s == (t // (2 * m)) * (2 * m) + m).astype(F32), G)
         up = jnp.exp(jnp.minimum(G - R, 0.0))
         kl = k * jnp.exp(jnp.minimum(R - G, 0.0))
-        A = A + masks[m] * _dot(k * up, kl, _NT)
-        Bm = Bm + masks[m] * _dot(q * up, kl, _NT)
-    N = beta * A
-    T = eye
-    for m in _levels(C):
-        T = T - _dot(T, _dot(N * masks[m], T))
+        Am, Bmm = _pairs(k * up, q * up, kl,
+                         _level_mask(t, s, m).astype(F32))
+        A, Bm = A + Am, Bm + Bmm
     eG = jnp.exp(G)
-    U = _dot(T, beta * (v - _dot(k * eG, S0)))
-    o_ref[...] = (_dot(q * eG, S0) + _dot(Bm, U)).astype(o_ref.dtype)
+    U = _dot(_inverse(beta * A), beta * (v - _dot(k * eG, S0)))
+    O = _dot(q * eG, S0) + _dot(Bm, U)
     Gc = G[C - 1:C, :]                              # [1, K]
-    # Diag(exp(Gc)) S0: scale row c of S0 by exp(Gc[c]); as a product
-    # with the diagonal matrix, so that no [1, K] -> [K, 1] relayout is
-    # asked for.
-    K = q.shape[1]
-    kk = (lax.broadcasted_iota(jnp.int32, (K, K), 0)
-          == lax.broadcasted_iota(jnp.int32, (K, K), 1))
-    decay = jnp.where(kk, jnp.broadcast_to(jnp.exp(Gc), (K, K)), 0.0)
-    state[...] = _dot(decay, S0) + _dot(k * jnp.exp(Gc - G), U, _TN)
+    S1 = _decayed(jnp.exp(Gc), S0) + _dot(k * jnp.exp(Gc - G), U, _TN)
+    return S1, O
 
 
-def _kda_forward(q, k, v, g, beta, *, chunk: int, interpret: bool):
+def _column(beta_ref, h):
+    """``beta`` arrives as the chunk's ``[C, H]`` rows; head ``h``'s
+    column of it, ``[C, 1]``."""
+    col = lax.broadcasted_iota(jnp.int32, beta_ref.shape, 1)
+    return jnp.sum(jnp.where(col == h, beta_ref[...].astype(F32), 0.0),
+                   axis=1, keepdims=True)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest):
+    """``rest``: the output of the states, in the call that writes them,
+    and the state's scratch."""
+    from jax.experimental import pallas as pl
+
+    *s0_refs, state = rest
+
+    @pl.when(pl.program_id(2) == 0)
+    def _reset():
+        state[...] = jnp.zeros_like(state)
+
+    S0 = state[...]
+    for s0_ref in s0_refs:
+        s0_ref[...] = S0
+    f = lambda ref: ref[...].astype(F32)
+    state[...], O = _chunk(S0, f(q_ref), f(k_ref), f(v_ref), f(g_ref),
+                           _column(beta_ref, pl.program_id(1)))
+    o_ref[...] = O.astype(o_ref.dtype)
+
+
+def _kda_forward(q, k, v, g, beta, *, chunk: int, interpret: bool,
+                 states: bool = False):
+    """``o [B, S, H, V]``; with ``states`` also the state each chunk
+    starts from, ``[B, H, S / chunk, K, V]`` float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -250,20 +391,150 @@ def _kda_forward(q, k, v, g, beta, *, chunk: int, interpret: bool):
     # (chunk, X) at column block h, so nothing is transposed in HBM.
     flat = lambda x: x.reshape(B, S, -1)
     blk = lambda X: pl.BlockSpec((None, chunk, X), lambda b, h, n: (b, n, h))
+    out_specs = [blk(V)]
+    out_shape = [jax.ShapeDtypeStruct((B, S, H * V), v.dtype)]
+    if states:
+        out_specs.append(pl.BlockSpec((None, None, None, K, V),
+                                      lambda b, h, n: (b, h, n, 0, 0)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((B, H, S // chunk, K, V), F32))
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk),
+        _fwd_kernel,
         grid=(B, H, S // chunk),
         in_specs=[blk(K), blk(K), blk(V), blk(K),
                   pl.BlockSpec((None, chunk, H), lambda b, h, n: (b, n, 0))],
-        out_specs=blk(V),
-        out_shape=jax.ShapeDtypeStruct((B, S, H * V), v.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((K, V), F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="hvd_kda_fwd",
     )(flat(q), flat(k), flat(v), flat(g.astype(F32)), beta.astype(F32))
-    return out.reshape(B, S, H, V)
+    o = out[0].reshape(B, S, H, V)
+    return (o, out[1]) if states else o
+
+
+# ---------------------------------------------------------------------------
+# the reverse kernel
+# ---------------------------------------------------------------------------
+
+# The scoped VMEM a Mosaic kernel gets on the v5e unasked; the reverse
+# kernel is compiled under it and carries the heads that fit.
+_SCOPED_VMEM = 16 << 20
+
+
+def _bwd_resident(heads: int, chunk: int, K: int, V: int,
+                  itemsize: int) -> int:
+    """VMEM bytes a grid step of the reverse kernel holds for ``heads``
+    heads, in tiles of ``[chunk, max(K, V)]`` float32 padded to whole
+    128-lane tiles: a head's blocks in two buffers each (``q, k, dq, dk``
+    and ``v, dO, dv`` in the operands' type, ``g, dg`` and the state in
+    float32), its state's cotangent and a tile more; and the 165 tiles
+    one head's chunk keeps between its forward and its transpose, which
+    the heads pass through one after another.  Checked against the
+    compiler: ahead-of-time compiles for a v5e (jax 0.9.0, libtpu
+    0.0.34; chunk 64, K = V = 128, bf16) need 5.5, 6.2, 7.1, 9.3 and
+    13.6 MiB for 1, 2, 4, 8 and 16 heads (5.7, 6.3, 7.4, 9.7, 14.2
+    here)."""
+    lanes = lambda d: -(-d // 128) * 128
+    k, v = lanes(K), lanes(V)
+    tile = chunk * max(k, v, lanes(chunk)) * 4
+    blocks = chunk * (4 * k + 3 * v) * itemsize + 2 * chunk * k * 4 \
+        + K * v * 4
+    return heads * (2 * blocks + K * v * 4 + tile) + 165 * tile
+
+
+def _head_block(H: int, chunk: int, K: int, V: int, itemsize: int) -> int:
+    """Heads a grid step of the reverse kernel carries: the most that
+    divide ``H``, keep a block's columns whole lane tiles (or are all of
+    ``H``) and fit :data:`_SCOPED_VMEM` by :func:`_bwd_resident` and half
+    as much again; one if none does.  On the v5e at the published shape
+    1 to 32 heads a step differ by under 1% (a head takes 10 us, a grid
+    step's own cost 2% of that), so the rule only has to stay inside the
+    VMEM every kernel gets."""
+    whole = lambda n: n == H or (n * K) % 128 == (n * V) % 128 == 0
+    return max([n for n in range(1, H + 1) if H % n == 0 and whole(n)
+                and _bwd_resident(n, chunk, K, V, itemsize) * 3 // 2
+                <= _SCOPED_VMEM], default=1)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *,
+                heads: int):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)                 # the last chunk
+    def _reset():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    K, V = s0_ref.shape[-2:]
+    col = lax.broadcasted_iota(jnp.int32, dbeta_ref.shape, 1)
+    first = pl.program_id(1) * heads                # of this block's heads
+
+    def head(j, dbeta):
+        ks = pl.ds(pl.multiple_of(j * K, K), K)
+        vs = pl.ds(pl.multiple_of(j * V, V), V)
+        f = lambda ref, sl: ref[:, sl].astype(F32)
+        _, vjp = jax.vjp(
+            _chunk, s0_ref[j], f(q_ref, ks), f(k_ref, ks), f(v_ref, vs),
+            f(g_ref, ks), _column(beta_ref, first + j))
+        dstate[j], dq, dk, dv, dg, db = vjp((dstate[j], f(do_ref, vs)))
+        dq_ref[:, ks] = dq.astype(dq_ref.dtype)
+        dk_ref[:, ks] = dk.astype(dk_ref.dtype)
+        dv_ref[:, vs] = dv.astype(dv_ref.dtype)
+        dg_ref[:, ks] = dg
+        return jnp.where(col == j, db, dbeta)
+
+    dbeta_ref[...] = lax.fori_loop(0, heads, head,
+                                   jnp.zeros(dbeta_ref.shape, F32))
+
+
+def _kda_backward(q, k, v, g, beta, states, do, *, chunk: int,
+                  interpret: bool):
+    """The five input cotangents from ``do`` and the states the forward
+    wrote: the chunks walked last to first."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, S, H, K = q.shape
+    V = v.shape[-1]
+    n = S // chunk
+    hb = _head_block(H, chunk, K, V, q.dtype.itemsize)
+    flat = lambda x: x.reshape(B, S, -1)
+    blk = lambda X: pl.BlockSpec((None, chunk, hb * X),
+                                 lambda b, h, i: (b, n - 1 - i, h))
+    dq, dk, dv, dg, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=hb),
+        grid=(B, H // hb, n),
+        in_specs=[blk(K), blk(K), blk(V), blk(K),
+                  pl.BlockSpec((None, chunk, H),
+                               lambda b, h, i: (b, n - 1 - i, 0)),
+                  pl.BlockSpec((None, hb, None, K, V),
+                               lambda b, h, i: (b, h, n - 1 - i, 0, 0)),
+                  blk(V)],
+        out_specs=[blk(K), blk(K), blk(V), blk(K),
+                   # heads lie on a parallel axis: a block of beta's own
+                   # [C, H] layout would be written by H / hb grid steps
+                   pl.BlockSpec((None, None, chunk, hb),
+                                lambda b, h, i: (b, h, n - 1 - i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, S, H * K), q.dtype),
+                   jax.ShapeDtypeStruct((B, S, H * K), k.dtype),
+                   jax.ShapeDtypeStruct((B, S, H * V), v.dtype),
+                   jax.ShapeDtypeStruct((B, S, H * K), F32),
+                   jax.ShapeDtypeStruct((B, H // hb, S, hb), F32)],
+        scratch_shapes=[pltpu.VMEM((hb, K, V), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_SCOPED_VMEM),
+        interpret=interpret,
+        name="hvd_kda_bwd",
+    )(flat(q), flat(k), flat(v), flat(g.astype(F32)), beta.astype(F32),
+      states, flat(do))
+    shape = lambda x, like: x.reshape(like.shape).astype(like.dtype)
+    dbeta = jnp.moveaxis(dbeta, 1, 2).reshape(beta.shape)
+    return (shape(dq, q), shape(dk, k), shape(dv, v), shape(dg, g),
+            dbeta.astype(beta.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -271,9 +542,13 @@ def _kda_forward(q, k, v, g, beta, *, chunk: int, interpret: bool):
 # ---------------------------------------------------------------------------
 
 def _off_tpu() -> bool:
-    """Where the kernel has to run interpreted (a test or a compile for a
-    described chip patches this, as ``llama._flash_backend``)."""
+    """Where the kernels have to run interpreted (a test or a compile for
+    a described chip patches this, as ``llama._flash_backend``)."""
     return jax.default_backend() != "tpu"
+
+
+def _interpreted(interpret: Optional[bool]) -> bool:
+    return _off_tpu() if interpret is None else interpret
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -282,19 +557,20 @@ def chunk_kda(q, k, v, g, beta, chunk: int = CHUNK,
     """``o`` of the recurrence above.  ``q, k, g [B, S, H, K]`` (``g`` the
     log decay), ``v [B, S, H, V]``, ``beta [B, S, H]`` -> ``[B, S, H, V]``
     in ``v``'s dtype.  ``interpret`` None: interpreted off the TPU."""
-    if interpret is None:
-        interpret = _off_tpu()
-    return _kda_forward(q, k, v, g, beta, chunk=chunk, interpret=interpret)
+    return _kda_forward(q, k, v, g, beta, chunk=chunk,
+                        interpret=_interpreted(interpret))
 
 
 def _fwd_rule(q, k, v, g, beta, chunk, interpret):
-    return chunk_kda(q, k, v, g, beta, chunk, interpret), (q, k, v, g, beta)
+    o, states = _kda_forward(q, k, v, g, beta, chunk=chunk, states=True,
+                             interpret=_interpreted(interpret))
+    return o, (q, k, v, g, beta, states)
 
 
 def _bwd_rule(chunk, interpret, res, do):
-    _, vjp = jax.vjp(functools.partial(chunk_kda_jnp, chunk=chunk), *res)
-    return vjp(do)
+    return _kda_backward(*res, do, chunk=chunk,
+                         interpret=_interpreted(interpret))
 
 
-chunk_kda.defvjp(_fwd_rule, _bwd_rule)
+chunk_kda.defvjp(_fwd_rule, _bwd_rule, optimize_remat=True)
 

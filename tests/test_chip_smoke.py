@@ -154,8 +154,10 @@ def test_kernels_compile_for_v5e(monkeypatch):
 
 
 def test_kimi_kernels_compile_for_v5e(monkeypatch):
-    """The KDA forward kernel through Mosaic at the Kimi-Linear cell's
-    shape (B4 x S8192, 32 heads of 128)."""
+    """The KDA kernels through Mosaic at the Kimi-Linear cell's shape (B4
+    x S8192, 32 heads of 128): the stateless forward, and the gradient,
+    which is the state-writing forward and the reverse kernel, within
+    the VMEM they ask for."""
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from horovod_tpu.ops import kda
@@ -175,12 +177,28 @@ def test_kimi_kernels_compile_for_v5e(monkeypatch):
         return jax.jit(fn).lower(*args).compile().as_text()
 
     B, S, H, K = 4, 8192, 32, 128
-    text = compiled_text(
-        lambda q, k, v, g, b: kda.chunk_kda(q, k, v, g, b, kda.CHUNK, False),
-        spec((B, S, H, K)), spec((B, S, H, K)), spec((B, S, H, K)),
-        spec((B, S, H, K), jnp.float32), spec((B, S, H), jnp.float32))
+    args = (spec((B, S, H, K)), spec((B, S, H, K)), spec((B, S, H, K)),
+            spec((B, S, H, K), jnp.float32), spec((B, S, H), jnp.float32))
+    core = lambda *a: kda.chunk_kda(*a, kda.CHUNK, False)
+    text = compiled_text(core, *args)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "hvd_kda_fwd" in text
+
+    text = compiled_text(jax.grad(
+        lambda *a: core(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4)), *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "hvd_kda_fwd" in text and "hvd_kda_bwd" in text
+    assert f"f32[{B},{H},{S // kda.CHUNK},{K},{K}]" in text     # the states
+    assert " while(" not in text
+    # Neither kernel asks for more than the default scoped VMEM: the
+    # forward for nothing, the reverse kernel for the default itself,
+    # under which it carries the heads that fit by its own estimate (the
+    # compile above held it to that).
+    heads = kda._head_block(H, kda.CHUNK, K, K, 2)
+    assert 1 < heads < H
+    assert kda._bwd_resident(heads, kda.CHUNK, K, K, 2) * 3 // 2 \
+        <= kda._SCOPED_VMEM == 16 << 20
 
 
 # (S, heads, kv heads, key width, value width, asks for VMEM).  Latent

@@ -48,12 +48,12 @@ def _close(a, b, tol):
 
 # -- the KDA core -------------------------------------------------------------
 
-def _kda_inputs(strong: bool, B=2, S=64, H=2, K=16):
+def _kda_inputs(strong: bool, B=2, S=64, H=2, K=16, V=16):
     ks = jax.random.split(jax.random.PRNGKey(7), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(jax.random.normal(ks[0], (B, S, H, K))) * K ** -0.5
     k = unit(jax.random.normal(ks[1], (B, S, H, K)))
-    v = jax.random.normal(ks[2], (B, S, H, K))
+    v = jax.random.normal(ks[2], (B, S, H, V))
     a = jax.random.uniform(ks[3], (H,), minval=1.0, maxval=16.0)
     pre = jax.random.normal(ks[4], (B, S, H, K))
     pre = 3 * pre + 4 if strong else pre - 4
@@ -62,18 +62,30 @@ def _kda_inputs(strong: bool, B=2, S=64, H=2, K=16):
     return q, k, v, g, beta
 
 
-FORMS = {"jnp": lambda *a: kda.chunk_kda_jnp(*a, chunk=16, group=2),
+FORMS = {"jnp": lambda *a: kda.chunk_kda_jnp(*a, chunk=16),
          "kernel": lambda *a: kda.chunk_kda(*a, 16)}
+# What the reverse kernel's layout has to get right, beside the base
+# case: heads that take more than one grid step of its head block (so
+# ``dbeta``'s blocks and the states' are found per block), a state's
+# cotangent carried across eight chunks, and values wider than keys.
+SHAPES = {"base": {}, "heads6": dict(H=6, B=1, K=128, V=128),
+          "chunks8": dict(S=128, B=1), "wide-v": dict(V=32, B=1)}
 
 
+@pytest.mark.parametrize("shape", list(SHAPES))
 @pytest.mark.parametrize("decay", ["weak", "strong"])
 @pytest.mark.parametrize("form", sorted(FORMS))
-def test_chunk_kda_matches_the_recurrence(form, decay):
-    """Outputs and all five input gradients.  Strong: a chunk's decays
-    sum to under -200, where exp(-G) overflows float32 (at 88)."""
-    args = _kda_inputs(decay == "strong")
+def test_chunk_kda_matches_the_recurrence(form, decay, shape, monkeypatch):
+    """Outputs and all five input gradients; the ``kernel`` form's are
+    ``hvd_kda_bwd``'s.  Strong: a chunk's decays sum to under -200, where
+    exp(-G) overflows float32 (at 88)."""
+    args = _kda_inputs(decay == "strong", **SHAPES[shape])
+    B, S, H, K = args[0].shape
+    if shape == "heads6":       # two grid steps of three heads each
+        monkeypatch.setattr(kda, "_SCOPED_VMEM", 4 << 20)
+        assert kda._head_block(H, 16, K, K, 4) == 3
     if decay == "strong":
-        assert float(args[3].reshape(2, 4, 16, 2, 16).sum(2).min()) < -200
+        assert float(args[3].reshape(B, S // 16, 16, H, K).sum(2).min()) < -200
     w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
     both = lambda fn: jax.value_and_grad(
         lambda *x: jnp.sum(fn(*x) * w), argnums=(0, 1, 2, 3, 4))(*args)
@@ -82,6 +94,44 @@ def test_chunk_kda_matches_the_recurrence(form, decay):
     for a, b in zip(got_g, want_g):
         _close(a, b, 2e-4)
     _close(FORMS[form](*args), kda.recurrent_kda(*args), 1e-4)
+
+
+def test_forward_writes_the_state_each_chunk_starts_from():
+    """The state-writing call of ``hvd_kda_fwd``: its states are the
+    recurrence's state before each chunk's first token, and its ``o`` is
+    the stateless call's bit for bit."""
+    args = _kda_inputs(False, S=128, H=3, V=32)
+    o, states = kda._kda_forward(*args, chunk=16, interpret=True,
+                                 states=True)
+    assert jnp.array_equal(
+        o, kda._kda_forward(*args, chunk=16, interpret=True))
+    assert states.shape == (2, 3, 8, 16, 32) and states.dtype == jnp.float32
+
+    f32 = lambda x: jnp.moveaxis(x.astype(jnp.float32), 1, 0)
+    _, before = jax.lax.scan(
+        lambda St, x: (kda._token_step(St, x)[0], St),
+        jnp.zeros((2, 3, 16, 32)), jax.tree.map(f32, args))  # [S, B, H, K, V]
+    assert float(jnp.max(jnp.abs(before[16]))) > 0
+    _close(states, jnp.moveaxis(before[::16], 0, 2), 1e-5)
+
+
+def test_gradient_lowers_to_the_reverse_kernel_and_no_scan():
+    """Lowered for the TPU (nothing runs): the gradient of ``chunk_kda``
+    is the two kernels, the forward writing its states, and no ``while``:
+    the ``jnp`` form's scans are in no program."""
+    spec = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    B, S, H, K = 1, 256, 6, 128
+    grad = jax.grad(lambda *a: kda.chunk_kda(*a, 64, False).sum(),
+                    argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(grad).trace(
+        spec(B, S, H, K), spec(B, S, H, K), spec(B, S, H, K),
+        spec(B, S, H, K), spec(B, S, H)
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert 'kernel_name = "hvd_kda_fwd"' in text
+    assert 'kernel_name = "hvd_kda_bwd"' in text
+    assert f"tensor<{B}x{H}x{S // 64}x{K}x{K}xf32>" in text    # the states
+    assert "while" not in text
 
 
 # -- the blocks against the reference -----------------------------------------
