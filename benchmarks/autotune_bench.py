@@ -27,6 +27,14 @@ if REPO not in sys.path:
 
 from horovod_tpu.utils.cpurig import force_cpu_platform  # noqa: E402
 
+# The PJRT CPU client runs every device's share of a program on one pool
+# of max(cores, devices) threads, and a collective holds its thread until
+# all 8 shares have arrived.  With several programs in flight on a loaded
+# box the pool fills with shares of later programs, one of the earliest
+# never starts, and XLA aborts the process after 40 s (what tier-1 saw of
+# this bench under six test workers).  A pool with room for the waiters
+# cannot starve; the variable is read when the client is made.
+os.environ.setdefault("PJRT_NPROC", "64")
 force_cpu_platform(8)
 
 import numpy as np  # noqa: E402

@@ -352,7 +352,7 @@ def main(argv=None) -> int:
         sys.exit(f"chip_smoke: JAX's first device is {platform!r}, not a "
                  "TPU; nothing was run")
 
-    from horovod_tpu.models import llama
+    from horovod_tpu.models import layers, llama
     from horovod_tpu.utils.compile_cache import ensure_compile_cache
 
     cache_dir = ensure_compile_cache()
@@ -360,7 +360,7 @@ def main(argv=None) -> int:
     clock = _CompileClock()
 
     if tiny:
-        llama._FORCE_FLASH_INTERPRET = True
+        layers._FORCE_FLASH_INTERPRET = True
         cfg = llama.LlamaConfig.tiny(n_kv_heads=4, n_layers=1)
         payload_bytes = 1 << 20
         batch_shape = (2, 128)

@@ -20,9 +20,11 @@ recurrence and their short convolution).  Three kinds of layer:
   weights, a shared expert, and the share ``[held_first, held_first +
   experts_held)`` of the routed experts that this chip holds.
 
-Layers of different kinds cannot be one ``lax.scan`` over one stacked
-tree.  The stack is cut into **runs** of consecutive layers of one kind,
-in the published order (:func:`layer_runs`); ``params["runs"]`` holds one
+A layer kind is a ``(mixer, mlp)`` pair (:func:`layer_pair`) in the one
+pre-norm frame of :func:`horovod_tpu.models.layers.block`.  Layers of
+different kinds cannot be one ``lax.scan`` over one stacked tree.  The
+stack is cut into **runs** of consecutive layers of one kind, in the
+published order (:func:`layer_runs`); ``params["runs"]`` holds one
 stacked tree per run and each run is scanned, remat per layer.
 
 Trains through :func:`horovod_tpu.models.llama.make_train_step` (pass
@@ -35,6 +37,7 @@ gradient and no update here.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -45,7 +48,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.kda import CHUNK, chunk_kda
 from ..parallel.moe import moe_layer_held
-from . import llama
+from .layers import (
+    attention,
+    block,
+    causal_lm_loss,
+    dense_mlp,
+    embed_lookup,
+    remat,
+    rmsnorm,
+)
 
 # llama.make_train_step: the third output of the step is (loss, stats).
 LOSS_HAS_AUX = True
@@ -291,9 +302,9 @@ def _kda_mixer(x, lp, cfg: KimiLinearConfig):
                                      ).astype(jnp.float32))
     o = chunk_kda(q.astype(dt), k.astype(dt), v.astype(dt), g, beta,
                   cfg.kda_chunk)
-    o = llama._rmsnorm(o, lp["o_norm"], cfg.rms_eps) * \
+    o = rmsnorm(o, lp["o_norm"], cfg.rms_eps) * \
         jax.nn.sigmoid(low("w_ga", "w_gb")).astype(dt)
-    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), None
 
 
 def _mla_mixer(x, lp, cfg: KimiLinearConfig, mesh):
@@ -301,13 +312,13 @@ def _mla_mixer(x, lp, cfg: KimiLinearConfig, mesh):
     C, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
     q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
     kva = jnp.einsum("bsd,dc->bsc", x, lp["w_kva"])
-    c = llama._rmsnorm(kva[..., :C], lp["kv_norm"], cfg.rms_eps)
+    c = rmsnorm(kva[..., :C], lp["kv_norm"], cfg.rms_eps)
     kv = jnp.einsum("bsc,chk->bshk", c, lp["w_kvb"])
     k_pe = jnp.broadcast_to(kva[:, :, None, C:],
                             (B, S, cfg.n_heads, cfg.qk_rope_dim))
     k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
-    o = llama._attention(q, k, kv[..., nope:], mesh, True)
-    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+    o = attention(q, k, kv[..., nope:], mesh, True)
+    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), None
 
 
 def _moe_mlp(x2, lp, cfg: KimiLinearConfig):
@@ -323,16 +334,13 @@ def _moe_mlp(x2, lp, cfg: KimiLinearConfig):
     return out.reshape(B, S, D), stats
 
 
-def _layer(h, lp, kind: str, cfg: KimiLinearConfig, mesh):
+def layer_pair(kind: str, cfg: KimiLinearConfig, mesh) -> tuple:
+    """The ``(mixer, mlp)`` of a layer kind, each ``(x, lp) -> (y,
+    extra)`` as :func:`horovod_tpu.models.layers.block` takes them."""
     mixer, mlp = kind.split("_")
-    x = llama._rmsnorm(h, lp["attn_norm"], cfg.rms_eps)
-    h = h + (_kda_mixer(x, lp, cfg) if mixer == "kda"
-             else _mla_mixer(x, lp, cfg, mesh))
-    x2 = llama._rmsnorm(h, lp["mlp_norm"], cfg.rms_eps)
-    if mlp == "dense":
-        return h + llama._dense_mlp(x2, lp), None
-    out, stats = _moe_mlp(x2, lp, cfg)
-    return h + out, stats
+    return (partial(_kda_mixer, cfg=cfg) if mixer == "kda"
+            else partial(_mla_mixer, cfg=cfg, mesh=mesh),
+            dense_mlp if mlp == "dense" else partial(_moe_mlp, cfg=cfg))
 
 
 def forward(params: dict, tokens: jax.Array, cfg: KimiLinearConfig, *,
@@ -340,18 +348,21 @@ def forward(params: dict, tokens: jax.Array, cfg: KimiLinearConfig, *,
     """Logits ``[B, S, vocab_rows]`` (float32) and the routing counts of
     the expert layers, in layer order: ``{"pairs_held": [n_moe],
     "expert_counts": [n_moe, experts_held]}``."""
-    h = llama._embed_lookup(params["embed"], tokens, cfg.dtype)
+    h = embed_lookup(params["embed"], tokens, cfg.dtype)
     stats = []
     for (kind, _, _), stack in zip(layer_runs(cfg), params["runs"]):
-        body = llama._remat(
-            lambda h, lp, kind=kind: _layer(h, lp, kind, cfg, mesh),
-            cfg.remat)
-        h, st = lax.scan(body, h, stack)
+        mixer, mlp = layer_pair(kind, cfg, mesh)
+
+        def body(h, lp):         # traced here, with this run's pair
+            h, _, st = block(h, lp, mixer, mlp, cfg.rms_eps)
+            return h, st
+
+        h, st = lax.scan(remat(body, cfg.remat), h, stack)
         if st is not None:
             stats.append(st)
     stats = jax.tree.map(lambda *a: jnp.concatenate(a), *stats) \
         if stats else {}
-    h = llama._rmsnorm(h, params["final_norm"], cfg.rms_eps)
+    h = rmsnorm(h, params["final_norm"], cfg.rms_eps)
     if return_hidden:
         return h, stats
     logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
@@ -362,19 +373,10 @@ def loss_fn(params: dict, batch: dict, cfg: KimiLinearConfig, *,
             mesh: Optional[Mesh] = None):
     """Causal LM loss over the rows held: ``batch = {"tokens": [B, S+1]}``.
     Returns ``(loss, routing counts)``; no auxiliary loss."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    if cfg.blockwise_ce:
-        from ..ops.losses import blockwise_cross_entropy
-        h, stats = forward(params, inputs, cfg, mesh=mesh, return_hidden=True)
-        nll = blockwise_cross_entropy(
-            h.reshape(-1, h.shape[-1]), params["lm_head"],
-            targets.reshape(-1).astype(jnp.int32))
-        return nll.mean(), stats
-    logits, stats = forward(params, inputs, cfg, mesh=mesh)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return (lse - picked).mean(), stats
+    return causal_lm_loss(
+        lambda inputs, hidden: forward(params, inputs, cfg, mesh=mesh,
+                                       return_hidden=hidden),
+        params["lm_head"], batch["tokens"], cfg.blockwise_ce)
 
 
 def record_routing(cfg: KimiLinearConfig, stats: dict) -> None:

@@ -14,13 +14,17 @@ story would be "bring your own torch model"), so this is built TPU-first:
 - **Control flow**: one ``lax.scan`` over stacked layer params (single
   compiled layer body; compile time independent of depth) with
   ``jax.checkpoint`` rematerialization per layer.
+- **One layer body**: :func:`horovod_tpu.models.layers.block` with
+  :func:`~horovod_tpu.models.layers.gqa_mixer`.  Training, the pipelined
+  regions, :func:`generate` and the three serving steps each pass their
+  own ``attend`` (where K and V go, what q attends over) and nothing else.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import sys
-from functools import lru_cache, partial
+from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -29,15 +33,26 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops import flash_attention as FA
 from ..parallel import sharding as shd
 from ..parallel.moe import moe_layer_local
-from ..parallel.ring_attention import (
-    ring_attention_local,
-    ulysses_attention_local,
+from .layers import (  # noqa: F401  (attention_path: re-exported)
+    POOL_DIMS,
+    attention,
+    attention_path,
+    block,
+    cached_attend,
+    causal_lm_loss,
+    dense_mlp,
+    embed_lookup,
+    gather_blocks,
+    gqa_mixer,
+    remat,
+    rmsnorm,
+    rope_tables,
+    sp_local_attention,
 )
-from ..utils import logging as hvd_logging
 
-log = hvd_logging.get_logger()
 _THIS = sys.modules[__name__]     # make_train_step's default model
 
 
@@ -55,12 +70,11 @@ class LlamaConfig:
     n_experts: int = 8
     capacity_factor: float = 1.25
     # Rematerialization of the layer body: True = full per-layer remat
-    # (least memory), False = save everything (fastest at the bench shape
-    # once trivial-mesh sharding constraints stopped fragmenting the
-    # saved-buffer fusions: TPU v5 lite in-process A/B 92.1 ms/step vs
-    # 93.7 "dots" vs ~98.8 full remat), or "dots" = jax.checkpoint with
-    # the dots_with_no_batch_dims_saveable policy — the memory/speed
-    # middle ground for configs that don't fit with remat=False.
+    # (least memory: the backward runs each layer's forward again),
+    # False = save everything, or "dots" = jax.checkpoint with the
+    # dots_with_no_batch_dims_saveable policy (matmul outputs saved, the
+    # rest recomputed).  What each costs a step is ROADMAP Speed 3's to
+    # measure on the training cells; no ledger line prices it yet.
     remat: Any = True
     moe_aux_weight: float = 0.01
     # pp microbatch count (None = auto: most M <= 2*pp dividing the local
@@ -75,34 +89,19 @@ class LlamaConfig:
     # preferable when heads >> sp and the sequence fits).
     sp_attention: str = "ring"
     # Unroll factor for the layer scan in the non-pipelined forward
-    # (lax.scan's ``unroll``).  1 = compile one layer body (fastest
-    # compile, depth-independent).  n_layers = fully unrolled: the
-    # stacked-residual dynamic-update-slice copies the rolled scan pays
-    # every layer (round-5 trace: 5.8 ms/step at the bench shape, pure
-    # copy traffic) disappear and XLA fuses across layer boundaries, at
-    # the cost of compile time linear in depth.  The bench config uses
-    # full unroll; deep configs should stay rolled or pick a divisor.
+    # (lax.scan's ``unroll``).  1 = compile one layer body (compile time
+    # independent of depth).  n_layers = fully unrolled: the rolled
+    # scan's per-layer dynamic-update-slice copies of the stacked
+    # residuals go and XLA fuses across layer boundaries, at a compile
+    # time linear in depth.  Whether those copies cost a cell anything is
+    # ROADMAP Speed 3's last question.
     scan_unroll: int = 1
     # Blockwise (online-softmax) cross-entropy (ops/losses.py): trades
     # one extra lm_head matmul for never materializing the [B,S,V] fp32
-    # logits.  Measured on TPU v5 lite (d1024/L8, B=8, S=1024, V=32000):
-    # ~13% SLOWER than the dense path (XLA already streams the dense
-    # softmax well) but saves the ~1 GB logits+grad residency — so it is
-    # an opt-in memory lever for configs that don't otherwise fit, not a
-    # default.
+    # logits: an opt-in memory lever for configs that do not otherwise
+    # fit.  Its price in tokens/s and in temporaries is ROADMAP Speed 8's
+    # to measure on the chip.
     blockwise_ce: bool = False
-    # Fused tp matmul + reduce-scatter on the decode projection layers
-    # (wo / w_down row-parallel psums in the stage-resident pp decode
-    # path), chunked so chunk c's reduce-scatter can overlap chunk c+1's
-    # partial matmul (ops/sched.matmul_reducescatter).  None = follow the
-    # engine's HOROVOD_TPU_SCHED_MODE knob (on when "decomposed");
-    # True/False force it.  Numerics: bit-identical at tp=2 (two-operand
-    # sums commute; token parity asserted in tests/test_sched.py) and
-    # within ~1 ulp beyond — psum and psum_scatter associate the tp-way
-    # sum in different ring orders (the same caveat as the engine's
-    # decomposed allreduce, docs/performance.md), so near-tie logits at
-    # tp>=4 could in principle pick a different token.
-    decode_tp_overlap: Optional[bool] = None
 
     @property
     def head_dim(self) -> int:
@@ -230,215 +229,20 @@ def init_params(cfg: LlamaConfig, key: jax.Array, mesh: Optional[Mesh] = None
     return jax.jit(build, out_shardings=shardings)(key)
 
 
-def _remat(body, mode):
-    """Apply the configured rematerialization mode to a layer body."""
-    if mode == "dots":
-        return jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    return jax.checkpoint(body) if mode else body
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
-def _rmsnorm_impl(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * rms * w).astype(x.dtype)
+def _expert_swiglu(w, x):
+    """One expert's SwiGLU on its rows: ``w`` its leaves, ``x [cap, D]``."""
+    g = jax.nn.silu(x @ w["w_gate"])
+    u = x @ w["w_up"]
+    return (g * u) @ w["w_down"]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
-    """RMSNorm with a hand-written VJP whose only residual is ``x``.
-
-    Autodiff of the plain version makes XLA save the fp32 normalized
-    activations for the backward — at the bench shape that is two
-    f32[B,S,D] tensors per layer (≈512 MB/step at d1024/L8/B8/S1024)
-    riding the layer-scan carry through HBM.  Recomputing the rsqrt from
-    the already-saved bf16 ``x`` in the backward is a handful of VPU ops
-    against ~2 ms/step of HBM traffic (round-5 trace: the fwd while
-    carried 2x f32[8,8,1024,1024] purely as norm residuals)."""
-    return _rmsnorm_impl(x, w, eps)
-
-
-def _rmsnorm_fwd(x, w, eps):
-    return _rmsnorm_impl(x, w, eps), (x, w)
-
-
-def _rmsnorm_bwd(eps, res, dy):
-    x, w = res
-    x32 = x.astype(jnp.float32)
-    r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    u = x32 * r                                   # normalized activations
-    du = dy.astype(jnp.float32) * w               # d(loss)/d(u)
-    s = jnp.mean(du * u, axis=-1, keepdims=True)
-    dx = (r * (du - u * s)).astype(x.dtype)
-    dw = jnp.sum(dy.astype(jnp.float32) * u,
-                 axis=tuple(range(x.ndim - 1))).astype(w.dtype)
-    return dx, dw
-
-
-_rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
-
-
-def _rope_tables(positions: jax.Array, theta: float, head_dim: int
-                 ) -> tuple[jax.Array, jax.Array]:
-    """cos/sin tables [B, S, half] for these positions.  Computed once per
-    forward and threaded through the layer scan as loop invariants rather
-    than re-deriving the transcendentals per layer.  (Measured step-time
-    effect on TPU v5 lite: none — XLA was already amortizing the
-    recompute — but the hoist keeps the scanned body minimal.)"""
-    half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,half]
-    return jnp.cos(angles), jnp.sin(angles)
-
-
-def _rope(x: jax.Array, rope: tuple[jax.Array, jax.Array]) -> jax.Array:
-    # x: [B, S, H, Dh]; rope: (cos, sin) each [B, S, Dh//2]
-    half = x.shape[-1] // 2
-    cos, sin = rope[0][:, :, None, :], rope[1][:, :, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
-
-
-def _embed_lookup(embed: jax.Array, tokens: jax.Array, dtype) -> jax.Array:
-    """Token embedding as a one-hot matmul rather than a gather: exact
-    (each one-hot row has a single nonzero), and the backward becomes a
-    transposed matmul on the MXU instead of a scatter-add.  In-process
-    A/B at the bench shape measured the two forms equal on TPU v5 lite
-    (XLA fuses the one-hot into the dot, and lowers the small-vocab
-    gather well); the matmul form is kept because it partitions cleanly
-    under the vocab_rows (tp, fsdp) sharding — a sharded gather lowers
-    to per-shard lookup + select + psum anyway."""
-    onehot = jax.nn.one_hot(tokens, embed.shape[0], dtype=dtype)
-    return jnp.einsum("bsv,vd->bsd", onehot, embed.astype(dtype))
-
-
-# One canonical expansion helper (shared with the dense oracle).
-from ..ops.flash_attention import gqa_expand as _gqa_expand  # noqa: E402
-
-
-def _attn_block(h, lp, rope, cfg: LlamaConfig, attention):
-    """Shared attention sub-block: RMSNorm -> QKV -> RoPE -> ``attention``
-    callable (handed GROUPED K/V — each path expands only if it must) ->
-    output projection + residual."""
-    x = _rmsnorm(h, lp["attn_norm"])
-    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
-    q = _rope(q, rope)
-    k = _rope(k, rope)
-    return h + jnp.einsum("bshk,hkd->bsd", attention(q, k, v), lp["wo"])
-
-
-def _swiglu_hidden(x2, lp):
-    """SwiGLU gate/up half: ``silu(x@w_gate) * (x@w_up)`` — shared so the
-    decode path's fused down-projection reuses the same hidden math."""
-    g = jax.nn.silu(jnp.einsum("bsd,df->bsf", x2, lp["w_gate"]))
-    u = jnp.einsum("bsd,df->bsf", x2, lp["w_up"])
-    return g * u
-
-
-def _dense_mlp(x2, lp):
-    """SwiGLU MLP shared by the scan and pipeline paths."""
-    return jnp.einsum("bsf,fd->bsd", _swiglu_hidden(x2, lp), lp["w_down"])
-
-
-# Test hook: route the TPU-gated flash branches through the Pallas
-# interpreter so the CPU rig can exercise the exact structures the TPU
-# path uses (the dp/fsdp/tp shard_map in `_attention` and the direct
-# kernel call inside the fully-manual pipeline region).
-_FORCE_FLASH_INTERPRET = False
-
-
-def _flash_backend() -> bool:
-    return jax.default_backend() == "tpu" or _FORCE_FLASH_INTERPRET
-
-
-@lru_cache(maxsize=None)
-def _log_attention_path(path: str, q_shape: tuple, mesh_shape) -> None:
-    """INFO line naming the attention implementation a traced step uses,
-    once per distinct (path, local shape, mesh)."""
-    log.info("llama attention path: %s (local q %s, mesh %s)", path,
-             q_shape, dict(mesh_shape) if mesh_shape else None)
-
-
-def _sp_local_attention(sp_mode: str):
-    """The mapped-context sequence-parallel attention for ``sp_mode``."""
-    if sp_mode == "ulysses":
-        return ulysses_attention_local
-    if sp_mode == "ring":
-        return ring_attention_local
-    raise ValueError(f"unknown sp_attention {sp_mode!r} "
-                     "(expected 'ring' or 'ulysses')")
-
-
-def attention_path(q_shape: tuple, itemsize: int, mesh: Optional[Mesh],
-                   sp_mode: str = "ring", v_dim: Optional[int] = None
-                   ) -> str:
-    """Which implementation :func:`_attention` runs for a global
-    ``[B, S, H, D]`` query on ``mesh``: ``"ring"``/``"ulysses"`` when the
-    sequence is sp-sharded, ``"flash"`` (the Pallas kernels) on TPU when
-    the per-chip shard divides evenly and :func:`FA.supported` accepts
-    it, ``"dense"`` (XLA) otherwise.  ``v_dim`` is the value width where
-    it differs from the key width ``D``."""
-    from ..ops import flash_attention as FA
-    shape = dict(mesh.shape) if mesh is not None else {}
-    if shape.get("sp", 1) > 1:
-        _sp_local_attention(sp_mode)
-        return sp_mode
-    B, S, H, D = q_shape
-    dpf = shape.get("dp", 1) * shape.get("fsdp", 1)
-    tp = shape.get("tp", 1)
-    if (_flash_backend() and B % dpf == 0 and H % tp == 0
-            and FA.supported((B // dpf, S, H // tp, D), itemsize, v_dim)):
-        return "flash"
-    return "dense"
-
-
-def _attention(q, k, v, mesh: Optional[Mesh], causal: bool,
-               sp_mode: str = "ring") -> jax.Array:
-    """Dispatch per :func:`attention_path`.  Under a mesh the sp paths and
-    the flash kernel are shard_mapped so each chip works on its own
-    batch/head shard (a bare pallas_call has no GSPMD partitioning rule
-    and would be replicated)."""
-    path = attention_path(q.shape, q.dtype.itemsize, mesh, sp_mode,
-                          v.shape[-1])
-    _log_attention_path(path, q.shape,
-                        tuple(mesh.shape.items()) if mesh is not None
-                        else None)
-    if path in ("ring", "ulysses"):
-        k, v = _gqa_expand(q, k, v)   # ring/Ulysses rotate full head sets
-        # Manual over every mesh axis: the batch/head dims are explicitly
-        # dp·fsdp / tp sliced instead of left to GSPMD, and the body only
-        # communicates over sp.
-        spec = P(("dp", "fsdp"), "sp", "tp", None)
-        fn = shard_map(
-            partial(_sp_local_attention(sp_mode), axis_name="sp",
-                    causal=causal),
-            mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-            check_vma=False)
-        return fn(q, k, v)
-    if path == "flash":
-        from ..ops import flash_attention as FA
-        flash = lambda q_, k_, v_: FA.flash_attention(
-            q_, k_, v_, None, causal, None, None, _FORCE_FLASH_INTERPRET)
-        if mesh is None:
-            return flash(q, k, v)
-        if k.shape[2] % mesh.shape.get("tp", 1):
-            # tp divides H but not KV: the grouped cache cannot shard
-            # over tp — expand K/V and keep the flash kernel (losing it
-            # entirely would be a 2-5x regression for the sake of the
-            # GQA memory win).
-            k, v = _gqa_expand(q, k, v)
-        spec = P(("dp", "fsdp"), None, "tp", None)
-        return shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
-    from ..ops.flash_attention import dense_attention
-    return dense_attention(q, k, v, 1.0 / np.sqrt(q.shape[-1]), causal)
+def _expert_swiglu_tp(w, x):
+    """The same inside a region manual over tp, the expert's hidden dim
+    Megatron-sliced: the row-parallel product summed over tp."""
+    return lax.psum(_expert_swiglu(w, x), "tp")
 
 
 def _moe_mlp(h2, lp, cfg: LlamaConfig, mesh: Optional[Mesh]):
@@ -446,14 +250,7 @@ def _moe_mlp(h2, lp, cfg: LlamaConfig, mesh: Optional[Mesh]):
     B, S, D = h2.shape
     flat = h2.reshape(B * S, D)
 
-    def expert_fn(w, x):
-        # w: dict leaves for ONE expert; x: [cap, D]
-        g = jax.nn.silu(x @ w["w_gate"])
-        u = x @ w["w_up"]
-        return (g * u) @ w["w_down"]
-
-    eparams = {"w_gate": lp["w_gate"], "w_up": lp["w_up"],
-               "w_down": lp["w_down"]}
+    eparams = {k: lp[k] for k in _EXPERT_LEAVES}
     ep = mesh.shape.get("ep", 1) if mesh is not None else 1
     if ep > 1:
         # Manual over every mesh axis: dp/fsdp/ep all count as token
@@ -462,14 +259,9 @@ def _moe_mlp(h2, lp, cfg: LlamaConfig, mesh: Optional[Mesh]):
         # Megatron-sliced over tp with an explicit row-parallel psum.
         all_axes = tuple(mesh.axis_names)
 
-        def expert_fn_tp(w, x):
-            g = jax.nn.silu(x @ w["w_gate"])
-            u = x @ w["w_up"]
-            return lax.psum((g * u) @ w["w_down"], "tp")
-
         def local_moe(tok, rk, pr):
             out, aux = moe_layer_local(
-                tok, rk, expert_fn_tp, pr, axis_name="ep",
+                tok, rk, _expert_swiglu_tp, pr, axis_name="ep",
                 capacity_factor=cfg.capacity_factor)
             # pmean over every axis: data axes average the per-shard aux
             # into the global mean; replicated axes (tp/pp) are forward
@@ -503,7 +295,7 @@ def _moe_mlp(h2, lp, cfg: LlamaConfig, mesh: Optional[Mesh]):
         logits = flat.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
         dispatch, combine, aux, _drops = switch_route(logits, cap)
         einputs = jnp.einsum("tec,td->ecd", dispatch.astype(flat.dtype), flat)
-        eouts = jax.vmap(expert_fn)(eparams, einputs)
+        eouts = jax.vmap(_expert_swiglu)(eparams, einputs)
         out = jnp.einsum("tec,ecd->td", combine.astype(flat.dtype), eouts)
     return out.reshape(B, S, D), aux
 
@@ -536,14 +328,43 @@ def _pick_microbatches(batch: int, mesh: Mesh,
     return 1
 
 
-def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
-    """Shared layer-stack machinery for the pipelined paths (GPipe forward
-    and 1F1B training): the fully-manual layer body with Megatron-tp psums,
-    ZeRO-3 fsdp gathers, ring attention over sp, MoE over ep — and the
-    in/out specs matching the at-rest parameter shardings."""
-    pp = mesh.shape["pp"]
-    tp = mesh.shape.get("tp", 1)
-    sp = mesh.shape.get("sp", 1)
+def _layer_dims(cfg: LlamaConfig) -> dict:
+    """Logical dims of one layer's leaves (the leading "stage" dropped)."""
+    return {k: d[1:] for k, d in param_logical_dims(cfg)["layers"].items()}
+
+
+def _layer_specs(cfg: LlamaConfig) -> dict:
+    """PartitionSpecs of the stacked layer leaves at rest: the in/out
+    specs of the manual regions."""
+    return jax.tree.map(shd.spec_for, param_logical_dims(cfg)["layers"],
+                        is_leaf=lambda x: isinstance(x, tuple))
+
+
+def _gather_fsdp(lp, cfg: LlamaConfig):
+    """ZeRO-3 gather inside a manual region: reassemble the embed dim of
+    one layer's weights from their fsdp shards; transpose = reduce-scatter
+    of the grads."""
+    layer_dims = _layer_dims(cfg)
+    out = {}
+    for k, leaf in lp.items():
+        for i, dname in enumerate(layer_dims[k]):
+            if dname == "embed":
+                leaf = lax.all_gather(leaf, "fsdp", axis=i, tiled=True)
+        out[k] = leaf
+    return out
+
+
+def _tp_sum(f):
+    """A mixer or mlp whose last product is row-parallel, inside a region
+    manual over tp: the partial products summed over tp (Megatron)."""
+    def summed(x, lp):
+        y, extra = f(x, lp)
+        return lax.psum(y, "tp"), extra
+    return summed
+
+
+def _check_stage_split(cfg: LlamaConfig, mesh: Mesh) -> None:
+    pp, tp = mesh.shape["pp"], mesh.shape.get("tp", 1)
     if cfg.n_layers % pp:
         raise ValueError(
             f"pp={pp} must divide n_layers={cfg.n_layers} evenly")
@@ -551,54 +372,34 @@ def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
         raise ValueError(
             f"tp={tp} must divide n_heads={cfg.n_heads} and "
             f"n_kv_heads={cfg.n_kv_heads}")
+
+
+def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
+    """Shared layer-stack machinery for the pipelined paths (GPipe forward
+    and 1F1B training): the fully-manual layer body with Megatron-tp psums,
+    ZeRO-3 fsdp gathers, ring attention over sp, MoE over ep — and the
+    in/out specs matching the at-rest parameter shardings."""
+    _check_stage_split(cfg, mesh)
+    sp = mesh.shape.get("sp", 1)
     if S % sp:
         raise ValueError(f"sp={sp} must divide sequence length {S}")
-    from ..ops import flash_attention as FA
-
     S_loc = S // sp
-    scale = 1.0 / np.sqrt(cfg.head_dim)
-    layer_dims = {k: d[1:]
-                  for k, d in param_logical_dims(cfg)["layers"].items()}
 
-    def gather_layer(lp):
-        # ZeRO-3 gather: reassemble the embed dim of this layer's weights
-        # from their fsdp shards; transpose = reduce-scatter of the grads.
-        out = {}
-        for k, leaf in lp.items():
-            for i, dname in enumerate(layer_dims[k]):
-                if dname == "embed":
-                    leaf = lax.all_gather(leaf, "fsdp", axis=i, tiled=True)
-            out[k] = leaf
-        return out
-
-    def attention(q, k, v):
+    def attend(q, k, v):
         # q is this rank's shard already (the region is manual over
         # every axis), so the mesh-free dispatch applies to it as is.
-        path = (cfg.sp_attention if sp > 1 else
-                attention_path(q.shape, q.dtype.itemsize, None))
-        _log_attention_path(path, q.shape, tuple(mesh.shape.items()))
         if sp > 1:
-            k, v = _gqa_expand(q, k, v)
-            return _sp_local_attention(cfg.sp_attention)(
-                q, k, v, axis_name="sp", causal=causal)
-        if path == "flash":
-            return FA.flash_attention(q, k, v, None, causal, None, None,
-                                      _FORCE_FLASH_INTERPRET)
-        return FA.dense_attention(q, k, v, scale, causal)
+            k, v = FA.gqa_expand(q, k, v)
+            return sp_local_attention(cfg.sp_attention)(
+                q, k, v, axis_name="sp", causal=causal), None
+        return attention(q, k, v, None, causal), None
 
     def moe_mlp_local(x2, lp):
         Bq, Sq, Dq = x2.shape
         flat = x2.reshape(Bq * Sq, Dq)
-
-        def expert_fn(w, x):
-            g = jax.nn.silu(x @ w["w_gate"])
-            u = x @ w["w_up"]
-            return lax.psum((g * u) @ w["w_down"], "tp")
-
-        eparams = {"w_gate": lp["w_gate"], "w_up": lp["w_up"],
-                   "w_down": lp["w_down"]}
         out, aux = moe_layer_local(
-            flat, lp["router"].astype(jnp.float32), expert_fn, eparams,
+            flat, lp["router"].astype(jnp.float32), _expert_swiglu_tp,
+            {k: lp[k] for k in _EXPERT_LEAVES},
             axis_name="ep", capacity_factor=cfg.capacity_factor)
         # pmean includes tp (a forward no-op — aux is tp-replicated) so the
         # aux gradient path is 1/tp-scaled per rank; the 1F1B step blanket-
@@ -608,29 +409,24 @@ def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
         return (out.reshape(Bq, Sq, Dq),
                 lax.pmean(aux, ("dp", "fsdp", "ep", "sp", "tp")))
 
+    # Row-parallel wo and w_down; the expert layer sums over tp itself.
+    mlp = moe_mlp_local if cfg.use_moe else _tp_sum(dense_mlp)
+
     def layer_body(h, lp, rope):
-        lp = gather_layer(lp)
-        x = _rmsnorm(h, lp["attn_norm"])
-        q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])     # heads local (tp)
-        k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
-        q = _rope(q, rope)
-        k = _rope(k, rope)
-        # K/V stay at kv_heads here; each attention path expands only if
-        # it must (the flash kernels index kv heads natively).
-        attn_out = jnp.einsum("bshk,hkd->bsd", attention(q, k, v), lp["wo"])
-        h = h + lax.psum(attn_out, "tp")                  # row-parallel wo
-        x2 = _rmsnorm(h, lp["mlp_norm"])
-        if cfg.use_moe:
-            mlp_out, aux = moe_mlp_local(x2, lp)
-        else:
-            mlp_out = lax.psum(_dense_mlp(x2, lp), "tp")  # row-parallel
-            aux = jnp.zeros((), jnp.float32)
-        return h + mlp_out, aux
+        h, _, aux = block(
+            h, _gather_fsdp(lp, cfg),
+            _tp_sum(partial(gqa_mixer, tables=rope, attend=attend)), mlp)
+        return h, jnp.zeros((), jnp.float32) if aux is None else aux
 
-    body = _remat(layer_body, cfg.remat)
+    body = remat(layer_body, cfg.remat)
 
-    def make_stage_fn(rope):
+    def make_stage_fn(rows: int):
+        # RoPE tables once per step (tick-invariant), not per tick, for
+        # this sp rank's positions and ``rows`` sequences a microbatch.
+        base = lax.axis_index("sp") * S_loc + jnp.arange(S_loc)
+        rope = rope_tables(jnp.broadcast_to(base[None, :], (rows, S_loc)),
+                           cfg.rope_theta, cfg.head_dim)
+
         def stage_fn(local_layers, x):
             # One pp rank's resident layers applied in sequence (scan: one
             # compiled body regardless of depth).
@@ -645,16 +441,22 @@ def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
 
         return stage_fn
 
-    layer_specs = jax.tree.map(
-        lambda dims: shd.spec_for(dims), param_logical_dims(cfg)["layers"],
-        is_leaf=lambda x: isinstance(x, tuple))
     return {
         "make_stage_fn": make_stage_fn,
-        "layer_specs": layer_specs,
-        "layer_dims": layer_dims,
+        "layer_specs": _layer_specs(cfg),
         "act_spec": P(("dp", "fsdp", "ep"), "sp", None),
         "S_loc": S_loc,
     }
+
+
+def _head(params, h, dims=None, mesh: Optional[Mesh] = None, rules=None):
+    """Final norm and lm_head on ``h [..., D]``: float32 logits, pinned to
+    the logical ``dims`` under a mesh."""
+    logits = jnp.einsum("...d,dv->...v", rmsnorm(h, params["final_norm"]),
+                        params["lm_head"])
+    if mesh is not None:
+        logits = shd.constrain(logits, dims, mesh, rules)
+    return logits.astype(jnp.float32)
 
 
 def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
@@ -696,7 +498,7 @@ def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
 
     B, S = tokens.shape
     D = cfg.d_model
-    h = _embed_lookup(params["embed"], tokens, cfg.dtype)   # [B,S,D]
+    h = embed_lookup(params["embed"], tokens, cfg.dtype)   # [B,S,D]
     h = shd.constrain(h, ("batch", "seq", None), mesh)
     M = _pick_microbatches(B, mesh, cfg.pp_microbatches)
 
@@ -708,12 +510,9 @@ def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
         # caught by the round-4 verify drive).
         B_loc = h_loc.shape[0]
         mbs = h_loc.reshape(M, B_loc // M, S_loc, D)
-        # RoPE tables once per step (tick-invariant), not per tick.
-        base = lax.axis_index("sp") * S_loc + jnp.arange(S_loc)
-        positions = jnp.broadcast_to(base[None, :], (B_loc // M, S_loc))
-        rope = _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
-        out, aux = pipeline_apply_local(make_stage_fn(rope), local_layers,
-                                        mbs, axis_name="pp", with_aux=True)
+        out, aux = pipeline_apply_local(
+            make_stage_fn(B_loc // M), local_layers, mbs, axis_name="pp",
+            with_aux=True)
         return out.reshape(B_loc, S_loc, D), aux
 
     layer_specs, act_spec = parts["layer_specs"], parts["act_spec"]
@@ -721,10 +520,7 @@ def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
                    out_specs=(act_spec, P()), check_vma=False)
     h, aux = fn(params["layers"], h)
     h = shd.constrain(h, ("batch", "seq", None), mesh)
-    h = _rmsnorm(h, params["final_norm"])
-    logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
-    logits = shd.constrain(logits, ("batch", "seq", "vocab"), mesh)
-    return logits.astype(jnp.float32), aux
+    return _head(params, h, ("batch", "seq", "vocab"), mesh), aux
 
 
 def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
@@ -739,79 +535,43 @@ def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
         assert not return_hidden, "blockwise CE requires a pp=1 mesh"
         return _forward_pipelined(params, tokens, cfg, mesh, causal)
     B, S = tokens.shape
-    h = _embed_lookup(params["embed"], tokens, cfg.dtype)   # [B,S,D]
+    h = embed_lookup(params["embed"], tokens, cfg.dtype)   # [B,S,D]
     h = shd.constrain(h, ("batch", "seq", None), mesh) if mesh else h
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    rope = _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
+    rope = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
     if mesh is not None:
         # Per-layer rule shardings for the scanned slices (leading "stage"
         # dim dropped).  Pinning the slices inside the body stops GSPMD's
         # propagator from deriving batch-flavored shardings for loop-body
         # weights — the source of "involuntary full rematerialization"
         # resharding on every layer (round-2 verdict finding).
-        layer_dims = {k: d[1:]
-                      for k, d in param_logical_dims(cfg)["layers"].items()}
+        layer_dims = _layer_dims(cfg)
         rules = shard_rules(cfg, mesh)
+
+    mixer = partial(
+        gqa_mixer, tables=rope,
+        attend=lambda q, k, v: (attention(q, k, v, mesh, causal,
+                                          cfg.sp_attention), None))
+    mlp = partial(_moe_mlp, cfg=cfg, mesh=mesh) if cfg.use_moe else dense_mlp
 
     def layer_body(carry, lp):
         h, aux = carry
         if mesh is not None:
             lp = {k: shd.constrain(v, layer_dims[k], mesh, rules)
                   for k, v in lp.items()}
-        h = _attn_block(h, lp, rope, cfg,
-                        lambda q, k, v: _attention(q, k, v, mesh, causal,
-                                                   cfg.sp_attention))
-        x2 = _rmsnorm(h, lp["mlp_norm"])
-        if cfg.use_moe:
-            mlp_out, moe_aux = _moe_mlp(x2, lp, cfg, mesh)
+        h, _, moe_aux = block(h, lp, mixer, mlp)
+        if moe_aux is not None:
             aux = aux + moe_aux
-        else:
-            mlp_out = _dense_mlp(x2, lp)
-        h = h + mlp_out
         if mesh is not None:
             h = shd.constrain(h, ("batch", "seq", None), mesh)
         return (h, aux), None
 
-    body = _remat(layer_body, cfg.remat)
+    body = remat(layer_body, cfg.remat)
     (h, aux), _ = lax.scan(body, (h, jnp.zeros((), jnp.float32)),
                            params["layers"], unroll=cfg.scan_unroll)
-    h = _rmsnorm(h, params["final_norm"])
     if return_hidden:
-        return h, aux
-    logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
-    if mesh is not None:
-        logits = shd.constrain(logits, ("batch", "seq", "vocab"), mesh)
-    return logits.astype(jnp.float32), aux
-
-
-def _layer_kv(x, lp, rope):
-    """Post-RoPE K/V for a normed input chunk (no GQA expand — the cache
-    stores kv_heads and expands at attention time)."""
-    k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
-    return _rope(k, rope), v
-
-
-def _cached_attend(q, keys, vals, mask, scale):
-    """Decode-path attention against a KV cache, GQA-grouped.
-
-    q [B,Sq,H,Dh]; keys/vals [B,T,KV,Dh]; mask [Sq,T] bool (shared across
-    the batch) or [B,Sq,T] (per-request — the serving engine's slots sit
-    at different context lengths).  The q heads are reshaped [KV, rep]
-    and contracted against the grouped cache directly — the cache is
-    never expanded to H heads (the repeat would rep x the dominant HBM
-    traffic of decoding, which is exactly reading the cache)."""
-    B, Sq, H, Dh = q.shape
-    KV = keys.shape[2]
-    rep = H // KV
-    qg = q.reshape(B, Sq, KV, rep, Dh)
-    s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, keys
-                   ).astype(jnp.float32) * scale
-    m = mask[None, None, None] if mask.ndim == 2 else mask[:, None, None]
-    s = jnp.where(m, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(vals.dtype), vals)
-    return o.reshape(B, Sq, H, Dh)
+        return rmsnorm(h, params["final_norm"]), aux
+    return _head(params, h, ("batch", "seq", "vocab"), mesh), aux
 
 
 def _pick_token(logits, step_key, temperature, dtype):
@@ -822,23 +582,81 @@ def _pick_token(logits, step_key, temperature, dtype):
         step_key, logits / temperature, axis=-1).astype(dtype)
 
 
-def _decode_tp_overlap_chunks(cfg: LlamaConfig, tp: int) -> int:
-    """Chunk count for the fused matmul+reduce-scatter decode projections
-    (0 = plain ``psum``).  ``cfg.decode_tp_overlap`` wins when set;
-    None follows the engine's schedule knob (``HOROVOD_TPU_SCHED_MODE``),
-    so one switch turns on decomposed collectives engine-wide AND the
-    decode-layer fusion."""
-    if tp <= 1:
-        return 0
-    from .. import context as ctx_mod
-    state = ctx_mod.global_state()
-    gcfg = state.config if state.initialized else None
-    enabled = cfg.decode_tp_overlap
-    if enabled is None:
-        enabled = gcfg is not None and gcfg.sched_mode == "decomposed"
-    if not enabled:
-        return 0
-    return max(2, gcfg.sched_chunks if gcfg is not None else 2)
+def _pin_kv(cfg: LlamaConfig, mesh: Optional[Mesh]):
+    """Constraint for K/V ``[B, T, KV, Dh]``: batch over dp/fsdp, heads
+    over tp.  Without the annotation the propagator happily replicates a
+    cache — the largest live tensor of the whole decode — on every tp
+    rank."""
+    if mesh is None:
+        return lambda c: c
+    rules = shard_rules(cfg, mesh)
+    return lambda c: shd.constrain(c, ("batch", None, "kv_heads", None),
+                                   mesh, rules)
+
+
+def _sample_loop(prefill, tick, head, prompt, max_new_tokens: int,
+                 temperature: float, key):
+    """The token loop of :func:`generate` on either kind of mesh.
+    ``prefill(prompt) -> (h [B, P, D], caches)`` and ``tick(caches, tok
+    [B], pos) -> (h [B, 1, D], caches)`` run the layer stack;
+    ``head(h [B, D])`` gives the logits a token is picked from."""
+    h, caches = prefill(prompt)
+    key, k0 = jax.random.split(key)
+    first_new = _pick_token(head(h[:, -1]), k0, temperature, prompt.dtype)
+
+    def decode_step(carry, step_key):
+        caches, tok, pos = carry
+        h, caches = tick(caches, tok, pos)
+        nxt = _pick_token(head(h[:, 0]), step_key, temperature, prompt.dtype)
+        return (caches, nxt, pos + 1), nxt
+
+    # max_new_tokens - 1 decode steps: the first new token came from the
+    # prefill logits, and collecting each step's OUTPUT token means no
+    # trailing step whose result would be discarded.
+    carry0 = (caches, first_new, jnp.asarray(prompt.shape[1], jnp.int32))
+    _, toks = lax.scan(decode_step, carry0,
+                       jax.random.split(key, max_new_tokens - 1))
+    new_toks = jnp.concatenate([first_new[:, None], toks.swapaxes(0, 1)],
+                               axis=1)
+    return jnp.concatenate([prompt, new_toks], axis=1)
+
+
+def _cache_stack(cfg: LlamaConfig, h, layers, ck, cv, pos,
+                 pin=lambda c: c, gather=lambda lp: lp,
+                 row_parallel=lambda f: f):
+    """The layer stack of the dense-cache decoders (:func:`generate` and
+    its pp form) on ``h [B, S, D]``, the tokens at positions ``pos`` on,
+    against the caches ``ck, cv [L, B, T, KV, Dh]``: each layer's fresh
+    K/V go into its cache (``pin`` constrains the result).  One token a
+    row (a decode tick) scores q against the cache; a prompt scores it
+    against its own keys only — scoring the zero-padded T-length cache
+    would pay T/P times the prefill attention FLOPs on masked slots.
+    Inside a manual region ``gather`` and ``row_parallel`` are
+    :func:`_gather_fsdp` and :func:`_tp_sum`.  Returns ``(h, (ck, cv))``."""
+    B, S = h.shape[:2]
+    prompt = S > 1
+    scale = 1.0 / np.sqrt(cfg.head_dim)
+    tables = rope_tables(jnp.broadcast_to(pos + jnp.arange(S), (B, S)),
+                         cfg.rope_theta, cfg.head_dim)
+    mask = jnp.tril(jnp.ones((S, S), bool)) if prompt \
+        else (jnp.arange(ck.shape[2]) <= pos)[None, :]              # [1, T]
+    put = lambda c, new: pin(lax.dynamic_update_slice(c, new, (0, pos, 0, 0)))
+
+    def layer(h, xs):
+        lp, ck, cv = xs
+
+        def attend(q, k1, v1):
+            ck2, cv2 = put(ck, k1), put(cv, v1)
+            keys, vals = (k1, v1) if prompt else (ck2, cv2)
+            return cached_attend(q, keys, vals, mask, scale), (ck2, cv2)
+
+        h, kept, _ = block(
+            h, gather(lp),
+            row_parallel(partial(gqa_mixer, tables=tables, attend=attend)),
+            row_parallel(dense_mlp))
+        return h, kept
+
+    return lax.scan(layer, h, (layers, ck, cv))
 
 
 def _generate_pp(params: dict, prompt: jax.Array, cfg: LlamaConfig,
@@ -862,87 +680,24 @@ def _generate_pp(params: dict, prompt: jax.Array, cfg: LlamaConfig,
     T = Plen + max_new_tokens
     pp = mesh.shape["pp"]
     tp = mesh.shape.get("tp", 1)
-    L, D, H, KV, Dh = (cfg.n_layers, cfg.d_model, cfg.n_heads,
-                       cfg.n_kv_heads, cfg.head_dim)
+    KV, Dh = cfg.n_kv_heads, cfg.head_dim
     dpf = mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
-    if cfg.n_layers % pp:
-        raise ValueError(f"pp={pp} must divide n_layers={L}")
-    if H % tp or KV % tp:
-        raise ValueError(f"tp={tp} must divide n_heads={H} and "
-                         f"n_kv_heads={KV}")
+    _check_stage_split(cfg, mesh)
     if B % dpf:
         raise ValueError(f"batch {B} must divide over dp*fsdp = {dpf}")
-    scale = 1.0 / np.sqrt(Dh)
-    tp_chunks = _decode_tp_overlap_chunks(cfg, tp)
-    dims = param_logical_dims(cfg)
-    layer_dims = {k: d[1:] for k, d in dims["layers"].items()}
-    layer_specs = jax.tree.map(lambda d: shd.spec_for(d), dims["layers"],
-                               is_leaf=lambda x: isinstance(x, tuple))
+    layer_specs = _layer_specs(cfg)
     cache_spec = P("pp", ("dp", "fsdp"), None, "tp", None)
     act_spec = P(("dp", "fsdp"), None, None)
     perm = [(i, i + 1) for i in range(pp - 1)]
 
-    def gather_layer(lp):
-        out = {}
-        for k2, leaf in lp.items():
-            for i, dname in enumerate(layer_dims[k2]):
-                if dname == "embed":
-                    leaf = lax.all_gather(leaf, "fsdp", axis=i, tiled=True)
-            out[k2] = leaf
-        return out
-
-    def _row_parallel(x2, w2):
-        """tp row-parallel projection: ``psum(x2 @ w2)``, or — behind the
-        schedule knob — the fused chunked matmul + reduce-scatter
-        (ops/sched), which lets chunk c's collective overlap chunk c+1's
-        partial matmul on the decode critical path."""
-        if tp_chunks:
-            from ..ops.sched import matmul_reducescatter
-            return matmul_reducescatter(x2, w2, "tp", chunks=tp_chunks)
-        return lax.psum(jnp.matmul(x2, w2), "tp")
-
-    def make_stage(rope, mask, write, attend_cache):
-        def layer_step(h, inputs):
-            lp, ck, cv = inputs
-            lp = gather_layer(lp)
-            x = _rmsnorm(h, lp["attn_norm"])
-            q = _rope(jnp.einsum("bsd,dhk->bshk", x, lp["wq"]), rope)
-            k1, v1 = _layer_kv(x, lp, rope)
-            ck = write(ck, k1)
-            cv = write(cv, v1)
-            if attend_cache:                       # decode: q vs cache
-                attn = _cached_attend(q, ck, cv, mask, scale)
-            else:   # prefill: attend over the Plen prompt keys only —
-                # scoring the zero-padded T-length cache would pay
-                # T/Plen x the prefill attention FLOPs on masked slots
-                # (same reasoning as the non-pp prefill_layer).
-                attn = _cached_attend(q, k1, v1, mask, scale)
-            # Row-parallel wo / w_down: the decode projection layers the
-            # schedule IR fuses (matmul + reduce-scatter) when enabled.
-            Bq, Sq = attn.shape[0], attn.shape[1]
-            h = h + _row_parallel(
-                attn.reshape(Bq, Sq, -1),
-                lp["wo"].reshape(-1, lp["wo"].shape[-1]))
-            x2 = _rmsnorm(h, lp["mlp_norm"])
-            h = h + _row_parallel(_swiglu_hidden(x2, lp), lp["w_down"])
-            return h, (ck, cv)
-
-        def stage(h, layers_loc, ck_loc, cv_loc):
-            h2, (ck2, cv2) = lax.scan(
-                lambda c, i: layer_step(c, i), h,
-                (layers_loc, ck_loc, cv_loc))
-            return h2, ck2, cv2
-
-        return stage
-
-    def pp_chain(stage, h, layers_loc, ck_loc, cv_loc):
+    def stages_local(layers_loc, ck, cv, h, pos):
+        stage = lambda op: _cache_stack(
+            cfg, *op, pos, gather=partial(_gather_fsdp, cfg=cfg),
+            row_parallel=_tp_sum)
         idx = lax.axis_index("pp")
-        ck, cv = ck_loc, cv_loc
         for s_ in range(pp):
-            h, ck, cv = lax.cond(
-                idx == s_,
-                lambda op: stage(op[0], op[1], op[2], op[3]),
-                lambda op: (op[0], op[2], op[3]),
+            h, (ck, cv) = lax.cond(
+                idx == s_, stage, lambda op: (op[0], (op[2], op[3])),
                 (h, layers_loc, ck, cv))
             if s_ < pp - 1:
                 h = lax.ppermute(h, "pp", perm)
@@ -951,67 +706,34 @@ def _generate_pp(params: dict, prompt: jax.Array, cfg: LlamaConfig,
             jnp.where(idx == pp - 1, h, jnp.zeros_like(h)), "pp"), ck, cv
 
     def prefill_local(layers_loc, h_loc):
-        B_loc = h_loc.shape[0]
         L_loc = jax.tree.leaves(layers_loc)[0].shape[0]
-        positions = jnp.broadcast_to(jnp.arange(Plen), (B_loc, Plen))
-        rope = _rope_tables(positions, cfg.rope_theta, Dh)
-        mask = jnp.tril(jnp.ones((Plen, Plen), bool))
-        write = lambda c, new: lax.dynamic_update_slice(
-            c, new, (0, 0, 0, 0))
-        ck0 = jnp.zeros((L_loc, B_loc, T, KV // tp, Dh), cfg.dtype)
-        stage = make_stage(rope, mask, write, attend_cache=False)
-        return pp_chain(stage, h_loc, layers_loc, ck0, ck0)
+        ck0 = jnp.zeros((L_loc, h_loc.shape[0], T, KV // tp, Dh), cfg.dtype)
+        return stages_local(layers_loc, ck0, ck0, h_loc, 0)
 
-    def decode_local(layers_loc, ck_loc, cv_loc, h_loc, pos):
-        B_loc = h_loc.shape[0]
-        rope = _rope_tables(
-            jnp.broadcast_to(pos[None, None], (B_loc, 1)),
-            cfg.rope_theta, Dh)
-        mask = (jnp.arange(T) <= pos)[None, :]                   # [1, T]
-        write = lambda c, new: lax.dynamic_update_slice(
-            c, new, (0, pos, 0, 0))
-        stage = make_stage(rope, mask, write, attend_cache=True)
-        return pp_chain(stage, h_loc, layers_loc, ck_loc, cv_loc)
+    def embed(tok):
+        h = embed_lookup(params["embed"], tok, cfg.dtype)
+        return shd.constrain(h, ("batch", None, None), mesh)
 
-    def head_logits(h_last):
-        h2 = _rmsnorm(h_last, params["final_norm"])
-        logits = jnp.einsum("bd,dv->bv", h2, params["lm_head"]
-                            ).astype(jnp.float32)
-        return shd.constrain(logits, ("batch", "vocab"), mesh)
+    def prefill(prompt):
+        h, ck, cv = shard_map(
+            prefill_local, mesh=mesh, in_specs=(layer_specs, act_spec),
+            out_specs=(act_spec, cache_spec, cache_spec),
+            check_vma=False)(params["layers"], embed(prompt))
+        return h, (ck, cv)
 
-    # ---- prefill ------------------------------------------------------
-    h = _embed_lookup(params["embed"], prompt, cfg.dtype)
-    h = shd.constrain(h, ("batch", None, None), mesh)
-    fn = shard_map(prefill_local, mesh=mesh,
-                   in_specs=(layer_specs, act_spec),
-                   out_specs=(act_spec, cache_spec, cache_spec),
-                   check_vma=False)
-    h, cache_k, cache_v = fn(params["layers"], h)
-    key, k0 = jax.random.split(key)
-    first_new = _pick_token(head_logits(h[:, -1]), k0, temperature,
-                            prompt.dtype)
+    def tick(caches, tok, pos):
+        h, ck, cv = shard_map(
+            stages_local, mesh=mesh,
+            in_specs=(layer_specs, cache_spec, cache_spec, act_spec, P()),
+            out_specs=(act_spec, cache_spec, cache_spec),
+            check_vma=False)(params["layers"], *caches, embed(tok[:, None]),
+                             pos)
+        return h, (ck, cv)
 
-    # ---- decode -------------------------------------------------------
-    def decode_step(carry, step_key):
-        ck, cv, tok, pos = carry
-        h = _embed_lookup(params["embed"], tok[:, None], cfg.dtype)
-        h = shd.constrain(h, ("batch", None, None), mesh)
-        fn = shard_map(decode_local, mesh=mesh,
-                       in_specs=(layer_specs, cache_spec, cache_spec,
-                                 act_spec, P()),
-                       out_specs=(act_spec, cache_spec, cache_spec),
-                       check_vma=False)
-        h, ck, cv = fn(params["layers"], ck, cv, h, pos)
-        nxt = _pick_token(head_logits(h[:, 0]), step_key, temperature,
-                          prompt.dtype)
-        return (ck, cv, nxt, pos + 1), nxt
-
-    carry0 = (cache_k, cache_v, first_new, jnp.asarray(Plen, jnp.int32))
-    _, toks = lax.scan(decode_step, carry0,
-                       jax.random.split(key, max_new_tokens - 1))
-    new_toks = jnp.concatenate([first_new[:, None], toks.swapaxes(0, 1)],
-                               axis=1)
-    return jnp.concatenate([prompt, new_toks], axis=1)
+    return _sample_loop(
+        prefill, tick, partial(_head, params, dims=("batch", "vocab"),
+                               mesh=mesh),
+        prompt, max_new_tokens, temperature, key)
 
 
 def generate(params: dict, prompt: jax.Array, cfg: LlamaConfig, *,
@@ -1055,86 +777,23 @@ def generate(params: dict, prompt: jax.Array, cfg: LlamaConfig, *,
     B, P = prompt.shape
     T = P + max_new_tokens
     KV, Dh = cfg.n_kv_heads, cfg.head_dim
-    scale = 1.0 / np.sqrt(Dh)
+    pin = _pin_kv(cfg, mesh)
 
-    def constrain_cache(c):
-        # Heads over tp, batch over dp/fsdp: without the annotation the
-        # propagator happily replicates the cache — the largest live
-        # tensor of the whole decode — on every tp rank.
-        if mesh is None:
-            return c
-        return shd.constrain(c, ("batch", None, "kv_heads", None), mesh,
-                             shard_rules(cfg, mesh))
+    def prefill(prompt):
+        # Build the cache over the prompt.
+        cache0 = jnp.zeros((cfg.n_layers, B, T, KV, Dh), cfg.dtype)
+        return _cache_stack(
+            cfg, embed_lookup(params["embed"], prompt, cfg.dtype),
+            params["layers"], cache0, cache0, 0, pin)
 
-    # ---- prefill: build the cache over the prompt ----------------------
-    h = _embed_lookup(params["embed"], prompt, cfg.dtype)
-    positions = jnp.broadcast_to(jnp.arange(P), (B, P))
-    rope_p = _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
-    prefill_mask = jnp.tril(jnp.ones((P, P), bool))
+    def tick(caches, tok, pos):
+        # One token per row, cache append.
+        return _cache_stack(
+            cfg, embed_lookup(params["embed"], tok[:, None], cfg.dtype),
+            params["layers"], *caches, pos, pin)
 
-    def prefill_layer(h, lp):
-        x = _rmsnorm(h, lp["attn_norm"])
-        q = _rope(jnp.einsum("bsd,dhk->bshk", x, lp["wq"]), rope_p)
-        k, v = _layer_kv(x, lp, rope_p)
-        # Attention over the P prompt keys only; the T-length cache is
-        # written separately (attending into the zero-padded cache would
-        # pay T/P times the prefill score FLOPs on masked positions).
-        attn = _cached_attend(q, k, v, prefill_mask, scale)
-        ck = constrain_cache(
-            jnp.zeros((B, T, KV, Dh), cfg.dtype).at[:, :P].set(k))
-        cv = constrain_cache(
-            jnp.zeros((B, T, KV, Dh), cfg.dtype).at[:, :P].set(v))
-        h = h + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
-        h = h + _dense_mlp(_rmsnorm(h, lp["mlp_norm"]), lp)
-        return h, (ck, cv)
-
-    h, (cache_k, cache_v) = lax.scan(prefill_layer, h, params["layers"])
-    key, k0 = jax.random.split(key)
-    logits = jnp.einsum("bd,dv->bv",
-                        _rmsnorm(h[:, -1], params["final_norm"]),
-                        params["lm_head"]).astype(jnp.float32)
-    first_new = _pick_token(logits, k0, temperature, prompt.dtype)  # [B]
-
-    # ---- decode: one token per tick, cache append ----------------------
-    def decode_step(carry, step_key):
-        cache_k, cache_v, tok, pos = carry
-        h = _embed_lookup(params["embed"], tok[:, None], cfg.dtype)
-        rope_1 = _rope_tables(
-            jnp.broadcast_to(pos[None, None], (B, 1)),
-            cfg.rope_theta, cfg.head_dim)
-        mask = (jnp.arange(T) <= pos)[None, :]          # [1, T]
-
-        def layer(h, inputs):
-            lp, ck, cv = inputs
-            x = _rmsnorm(h, lp["attn_norm"])
-            q = _rope(jnp.einsum("bsd,dhk->bshk", x, lp["wq"]), rope_1)
-            k1, v1 = _layer_kv(x, lp, rope_1)
-            ck = constrain_cache(
-                lax.dynamic_update_slice(ck, k1, (0, pos, 0, 0)))
-            cv = constrain_cache(
-                lax.dynamic_update_slice(cv, v1, (0, pos, 0, 0)))
-            attn = _cached_attend(q, ck, cv, mask, scale)
-            h = h + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
-            h = h + _dense_mlp(_rmsnorm(h, lp["mlp_norm"]), lp)
-            return h, (ck, cv)
-
-        h, (cache_k, cache_v) = lax.scan(
-            layer, h, (params["layers"], cache_k, cache_v))
-        logits = jnp.einsum("bd,dv->bv",
-                            _rmsnorm(h[:, 0], params["final_norm"]),
-                            params["lm_head"]).astype(jnp.float32)
-        nxt = _pick_token(logits, step_key, temperature, prompt.dtype)
-        return (cache_k, cache_v, nxt, pos + 1), nxt
-
-    # max_new_tokens - 1 decode steps: the first new token came from the
-    # prefill logits, and collecting each step's OUTPUT token means no
-    # trailing step whose result would be discarded.
-    carry0 = (cache_k, cache_v, first_new, jnp.asarray(P, jnp.int32))
-    _, toks = lax.scan(decode_step, carry0,
-                       jax.random.split(key, max_new_tokens - 1))
-    new_toks = jnp.concatenate([first_new[:, None], toks.swapaxes(0, 1)],
-                               axis=1)
-    return jnp.concatenate([prompt, new_toks], axis=1)
+    return _sample_loop(prefill, tick, partial(_head, params), prompt,
+                        max_new_tokens, temperature, key)
 
 
 # ---------------------------------------------------------------------------
@@ -1143,6 +802,34 @@ def generate(params: dict, prompt: jax.Array, cfg: LlamaConfig, *,
 # for op, so greedy decode through the engine reproduces generate()'s
 # tokens; only cache PLACEMENT differs (the engine owns the page pool).
 # ---------------------------------------------------------------------------
+
+def _serve_layers(params, tok, positions, cfg: LlamaConfig, mesh, attend,
+                  state=None):
+    """The skeleton under the three serving steps: embed ``tok [B, S]``,
+    the rope tables of ``positions [B, S]``, then the frame scanned over
+    (layers, layer index).  ``attend(q, k, v, li, state) -> (o, (state,
+    out))`` is the step's own: where the layer's K and V go and what q
+    attends over; ``state`` (the pools) rides the scan's carry, ``out``
+    (a layer's K and V) is stacked.  Returns ``(h, state, outs)``."""
+    h = embed_lookup(params["embed"], tok, cfg.dtype)
+    if mesh is not None:
+        h = shd.constrain(h, ("batch", None, None), mesh,
+                          shard_rules(cfg, mesh))
+    tables = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
+
+    def layer(carry, xs):
+        h, state = carry
+        lp, li = xs
+        h, (state, out), _ = block(
+            h, lp, partial(gqa_mixer, tables=tables,
+                           attend=partial(attend, li=li, state=state)),
+            dense_mlp)
+        return (h, state), out
+
+    (h, state), outs = lax.scan(
+        layer, (h, state), (params["layers"], jnp.arange(cfg.n_layers)))
+    return h, state, outs
+
 
 def prefill_step(params, tokens: jax.Array, cfg: LlamaConfig, *,
                  mesh: Optional[Mesh] = None,
@@ -1159,42 +846,21 @@ def prefill_step(params, tokens: jax.Array, cfg: LlamaConfig, *,
     B, P = tokens.shape
     scale = 1.0 / np.sqrt(cfg.head_dim)
     rules = shard_rules(cfg, mesh)
-    h = _embed_lookup(params["embed"], tokens, cfg.dtype)
-    if mesh is not None:
-        h = shd.constrain(h, ("batch", None, None), mesh, rules)
-    positions = jnp.broadcast_to(jnp.arange(P), (B, P))
-    rope_p = _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
     mask = jnp.tril(jnp.ones((P, P), bool))
+    pin = _pin_kv(cfg, mesh)
 
-    def layer(h, lp):
-        x = _rmsnorm(h, lp["attn_norm"])
-        q = _rope(jnp.einsum("bsd,dhk->bshk", x, lp["wq"]), rope_p)
-        k, v = _layer_kv(x, lp, rope_p)
-        attn = _cached_attend(q, k, v, mask, scale)
-        h = h + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
-        h = h + _dense_mlp(_rmsnorm(h, lp["mlp_norm"]), lp)
-        if mesh is not None:
-            k = shd.constrain(k, ("batch", None, "kv_heads", None), mesh,
-                              rules)
-            v = shd.constrain(v, ("batch", None, "kv_heads", None), mesh,
-                              rules)
-        return h, (k, v)
+    def attend(q, k, v, li, state):
+        return cached_attend(q, k, v, mask, scale), (state, (pin(k), pin(v)))
 
-    h, (ks, vs) = lax.scan(layer, h, params["layers"])
+    h, _, (ks, vs) = _serve_layers(
+        params, tokens, jnp.broadcast_to(jnp.arange(P), (B, P)), cfg, mesh,
+        attend)
     if last_pos is None:
         h_last = h[:, -1]
     else:
         h_last = jnp.take_along_axis(
             h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = jnp.einsum("bd,dv->bv", _rmsnorm(h_last, params["final_norm"]),
-                        params["lm_head"]).astype(jnp.float32)
-    if mesh is not None:
-        logits = shd.constrain(logits, ("batch", "vocab"), mesh, rules)
-    return logits, ks, vs
-
-
-# Logical dims of the serving page pool [L, NB, BS, KV, Dh].
-_POOL_DIMS = (None, None, None, "kv_heads", None)
+    return _head(params, h_last, ("batch", "vocab"), mesh, rules), ks, vs
 
 
 def paged_kernel_ok(cfg: LlamaConfig, mesh: Optional[Mesh],
@@ -1204,7 +870,6 @@ def paged_kernel_ok(cfg: LlamaConfig, mesh: Optional[Mesh],
     (each chip runs the kernel on its own ``kv_heads`` shard), and the
     per-chip pool geometry must be one the kernel compiles for (any is,
     for the interpreter)."""
-    from ..ops import flash_attention as FA
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if cfg.n_heads % tp or cfg.n_kv_heads % tp:
         return False
@@ -1221,12 +886,11 @@ def _paged_kernel_attend(q, kp, vp, layer, tables, lengths, scale, mesh,
     for the same reason as the training kernel in :func:`_attention`: a
     bare pallas_call has no GSPMD partitioning rule and would gather the
     pool onto every chip."""
-    from ..ops import flash_attention as FA
     kernel = partial(FA.paged_attention, scale=scale, interpret=interpret)
     if mesh is None:
         return kernel(q, kp, vp, layer, tables, lengths)
     q_spec = shd.spec_for(("batch", "heads", None), rules)
-    pool_spec = shd.spec_for(_POOL_DIMS, rules)
+    pool_spec = shd.spec_for(POOL_DIMS, rules)
     return shard_map(
         kernel, mesh=mesh,
         in_specs=(q_spec, pool_spec, pool_spec, P(),
@@ -1234,6 +898,38 @@ def _paged_kernel_attend(q, kp, vp, layer, tables, lengths, scale, mesh,
                   shd.spec_for(("batch",), rules)),
         out_specs=q_spec, check_vma=False)(
             q, kp, vp, layer, tables, lengths)
+
+
+def _paged_attend(cfg: LlamaConfig, mesh, tables, blk, off, mask,
+                  last=None, interpret: bool = False):
+    """The ``attend`` of the two paged steps (see :func:`_serve_layers`):
+    each layer writes its fresh K/V rows into page ``blk`` at offset
+    ``off`` of its own pages first, then reads the table's logical window
+    back: one query a row through the Pallas paged kernel where each
+    stream's ``last`` position is given, else through the contiguous
+    gather under ``mask`` (stale slots masked)."""
+    scale = 1.0 / np.sqrt(cfg.head_dim)
+    rules = shard_rules(cfg, mesh)
+
+    def put(pool, li, new):
+        # blk/off are [B] for one token a row, [B, S] for S.
+        pool = pool.at[li, blk, off].set(
+            new.reshape(blk.shape + new.shape[2:]))
+        if mesh is None:
+            return pool
+        return shd.constrain(pool, POOL_DIMS, mesh, rules)
+
+    def attend(q, k1, v1, li, state):
+        kp, vp = put(state[0], li, k1), put(state[1], li, v1)
+        if last is not None:
+            o = _paged_kernel_attend(q[:, 0], kp, vp, li, tables, last + 1,
+                                     scale, mesh, rules, interpret)[:, None]
+        else:
+            o = cached_attend(q, gather_blocks(kp[li], tables),
+                              gather_blocks(vp[li], tables), mask, scale)
+        return o, ((kp, vp), None)
+
+    return attend
 
 
 def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
@@ -1256,55 +952,18 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
     (``use_flash``; callers check :func:`paged_kernel_ok`).
     Returns (logits [B, V] fp32, k_pool, v_pool) — pass the pools donated
     so the writes land in place."""
-    from ..serving.kv_pager import gather_blocks
-
     B = tok.shape[0]
-    L, NB, BS, KV, Dh = k_pool.shape
-    scale = 1.0 / np.sqrt(cfg.head_dim)
-    rules = shard_rules(cfg, mesh)
+    BS = k_pool.shape[2]
     T = tables.shape[1] * BS
-    h = _embed_lookup(params["embed"], tok[:, None], cfg.dtype)
-    if mesh is not None:
-        h = shd.constrain(h, ("batch", None, None), mesh, rules)
-    rope_1 = _rope_tables(positions[:, None], cfg.rope_theta, cfg.head_dim)
     mask = (jnp.arange(T)[None, :] <= positions[:, None])[:, None, :]
-    b_idx = jnp.arange(B)
-    blk = tables[b_idx, positions // BS]                       # [B]
-    off = positions % BS
-
-    def constrain_pool(p):
-        if mesh is None:
-            return p
-        return shd.constrain(p, _POOL_DIMS, mesh, rules)
-
-    def layer(carry, xs):
-        h, kp, vp = carry
-        lp, li = xs
-        x = _rmsnorm(h, lp["attn_norm"])
-        q = _rope(jnp.einsum("bsd,dhk->bshk", x, lp["wq"]), rope_1)
-        k1, v1 = _layer_kv(x, lp, rope_1)                  # [B, 1, KV, Dh]
-        kp = constrain_pool(kp.at[li, blk, off].set(k1[:, 0]))
-        vp = constrain_pool(vp.at[li, blk, off].set(v1[:, 0]))
-        if use_flash:
-            attn = _paged_kernel_attend(
-                q[:, 0], kp, vp, li, tables, positions + 1, scale,
-                mesh, rules, interpret)[:, None]
-        else:
-            keys = gather_blocks(kp[li], tables)           # [B, T, KV, Dh]
-            vals = gather_blocks(vp[li], tables)
-            attn = _cached_attend(q, keys, vals, mask, scale)
-        h = h + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
-        h = h + _dense_mlp(_rmsnorm(h, lp["mlp_norm"]), lp)
-        return (h, kp, vp), None
-
-    (h, k_pool, v_pool), _ = lax.scan(
-        layer, (h, k_pool, v_pool), (params["layers"], jnp.arange(L)))
-    logits = jnp.einsum("bd,dv->bv",
-                        _rmsnorm(h[:, 0], params["final_norm"]),
-                        params["lm_head"]).astype(jnp.float32)
-    if mesh is not None:
-        logits = shd.constrain(logits, ("batch", "vocab"), mesh, rules)
-    return logits, k_pool, v_pool
+    blk = tables[jnp.arange(B), positions // BS]                # [B]
+    h, (k_pool, v_pool), _ = _serve_layers(
+        params, tok[:, None], positions[:, None], cfg, mesh,
+        _paged_attend(cfg, mesh, tables, blk, positions % BS, mask,
+                      positions if use_flash else None, interpret),
+        (k_pool, v_pool))
+    return (_head(params, h[:, 0], ("batch", "vocab"), mesh,
+                  shard_rules(cfg, mesh)), k_pool, v_pool)
 
 
 def extend_step_paged(params, tok: jax.Array, positions: jax.Array,
@@ -1337,52 +996,18 @@ def extend_step_paged(params, tok: jax.Array, positions: jax.Array,
     go through the contiguous-gather path (GSPMD-shardable); the Pallas
     decode kernel is single-query and does not apply here.  Returns
     (logits [B, S, V] fp32, k_pool, v_pool) — donate the pools."""
-    from ..serving.kv_pager import gather_blocks
-
-    B, S = tok.shape
-    L, NB, BS, KV, Dh = k_pool.shape
-    scale = 1.0 / np.sqrt(cfg.head_dim)
-    rules = shard_rules(cfg, mesh)
+    BS = k_pool.shape[2]
     T = tables.shape[1] * BS
-    h = _embed_lookup(params["embed"], tok, cfg.dtype)
-    if mesh is not None:
-        h = shd.constrain(h, ("batch", None, None), mesh, rules)
-    rope_s = _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
     mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]  # [B,S,T]
     blk = jnp.where(valid,
                     jnp.take_along_axis(tables, positions // BS, axis=1),
                     0)                                             # [B,S]
     off = jnp.where(valid, positions % BS, 0)
-
-    def constrain_pool(p):
-        if mesh is None:
-            return p
-        return shd.constrain(p, _POOL_DIMS, mesh, rules)
-
-    def layer(carry, xs):
-        h, kp, vp = carry
-        lp, li = xs
-        x = _rmsnorm(h, lp["attn_norm"])
-        q = _rope(jnp.einsum("bsd,dhk->bshk", x, lp["wq"]), rope_s)
-        k1, v1 = _layer_kv(x, lp, rope_s)                  # [B, S, KV, Dh]
-        kp = constrain_pool(kp.at[li, blk, off].set(k1))
-        vp = constrain_pool(vp.at[li, blk, off].set(v1))
-        keys = gather_blocks(kp[li], tables)               # [B, T, KV, Dh]
-        vals = gather_blocks(vp[li], tables)
-        attn = _cached_attend(q, keys, vals, mask, scale)
-        h = h + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
-        h = h + _dense_mlp(_rmsnorm(h, lp["mlp_norm"]), lp)
-        return (h, kp, vp), None
-
-    (h, k_pool, v_pool), _ = lax.scan(
-        layer, (h, k_pool, v_pool), (params["layers"], jnp.arange(L)))
-    logits = jnp.einsum("bsd,dv->bsv",
-                        _rmsnorm(h, params["final_norm"]),
-                        params["lm_head"]).astype(jnp.float32)
-    if mesh is not None:
-        logits = shd.constrain(logits, ("batch", None, "vocab"), mesh,
-                               rules)
-    return logits, k_pool, v_pool
+    h, (k_pool, v_pool), _ = _serve_layers(
+        params, tok, positions, cfg, mesh,
+        _paged_attend(cfg, mesh, tables, blk, off, mask), (k_pool, v_pool))
+    return (_head(params, h, ("batch", None, "vocab"), mesh,
+                  shard_rules(cfg, mesh)), k_pool, v_pool)
 
 
 def _use_blockwise_ce(cfg: LlamaConfig, mesh: Optional[Mesh]) -> bool:
@@ -1401,24 +1026,11 @@ def _use_blockwise_ce(cfg: LlamaConfig, mesh: Optional[Mesh]) -> bool:
 def loss_fn(params: dict, batch: dict, cfg: LlamaConfig, *,
             mesh: Optional[Mesh] = None) -> jax.Array:
     """Causal LM loss: batch = {"tokens": [B,S+1] int32}."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    if _use_blockwise_ce(cfg, mesh):
-        from ..ops.losses import blockwise_cross_entropy
-        h, aux = forward(params, inputs, cfg, mesh=mesh,
-                         return_hidden=True)
-        B, S, D = h.shape
-        nll = blockwise_cross_entropy(
-            h.reshape(B * S, D), params["lm_head"],
-            targets.reshape(-1).astype(jnp.int32))
-        return nll.mean() + cfg.moe_aux_weight * aux
-    logits, aux = forward(params, inputs, cfg, mesh=mesh)
-    # logsumexp form of the CE — identical math to log_softmax + gather,
-    # but the [B,S,V] fp32 log-prob tensor is never materialized, only
-    # its row reduction (memory win; step time measured equal on TPU).
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return (lse - picked).mean() + cfg.moe_aux_weight * aux
+    loss, aux = causal_lm_loss(
+        lambda inputs, hidden: forward(params, inputs, cfg, mesh=mesh,
+                                       return_hidden=hidden),
+        params["lm_head"], batch["tokens"], _use_blockwise_ce(cfg, mesh))
+    return loss + cfg.moe_aux_weight * aux
 
 
 def _opt_shardings(tx, cfg, mesh: Mesh, model=None):
@@ -1508,7 +1120,7 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
         M = _pick_microbatches(B, mesh, cfg.pp_microbatches)
 
         def embed_fn(emb):
-            h = _embed_lookup(emb, inputs, cfg.dtype)
+            h = embed_lookup(emb, inputs, cfg.dtype)
             return shd.constrain(h, ("batch", "seq", None), mesh)
 
         h, embed_vjp = jax.vjp(embed_fn, params["embed"])
@@ -1520,9 +1132,6 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
             mb_loc = B_loc // M
             mbs = h_loc.reshape(M, mb_loc, S_loc, D)
             tgts = tgt_loc.reshape(M, mb_loc, S_loc)
-            base = lax.axis_index("sp") * S_loc + jnp.arange(S_loc)
-            positions = jnp.broadcast_to(base[None, :], (mb_loc, S_loc))
-            rope = _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
 
             # lm_head fsdp gather ONCE per step, outside the tick loop
             # (XLA does not hoist collectives out of while loops); its
@@ -1534,7 +1143,7 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
             }
 
             def loss_head(head, y, m):
-                h2 = _rmsnorm(y, head["final_norm"])
+                h2 = rmsnorm(y, head["final_norm"])
                 logits = jnp.einsum("bsd,dv->bsv", h2, head["lm_head"]
                                     ).astype(jnp.float32)
                 # CE over the tp-sharded vocab.  The max shift is taken on
@@ -1558,7 +1167,7 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
                 return (lse - picked).mean()
 
             loss, aux, dmbs, dlayers, dhead = pipeline_train_local(
-                make_stage_fn(rope), layers_loc, mbs, loss_head, head_full,
+                make_stage_fn(mb_loc), layers_loc, mbs, loss_head, head_full,
                 axis_name="pp", aux_weight=cfg.moe_aux_weight,
                 seed_scale=1.0 / n_data)
             loss = lax.pmean(loss, data_axes)

@@ -543,7 +543,7 @@ def _kda_backward(q, k, v, g, beta, states, do, *, chunk: int,
 
 def _off_tpu() -> bool:
     """Where the kernels have to run interpreted (a test or a compile for
-    a described chip patches this, as ``llama._flash_backend``)."""
+    a described chip patches this, as ``models.layers._flash_backend``)."""
     return jax.default_backend() != "tpu"
 
 

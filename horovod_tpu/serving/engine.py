@@ -31,6 +31,7 @@ from typing import Any, Optional
 import numpy as np
 
 from ..models import llama
+from ..models.layers import POOL_DIMS
 from .. import chaos
 from ..obs import REGISTRY as _obs
 from ..obs import trace as _trace
@@ -178,8 +179,7 @@ class ServingEngine:
             if mesh is not None:
                 from ..parallel import sharding as shd
                 pool = jax.device_put(pool, shd.logical_sharding(
-                    mesh, (None, None, None, "kv_heads", None),
-                    llama.shard_rules(cfg, mesh)))
+                    mesh, POOL_DIMS, llama.shard_rules(cfg, mesh)))
             return pool
 
         self.k_pool = fresh_pool()

@@ -40,6 +40,8 @@ from typing import Sequence
 
 import numpy as np
 
+from ..models.layers import gather_blocks  # noqa: F401  (its old home)
+
 
 class OutOfBlocks(RuntimeError):
     """The pool has no free block; callers preempt a request and retry."""
@@ -250,16 +252,3 @@ class KVPager:
             "block both free and held"
         assert len(self._refs) + len(self._free) \
             == self.cache.num_blocks - 1, "blocks lost or duplicated"
-
-
-def gather_blocks(pool, table) -> "jax.Array":  # noqa: F821
-    """Contiguous ``[B, n_cols * block_size, KV, D]`` view of each row's
-    blocks: the XLA paged-attention dispatch (a take along the block dim,
-    shardable by GSPMD like any gather).
-
-    pool: ``[num_blocks, block_size, KV, D]`` (one layer's pages);
-    table: ``[B, n_cols]`` int32.
-    """
-    B, n_cols = table.shape
-    g = pool[table]                       # [B, n_cols, BS, KV, D]
-    return g.reshape(B, n_cols * pool.shape[1], *pool.shape[2:])

@@ -23,7 +23,7 @@ from chipbench import reference_kimi_linear as ref            # noqa: E402
 from chipbench import run as runmod                           # noqa: E402
 from chipbench import weights_kimi_linear as wts              # noqa: E402
 from chipbench.drivers.train_kimi_linear import kimi_config   # noqa: E402
-from horovod_tpu.models import kimi_linear as KL, llama       # noqa: E402
+from horovod_tpu.models import kimi_linear as KL, layers, llama  # noqa: E402
 from horovod_tpu.ops import kda                               # noqa: E402
 from horovod_tpu.parallel import moe                          # noqa: E402
 
@@ -145,11 +145,11 @@ def _layer_weights(i):
 def test_mla_block_matches_the_reference(path, monkeypatch):
     """Keys 24 wide, values 16: through the XLA path and through the flash
     kernels (interpreted), which take the two widths."""
-    monkeypatch.setattr(llama, "_FORCE_FLASH_INTERPRET", path == "flash")
+    monkeypatch.setattr(layers, "_FORCE_FLASH_INTERPRET", path == "flash")
     w = _layer_weights(3)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, DIMS["d_model"]))
     assert llama.attention_path((2, 128, 2, 24), 4, None, v_dim=16) == path
-    got = KL._mla_mixer(x, w, KCFG, None)
+    got, _ = KL._mla_mixer(x, w, KCFG, None)
     want = jax.vmap(lambda h: ref.mla_mixer(w, h, DIMS))(x)
     _close(got, want, 1e-4)
 
@@ -157,7 +157,7 @@ def test_mla_block_matches_the_reference(path, monkeypatch):
 def test_kda_block_matches_the_reference():
     w = _layer_weights(1)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 32, DIMS["d_model"]))
-    got = KL._kda_mixer(x, w, KCFG)
+    got, _ = KL._kda_mixer(x, w, KCFG)
     want = jax.vmap(lambda h: ref.kda_mixer(w, h, DIMS))(x)
     _close(got, want, 1e-4)
 

@@ -190,7 +190,7 @@ def test_flash_model_path_matches_dense_on_mesh():
     and gradients as the dense path — exercised on the CPU rig through
     the Pallas interpreter via the ``_FORCE_FLASH_INTERPRET`` hook.
     (The pp-mesh counterpart is ``test_pp_flash_attention_matches_dense``.)"""
-    from horovod_tpu.models import llama as L
+    from horovod_tpu.models import layers as L
 
     mesh = build_mesh(MeshConfig(dp=4, tp=2))
     # Shapes satisfying FA.supported on the LOCAL view: S=256 (block
@@ -235,7 +235,7 @@ def test_flash_kept_when_tp_exceeds_kv_heads():
     the flash path must survive by expanding K/V (round-5 review: the
     grouped-KV dispatch silently dropped to dense here, a 2-5x
     regression), and the result must match the dense oracle."""
-    from horovod_tpu.models import llama as L
+    from horovod_tpu.models import layers as L
 
     mesh = build_mesh(MeshConfig(dp=2, tp=4))
     cfg = llama.LlamaConfig.tiny(
@@ -327,7 +327,7 @@ def test_pp_flash_attention_matches_dense():
     """Flash attention under pp (direct kernel call in the fully-manual
     pipeline region — the round-3 1.4x-gradient bug is gone): loss AND
     grads must match the dense path on the same pp mesh."""
-    from horovod_tpu.models import llama as L
+    from horovod_tpu.models import layers as L
 
     from horovod_tpu.ops import flash_attention as FA
 

@@ -226,3 +226,57 @@ def test_dlrm_interaction_shape():
     out = dlrm_mod.interact_features(
         jnp.zeros((B, D)), jnp.zeros((B, T, D)))
     assert out.shape == (B, D + (T + 1) * T // 2)
+
+
+# ---------------------------------------------------------------------------
+# The import graph: models/ points down
+# ---------------------------------------------------------------------------
+
+def _imports_of(path):
+    """Absolute module names a file imports, function-level imports
+    included (a lazy ``from ..serving import x`` is still an arrow up)."""
+    import ast
+    pkg = ["horovod_tpu", "models"]
+    out = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            out.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = pkg[:len(pkg) - node.level + 1] if node.level else []
+            mod = ".".join(base + ([node.module] if node.module else []))
+            out.add(mod)
+            out.update(f"{mod}.{a.name}" for a in node.names)
+    return out
+
+
+def test_model_files_import_nothing_above_them():
+    """No file under ``models/`` imports ``serving`` or ``context``, at
+    module level or inside a function, and ``kimi_linear`` does not import
+    ``llama``: what they share is in ``models/layers.py``.  (``context``
+    is read from the source, not from ``sys.modules``: the package's own
+    ``__init__`` imports it before any model.)"""
+    import pathlib
+    models = pathlib.Path(hvd.__file__).parent / "models"
+    for path in sorted(models.glob("*.py")):
+        for mod in _imports_of(path):
+            assert not mod.startswith(("horovod_tpu.serving",
+                                       "horovod_tpu.context")), \
+                f"{path.name} imports {mod}"
+    kimi = _imports_of(models / "kimi_linear.py")
+    assert "horovod_tpu.models.llama" not in kimi, kimi
+    assert "horovod_tpu.models.layers" in kimi
+
+
+def test_importing_the_models_leaves_serving_and_each_other_out():
+    """In a fresh interpreter: ``kimi_linear`` alone does not bring
+    ``llama`` in, and neither brings ``horovod_tpu.serving``."""
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "import horovod_tpu.models.kimi_linear\n"
+        "assert 'horovod_tpu.models.llama' not in sys.modules\n"
+        "import horovod_tpu.models.llama\n"
+        "up = [m for m in sys.modules if m.startswith('horovod_tpu.serving')]\n"
+        "assert not up, up\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=300)

@@ -251,8 +251,45 @@ def test_engine_paged_flash_kernel_mode(tiny):
         assert list(f.result().full_sequence) == list(ref)
 
 
+@pytest.mark.parametrize("use_flash", [False, True],
+                         ids=["gather", "kernel-interpret"])
+def test_decode_tick_is_extend_with_one_token_a_row(tiny, use_flash):
+    """The two paged steps are one skeleton: a decode tick (through the
+    gather and through the interpreted kernel) and ``extend_step_paged``
+    with one token a row write the same pools and return the same
+    logits."""
+    cfg, params = tiny
+    rng = np.random.RandomState(3)
+    B, NB, BS, C = 3, 12, 4, 3
+    shape = (cfg.n_layers, NB, BS, cfg.n_kv_heads, cfg.head_dim)
+    kp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    vp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    tables = jnp.asarray(1 + rng.permutation(NB - 1)[:B * C].reshape(B, C),
+                         jnp.int32)
+    tok = jnp.asarray(rng.randint(0, cfg.vocab_size, size=(B,)), jnp.int32)
+    pos = jnp.asarray([5, 2, 9], jnp.int32)
+    logits_d, kp_d, vp_d = llama.decode_step_paged(
+        params, tok, pos, kp, vp, tables, cfg, use_flash=use_flash,
+        interpret=use_flash)
+    logits_e, kp_e, vp_e = llama.extend_step_paged(
+        params, tok[:, None], pos[:, None], jnp.ones((B, 1), bool), kp, vp,
+        tables, cfg)
+    assert logits_d.shape == (B, cfg.vocab_size)
+    np.testing.assert_allclose(logits_d, logits_e[:, 0], rtol=1e-4,
+                               atol=1e-4)
+    # The first layer's rows are the same numbers on either path; later
+    # layers' follow an attention that the kernel sums in another order.
+    same = np.testing.assert_array_equal
+    close = lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4,
+                                                    atol=1e-5)
+    for got, want in ((kp_d, kp_e), (vp_d, vp_e)):
+        same(got[0], want[0])
+        (close if use_flash else same)(got, want)
+    assert not np.array_equal(np.asarray(kp_d), np.asarray(kp))
+
+
 def test_paged_attention_kernel_vs_gather_oracle():
-    from horovod_tpu.models.llama import _cached_attend
+    from horovod_tpu.models.layers import cached_attend
     from horovod_tpu.ops import flash_attention as FA
     rng = np.random.RandomState(0)
     B, H, KV, Dh, NB, BS, C = 3, 8, 2, 64, 16, 8, 4
@@ -267,7 +304,7 @@ def test_paged_attention_kernel_vs_gather_oracle():
     out = FA.paged_attention(q, kp, vp, li, tables, lengths, interpret=True)
     keys, vals = gather_blocks(kp[li], tables), gather_blocks(vp[li], tables)
     mask = (jnp.arange(C * BS)[None, :] < lengths[:, None])[:, None, :]
-    ref = _cached_attend(q[:, None], keys, vals, mask,
+    ref = cached_attend(q[:, None], keys, vals, mask,
                          1.0 / np.sqrt(Dh))[:, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
@@ -318,7 +355,7 @@ def test_paged_attention_kernel_cases(monkeypatch, KV, rep, dtype, BS, C,
     convex combination of V's rows, so the gap is under 4 * 2**-9 *
     max|V|.  float32 pools round nothing: only the summation order
     differs."""
-    from horovod_tpu.models.llama import _cached_attend
+    from horovod_tpu.models.layers import cached_attend
     from horovod_tpu.ops import flash_attention as FA
     if group is not None:
         monkeypatch.setattr(FA, "_PAGED_GROUP_TOKENS", group)
@@ -347,7 +384,7 @@ def test_paged_attention_kernel_cases(monkeypatch, KV, rep, dtype, BS, C,
     keys = gather_blocks(clean_k[li], tables)
     vals = gather_blocks(clean_v[li], tables)
     mask = (jnp.arange(C * BS)[None, :] < lengths[:, None])[:, None, :]
-    ref = _cached_attend(q[:, None], keys, vals, mask,
+    ref = cached_attend(q[:, None], keys, vals, mask,
                          1.0 / np.sqrt(Dh))[:, 0]
     assert out.dtype == q.dtype and out.shape == q.shape
     if dtype == jnp.float32:
