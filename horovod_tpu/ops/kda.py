@@ -37,6 +37,19 @@ uses the same tree: ``T <- T - T (N * mask_m) T`` merges the inverses of
 two blocks of ``m`` into that of their block of ``2m`` (block forward
 substitution, products only).
 
+**The running sums as one signed product.**  ``G`` and the six rebased
+differences ``D_m = G - R_m`` are sums of ``g`` over runs of tokens, so
+all seven are ``W g`` for one matrix ``W [7C, C]`` of 0, +1 and -1
+(:func:`_signed_sums`; ``D_m[t]`` adds the tokens after ``r`` up to ``t``,
+or takes away those after ``t`` up to ``r``).  ``W`` is exact in bfloat16
+and ``g`` is split into three bfloat16 pieces that add up to it bit for
+bit, so ``W hi + W mid + W lo`` accumulated in float32 is the product at
+``highest`` without the three of its six passes that multiply by the
+zero middle and low parts of ``W``.  It is the chunk's one product below
+``highest``, and exact there and nowhere else: no other operand is
+exact in bfloat16.  ``D_m`` as one sum also cancels less than ``G - R_m``
+from two.
+
 Both directions are Pallas kernels under one ``custom_vjp``, around one
 function of values, :func:`_chunk`: ``(S0, q, k, v, g, beta) -> (S1, O)``
 for one chunk of one head.  The forward (``hvd_kda_fwd``) has one grid
@@ -61,11 +74,11 @@ scratch from the last chunk (zero there) to the first.  A grid step
 loads a chunk's operands, its ``S0`` and its ``dO``, and takes
 ``jax.vjp`` of :func:`_chunk` inside the kernel body with cotangents
 ``(dS1, dO)``: the chunk's intermediates are made again and transposed,
-float32 at ``highest`` throughout, and ``dq, dk, dv, dg`` (float32),
-``dbeta`` and the new state cotangent are written.  Four pieces of the
-chunk carry a transpose of their own (:func:`_inverse`, :func:`_pairs`,
-:func:`_diagonal`, :func:`_decayed`) where autodiff's would spend
-products the derivative does not need.  ``dbeta`` has a layout of its
+float32 at ``highest`` but for the signed sums, and ``dq, dk, dv, dg``
+(float32), ``dbeta`` and the new state cotangent are written.  Three
+pieces of the chunk carry a transpose of their own (:func:`_sums`,
+:func:`_pairs`, :func:`_inverse`) where autodiff's would spend products
+the derivative does not need.  ``dbeta`` has a layout of its
 own, ``[B, H / block, S, block]``: heads lie on a ``parallel`` axis, and
 a block of ``beta``'s ``[C, H]`` rows would be written by every block of
 heads.  The block of heads (:func:`_head_block`) is the most heads that
@@ -207,12 +220,13 @@ _NT = (((1,), (1,)), ((), ()))     # a @ b.T
 _TN = (((0,), (0,)), ((), ()))     # a.T @ b
 
 
-def _own_transpose(fwd, bwd):
+def _own_transpose(fwd, bwd, static=()):
     """``custom_vjp`` for a piece of the chunk whose transpose is cheaper
     written out than derived: ``fwd`` returns the result and what ``bwd``
-    keeps."""
+    keeps; the arguments ``static`` are Python values, handed to ``bwd``
+    first."""
     def attach(primal):
-        f = jax.custom_vjp(primal)
+        f = jax.custom_vjp(primal, nondiff_argnums=static)
         f.defvjp(fwd, bwd)
         return f
     return attach
@@ -223,50 +237,140 @@ def _iotas(n: int):
             lax.broadcasted_iota(jnp.int32, (n, n), 1))
 
 
-# The kernels are bound by the matrix unit's instructions, one a cycle: a
-# product at ``highest`` is six passes, and a pass of ``[M, K] x [K, N]``
-# (N to 128) is M/8 pushes and K/8 latches whatever of the unit it fills.
-# So the pieces below count products, not FLOPs: what autodiff would
-# transpose product by product is here written with fewer, or with none.
+# The kernels are bound by the matrix unit, so the pieces below count its
+# instructions, not FLOPs.  A float32 product at ``highest`` is six
+# passes; a pass of ``[M, k] x [k, N]`` (k, N to 128) is M/8 pushes of
+# eight rows of the left operand and 16 latches of the right one,
+# whatever of the unit they fill (in the compiler's schedule a push
+# holds one of the chip's four units for eight cycles, a latch for two).
+# So: operands that share the other side are stacked (one set of
+# latches); rows that a mask would zero are not pushed; what is a sum
+# over lanes goes to the vector unit; and the sums of ``g``, whose left
+# operand is exact in bfloat16, take the three passes that are not 0.
+# By that rule a chunk of 64 at K = V = 128 is 3,408 instructions
+# forward (1,264 pushes + 2,144 latches) and 6,960 in reverse (2,704 +
+# 4,256); before PR 32 it was 5,568 and 9,696, which is what Mosaic's
+# dump showed (now 1,208 + 2,128 and 2,600 + 4,144: a bfloat16 push is
+# 16 rows).  ``tests/test_kimi_linear.py`` counts them from the jaxpr.
 
-def _diagonal_fwd(q, k):
-    return _diagonal(q, k), (q, k)
+def _signed_sums(C: int, transposed: bool = False):
+    """``W`` of 0, +1, -1 (exact in bfloat16), ``L`` the levels of the
+    tree: ``[(L + 1) C, 2 C]``, the ``C`` columns twice, or transposed
+    ``[C, (L + 1) C]``.  Rows 0..C-1 are the triangle ``s <= t``, so that
+    ``W g`` starts with the running sum ``G``; the rows of level ``m`` are
+    ``tri[t] - tri[r]`` with ``r = (t // 2m) * 2m + m`` the first token of
+    the upper half of ``t``'s block, so that they give ``D_m = G - R_m``
+    as one sum: +1 for ``r < s <= t``, -1 for ``t < s <= r``.  Built from
+    iotas by shifts (``C`` is a power of two), level ``i >= 1`` being ``m
+    = 2^(i-1)``."""
+    bits = C.bit_length() - 1
+    shape = (C, (bits + 1) * C) if transposed else ((bits + 1) * C, 2 * C)
+    i, j = (lax.broadcasted_iota(jnp.int32, shape, d) for d in (0, 1))
+    row, s = (j, i) if transposed else (i, j & (C - 1))
+    shr, one = lax.shift_right_logical, jnp.int32(1)
+    level, t = shr(row, jnp.int32(bits)), row & (C - 1)
+    r = lax.shift_left(shr(t, level), level) \
+        + shr(lax.shift_left(one, level), one)
+    W = (s <= t).astype(F32) - ((level > 0) & (s <= r)).astype(F32)
+    return W.astype(jnp.bfloat16)
 
 
-def _diagonal_bwd(res, d):
-    q, k = res
-    t, s = _iotas(q.shape[0])
-    d = jnp.sum(jnp.where(s == t, d, 0.0), axis=1, keepdims=True)
-    return d * k, d * q
+def _split(x):
+    """float32 ``x`` as three bfloat16 pieces, ``hi + mid + lo == x`` to
+    the last bit: 3 x 8 significant bits hold float32's 24."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(F32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(F32)).astype(jnp.bfloat16)
 
 
-@_own_transpose(_diagonal_fwd, _diagonal_bwd)
+def _signed_dot(W, x):
+    """One pass of bfloat16 operands, accumulated in float32: exact for a
+    ``W`` of 0 and +-1 and a piece of :func:`_split`."""
+    return lax.dot_general(W, x, (((1,), (0,)), ((), ())),
+                           preferred_element_type=F32)
+
+
+def _sums_fwd(g):
+    return _sums(g), None
+
+
+def _sums_bwd(_, d):
+    Wt = _signed_sums(d[0].shape[0], transposed=True)
+    return (sum(_signed_dot(Wt, piece)
+                for piece in _split(jnp.concatenate(d, axis=0))),)
+
+
+@_own_transpose(_sums_fwd, _sums_bwd)
+def _sums(g):
+    """The running sum ``G`` of ``g [C, K]`` and, a level ``m`` of the
+    tree each, ``D_m = G - R_m``, ``R_m`` being ``G`` at the first token
+    of the upper half of the row's block of ``2m``: one signed product
+    (:func:`_signed_sums`) in two passes of the unit, ``hi`` and ``mid``
+    stacked on the contraction, which ``C`` rows half fill.  ``D_m`` is
+    exactly 0 on the row ``t = r``.  Its transpose is ``W^T dD``, three
+    passes."""
+    C = g.shape[0]
+    W = _signed_sums(C)
+    hi, mid, lo = _split(g)
+    D = _signed_dot(W, jnp.concatenate([hi, mid], axis=0)) \
+        + _signed_dot(W, jnp.concatenate([lo, jnp.zeros_like(lo)], axis=0))
+    return tuple(D[i * C:(i + 1) * C] for i in range(len(_levels(C)) + 1))
+
+
 def _diagonal(q, k):
-    """``Diag(q k^T)``, the pairs ``s == t`` of ``B``."""
+    """``Diag(q k^T)``, the pairs ``s == t`` of ``B``: a row sum placed
+    on the diagonal, no product, and none in its transpose."""
     t, s = _iotas(q.shape[0])
-    return _dot(q, k, _NT) * (s == t).astype(F32)
+    return jnp.where(s == t, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
 
 
-def _pairs_fwd(ku, qu, kl, mask):
-    # one product for both: they share ``kl``
-    both = jnp.concatenate([ku, qu], axis=0)
+_SUBLANES = 8      # rows of a float32 tile: slices by whole tiles are free
+
+
+def _upper(x, m: int):
+    """The rows of ``x [C, .]`` in the upper half of their block of
+    ``2m``, ``[C / 2, .]``; all rows where ``m`` is not whole tiles."""
+    if m % _SUBLANES:
+        return x
+    return jnp.concatenate([x[i + m:i + 2 * m]
+                            for i in range(0, x.shape[0], 2 * m)], axis=0)
+
+
+def _spread(y, m: int):
+    """:func:`_upper` undone: the rows back in their places, zeros in the
+    lower halves."""
+    if m % _SUBLANES:
+        return y
+    zero = jnp.zeros((m,) + y.shape[1:], y.dtype)
+    return jnp.concatenate([part for i in range(0, y.shape[0], m)
+                            for part in (zero, y[i:i + m])], axis=0)
+
+
+def _pairs_fwd(ku, qu, kl, mask, m):
+    # one product for both: they share ``kl``; and of the rows only the
+    # upper halves, which are all that ``mask`` keeps
+    both = jnp.concatenate([_upper(ku, m), _upper(qu, m)], axis=0)
     P = _dot(both, kl, _NT)
-    C = ku.shape[0]
-    return (mask * P[:C], mask * P[C:]), (both, kl, mask)
+    h = P.shape[0] // 2
+    return (mask * _spread(P[:h], m), mask * _spread(P[h:], m)), \
+        (both, kl, mask)
 
 
-def _pairs_bwd(res, d):
+def _pairs_bwd(m, res, d):
     both, kl, mask = res
-    D = jnp.concatenate([mask * d[0], mask * d[1]], axis=0)     # [2C, C]
+    D = jnp.concatenate([_upper(mask * d[0], m), _upper(mask * d[1], m)],
+                        axis=0)
     dboth = _dot(D, kl)
-    C = kl.shape[0]
-    return dboth[:C], dboth[C:], _dot(D, both, _TN), None
+    h = dboth.shape[0] // 2
+    return (_spread(dboth[:h], m), _spread(dboth[h:], m),
+            _dot(D, both, _TN), None)
 
 
-@_own_transpose(_pairs_fwd, _pairs_bwd)
-def _pairs(ku, qu, kl, mask):
-    """One level's part of ``A`` and of ``B``."""
-    return mask * _dot(ku, kl, _NT), mask * _dot(qu, kl, _NT)
+@_own_transpose(_pairs_fwd, _pairs_bwd, static=(4,))
+def _pairs(ku, qu, kl, mask, m):
+    """Level ``m``'s part of ``A`` and of ``B``."""
+    return _pairs_fwd(ku, qu, kl, mask, m)[0]
 
 
 def _inverse_fwd(N):
@@ -283,66 +387,53 @@ def _inverse_bwd(T, dT):
 def _inverse(N):
     """``(I + N)^-1`` for a strictly lower triangular ``N [C, C]``, by
     the tree of the module's text: twelve products, each waiting for the
-    last."""
+    last.  A level's ``N * mask`` is 0 outside the upper halves' rows and
+    ``T`` is still block diagonal there, so both products run on those
+    rows alone."""
     t, s = _iotas(N.shape[0])
     T = (s == t).astype(F32)
     for m in _levels(N.shape[0]):
-        T = T - _dot(T, _dot(N * _level_mask(t, s, m).astype(F32), T))
+        X = _dot(_upper(N * _level_mask(t, s, m).astype(F32), m), T)
+        T = T - _spread(_dot(_upper(T, m), _spread(X, m)), m)
     return T
 
 
-def _diag_of(e):
-    """``Diag(e)`` of a row ``e [1, K]``."""
-    K = e.shape[1]
-    r, c = _iotas(K)
-    return jnp.where(r == c, jnp.broadcast_to(e, (K, K)), 0.0)
-
-
-def _decayed_fwd(e, S0):
-    return _decayed(e, S0), (e, S0)
-
-
-def _decayed_bwd(res, dS1):
-    e, S0 = res
-    K = e.shape[1]
-    r, c = _iotas(K)
-    column = jnp.sum(_diag_of(e), axis=1, keepdims=True)            # [K, 1]
-    de = jnp.sum(dS1 * S0, axis=1, keepdims=True)                   # [K, 1]
-    de = jnp.sum(jnp.where(r == c, jnp.broadcast_to(de, (K, K)), 0.0),
-                 axis=0, keepdims=True)                             # [1, K]
-    return de, column * dS1
-
-
-@_own_transpose(_decayed_fwd, _decayed_bwd)
 def _decayed(e, S0):
-    """``Diag(e) S0``: row ``c`` of ``S0 [K, V]`` scaled by ``e[0, c]``;
-    as a product with the diagonal matrix, so that no ``[1, K] -> [K,
-    1]`` relayout is asked for."""
-    return _dot(_diag_of(e), S0)
+    """``Diag(e) S0``: row ``c`` of ``S0 [K, V]`` scaled by ``e[0, c]``.
+    The row ``e [1, K]`` becomes the column ``[K, 1]`` as ``Diag(e)``
+    summed over its lanes, so that no product and no ``[1, K] -> [K, 1]``
+    relayout is asked for, here or in the transpose."""
+    K = e.shape[1]
+    r, c = _iotas(K)
+    column = jnp.sum(jnp.where(r == c, jnp.broadcast_to(e, (K, K)), 0.0),
+                     axis=1, keepdims=True)
+    return column * S0
 
 
 def _chunk(S0, q, k, v, g, beta):
     """One chunk of one head on values, as the kernels run it: ``S0 [K,
     V]``; ``q, k, g [C, K]``; ``v [C, V]``; ``beta [C, 1]``; all float32.
     Returns ``(S1, O)``.  :func:`_chunk_step`'s arithmetic in the forms
-    Mosaic lowers: two-dimensional products only, ``R`` picked by a
-    one-hot product."""
+    Mosaic lowers: two-dimensional products only, the running sum and its
+    rebased differences from one signed product, the rows a level's
+    mask keeps compacted where they are whole tiles."""
     C = q.shape[0]
     t, s = _iotas(C)
-    G = _dot((s <= t).astype(F32), g)
+    G, *Ds = _sums(g)
     A = jnp.zeros((C, C), F32)
     Bm = _diagonal(q, k)
-    for m in _levels(C):
-        # R: G at row (t // 2m) * 2m + m, picked by a one-hot product.
-        R = _dot((s == (t // (2 * m)) * (2 * m) + m).astype(F32), G)
-        up = jnp.exp(jnp.minimum(G - R, 0.0))
-        kl = k * jnp.exp(jnp.minimum(R - G, 0.0))
+    for m, D in zip(_levels(C), Ds):
+        # D = G - R, R being G at row (t // 2m) * 2m + m
+        up = jnp.exp(jnp.minimum(D, 0.0))
+        kl = k * jnp.exp(jnp.minimum(-D, 0.0))
         Am, Bmm = _pairs(k * up, q * up, kl,
-                         _level_mask(t, s, m).astype(F32))
+                         _level_mask(t, s, m).astype(F32), m)
         A, Bm = A + Am, Bm + Bmm
     eG = jnp.exp(G)
-    U = _dot(_inverse(beta * A), beta * (v - _dot(k * eG, S0)))
-    O = _dot(q * eG, S0) + _dot(Bm, U)
+    # k and q against the state in one product: they share ``S0``
+    from_state = _dot(jnp.concatenate([k * eG, q * eG], axis=0), S0)
+    U = _dot(_inverse(beta * A), beta * (v - from_state[:C]))
+    O = from_state[C:] + _dot(Bm, U)
     Gc = G[C - 1:C, :]                              # [1, K]
     S1 = _decayed(jnp.exp(Gc), S0) + _dot(k * jnp.exp(Gc - G), U, _TN)
     return S1, O
@@ -430,19 +521,19 @@ def _bwd_resident(heads: int, chunk: int, K: int, V: int,
     heads, in tiles of ``[chunk, max(K, V)]`` float32 padded to whole
     128-lane tiles: a head's blocks in two buffers each (``q, k, dq, dk``
     and ``v, dO, dv`` in the operands' type, ``g, dg`` and the state in
-    float32), its state's cotangent and a tile more; and the 165 tiles
+    float32), its state's cotangent and a tile more; and the 140 tiles
     one head's chunk keeps between its forward and its transpose, which
     the heads pass through one after another.  Checked against the
     compiler: ahead-of-time compiles for a v5e (jax 0.9.0, libtpu
-    0.0.34; chunk 64, K = V = 128, bf16) need 5.5, 6.2, 7.1, 9.3 and
-    13.6 MiB for 1, 2, 4, 8 and 16 heads (5.7, 6.3, 7.4, 9.7, 14.2
+    0.0.34; chunk 64, K = V = 128, bf16) need 4.6, 5.4, 6.4, 8.5 and
+    12.8 MiB for 1, 2, 4, 8 and 16 heads (4.9, 5.5, 6.6, 8.9, 13.4
     here)."""
     lanes = lambda d: -(-d // 128) * 128
     k, v = lanes(K), lanes(V)
     tile = chunk * max(k, v, lanes(chunk)) * 4
     blocks = chunk * (4 * k + 3 * v) * itemsize + 2 * chunk * k * 4 \
         + K * v * 4
-    return heads * (2 * blocks + K * v * 4 + tile) + 165 * tile
+    return heads * (2 * blocks + K * v * 4 + tile) + 140 * tile
 
 
 def _head_block(H: int, chunk: int, K: int, V: int, itemsize: int) -> int:
@@ -450,9 +541,9 @@ def _head_block(H: int, chunk: int, K: int, V: int, itemsize: int) -> int:
     divide ``H``, keep a block's columns whole lane tiles (or are all of
     ``H``) and fit :data:`_SCOPED_VMEM` by :func:`_bwd_resident` and half
     as much again; one if none does.  On the v5e at the published shape
-    1 to 32 heads a step differ by under 1% (a head takes 10 us, a grid
-    step's own cost 2% of that), so the rule only has to stay inside the
-    VMEM every kernel gets."""
+    1 to 32 heads a step differed by under 1% when a head took 10 us (PR
+    30; 6 us since PR 32) against a grid step's own 0.2, so the rule
+    only has to stay inside the VMEM every kernel gets."""
     whole = lambda n: n == H or (n * K) % 128 == (n * V) % 128 == 0
     return max([n for n in range(1, H + 1) if H % n == 0 and whole(n)
                 and _bwd_resident(n, chunk, K, V, itemsize) * 3 // 2

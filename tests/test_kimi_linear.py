@@ -134,6 +134,122 @@ def test_gradient_lowers_to_the_reverse_kernel_and_no_scan():
     assert "while" not in text
 
 
+def _unit_instructions(jaxpr) -> int:
+    """Matrix-unit instructions of every ``dot_general`` in ``jaxpr``, by
+    the rule ``ops/kda.py`` is written to: a pass of ``[M, k] x [k, N]``
+    is M/8 pushes and 16 latches a 128-wide tile of the contraction and
+    of N; float32 operands (all at ``highest``) take six passes, bfloat16
+    one.  On the parent of PR 32 this is the Mosaic dump's count of
+    ``vmatmul`` and ``vlatch`` to the instruction."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += _unit_instructions(sub)
+        if eqn.primitive.name != "dot_general":
+            continue
+        a, b = (x.aval for x in eqn.invars)
+        ((ca,), _), _ = eqn.params["dimension_numbers"]
+        k = a.shape[ca]
+        m, n = a.size // k, b.size // k
+        if a.dtype == jnp.float32:
+            assert eqn.params["precision"] is not None and all(
+                p == jax.lax.Precision.HIGHEST
+                for p in eqn.params["precision"]), eqn
+            passes = 6
+        else:
+            assert a.dtype == b.dtype == jnp.bfloat16, eqn
+            passes = 1
+        total += passes * -(-k // 128) * -(-n // 128) * (m // 8 + 16)
+    return total
+
+
+def test_chunk_issues_fewer_matrix_unit_instructions():
+    """What PR 32 is about, counted where it cannot drift: the chunk at
+    the published shape (C 64, K = V = 128), forward and reverse (the
+    ``jax.vjp`` the reverse kernel takes, dead code dropped as Mosaic
+    drops it).  The parent counted 5,568 and 9,696, which is what its
+    Mosaic dump showed; a product put back shows here."""
+    from jax.interpreters import partial_eval as pe
+    C, K = 64, 128
+    z = lambda *s: jnp.zeros(s, jnp.float32)
+    args = (z(K, K), z(C, K), z(C, K), z(C, K), z(C, K), z(C, 1))
+
+    def reverse(*a):
+        return jax.vjp(kda._chunk, *a)[1]((z(K, K), z(C, K)))
+
+    fwd = jax.make_jaxpr(kda._chunk)(*args).jaxpr
+    rev = jax.make_jaxpr(reverse)(*args).jaxpr
+    rev, _ = pe.dce_jaxpr(rev, [True] * len(rev.outvars))
+    parent_fwd, parent_rev = 5568, 9696
+    assert _unit_instructions(fwd) == 3408 <= 4300 < parent_fwd
+    assert _unit_instructions(rev) == 6960 <= 0.85 * parent_rev
+
+
+@pytest.mark.parametrize("decay", ["weak", "strong"])
+def test_kernels_chunk_is_the_plain_step_at_64_tokens(decay):
+    """``_chunk`` at the published chunk, where three levels of the tree
+    (8, 16, 32) push their upper halves' rows only, against the plain
+    step of ``chunk_kda_jnp``, which pushes all: both results and all six
+    cotangents.  (The recurrence tests run chunks of 16: one such level.)"""
+    C = 64
+    q, k, v, g, beta = (x[0, :, 0] for x in _kda_inputs(
+        decay == "strong", B=1, S=C, H=1, K=16, V=32))
+    ks = jax.random.split(jax.random.PRNGKey(13), 3)
+    args = (jax.random.normal(ks[0], (16, 32)), q, k, v, g, beta[:, None])
+    plain = lambda S0, q, k, v, g, beta: kda._chunk_step(
+        S0, q, k, v, g, beta[:, 0])
+    cot = (jax.random.normal(ks[1], (16, 32)),
+           jax.random.normal(ks[2], (C, 32)))
+    got, got_vjp = jax.vjp(kda._chunk, *args)
+    want, want_vjp = jax.vjp(plain, *args)
+    for a, b in zip(got + got_vjp(cot), want + want_vjp(cot)):
+        _close(a, b, 2e-5)
+
+
+@pytest.mark.parametrize("decay", ["weak", "strong"])
+def test_signed_sums_are_exact(decay):
+    """The one product below ``highest``: its left operand is 0 and +-1,
+    the right one is split into three bfloat16 pieces that add up to it
+    bit for bit, so nothing is lost: the running sum is a float64 one to
+    float32 accumulation's own error, and the rebased difference is
+    exactly 0 on the row it is rebased on."""
+    C = 64
+    g = _kda_inputs(decay == "strong", B=1, S=C, H=1, K=128)[3][0, :, 0]
+    if decay == "strong":
+        assert float(g.sum(0).min()) < -200
+    hi, mid, lo = kda._split(g)
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    f32 = lambda x: x.astype(jnp.float32)
+    assert jnp.array_equal((f32(hi) + f32(mid)) + f32(lo), g)
+
+    W = np.asarray(kda._signed_sums(C).astype(jnp.float32))
+    assert set(np.unique(W)) == {-1.0, 0.0, 1.0}
+    assert np.array_equal(W[:, :C], W[:, C:])          # the columns twice
+    assert np.array_equal(
+        np.asarray(kda._signed_sums(C, transposed=True).astype(jnp.float32)),
+        W[:, :C].T)
+
+    G, *Ds = kda._sums(g)
+    g64 = np.asarray(g, np.float64)
+    room = C * 2.0 ** -24 * np.abs(g64).sum(0)
+    assert np.all(np.abs(np.asarray(G, np.float64) - np.cumsum(g64, 0))
+                  <= room)
+    t = np.arange(C)
+    for m, D in zip(kda._levels(C), Ds):
+        r = (t // (2 * m)) * (2 * m) + m
+        want = np.cumsum(g64, 0) - np.cumsum(g64, 0)[r]
+        assert np.all(np.abs(np.asarray(D, np.float64) - want) <= room)
+        assert np.all(np.asarray(D)[r] == 0.0)             # rows t = r
+
+    # its own transpose against autodiff of the plain running sums
+    d = jax.random.normal(jax.random.PRNGKey(11), (len(Ds) + 1, C, 128))
+    plain = lambda g: jnp.stack(
+        [jnp.cumsum(g, 0)] + [jnp.cumsum(g, 0) - jnp.cumsum(g, 0)[
+            (t // (2 * m)) * (2 * m) + m] for m in kda._levels(C)])
+    got = jax.grad(lambda g: jnp.sum(jnp.stack(kda._sums(g)) * d))(g)
+    _close(got, jax.grad(lambda g: jnp.sum(plain(g) * d))(g), 1e-5)
+
+
 # -- the blocks against the reference -----------------------------------------
 
 def _layer_weights(i):
