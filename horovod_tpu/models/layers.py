@@ -1,9 +1,12 @@
 """The decoder block, written once, and the pieces every decoder shares.
 
 A decoder layer is :func:`block`: ``h + mixer(norm(h))`` then ``h +
-mlp(norm(h))``.  A layer *kind* is a ``(mixer, mlp)`` pair of callables,
-each ``(x, lp) -> (y, extra)``; what differs between training, prefill
-and a paged decode tick of one model is passed in, never branched on:
+mlp(norm(h))``, each branch normed once more before it is added where the
+layer has the gains for it (sandwich norm).  A stack whose weights are
+used several times over is :func:`looped`.  A layer *kind* is a ``(mixer,
+mlp)`` pair of callables, each ``(x, lp) -> (y, extra)``; what differs
+between training, prefill and a paged decode tick of one model is passed
+in, never branched on:
 
 - the **mixer** (:func:`gqa_mixer` for the Llama family; Kimi-Linear
   brings its KDA and latent-attention mixers) takes an ``attend``
@@ -133,11 +136,46 @@ def dense_mlp(x2, lp):
 def block(h, lp, mixer, mlp, eps: float = 1e-5):
     """The pre-norm decoder block: ``h + mixer(norm(h))``, then ``h +
     mlp(norm(h))``.  ``mixer`` and ``mlp`` are ``(x, lp) -> (y, extra)``;
-    returns ``(h, the mixer's extra, the mlp's extra)``."""
+    returns ``(h, the mixer's extra, the mlp's extra)``.  A layer that
+    holds ``attn_post_norm`` and ``mlp_post_norm`` (sandwich norm) norms
+    each branch's output with them before the residual takes it."""
     y, kept = mixer(rmsnorm(h, lp["attn_norm"], eps), lp)
+    if "attn_post_norm" in lp:
+        y = rmsnorm(y, lp["attn_post_norm"], eps)
     h = h + y
     y, aux = mlp(rmsnorm(h, lp["mlp_norm"], eps), lp)
+    if "mlp_post_norm" in lp:
+        y = rmsnorm(y, lp["mlp_post_norm"], eps)
     return h + y, kept, aux
+
+
+def looped(layer, carry, stacked, loops: int, renorm, xs=None,
+           unroll: int = 1):
+    """The scan of a stack run ``loops`` times over the same weights (a
+    looped transformer).  ``layer((h, rest), (lp, x)) -> ((h, rest),
+    out)`` is one layer; ``stacked`` holds the layers' leaves on a leading
+    axis of ``L``, ``xs`` what differs by pass and layer (leading axis
+    ``loops * L``: step ``t * L + l`` is layer ``l`` of pass ``t``), the
+    ``out`` leaves come back stacked as deep.  Every pass after the first
+    starts from ``renorm`` of what the pass before it left, so with the
+    head's own norm after the last, each pass ends normed.  One loop over
+    all steps, the layer picked by index: the weights are each step's to
+    read where they lie, as in the plain decoder's scan, which is
+    ``loops == 1``."""
+    if loops == 1:
+        return jax.lax.scan(layer, carry, (stacked, xs), unroll=unroll)
+    L = jax.tree.leaves(stacked)[0].shape[0]
+
+    def step(carry, ix):
+        i, x = ix
+        h, rest = carry
+        h = jax.lax.cond((i % L == 0) & (i > 0), renorm, lambda h: h, h)
+        lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, i % L, keepdims=False), stacked)
+        return layer((h, rest), (lp, x))
+
+    return jax.lax.scan(step, carry, (jnp.arange(loops * L), xs),
+                        unroll=unroll)
 
 
 def gqa_mixer(x, lp, tables, attend):

@@ -47,6 +47,7 @@ from .layers import (  # noqa: F401  (attention_path: re-exported)
     embed_lookup,
     gather_blocks,
     gqa_mixer,
+    looped,
     remat,
     rmsnorm,
     rope_tables,
@@ -102,10 +103,30 @@ class LlamaConfig:
     # fit.  Its price in tokens/s and in temporaries is ROADMAP Speed 8's
     # to measure on the chip.
     blockwise_ce: bool = False
+    # RMSNorm's epsilon, every norm of the model.
+    rms_eps: float = 1e-5
+    # Sandwich norm: each layer norms its attention's and its MLP's output
+    # once more (leaves attn_post_norm, mlp_post_norm) before the
+    # residual takes it.
+    sandwich_norm: bool = False
+    # A looped stack: the n_layers layers run ``loops`` times over the
+    # same weights, each pass closed by the final norm, the head on the
+    # last pass's output.  A query of pass t attends to the keys and
+    # values pass t made, so the serving cache is ``cache_layers`` deep.
+    # Every token takes every pass (no early exit).  Served and
+    # generated; training such a stack wants the objective over the
+    # passes' exits, which is not here (make_train_step refuses).
+    loops: int = 1
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of K and V a token leaves in a cache: one for every
+        (pass, layer)."""
+        return self.loops * self.n_layers
 
     @staticmethod
     def tiny(**kw) -> "LlamaConfig":
@@ -134,6 +155,9 @@ def param_logical_dims(cfg: LlamaConfig) -> dict:
         "wo": ("stage", "heads", "head_dim", "embed"),
         "mlp_norm": ("stage", None),
     }
+    if cfg.sandwich_norm:
+        layer.update({"attn_post_norm": ("stage", None),
+                      "mlp_post_norm": ("stage", None)})
     if cfg.use_moe:
         layer.update({
             "router": ("stage", None, None),
@@ -202,6 +226,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array, mesh: Optional[Mesh] = None
             "wo": rnd(ks[3], (L, H, Dh, D), H * Dh),
             "mlp_norm": norm((L, D)),
         }
+        if cfg.sandwich_norm:
+            layers.update({"attn_post_norm": norm((L, D)),
+                           "mlp_post_norm": norm((L, D))})
         if cfg.use_moe:
             E = cfg.n_experts
             layers.update({
@@ -365,6 +392,10 @@ def _tp_sum(f):
 
 def _check_stage_split(cfg: LlamaConfig, mesh: Mesh) -> None:
     pp, tp = mesh.shape["pp"], mesh.shape.get("tp", 1)
+    if cfg.loops > 1:
+        raise NotImplementedError(
+            "a looped stack (loops > 1) has no pipelined form: every pass "
+            "would cross all the stages; use a pp=1 mesh")
     if cfg.n_layers % pp:
         raise ValueError(
             f"pp={pp} must divide n_layers={cfg.n_layers} evenly")
@@ -415,7 +446,8 @@ def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
     def layer_body(h, lp, rope):
         h, _, aux = block(
             h, _gather_fsdp(lp, cfg),
-            _tp_sum(partial(gqa_mixer, tables=rope, attend=attend)), mlp)
+            _tp_sum(partial(gqa_mixer, tables=rope, attend=attend)), mlp,
+            cfg.rms_eps)
         return h, jnp.zeros((), jnp.float32) if aux is None else aux
 
     body = remat(layer_body, cfg.remat)
@@ -449,11 +481,13 @@ def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
     }
 
 
-def _head(params, h, dims=None, mesh: Optional[Mesh] = None, rules=None):
+def _head(params, h, cfg: LlamaConfig, dims=None,
+          mesh: Optional[Mesh] = None, rules=None):
     """Final norm and lm_head on ``h [..., D]``: float32 logits, pinned to
     the logical ``dims`` under a mesh."""
-    logits = jnp.einsum("...d,dv->...v", rmsnorm(h, params["final_norm"]),
-                        params["lm_head"])
+    logits = jnp.einsum(
+        "...d,dv->...v", rmsnorm(h, params["final_norm"], cfg.rms_eps),
+        params["lm_head"])
     if mesh is not None:
         logits = shd.constrain(logits, dims, mesh, rules)
     return logits.astype(jnp.float32)
@@ -520,7 +554,7 @@ def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
                    out_specs=(act_spec, P()), check_vma=False)
     h, aux = fn(params["layers"], h)
     h = shd.constrain(h, ("batch", "seq", None), mesh)
-    return _head(params, h, ("batch", "seq", "vocab"), mesh), aux
+    return _head(params, h, cfg, ("batch", "seq", "vocab"), mesh), aux
 
 
 def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
@@ -559,7 +593,7 @@ def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
         if mesh is not None:
             lp = {k: shd.constrain(v, layer_dims[k], mesh, rules)
                   for k, v in lp.items()}
-        h, _, moe_aux = block(h, lp, mixer, mlp)
+        h, _, moe_aux = block(h, lp, mixer, mlp, cfg.rms_eps)
         if moe_aux is not None:
             aux = aux + moe_aux
         if mesh is not None:
@@ -567,11 +601,14 @@ def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
         return (h, aux), None
 
     body = remat(layer_body, cfg.remat)
-    (h, aux), _ = lax.scan(body, (h, jnp.zeros((), jnp.float32)),
-                           params["layers"], unroll=cfg.scan_unroll)
+    final_norm = lambda h: rmsnorm(h, params["final_norm"], cfg.rms_eps)
+
+    (h, aux), _ = looped(lambda carry, x: body(carry, x[0]),
+                         (h, jnp.zeros((), jnp.float32)), params["layers"],
+                         cfg.loops, final_norm, unroll=cfg.scan_unroll)
     if return_hidden:
-        return rmsnorm(h, params["final_norm"]), aux
-    return _head(params, h, ("batch", "seq", "vocab"), mesh), aux
+        return final_norm(h), aux
+    return _head(params, h, cfg, ("batch", "seq", "vocab"), mesh), aux
 
 
 def _pick_token(logits, step_key, temperature, dtype):
@@ -623,11 +660,12 @@ def _sample_loop(prefill, tick, head, prompt, max_new_tokens: int,
 
 def _cache_stack(cfg: LlamaConfig, h, layers, ck, cv, pos,
                  pin=lambda c: c, gather=lambda lp: lp,
-                 row_parallel=lambda f: f):
+                 row_parallel=lambda f: f, renorm=None):
     """The layer stack of the dense-cache decoders (:func:`generate` and
     its pp form) on ``h [B, S, D]``, the tokens at positions ``pos`` on,
-    against the caches ``ck, cv [L, B, T, KV, Dh]``: each layer's fresh
-    K/V go into its cache (``pin`` constrains the result).  One token a
+    against the caches ``ck, cv [loops * L, B, T, KV, Dh]``: each layer's
+    fresh K/V go into the cache of its pass (``pin`` constrains the
+    result; ``renorm``, the final norm, stands between passes).  One token a
     row (a decode tick) scores q against the cache; a prompt scores it
     against its own keys only — scoring the zero-padded T-length cache
     would pay T/P times the prefill attention FLOPs on masked slots.
@@ -642,8 +680,9 @@ def _cache_stack(cfg: LlamaConfig, h, layers, ck, cv, pos,
         else (jnp.arange(ck.shape[2]) <= pos)[None, :]              # [1, T]
     put = lambda c, new: pin(lax.dynamic_update_slice(c, new, (0, pos, 0, 0)))
 
-    def layer(h, xs):
-        lp, ck, cv = xs
+    def layer(carry, xs):
+        h, _ = carry
+        lp, (ck, cv) = xs
 
         def attend(q, k1, v1):
             ck2, cv2 = put(ck, k1), put(cv, v1)
@@ -653,10 +692,12 @@ def _cache_stack(cfg: LlamaConfig, h, layers, ck, cv, pos,
         h, kept, _ = block(
             h, gather(lp),
             row_parallel(partial(gqa_mixer, tables=tables, attend=attend)),
-            row_parallel(dense_mlp))
-        return h, kept
+            row_parallel(dense_mlp), cfg.rms_eps)
+        return (h, None), kept
 
-    return lax.scan(layer, h, (layers, ck, cv))
+    (h, _), kept = looped(layer, (h, None), layers, cfg.loops, renorm,
+                          (ck, cv))
+    return h, kept
 
 
 def _generate_pp(params: dict, prompt: jax.Array, cfg: LlamaConfig,
@@ -731,8 +772,8 @@ def _generate_pp(params: dict, prompt: jax.Array, cfg: LlamaConfig,
         return h, (ck, cv)
 
     return _sample_loop(
-        prefill, tick, partial(_head, params, dims=("batch", "vocab"),
-                               mesh=mesh),
+        prefill, tick, partial(_head, params, cfg=cfg,
+                               dims=("batch", "vocab"), mesh=mesh),
         prompt, max_new_tokens, temperature, key)
 
 
@@ -778,22 +819,23 @@ def generate(params: dict, prompt: jax.Array, cfg: LlamaConfig, *,
     T = P + max_new_tokens
     KV, Dh = cfg.n_kv_heads, cfg.head_dim
     pin = _pin_kv(cfg, mesh)
+    renorm = lambda h: rmsnorm(h, params["final_norm"], cfg.rms_eps)
 
     def prefill(prompt):
         # Build the cache over the prompt.
-        cache0 = jnp.zeros((cfg.n_layers, B, T, KV, Dh), cfg.dtype)
+        cache0 = jnp.zeros((cfg.cache_layers, B, T, KV, Dh), cfg.dtype)
         return _cache_stack(
             cfg, embed_lookup(params["embed"], prompt, cfg.dtype),
-            params["layers"], cache0, cache0, 0, pin)
+            params["layers"], cache0, cache0, 0, pin, renorm=renorm)
 
     def tick(caches, tok, pos):
         # One token per row, cache append.
         return _cache_stack(
             cfg, embed_lookup(params["embed"], tok[:, None], cfg.dtype),
-            params["layers"], *caches, pos, pin)
+            params["layers"], *caches, pos, pin, renorm=renorm)
 
-    return _sample_loop(prefill, tick, partial(_head, params), prompt,
-                        max_new_tokens, temperature, key)
+    return _sample_loop(prefill, tick, partial(_head, params, cfg=cfg),
+                        prompt, max_new_tokens, temperature, key)
 
 
 # ---------------------------------------------------------------------------
@@ -807,10 +849,13 @@ def _serve_layers(params, tok, positions, cfg: LlamaConfig, mesh, attend,
                   state=None):
     """The skeleton under the three serving steps: embed ``tok [B, S]``,
     the rope tables of ``positions [B, S]``, then the frame scanned over
-    (layers, layer index).  ``attend(q, k, v, li, state) -> (o, (state,
-    out))`` is the step's own: where the layer's K and V go and what q
-    attends over; ``state`` (the pools) rides the scan's carry, ``out``
-    (a layer's K and V) is stacked.  Returns ``(h, state, outs)``."""
+    (layers, cache layer index), ``cfg.loops`` times over the same
+    stacked weights (:func:`~horovod_tpu.models.layers.looped`).
+    ``attend(q, k, v, li, state) -> (o, (state, out))`` is the step's
+    own: where the layer's K and V go and what q attends over; ``li`` is
+    the cache layer, ``t * n_layers + l`` in pass ``t``; ``state`` (the
+    pools) rides the scan's carry, ``out`` (a layer's K and V) is
+    stacked, ``cache_layers`` deep.  Returns ``(h, state, outs)``."""
     h = embed_lookup(params["embed"], tok, cfg.dtype)
     if mesh is not None:
         h = shd.constrain(h, ("batch", None, None), mesh,
@@ -823,11 +868,13 @@ def _serve_layers(params, tok, positions, cfg: LlamaConfig, mesh, attend,
         h, (state, out), _ = block(
             h, lp, partial(gqa_mixer, tables=tables,
                            attend=partial(attend, li=li, state=state)),
-            dense_mlp)
+            dense_mlp, cfg.rms_eps)
         return (h, state), out
 
-    (h, state), outs = lax.scan(
-        layer, (h, state), (params["layers"], jnp.arange(cfg.n_layers)))
+    (h, state), outs = looped(
+        layer, (h, state), params["layers"], cfg.loops,
+        lambda h: rmsnorm(h, params["final_norm"], cfg.rms_eps),
+        jnp.arange(cfg.cache_layers))
     return h, state, outs
 
 
@@ -838,9 +885,10 @@ def prefill_step(params, tokens: jax.Array, cfg: LlamaConfig, *,
     """Prompt prefill for the serving engine.
 
     tokens [B, P] int32 → (next-token greedy tokens' logits [B, V] fp32,
-    per-layer K [L, B, P, KV, Dh], per-layer V).  ``last_pos`` [B] selects
-    the logits position per row (bucketed prompts are right-padded: the
-    real last token sits at ``len-1``, not ``P-1``); None means ``P-1``.
+    per-cache-layer K [cache_layers, B, P, KV, Dh], per-cache-layer V).
+    ``last_pos`` [B] selects the logits position per row (bucketed
+    prompts are right-padded: the real last token sits at ``len-1``, not
+    ``P-1``); None means ``P-1``.
     Causality makes the padded tail inert for every real position, so a
     bucketed prefill emits the same token as an exact-length one."""
     B, P = tokens.shape
@@ -860,7 +908,8 @@ def prefill_step(params, tokens: jax.Array, cfg: LlamaConfig, *,
     else:
         h_last = jnp.take_along_axis(
             h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return _head(params, h_last, ("batch", "vocab"), mesh, rules), ks, vs
+    return (_head(params, h_last, cfg, ("batch", "vocab"), mesh, rules),
+            ks, vs)
 
 
 def paged_kernel_ok(cfg: LlamaConfig, mesh: Optional[Mesh],
@@ -941,7 +990,7 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
     """One decode tick for the serving engine against the paged pool.
 
     tok [B] int32 (this tick's input token per slot); positions [B] its
-    absolute position; k_pool/v_pool [L, NB, BS, KV, Dh]; tables
+    absolute position; k_pool/v_pool [cache_layers, NB, BS, KV, Dh]; tables
     [B, n_cols] int32 block tables (inactive rows all-scratch).  Each
     layer writes its fresh K/V into ``tables[b][positions[b] // BS]`` at
     offset ``positions[b] % BS`` and attends over the table's logical
@@ -962,7 +1011,7 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
         _paged_attend(cfg, mesh, tables, blk, positions % BS, mask,
                       positions if use_flash else None, interpret),
         (k_pool, v_pool))
-    return (_head(params, h[:, 0], ("batch", "vocab"), mesh,
+    return (_head(params, h[:, 0], cfg, ("batch", "vocab"), mesh,
                   shard_rules(cfg, mesh)), k_pool, v_pool)
 
 
@@ -1006,7 +1055,7 @@ def extend_step_paged(params, tok: jax.Array, positions: jax.Array,
     h, (k_pool, v_pool), _ = _serve_layers(
         params, tok, positions, cfg, mesh,
         _paged_attend(cfg, mesh, tables, blk, off, mask), (k_pool, v_pool))
-    return (_head(params, h, ("batch", None, "vocab"), mesh,
+    return (_head(params, h, cfg, ("batch", None, "vocab"), mesh,
                   shard_rules(cfg, mesh)), k_pool, v_pool)
 
 
@@ -1143,7 +1192,7 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
             }
 
             def loss_head(head, y, m):
-                h2 = rmsnorm(y, head["final_norm"])
+                h2 = rmsnorm(y, head["final_norm"], cfg.rms_eps)
                 logits = jnp.einsum("bsd,dv->bsv", h2, head["lm_head"]
                                     ).astype(jnp.float32)
                 # CE over the tp-sharded vocab.  The max shift is taken on
@@ -1227,6 +1276,12 @@ def make_train_step(cfg, mesh: Mesh, tx, *,
     microbatches) or "gpipe" (autodiff through the fill-drain forward);
     both are the Llama stack's."""
     model = model or _THIS
+    if getattr(cfg, "loops", 1) > 1:
+        raise NotImplementedError(
+            f"loops={cfg.loops}: training a looped stack needs its own "
+            "objective, each pass's loss weighed by a learned exit "
+            "distribution with an entropy term, which is not here; plain "
+            "cross-entropy on the last pass would train another model")
     if mesh.shape.get("pp", 1) > 1 and model is not _THIS:
         raise NotImplementedError(
             f"{model.__name__} has no pipelined forward; use a pp=1 mesh")

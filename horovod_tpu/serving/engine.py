@@ -3,7 +3,8 @@
 Transport half of the policy/transport split (the scheduler decides what
 runs; this owns how it runs on devices):
 
-- **Page pool** — ``[L, num_blocks, block_size, KV, Dh]`` K and V arrays,
+- **Page pool** — ``[L, num_blocks, block_size, KV, Dh]`` K and V arrays
+  (``L`` the model's ``cache_layers``: its layers times its passes),
   allocated once, donated through every jitted step so writes land in
   place.  On a mesh the pool is constrained ``kv_heads`` over tp (the
   round-5 never-replicate-the-cache rule) and activations ``batch`` over
@@ -69,6 +70,17 @@ _m_table_slots = _obs.counter(
 _m_table_blocks = _obs.counter(
     "hvd_serving_decode_table_blocks_total",
     "of those, entries that name a real (non-scratch) block")
+_m_reprefill_tokens = _obs.counter(
+    "hvd_serving_reprefill_tokens_total",
+    "of the prompt tokens prefilled, those of a preempted request's "
+    "prefill after it came back (prompt and what it had generated)")
+# What a token costs the pool, set once an engine is built.
+_m_cache_layers = _obs.gauge(
+    "hvd_serving_cache_layers",
+    "K/V layers of the page pool: the model's layers x its passes")
+_m_kv_bytes_per_token = _obs.gauge(
+    "hvd_serving_kv_bytes_per_token",
+    "bytes of K and V one token holds in the pool, all cache layers")
 
 _span = _trace.profiler_span
 
@@ -159,10 +171,14 @@ class ServingEngine:
         self._jax, self._jnp = jax, jnp
 
         self.cache = PagedKVCache(
-            n_layers=cfg.n_layers, num_blocks=engine_cfg.num_blocks,
+            n_layers=cfg.cache_layers, num_blocks=engine_cfg.num_blocks,
             block_size=engine_cfg.block_size, kv_heads=cfg.n_kv_heads,
             head_dim=cfg.head_dim)
         self.pager = KVPager(self.cache)
+        _m_cache_layers.set(self.cache.n_layers)
+        _m_kv_bytes_per_token.set(
+            self.cache.bytes_per_block(jnp.dtype(cfg.dtype).itemsize)
+            // self.cache.block_size)
         self.prefix_cache = None
         if engine_cfg.prefix_cache:
             from .frontdoor.prefix_cache import PrefixCache
@@ -185,6 +201,8 @@ class ServingEngine:
         self.k_pool = fresh_pool()
         self.v_pool = fresh_pool()
 
+        #: what the prefill and decode spans say of the model's depth
+        self._depth = dict(loops=cfg.loops, cache_layers=cfg.cache_layers)
         self._slots: list[Optional[Request]] = \
             [None] * engine_cfg.max_active
         self._next_id = 0
@@ -354,8 +372,10 @@ class ServingEngine:
                 admitted = self.scheduler.admit()
             for req in admitted:
                 self._assign_slot(req)
-                _m_prefill_tokens.inc(
-                    int(req.prefill_tokens.shape[0]) - req.cached_tokens)
+                n = int(req.prefill_tokens.shape[0]) - req.cached_tokens
+                _m_prefill_tokens.inc(n)
+                if req.preemptions:
+                    _m_reprefill_tokens.inc(n)
                 emitted.append((req, self._prefill_one(req)))
                 if req.migrate_cb is not None \
                         and req.state == RequestState.RUNNING:
@@ -365,7 +385,7 @@ class ServingEngine:
                     # replica continue the request (serving/disagg).
                     self._migrate_out(req)
             if self.scheduler.running:
-                with _span("hvd.serve.decode") as tick:
+                with _span("hvd.serve.decode", **self._depth) as tick:
                     ticked = (self.spec.tick(tick) if self.spec is not None
                               else self._decode_tick(tick))
                 _m_decode_tokens.inc(len(ticked))
@@ -424,7 +444,8 @@ class ServingEngine:
         P = int(toks.shape[0])
         Pb = self._bucket_len(P)
         sp = req.open_phase("prefill", tokens=P, bucket=Pb)
-        with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=0):
+        with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=0,
+                   resumed=P if req.preemptions else 0, **self._depth):
             # The span is the context's current span while the prefill
             # dispatches, so nested layers (collectives the model
             # enqueues) attach their events to this request's chain.
@@ -471,7 +492,8 @@ class ServingEngine:
         S = P - C
         Sb = _bucket_pow2(S)
         sp = req.open_phase("prefill", tokens=P, cached=C, bucket=Sb)
-        with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=C):
+        with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=C,
+                   resumed=S if req.preemptions else 0, **self._depth):
             with sp.use(), _span("hvd.serve.prefill.dispatch"):
                 req.trace.event("prefill_skip", cached_tokens=C)
                 tok2 = np.zeros((1, Sb), np.int32)
@@ -507,14 +529,17 @@ class ServingEngine:
         return token
 
     def _count_table(self, tick, tables: np.ndarray) -> None:
-        """What one decode step's block table holds, onto the step's
-        profiler span and the cumulative counters.  Block 0 is scratch
-        and never in a request's table, so the non-zero entries are the
-        real pages."""
+        """What one decode step's block table holds and how full the
+        pool stands, onto the step's profiler span and the cumulative
+        counters.  Block 0 is scratch and never in a request's table, so
+        the non-zero entries are the real pages."""
         blocks = int(np.count_nonzero(tables))
         _m_table_slots.inc(tables.size)
         _m_table_blocks.inc(blocks)
-        tick.set_metadata(n_cols=tables.shape[1], blocks=blocks)
+        usable = self.cache.num_blocks - 1
+        tick.set_metadata(n_cols=tables.shape[1], blocks=blocks,
+                          blocks_held=usable - self.pager.free_blocks,
+                          blocks_usable=usable)
 
     def _decode_tick(self, tick) -> list[tuple[Request, int]]:
         """One decode step for the running set, under ``tick``, the

@@ -74,7 +74,7 @@ class SpecDecoder:
         self._jnp = jnp
         # Same block geometry as the target pool -> shared block tables.
         self.cache = PagedKVCache(
-            n_layers=draft_cfg.n_layers,
+            n_layers=draft_cfg.cache_layers,
             num_blocks=engine.cache.num_blocks,
             block_size=engine.cache.block_size,
             kv_heads=draft_cfg.n_kv_heads, head_dim=draft_cfg.head_dim)

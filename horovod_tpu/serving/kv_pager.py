@@ -55,6 +55,8 @@ class PagedKVCache:
     through the jitted step functions); this object owns the static
     geometry the allocator and the step builders agree on."""
 
+    #: layers of cache, the model's ``cache_layers``: for a looped stack
+    #: its layers times its passes, not the layers of weights
     n_layers: int
     num_blocks: int
     block_size: int

@@ -46,9 +46,11 @@ PARENT = {
 COUNTERS = ("hvd_serving_decode_table_slots_total",
             "hvd_serving_decode_table_blocks_total")
 #: every attribute a span may carry: each has a reader (PERF.md, section 3)
+DEPTH = {"loops", "cache_layers"}            # the model's, on both (PR 33)
 ATTRS = {"hvd.serve.step": {"step"},
-         "hvd.serve.prefill": {"req", "tokens", "cached"},
-         "hvd.serve.decode": {"n_cols", "blocks"}}
+         "hvd.serve.prefill": {"req", "tokens", "cached", "resumed"} | DEPTH,
+         "hvd.serve.decode": {"n_cols", "blocks", "blocks_held",
+                              "blocks_usable"} | DEPTH}
 
 
 @pytest.fixture(scope="module")
@@ -92,9 +94,11 @@ def test_spans_attributes_and_counters_of_a_traced_run(tiny, use_flash,
     ticks = []
 
     def table_matrix(ids, n_cols):
+        usable = engine.cache.num_blocks - 1
         ticks.append(dict(
             blocks=sum(len(engine.pager.table(i)) for i in ids if i >= 0),
-            n_cols=n_cols))
+            n_cols=n_cols, blocks_held=usable - engine.pager.free_blocks,
+            blocks_usable=usable, loops=1, cache_layers=2))
         return real_tables(ids, n_cols)
     engine.pager.table_matrix = table_matrix
 
@@ -126,7 +130,8 @@ def test_spans_attributes_and_counters_of_a_traced_run(tiny, use_flash,
 
     prefills = by_name["hvd.serve.prefill"]
     assert sum(p[3]["tokens"] for p in prefills) == sum(lens)
-    assert all(p[3]["cached"] == 0 for p in prefills)
+    assert all(p[3]["cached"] == 0 and p[3]["resumed"] == 0
+               and p[3]["cache_layers"] == 2 for p in prefills)
     assert len({p[3]["req"] for p in prefills}) == len(lens)
 
     decodes = by_name["hvd.serve.decode"]
