@@ -955,8 +955,9 @@ def _paged_attend(cfg: LlamaConfig, mesh, tables, blk, off, mask,
     each layer writes its fresh K/V rows into page ``blk`` at offset
     ``off`` of its own pages first, then reads the table's logical window
     back: one query a row through the Pallas paged kernel where each
-    stream's ``last`` position is given, else through the contiguous
-    gather under ``mask`` (stale slots masked)."""
+    stream's ``last`` position is given (a row whose table is all
+    scratch has no stream, and the kernel skips it), else through the
+    contiguous gather under ``mask`` (stale slots masked)."""
     scale = 1.0 / np.sqrt(cfg.head_dim)
     rules = shard_rules(cfg, mesh)
 
@@ -968,10 +969,16 @@ def _paged_attend(cfg: LlamaConfig, mesh, tables, blk, off, mask,
             return pool
         return shd.constrain(pool, POOL_DIMS, mesh, rules)
 
+    # Block 0 is scratch and in no stream's table, and a row with no
+    # stream is all block 0: it reaches the kernel as length 0, which the
+    # kernel skips, where ``last + 1`` would read as a stream of one token.
+    lengths = None if last is None else jnp.where(
+        tables[:, 0] != 0, last + 1, 0)
+
     def attend(q, k1, v1, li, state):
         kp, vp = put(state[0], li, k1), put(state[1], li, v1)
-        if last is not None:
-            o = _paged_kernel_attend(q[:, 0], kp, vp, li, tables, last + 1,
+        if lengths is not None:
+            o = _paged_kernel_attend(q[:, 0], kp, vp, li, tables, lengths,
                                      scale, mesh, rules, interpret)[:, None]
         else:
             o = cached_attend(q, gather_blocks(kp[li], tables),

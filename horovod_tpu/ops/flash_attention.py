@@ -419,27 +419,33 @@ def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm,
     """One stream of paged decode attention: walk the stream's LIVE pages
     in groups of ``P``, online-softmax accumulating all q heads at once.
 
-    The pools stay in HBM and the kernel fetches a group's ``P`` pages
-    itself (``make_async_copy``) into one of two VMEM slots, starting
-    group ``g+1`` before it computes group ``g``; a stream's last group
-    starts the next stream's first (the grid runs in order, and
-    ``first_slot`` hands the slot on), so only the call's very first
-    copies are waited for in the open.  The loop's trip count is
-    ``ceil(length / (P*BS))``: a padded table slot is never visited and
-    the table's width costs nothing; a group's page indices past the
-    stream's last live page are clamped to it and masked.
+    The pools stay in HBM and the kernel fetches a group's pages itself
+    (``make_async_copy``) into one of two VMEM slots, starting group
+    ``g+1`` before it computes group ``g``; a stream's last group starts
+    the first group of the next row that has a stream (the grid runs in
+    order, and ``first_slot`` hands the slot on), so only the call's very
+    first copies are waited for in the open.  Only live pages are copied:
+    the loop's trip count is ``ceil(length / (P*BS))``, so a padded table
+    slot is never visited and the table's width costs nothing; a stream's
+    last group copies the ``ceil(length / BS) - g*P`` pages it holds and
+    no more; a row of length 0 (a slot of the batch with no stream)
+    starts no copy, waits for none, runs no product and reads as zeros.
 
     A group lands as ``[P*BS*KV, Dh]``: rows are (token, kv head), the
     pool's own order, so no transpose or relayout of a page is asked of
-    Mosaic.  Both products run on the MXU with operands in the pool's
-    dtype and float32 accumulation (the contract of :func:`_fwd_kernel`):
-    ``q [H, Dh] . K^T -> [H, P*BS*KV]``, every column whose kv head is
-    not the row's masked together with the columns past ``length``, and
-    ``p . V -> [H, Dh]`` with ``p`` cast to V's dtype.  Masked entries
-    are exact zeros, so the result is the GQA attention; the KV-fold
-    surplus of MXU work and ``exp``s is on units that otherwise idle
-    while the pages arrive (on v5e the copies alone take 1.7 to 2.6
-    times the compute alone: PERF.md, PR 28).
+    Mosaic.  Both products run on the MXU over the whole group, with
+    operands in the pool's dtype and float32 accumulation (the contract
+    of :func:`_fwd_kernel`): ``q [H, Dh] . K^T -> [H, P*BS*KV]``, every
+    column whose kv head is not the row's masked together with the
+    columns past ``length``, and ``p . V -> [H, Dh]`` with ``p`` cast to
+    V's dtype.  Masked entries are exact zeros, so the result is the GQA
+    attention; rows of a slot that a short group's copies did not reach
+    hold an earlier group's pages, or the zeros the call's first step
+    fills V's slots with, so they are finite under ``p == 0`` (K's are
+    replaced by the mask).  The KV-fold surplus of MXU work and ``exp``s
+    is on units that otherwise idle while the pages arrive (on v5e the
+    copies alone take 1.7 to 2.6 times the compute alone: PERF.md, PR
+    28).
 
     Pools narrower than 32 bits move as uint32 words: a word holds the
     same column of two adjacent rows, the sublane packing of both the
@@ -458,9 +464,20 @@ def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm,
     P = kbuf.shape[1] // page_rows
     G = P * BS                                        # tokens a group
     b = pl.program_id(0)
+    B = pl.num_programs(0)
     li = layer_ref[0]
     length = lengths_ref[b]
-    n_groups = jnp.maximum((length + G - 1) // G, 1)
+    n_groups = (length + G - 1) // G
+
+    def pages_of(stream, g):
+        """Live pages of ``stream``'s group ``g``: ``P`` but in its last."""
+        return jnp.minimum((lengths_ref[stream] + BS - 1) // BS - g * P, P)
+
+    def next_stream(row):
+        """The first row from ``row`` on that has a stream, else ``B``."""
+        return jax.lax.while_loop(
+            lambda r: (r < B) & (lengths_ref[jnp.minimum(r, B - 1)] == 0),
+            lambda r: r + 1, row)
 
     def start(stream, g, slot):
         # A run-time loop over the pages, not 2P descriptors written out:
@@ -468,30 +485,38 @@ def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm,
         # out its three start sites cost a decode compile more host time
         # than the rest of the step (2 s of the backlog cell's set-up),
         # for no device time.
-        last_page = jnp.maximum(
-            (lengths_ref[stream] + BS - 1) // BS - 1, 0)
-
         def page(p, _):
-            blk = tables_ref[stream, jnp.minimum(g * P + p, last_page)]
+            blk = tables_ref[stream, g * P + p]
             rows = pl.ds(pl.multiple_of(p * page_rows, page_rows),
                          page_rows)
             for i, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
                 pltpu.make_async_copy(
                     hbm.at[li, blk].reshape(page_rows, Dh),
                     buf.at[slot, rows], sem.at[i, slot]).start()
-        jax.lax.fori_loop(0, P, page, None)
+        jax.lax.fori_loop(0, pages_of(stream, g), page, None)
 
-    def wait(slot):
-        # A slot's P copies of K (of V) signal one semaphore, which
-        # counts bytes: one wait for the whole slot's worth takes all P.
-        for i, buf in enumerate((kbuf, vbuf)):
-            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
-                                  sem.at[i, slot]).wait()
+    def wait(g, slot):
+        # A slot's copies of K (of V) signal one semaphore, which counts
+        # bytes: the waits have to take exactly what ``start`` sent, and
+        # do so by the binary digits of the page count, so a whole group
+        # is still one wait and a short one at most log2(P) + 1.
+        pages = pages_of(b, g)
+        for digit in reversed(range(P.bit_length())):
+            rows = pl.ds(0, page_rows << digit)
+
+            @pl.when((pages >> digit) & 1 == 1)
+            def _take():
+                for i, buf in enumerate((kbuf, vbuf)):
+                    pltpu.make_async_copy(buf.at[slot, rows],
+                                          buf.at[slot, rows],
+                                          sem.at[i, slot]).wait()
 
     @pl.when(b == 0)
     def _first():
         first_slot[0] = 0
-        start(0, 0, 0)
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        head = next_stream(0)
+        pl.when(head < B)(lambda: start(head, 0, 0))
 
     slot0 = first_slot[0]
     q = q_ref[...]
@@ -509,11 +534,17 @@ def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm,
     def body(g, carry):
         m, l, acc = carry
         slot = (slot0 + g) % 2
-        more = g + 1 < n_groups
-        pl.when(more)(lambda: start(b, g + 1, 1 - slot))
-        pl.when(jnp.logical_not(more) & (b + 1 < pl.num_programs(0)))(
-            lambda: start(b + 1, 0, 1 - slot))
-        wait(slot)
+
+        @pl.when(g + 1 < n_groups)
+        def _more():
+            start(b, g + 1, 1 - slot)
+
+        @pl.when(g + 1 == n_groups)
+        def _hand_over():
+            nxt = next_stream(b + 1)
+            pl.when(nxt < B)(lambda: start(nxt, 0, 1 - slot))
+
+        wait(g, slot)
         k, v = load(kbuf, slot), load(vbuf, slot)     # [G*KV, Dh]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -533,7 +564,7 @@ def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm,
     _, l, acc = jax.lax.fori_loop(0, n_groups, body, (m0, l0, acc0))
     first_slot[0] = (slot0 + n_groups) % 2
     # length >= 1 leaves every row a live column in every group it
-    # visits, so l > 0; a length of 0 reads as zeros.
+    # visits, so l > 0; a length of 0 visits none and reads as zeros.
     o_ref[...] = jnp.where(length > 0, acc / l, 0.0).astype(o_ref.dtype)
 
 
@@ -544,8 +575,11 @@ _VMEM_BUDGET = 12 << 20
 # Tokens the paged decode kernel takes in one group where VMEM allows:
 # of 64, 128, 256 and 512 the fastest at the backlog cell's shape on v5e
 # (the sweep is in PERF.md, PR 28).  Larger groups make fewer loop turns;
-# a stream's last group is fetched whole, so they also fetch more dead
-# tokens (half a group a stream).
+# a stream's last group copies only the pages it holds but both products
+# still run over the whole group, so a larger group also means more
+# masked columns (half a group a stream): products over the live halves
+# or quarters of a group alone were slower at both served shapes
+# (PERF.md, PR 34).
 _PAGED_GROUP_TOKENS = 256
 
 
@@ -597,7 +631,9 @@ def paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     pool, where slicing a layer out first would hand the custom call a
     copy of that layer's pages every call; tables [B, n_cols] int32
     physical block ids (rows padded with the scratch block 0); lengths
-    [B] — logical positions ``< lengths[b]`` are live, the rest masked.
+    [B] — logical positions ``< lengths[b]`` are live, the rest masked;
+    a row of length 0 has no stream: none of its table is read, and its
+    result is zeros.
 
     Layer, table and lengths ride ``PrefetchScalarGridSpec``'s
     scalar-prefetch channel; the pools are handed over in HBM
@@ -605,7 +641,8 @@ def paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     stream's live pages in itself — no gathered ``[B, T, KV, Dh]`` copy
     ever lands in HBM (the XLA fallback in the serving engine
     materializes exactly that copy), and the time follows the live KV
-    bytes, not the table's width.  Returns [B, H, Dh].
+    bytes, not the table's width or the batch's empty rows.  Returns
+    [B, H, Dh].
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu

@@ -70,6 +70,10 @@ _m_table_slots = _obs.counter(
 _m_table_blocks = _obs.counter(
     "hvd_serving_decode_table_blocks_total",
     "of those, entries that name a real (non-scratch) block")
+_m_idle_rows = _obs.counter(
+    "hvd_serving_decode_idle_rows_total",
+    "rows of decode steps' tables that held no stream (all scratch): "
+    "the Pallas kernel skips them")
 _m_reprefill_tokens = _obs.counter(
     "hvd_serving_reprefill_tokens_total",
     "of the prompt tokens prefilled, those of a preempted request's "
@@ -532,12 +536,16 @@ class ServingEngine:
         """What one decode step's block table holds and how full the
         pool stands, onto the step's profiler span and the cumulative
         counters.  Block 0 is scratch and never in a request's table, so
-        the non-zero entries are the real pages."""
+        the non-zero entries are the real pages and a row whose first
+        entry is non-zero has a stream (the decode program tells by the
+        same)."""
         blocks = int(np.count_nonzero(tables))
+        rows = int(np.count_nonzero(tables[:, 0]))
         _m_table_slots.inc(tables.size)
         _m_table_blocks.inc(blocks)
+        _m_idle_rows.inc(tables.shape[0] - rows)
         usable = self.cache.num_blocks - 1
-        tick.set_metadata(n_cols=tables.shape[1], blocks=blocks,
+        tick.set_metadata(n_cols=tables.shape[1], blocks=blocks, rows=rows,
                           blocks_held=usable - self.pager.free_blocks,
                           blocks_usable=usable)
 

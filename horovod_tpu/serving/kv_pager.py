@@ -20,7 +20,9 @@ vLLM's central idea):
 
 Block 0 is RESERVED as a scratch target: inactive decode slots in the
 fixed-shape step function point their table rows at it, so their masked
-garbage writes can never land in a live request's block.
+garbage writes can never land in a live request's block; a table row
+whose first entry is block 0 is therefore a slot with no stream, which
+is how the decode program tells the paged kernel to skip it.
 
 Blocks are REFCOUNTED so the prefix cache
 (:mod:`horovod_tpu.serving.frontdoor.prefix_cache`) can share one

@@ -288,6 +288,38 @@ def test_decode_tick_is_extend_with_one_token_a_row(tiny, use_flash):
     assert not np.array_equal(np.asarray(kp_d), np.asarray(kp))
 
 
+def test_decode_tick_skips_rows_with_no_stream(tiny):
+    """A slot of the batch with no stream reaches the decode program as
+    the engine builds it: token 0, position 0, a table row of scratch
+    block 0.  Through the kernel such a row is length 0 (told by its
+    table's first entry), not a stream of one token: nothing of block 0
+    is read, so NaN there stays there; the live rows' logits and pages
+    are those of the gather path."""
+    cfg, params = tiny
+    rng = np.random.RandomState(11)
+    B, NB, BS, C = 5, 16, 4, 3
+    shape = (cfg.n_layers, NB, BS, cfg.n_kv_heads, cfg.head_dim)
+    kp, vp = rng.randn(2, *shape).astype(np.float32)
+    live = np.asarray([False, True, False, False, True])
+    tables = np.zeros((B, C), np.int32)
+    tables[live] = 1 + rng.permutation(NB - 1)[:2 * C].reshape(2, C)
+    tok = np.where(live, rng.randint(0, cfg.vocab_size, size=B), 0)
+    pos = np.where(live, [0, 6, 0, 0, 9], 0)
+    args = [jnp.asarray(a, jnp.int32) for a in (tok, pos)]
+    step = lambda k, v, flash: llama.decode_step_paged(
+        params, *args, jnp.asarray(k), jnp.asarray(v),
+        jnp.asarray(tables), cfg, use_flash=flash, interpret=flash)
+    logits_g, kp_g, vp_g = step(kp, vp, False)
+    kp[:, 0, 1:] = vp[:, 0, 1:] = np.nan      # offset 0 is written first
+    logits_k, kp_k, vp_k = step(kp, vp, True)
+    assert np.isfinite(np.asarray(logits_k)).all()
+    np.testing.assert_allclose(logits_k[live], logits_g[live], rtol=1e-4,
+                               atol=1e-4)
+    for got, want in ((kp_k, kp_g), (vp_k, vp_g)):
+        np.testing.assert_allclose(got[:, 1:], want[:, 1:], rtol=1e-4,
+                                   atol=1e-5)
+
+
 def test_paged_attention_kernel_vs_gather_oracle():
     from horovod_tpu.models.layers import cached_attend
     from horovod_tpu.ops import flash_attention as FA
@@ -311,9 +343,9 @@ def test_paged_attention_kernel_vs_gather_oracle():
 
 
 # (id, KV, rep, dtype, BS, n_cols, group tokens or None for the kernel's
-# own, lengths; None in lengths is an inactive row: length 1, table all
-# scratch block 0).  With G = the group's tokens: lengths of 1, one short
-# of a page, exactly a group, one past a group, the whole table.
+# own, lengths; None in lengths is a row with no stream: length 0, table
+# all scratch block 0).  With G = the group's tokens: lengths of 1, one
+# short of a page, exactly a group, one past a group, the whole table.
 _PAGED_CASES = [
     ("kv2-rep4-f32", 2, 4, "float32", 4, 12, 16, [1, 3, 16, 17, 48]),
     ("kv8-rep1-f32", 8, 1, "float32", 4, 12, 16, [1, 3, 16, 17, 48]),
@@ -326,14 +358,41 @@ _PAGED_CASES = [
     ("inactive-rows-bf16", 2, 4, "bfloat16", 4, 12, 16, [None, 17, None]),
     # the table narrower than a group's P = 8 pages: P becomes n_cols
     ("table-narrower-than-group", 2, 4, "float32", 4, 3, 32, [1, 5, 12]),
-    # ... and not a multiple of P = 2: the last group's second page is
-    # past the table, clamped onto its last live page
+    # ... and not a multiple of P = 2: the last group holds one page
     ("table-not-a-multiple", 2, 4, "float32", 8, 5, 16, [8, 31, 33, 40]),
     ("table-not-a-multiple-bf16", 8, 4, "bfloat16", 8, 5, 16, [8, 33, 40]),
     # the kernel's own group size (256 tokens, P = 32) on a table 2.5
     # groups wide
     ("default-group", 2, 4, "float32", 8, 80, None, [1, 255, 256, 257, 640]),
     ("default-group-bf16", 2, 4, "bfloat16", 8, 80, None, [256, 257, 640]),
+    # Rows with no stream, wherever slots fall free (PR 34), at the two
+    # served geometries (Mistral KV 8 / H 32, Ouro KV 16 / H 16): first,
+    # last, two in a row, alternating, all of them.  G = 32 tokens, P = 8.
+    ("idle-first-kv8-h32", 8, 4, "bfloat16", 4, 24, 32, [None, 33, 5]),
+    ("idle-last-kv16-h16", 16, 1, "bfloat16", 4, 24, 32, [40, 7, None]),
+    ("idle-two-in-a-row-kv16-h16", 16, 1, "bfloat16", 4, 24, 32,
+     [64, None, None, 29, None]),
+    ("idle-two-in-a-row-kv8-h32", 8, 4, "float32", 4, 24, 32,
+     [None, None, 96, None, None, 3]),
+    ("idle-alternating-kv8-h32", 8, 4, "bfloat16", 4, 24, 32,
+     [None, 31, None, 65, None, 1, None]),
+    ("idle-alternating-kv16-h16", 16, 1, "float32", 4, 24, 32,
+     [12, None, 45, None, 32, None]),
+    ("idle-all-kv8-h32", 8, 4, "bfloat16", 4, 24, 32, [None, None, None]),
+    ("idle-all-kv16-h16", 16, 1, "bfloat16", 4, 24, 32, [None]),
+    # a last group of one live page (G + 1 and 2G + 3), a whole number
+    # of groups (G, 2G, 3G), lengths 1 and G + 1, page counts with every
+    # binary digit (7 = 4 + 2 + 1 pages of the last group's 8)
+    ("last-group-one-page-kv8-h32", 8, 4, "bfloat16", 4, 24, 32,
+     [33, 67, 1, 36]),
+    ("last-group-one-page-kv16-h16", 16, 1, "bfloat16", 4, 24, 32,
+     [33, 1, 67, 34]),
+    ("whole-groups-kv8-h32", 8, 4, "bfloat16", 4, 24, 32, [32, 64, 96]),
+    ("whole-groups-kv16-h16", 16, 1, "float32", 4, 24, 32, [96, 32, 64]),
+    ("page-counts-kv16-h16", 16, 1, "bfloat16", 4, 24, 32,
+     [4, 8, 12, 20, 28, 60, 93]),
+    ("page-counts-kv8-h32", 8, 4, "float32", 4, 24, 32,
+     [9, 13, 24, 55, 91, None, 17]),
 ]
 
 
@@ -345,9 +404,11 @@ def test_paged_attention_kernel_cases(monkeypatch, KV, rep, dtype, BS, C,
                                       group, lens, poison):
     """The paged kernel against the gather oracle over its geometry and
     the lengths at which its walk changes shape.  ``poison`` fills every
-    page of the pool that lies wholly past its stream's length (and
-    every page no table names) with NaN or 1e30 for the kernel alone:
-    the kernel never reads them, so the result is the clean pool's.
+    page of the pool that holds no live token (those wholly past their
+    stream's length, those no table names, and the scratch block 0 that
+    a row with no stream is made of) with NaN or 1e30 for the kernel
+    alone: the kernel copies live pages only, so the result is the clean
+    pool's, and zeros on a row with no stream.
 
     bf16 pools: both sides round ``p`` (at most 1) to bf16, the kernel
     before the row sum divides it and the oracle after, and round the
@@ -367,12 +428,13 @@ def test_paged_attention_kernel_cases(monkeypatch, KV, rep, dtype, BS, C,
     kp = rng.randn(L, NB, BS, KV, Dh).astype(np.float32)
     vp = rng.randn(L, NB, BS, KV, Dh).astype(np.float32)
     tables = (1 + rng.permutation(B * C)).reshape(B, C).astype(np.int32)
-    lengths = np.asarray([1 if n is None else n for n in lens], np.int32)
+    lengths = np.asarray([0 if n is None else n for n in lens], np.int32)
+    idle = lengths == 0
+    tables[idle] = 0
     live = np.zeros(NB, bool)                 # blocks holding a live token
-    for b, n in enumerate(lens):
-        if n is None:
-            tables[b] = 0
-        live[tables[b, :-(-int(lengths[b]) // BS)]] = True
+    for b, n in enumerate(lengths):
+        live[tables[b, :-(-int(n) // BS)]] = True
+    assert not live[0]
     clean_k, clean_v = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
     if poison is not None:                    # copies: jnp may alias numpy
         kp, vp = kp.copy(), vp.copy()
@@ -383,17 +445,22 @@ def test_paged_attention_kernel_cases(monkeypatch, KV, rep, dtype, BS, C,
                              interpret=True)
     keys = gather_blocks(clean_k[li], tables)
     vals = gather_blocks(clean_v[li], tables)
-    mask = (jnp.arange(C * BS)[None, :] < lengths[:, None])[:, None, :]
+    # The oracle's softmax over a row with nothing live is a mean of V:
+    # give it one live column and compare that row with zeros instead.
+    mask = (jnp.arange(C * BS)[None, :]
+            < jnp.maximum(lengths, 1)[:, None])[:, None, :]
     ref = cached_attend(q[:, None], keys, vals, mask,
                          1.0 / np.sqrt(Dh))[:, 0]
+    ref = jnp.where(idle[:, None, None], 0, ref)
     assert out.dtype == q.dtype and out.shape == q.shape
     if dtype == jnp.float32:
         tol = dict(rtol=1e-5, atol=2e-6)
     else:
         tol = dict(rtol=0, atol=4 * 2.0 ** -9 * float(
-            np.abs(vp[li, live]).max()))
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), **tol)
+            np.abs(vp[li, live]).max(initial=0.0)))
+    out = np.asarray(out, np.float32)
+    assert not out[idle].any()
+    np.testing.assert_allclose(out, np.asarray(ref, np.float32), **tol)
 
 
 def test_paged_group_pages_follow_the_shapes():

@@ -44,12 +44,13 @@ PARENT = {
     "hvd.serve.deliver": None,
 }
 COUNTERS = ("hvd_serving_decode_table_slots_total",
-            "hvd_serving_decode_table_blocks_total")
+            "hvd_serving_decode_table_blocks_total",
+            "hvd_serving_decode_idle_rows_total")
 #: every attribute a span may carry: each has a reader (PERF.md, section 3)
 DEPTH = {"loops", "cache_layers"}            # the model's, on both (PR 33)
 ATTRS = {"hvd.serve.step": {"step"},
          "hvd.serve.prefill": {"req", "tokens", "cached", "resumed"} | DEPTH,
-         "hvd.serve.decode": {"n_cols", "blocks", "blocks_held",
+         "hvd.serve.decode": {"n_cols", "blocks", "rows", "blocks_held",
                               "blocks_usable"} | DEPTH}
 
 
@@ -97,6 +98,7 @@ def test_spans_attributes_and_counters_of_a_traced_run(tiny, use_flash,
         usable = engine.cache.num_blocks - 1
         ticks.append(dict(
             blocks=sum(len(engine.pager.table(i)) for i in ids if i >= 0),
+            rows=sum(i >= 0 for i in ids),
             n_cols=n_cols, blocks_held=usable - engine.pager.free_blocks,
             blocks_usable=usable, loops=1, cache_layers=2))
         return real_tables(ids, n_cols)
@@ -141,7 +143,11 @@ def test_spans_attributes_and_counters_of_a_traced_run(tiny, use_flash,
     after = [REGISTRY.get(c).value for c in COUNTERS]
     assert [b - a for a, b in zip(before, after)] == [
         sum(engine.ecfg.max_active * d[3]["n_cols"] for d in decodes),
-        sum(d[3]["blocks"] for d in decodes)]
+        sum(d[3]["blocks"] for d in decodes),
+        sum(engine.ecfg.max_active - d[3]["rows"] for d in decodes)]
+    # 6 requests over 4 slots: the tail of the run leaves slots empty
+    rows = {d[3]["rows"] for d in decodes}
+    assert max(rows) == 4 and 0 < min(rows) < 4
 
 
 def test_prefix_hit_and_speculative_rounds_carry_the_same_spans(tiny,
@@ -257,6 +263,29 @@ def test_kernel_names_reach_the_compiled_tpu_module(monkeypatch):
         FA.paged_attention, spec((4, 32, 128)), pool, pool,
         spec((), jnp.int32), spec((4, 8), jnp.int32), spec((4,), jnp.int32))
     assert len(names) == 1 and "hvd_paged_decode" in names[0], names
+
+
+@pytest.mark.parametrize("n_cols", [8, 256])
+@pytest.mark.parametrize("KV,H", [(8, 32), (16, 16)],
+                         ids=["mistral-kv8-h32", "ouro-kv16-h16"])
+def test_paged_kernel_compiles_at_both_served_geometries(monkeypatch, KV, H,
+                                                         n_cols):
+    """The kernel that copies live pages only (PR 34: run-time page
+    counts, waits by their binary digits, a scalar search for the next
+    row with a stream), compiled for a v5e at the two serving cells'
+    geometries, 32 rows of 16-token pages, the narrowest and the widest
+    table: one Mosaic call, named ``hvd_paged_decode``, and no
+    temporary near a layer's pages, so the pool is not copied."""
+    spec = _v5e_spec(monkeypatch)
+    L, NB, BS, R = 2, 512, 16, 32
+    pool = spec((L, NB, BS, KV, 128))
+    compiled = jax.jit(FA.paged_attention).lower(
+        spec((R, H, 128)), pool, pool, spec((), jnp.int32),
+        spec((R, n_cols), jnp.int32), spec((R,), jnp.int32)).compile()
+    names = _mosaic_lines(compiled)
+    assert len(names) == 1 and "hvd_paged_decode" in names[0], names
+    layer_bytes = NB * BS * KV * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes // 8
 
 
 def test_decode_step_compiles_to_one_paged_kernel_and_no_pool_copy(
