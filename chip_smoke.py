@@ -280,8 +280,8 @@ def phase_serve(out: dict, devices, cfg, serve_kw: dict, lens, budgets,
             assert "error" not in m, m
             assert m["new_tokens"] == budget, m
         assert session.recoveries == 0, session.recoveries
-        _check_spread({"params": params, "k_pool": engine.k_pool,
-                       "v_pool": engine.v_pool}, devices, "serve state")
+        _check_spread({"params": params, "pools": engine.pools}, devices,
+                      "serve state")
 
         n_cols = 64
         if not interpret:
@@ -306,18 +306,16 @@ def phase_serve(out: dict, devices, cfg, serve_kw: dict, lens, budgets,
 
         def tick(use_flash):
             return jax.jit(
-                lambda p, kp, vp: llama.decode_step_paged(
-                    p, jnp.asarray(tok), jnp.asarray(pos), kp, vp,
+                lambda p, pools: llama.decode_step_paged(
+                    p, jnp.asarray(tok), jnp.asarray(pos), pools,
                     jnp.asarray(tables), cfg, mesh=mesh,
-                    use_flash=use_flash, interpret=interpret),
-                donate_argnums=(1, 2))
+                    use_flash=use_flash, interpret=interpret)[:2],
+                donate_argnums=(1,))
 
         # The pools are donated through both ticks; each writes the same
         # K/V rows at ``pos``, so both attend over identical pools.
-        kp, vp = engine.k_pool, engine.v_pool
-        logits_k, kp, vp = tick(True)(params, kp, vp)
-        logits_g, kp, vp = tick(False)(params, kp, vp)
-        engine.k_pool, engine.v_pool = kp, vp
+        logits_k, pools = tick(True)(params, engine.pools)
+        logits_g, engine.pools = tick(False)(params, pools)
         logits_k, logits_g = np.asarray(logits_k), np.asarray(logits_g)
         assert np.isfinite(logits_k).all()
         diff = float(np.max(np.abs(logits_k - logits_g)))

@@ -10,10 +10,12 @@ recurrence and their short convolution).  Three kinds of layer:
   per-channel log decay ``g = -exp(A_log) * softplus((x W_fa) W_fb +
   dt_bias)``, ``beta = sigmoid(x W_b)``, the gated delta rule, then
   ``rmsnorm(o) * o_norm * sigmoid((x W_ga) W_gb)`` through ``W_o``.
-- **MLA mixer**: full-rank queries of nope + rope width, keys and values
-  expanded from a normalised latent of ``kv_lora_rank`` plus one shared
-  ``k_pe`` per token, no rotation; causal softmax attention with keys
-  wider than values, through the flash kernels the Llama path uses.
+- **MLA mixer** (:func:`horovod_tpu.models.layers.mla_mixer`, the one
+  GLM-4.7-Flash shares): full-rank queries of nope + rope width, keys
+  and values expanded from a normalised latent of ``kv_lora_rank`` plus
+  one shared ``k_pe`` per token, no rotation; causal softmax attention
+  with keys wider than values, through the flash kernels the Llama path
+  uses.
 - **MLP**: SwiGLU, dense in the first ``first_k_dense`` layers, then the
   expert layer of :func:`horovod_tpu.parallel.moe.moe_layer_held`:
   sigmoid scores, a selection bias, top-k, renormalised and scaled
@@ -49,11 +51,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.kda import CHUNK, chunk_kda
 from ..parallel.moe import moe_layer_held
 from .layers import (
-    attention,
     block,
     causal_lm_loss,
     dense_mlp,
     embed_lookup,
+    mla_expanded_attend,
+    mla_mixer,
     remat,
     rmsnorm,
 )
@@ -307,20 +310,6 @@ def _kda_mixer(x, lp, cfg: KimiLinearConfig):
     return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), None
 
 
-def _mla_mixer(x, lp, cfg: KimiLinearConfig, mesh):
-    B, S, _ = x.shape
-    C, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
-    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
-    kva = jnp.einsum("bsd,dc->bsc", x, lp["w_kva"])
-    c = rmsnorm(kva[..., :C], lp["kv_norm"], cfg.rms_eps)
-    kv = jnp.einsum("bsc,chk->bshk", c, lp["w_kvb"])
-    k_pe = jnp.broadcast_to(kva[:, :, None, C:],
-                            (B, S, cfg.n_heads, cfg.qk_rope_dim))
-    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
-    o = attention(q, k, kv[..., nope:], mesh, True)
-    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), None
-
-
 def _moe_mlp(x2, lp, cfg: KimiLinearConfig):
     B, S, D = x2.shape
     out, stats = moe_layer_held(
@@ -339,7 +328,9 @@ def layer_pair(kind: str, cfg: KimiLinearConfig, mesh) -> tuple:
     extra)`` as :func:`horovod_tpu.models.layers.block` takes them."""
     mixer, mlp = kind.split("_")
     return (partial(_kda_mixer, cfg=cfg) if mixer == "kda"
-            else partial(_mla_mixer, cfg=cfg, mesh=mesh),
+            else partial(mla_mixer, nope=cfg.qk_nope_dim, eps=cfg.rms_eps,
+                         tables=None,
+                         attend=mla_expanded_attend(cfg.qk_nope_dim, mesh)),
             dense_mlp if mlp == "dense" else partial(_moe_mlp, cfg=cfg))
 
 

@@ -8,8 +8,9 @@ mlp)`` pair of callables, each ``(x, lp) -> (y, extra)``; what differs
 between training, prefill and a paged decode tick of one model is passed
 in, never branched on:
 
-- the **mixer** (:func:`gqa_mixer` for the Llama family; Kimi-Linear
-  brings its KDA and latent-attention mixers) takes an ``attend``
+- the **mixer** (:func:`gqa_mixer` for the Llama family,
+  :func:`mla_mixer` for latent attention; Kimi-Linear brings its KDA
+  mixer) takes an ``attend``
   closure that is handed rotated q and grouped k, v and returns the
   attention output and whatever the caller's scan must carry out: the
   new K and V, the updated pools, or nothing;
@@ -191,6 +192,71 @@ def gqa_mixer(x, lp, tables, attend):
     return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), kept
 
 
+def mla_mixer(x, lp, nope: int, eps: float, tables, attend):
+    """The latent-attention mixer (MLA), one function for every model
+    that has the layer: queries full-rank (``wq``) or compressed and
+    normed (``w_qa``, ``q_norm``, ``w_qb``), by the leaves the layer
+    holds; the normed latent ``c [B, S, C]`` and the one key of ``rope``
+    columns all heads share, ``k_pe [B, S, R]``; the last ``R`` columns
+    of a query head and ``k_pe`` rotated by the rope ``tables`` (None: no
+    position, as Kimi-Linear's).  ``attend(q, c, k_pe, w_kvb) -> (o,
+    kept)`` is the caller's, as :func:`gqa_mixer`'s: expanded keys and
+    values (:func:`mla_expand`) for training and prefill, the query
+    taken into the latent's space (:func:`mla_absorb`) against a cache of
+    ``(c, k_pe)`` rows for a decode tick."""
+    C = lp["kv_norm"].shape[-1]
+    if "w_qa" in lp:
+        cq = rmsnorm(jnp.einsum("bsd,dr->bsr", x, lp["w_qa"]),
+                     lp["q_norm"], eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, lp["w_qb"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
+    kva = jnp.einsum("bsd,dc->bsc", x, lp["w_kva"])
+    c = rmsnorm(kva[..., :C], lp["kv_norm"], eps)
+    k_pe = kva[..., C:]
+    if tables is not None:
+        q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], tables)], -1)
+        k_pe = rope(k_pe[:, :, None, :], tables)[:, :, 0]
+    o, kept = attend(q, c, k_pe, lp["w_kvb"])
+    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), kept
+
+
+def mla_expand(c, k_pe, w_kvb, nope: int):
+    """Every head's keys ``[B, S, H, nope + R]`` and values from the
+    latent ``c`` and the shared rope key: the expanded form of latent
+    attention."""
+    kv = jnp.einsum("bsc,chk->bshk", c, w_kvb)
+    B, S, H, _ = kv.shape
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :], (B, S, H, k_pe.shape[-1]))
+    return jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
+
+
+def mla_expanded_attend(nope: int, mesh):
+    """The ``attend`` of :func:`mla_mixer` in the expanded form, for a
+    whole sequence (training, the tests' forward): every head's keys and
+    values through the attention dispatch (the flash kernels)."""
+    def attend(q, c, k_pe, w_kvb):
+        k, v = mla_expand(c, k_pe, w_kvb, nope)
+        return attention(q, k, v, mesh, True), None
+    return attend
+
+
+def mla_row(c, k_pe, width: int):
+    """A token's cache row ``[..., width]``: latent, rope key, zeros."""
+    row = jnp.concatenate([c, k_pe.astype(c.dtype)], axis=-1)
+    pad = width - row.shape[-1]
+    return jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, pad)]) if pad \
+        else row
+
+
+def mla_absorb(q, w_kvb, nope: int, width: int):
+    """The query in the cache row's space (the absorbed form): ``q_nope
+    W_uk^T`` over the latent's columns, ``q_pe`` over the rope key's, so
+    that ``q_row . row == q . k`` of the expanded form."""
+    q_lat = jnp.einsum("bshn,chn->bshc", q[..., :nope], w_kvb[..., :nope])
+    return mla_row(q_lat, q[..., nope:], width)
+
+
 # -- attention ----------------------------------------------------------------
 
 # Test hook: route the TPU-gated flash branches through the Pallas
@@ -289,7 +355,7 @@ def attention(q, k, v, mesh: Optional[Mesh], causal: bool,
 def cached_attend(q, keys, vals, mask, scale):
     """Decode-path attention against a KV cache, GQA-grouped.
 
-    q [B,Sq,H,Dh]; keys/vals [B,T,KV,Dh]; mask [Sq,T] bool (shared across
+    q [B,Sq,H,Dh]; keys [B,T,KV,Dh], vals [B,T,KV,Dv]; mask [Sq,T] bool (shared across
     the batch) or [B,Sq,T] (per-request — the serving engine's slots sit
     at different context lengths).  The q heads are reshaped [KV, rep]
     and contracted against the grouped cache directly — the cache is
@@ -305,7 +371,7 @@ def cached_attend(q, keys, vals, mask, scale):
     s = jnp.where(m, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(vals.dtype), vals)
-    return o.reshape(B, Sq, H, Dh)
+    return o.reshape(B, Sq, H, vals.shape[-1])
 
 
 def gather_blocks(pool, table) -> jax.Array:

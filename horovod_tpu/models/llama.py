@@ -845,71 +845,152 @@ def generate(params: dict, prompt: jax.Array, cfg: LlamaConfig, *,
 # tokens; only cache PLACEMENT differs (the engine owns the page pool).
 # ---------------------------------------------------------------------------
 
-def _serve_layers(params, tok, positions, cfg: LlamaConfig, mesh, attend,
-                  state=None):
-    """The skeleton under the three serving steps: embed ``tok [B, S]``,
-    the rope tables of ``positions [B, S]``, then the frame scanned over
-    (layers, cache layer index), ``cfg.loops`` times over the same
-    stacked weights (:func:`~horovod_tpu.models.layers.looped`).
-    ``attend(q, k, v, li, state) -> (o, (state, out))`` is the step's
-    own: where the layer's K and V go and what q attends over; ``li`` is
-    the cache layer, ``t * n_layers + l`` in pass ``t``; ``state`` (the
-    pools) rides the scan's carry, ``out`` (a layer's K and V) is
-    stacked, ``cache_layers`` deep.  Returns ``(h, state, outs)``."""
-    h = embed_lookup(params["embed"], tok, cfg.dtype)
+def model_of(cfg):
+    """The module a served configuration belongs to, by its class: this
+    one for a :class:`LlamaConfig`,
+    :mod:`horovod_tpu.models.glm_moe_lite` for its own.  The serving
+    steps ask it for what differs between models and nothing else:
+    ``serve_embed``, ``serve_runs`` (the layer runs and their ``(mixer,
+    mlp)`` pairs),
+    ``prefill_attend`` and ``paged_attend`` (where a layer's cache
+    entries go and what a query reads), ``cache_rows`` and ``pool_dims``
+    (the pools' geometry), ``serve_stats``, ``check_servable``,
+    ``paged_kernel_ok`` and ``PAGED_KERNEL``."""
+    model = sys.modules[type(cfg).__module__]
+    if not hasattr(model, "serve_runs"):
+        raise NotImplementedError(
+            f"{model.__name__} has no serving steps (serve_runs and the "
+            "functions beside it)")
+    return model
+
+
+#: what :attr:`ServingEngine.attention_path` calls this model's kernel
+PAGED_KERNEL = "pallas"
+
+
+def check_servable(cfg: LlamaConfig, mesh: Optional[Mesh]) -> None:
+    """Raise, by name, for what of a :class:`LlamaConfig` the serving
+    steps cannot run."""
+    if cfg.use_moe:
+        raise NotImplementedError(
+            "serving does not run the capacity-routed Switch expert layer "
+            "(LlamaConfig.use_moe): it drops the tokens over an expert's "
+            "capacity, so a request's answer would depend on its batch; "
+            "the dropless expert layer is served (models/glm_moe_lite.py)")
+
+
+def cache_rows(cfg: LlamaConfig) -> tuple:
+    """Per-token shape of each pool of the paged cache: keys and values,
+    grouped."""
+    return ((cfg.n_kv_heads, cfg.head_dim),) * 2
+
+
+def pool_dims(cfg: LlamaConfig) -> tuple:
+    return POOL_DIMS
+
+
+def serve_runs(params, cfg: LlamaConfig, positions, mesh) -> list:
+    """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack."""
+    tables = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
+    return [(params["layers"],
+             lambda attend: partial(gqa_mixer, tables=tables, attend=attend),
+             dense_mlp)]
+
+
+def serve_stats(cfg: LlamaConfig, extras: list) -> dict:
+    return {}
+
+
+#: the serving steps' embedding: the one-hot product of training, which
+#: partitions under the vocab_rows sharding (a model served on one chip
+#: with a large vocabulary brings a lookup: glm_moe_lite.serve_embed)
+serve_embed = embed_lookup
+
+
+def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
+    """The skeleton under the three serving steps, for every served
+    model: embed ``tok [B, S]``, then each of the model's layer runs
+    (:func:`model_of`'s ``serve_runs``: one for the Llama family, the
+    dense layers and the expert layers for GLM-4.7-Flash) scanned through
+    the frame over (layers, cache layer index), ``cfg.loops`` times over
+    the same stacked weights (:func:`~horovod_tpu.models.layers.looped`).
+    ``attend(..., li, state) -> (o, (state, out))`` is the step's own,
+    handed what the run's mixer hands it (rotated q and grouped k, v; or
+    q, the latent, the shared key and the up-projection): where the
+    layer's cache entries go and what q attends over; ``li`` is the cache
+    layer, ``t * n_layers + l`` in pass ``t``; ``state`` (the pools)
+    rides the scan's carry, ``out`` (a layer's entries) is stacked,
+    ``cache_layers`` deep.  Returns ``(h, state, outs, stats)``, ``stats``
+    what the model makes of its mlps' extras (the expert layers' counts,
+    or nothing)."""
+    model = model_of(cfg)
+    h = model.serve_embed(params["embed"], tok, cfg.dtype)
     if mesh is not None:
         h = shd.constrain(h, ("batch", None, None), mesh,
-                          shard_rules(cfg, mesh))
-    tables = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
+                          model.shard_rules(cfg, mesh))
+    renorm = lambda h: rmsnorm(h, params["final_norm"], cfg.rms_eps)
+    first, outs, extras = 0, [], []
+    for stacked, mixer_of, mlp in model.serve_runs(params, cfg, positions,
+                                                   mesh):
+        def layer(carry, xs):    # traced here, with this run's pair
+            h, state = carry
+            lp, li = xs
+            h, (state, out), extra = block(
+                h, lp, mixer_of(partial(attend, li=li, state=state)), mlp,
+                cfg.rms_eps)
+            return (h, state), (out, extra)
 
-    def layer(carry, xs):
-        h, state = carry
-        lp, li = xs
-        h, (state, out), _ = block(
-            h, lp, partial(gqa_mixer, tables=tables,
-                           attend=partial(attend, li=li, state=state)),
-            dense_mlp, cfg.rms_eps)
-        return (h, state), out
-
-    (h, state), outs = looped(
-        layer, (h, state), params["layers"], cfg.loops,
-        lambda h: rmsnorm(h, params["final_norm"], cfg.rms_eps),
-        jnp.arange(cfg.cache_layers))
-    return h, state, outs
+        n = cfg.loops * jax.tree.leaves(stacked)[0].shape[0]
+        (h, state), (out, extra) = looped(
+            layer, (h, state), stacked, cfg.loops, renorm,
+            jnp.arange(first, first + n))
+        first += n
+        outs.append(out)
+        extras.append(extra)
+    outs = outs[0] if len(outs) == 1 else jax.tree.map(
+        lambda *a: jnp.concatenate(a), *outs)
+    return h, state, outs, model.serve_stats(cfg, extras)
 
 
-def prefill_step(params, tokens: jax.Array, cfg: LlamaConfig, *,
-                 mesh: Optional[Mesh] = None,
-                 last_pos: Optional[jax.Array] = None
-                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Prompt prefill for the serving engine.
-
-    tokens [B, P] int32 → (next-token greedy tokens' logits [B, V] fp32,
-    per-cache-layer K [cache_layers, B, P, KV, Dh], per-cache-layer V).
-    ``last_pos`` [B] selects the logits position per row (bucketed
-    prompts are right-padded: the real last token sits at ``len-1``, not
-    ``P-1``); None means ``P-1``.
-    Causality makes the padded tail inert for every real position, so a
-    bucketed prefill emits the same token as an exact-length one."""
-    B, P = tokens.shape
+def prefill_attend(cfg: LlamaConfig, mesh, P: int):
+    """``attend`` of the prompt prefill: causal attention of the prompt
+    over its own keys; the layer's K and V are what it leaves."""
     scale = 1.0 / np.sqrt(cfg.head_dim)
-    rules = shard_rules(cfg, mesh)
     mask = jnp.tril(jnp.ones((P, P), bool))
     pin = _pin_kv(cfg, mesh)
 
     def attend(q, k, v, li, state):
         return cached_attend(q, k, v, mask, scale), (state, (pin(k), pin(v)))
 
-    h, _, (ks, vs) = _serve_layers(
+    return attend
+
+
+def prefill_step(params, tokens: jax.Array, cfg, *,
+                 mesh: Optional[Mesh] = None,
+                 last_pos: Optional[jax.Array] = None) -> tuple:
+    """Prompt prefill for the serving engine.
+
+    tokens [B, P] int32 → (next-token logits [B, V] fp32, the prompt's
+    cache entries, one array ``[cache_layers, B, P, *row]`` for each pool
+    of the model's cache (K and V for the Llama family, the latent rows
+    for GLM-4.7-Flash), the step's stats).
+    ``last_pos`` [B] selects the logits position per row (bucketed
+    prompts are right-padded: the real last token sits at ``len-1``, not
+    ``P-1``); None means ``P-1``.
+    Causality makes the padded tail inert for every real position, so a
+    bucketed prefill emits the same token as an exact-length one."""
+    model = model_of(cfg)
+    B, P = tokens.shape
+    h, _, kept, stats = _serve_layers(
         params, tokens, jnp.broadcast_to(jnp.arange(P), (B, P)), cfg, mesh,
-        attend)
+        model.prefill_attend(cfg, mesh, P))
     if last_pos is None:
         h_last = h[:, -1]
     else:
         h_last = jnp.take_along_axis(
             h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return (_head(params, h_last, cfg, ("batch", "vocab"), mesh, rules),
-            ks, vs)
+    return (_head(params, h_last, cfg, ("batch", "vocab"), mesh,
+                  model.shard_rules(cfg, mesh)), kept, stats)
 
 
 def paged_kernel_ok(cfg: LlamaConfig, mesh: Optional[Mesh],
@@ -949,8 +1030,8 @@ def _paged_kernel_attend(q, kp, vp, layer, tables, lengths, scale, mesh,
             q, kp, vp, layer, tables, lengths)
 
 
-def _paged_attend(cfg: LlamaConfig, mesh, tables, blk, off, mask,
-                  last=None, interpret: bool = False):
+def paged_attend(cfg: LlamaConfig, mesh, tables, blk, off, mask,
+                 last=None, interpret: bool = False):
     """The ``attend`` of the two paged steps (see :func:`_serve_layers`):
     each layer writes its fresh K/V rows into page ``blk`` at offset
     ``off`` of its own pages first, then reads the table's logical window
@@ -989,81 +1070,81 @@ def _paged_attend(cfg: LlamaConfig, mesh, tables, blk, off, mask,
 
 
 def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
-                      k_pool: jax.Array, v_pool: jax.Array,
-                      tables: jax.Array, cfg: LlamaConfig, *,
+                      pools: tuple, tables: jax.Array, cfg, *,
                       mesh: Optional[Mesh] = None, use_flash: bool = False,
-                      interpret: bool = False
-                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode tick for the serving engine against the paged pool.
+                      interpret: bool = False) -> tuple:
+    """One decode tick for the serving engine against the paged pools.
 
     tok [B] int32 (this tick's input token per slot); positions [B] its
-    absolute position; k_pool/v_pool [cache_layers, NB, BS, KV, Dh]; tables
+    absolute position; ``pools`` the model's cache, each ``[cache_layers,
+    NB, BS, *row]`` (K and V ``[.., KV, Dh]`` for the Llama family, one
+    pool of latent rows for GLM-4.7-Flash); tables
     [B, n_cols] int32 block tables (inactive rows all-scratch).  Each
-    layer writes its fresh K/V into ``tables[b][positions[b] // BS]`` at
-    offset ``positions[b] % BS`` and attends over the table's logical
+    layer writes its fresh entries into ``tables[b][positions[b] // BS]``
+    at offset ``positions[b] % BS`` and attends over the table's logical
     window with a per-request ``<= position`` mask (stale slots masked).
     The attention reads the pool either through a contiguous gather (XLA
-    path, GSPMD-shardable) or in the Pallas paged kernel, which copies
-    each stream's live pages in by the scalar-prefetched table
-    (``use_flash``; callers check :func:`paged_kernel_ok`).
-    Returns (logits [B, V] fp32, k_pool, v_pool) — pass the pools donated
+    path, GSPMD-shardable) or in the model's Pallas paged kernel, which
+    copies each stream's live pages in by the scalar-prefetched table
+    (``use_flash``; callers check the model's ``paged_kernel_ok``).
+    Returns (logits [B, V] fp32, pools, stats) — pass the pools donated
     so the writes land in place."""
+    model = model_of(cfg)
     B = tok.shape[0]
-    BS = k_pool.shape[2]
+    BS = pools[0].shape[2]
     T = tables.shape[1] * BS
     mask = (jnp.arange(T)[None, :] <= positions[:, None])[:, None, :]
     blk = tables[jnp.arange(B), positions // BS]                # [B]
-    h, (k_pool, v_pool), _ = _serve_layers(
+    h, pools, _, stats = _serve_layers(
         params, tok[:, None], positions[:, None], cfg, mesh,
-        _paged_attend(cfg, mesh, tables, blk, positions % BS, mask,
-                      positions if use_flash else None, interpret),
-        (k_pool, v_pool))
+        model.paged_attend(cfg, mesh, tables, blk, positions % BS, mask,
+                           positions if use_flash else None, interpret),
+        tuple(pools))
     return (_head(params, h[:, 0], cfg, ("batch", "vocab"), mesh,
-                  shard_rules(cfg, mesh)), k_pool, v_pool)
+                  model.shard_rules(cfg, mesh)), pools, stats)
 
 
 def extend_step_paged(params, tok: jax.Array, positions: jax.Array,
-                      valid: jax.Array, k_pool: jax.Array,
-                      v_pool: jax.Array, tables: jax.Array,
-                      cfg: LlamaConfig, *, mesh: Optional[Mesh] = None
-                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                      valid: jax.Array, pools: tuple, tables: jax.Array,
+                      cfg, *, mesh: Optional[Mesh] = None) -> tuple:
     """Multi-token paged forward: S tokens per row in ONE dispatch.
 
     The serving front door's verify-forward entry — it serves both
     (a) **prefix-hit tail prefill**: a prompt whose head is already in
     the pool (radix prefix cache) prefills only its tail while attending
-    over the cached prefix K/V, and (b) **speculative-decode verify**:
+    over the cached prefix, and (b) **speculative-decode verify**:
     the target model scores ``k + 1`` positions (last accepted token +
     k draft tokens) in one forward so the accepted prefix falls out of a
     single logits comparison.
 
     tok [B, S] int32; positions [B, S] absolute positions per token;
     valid [B, S] bool — False slots (right-padding, inactive verify
-    rows) route their K/V writes to scratch block 0 so a padded slot
+    rows) route their cache writes to scratch block 0 so a padded slot
     repeating a real position can never double-write a live (block,
     offset); their logits are meaningless and must be ignored.
-    k_pool/v_pool [L, NB, BS, KV, Dh]; tables [B, n_cols] int32.
+    ``pools`` as :func:`decode_step_paged`'s; tables [B, n_cols] int32.
 
-    Each layer writes all S fresh K/V rows first, then attends over the
+    Each layer writes all S fresh entries first, then attends over the
     table's logical window with the per-token causal mask ``pool_pos <=
     positions[b, s]`` — so token s sees the cached prefix AND the
-    earlier tokens of this same call (their K/V just landed in the
+    earlier tokens of this same call (their entries just landed in the
     pool), exactly the visibility a monolithic prefill gives it.  Reads
     go through the contiguous-gather path (GSPMD-shardable); the Pallas
-    decode kernel is single-query and does not apply here.  Returns
-    (logits [B, S, V] fp32, k_pool, v_pool) — donate the pools."""
-    BS = k_pool.shape[2]
+    decode kernels are single-query and do not apply here.  Returns
+    (logits [B, S, V] fp32, pools, stats) — donate the pools."""
+    model = model_of(cfg)
+    BS = pools[0].shape[2]
     T = tables.shape[1] * BS
     mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]  # [B,S,T]
     blk = jnp.where(valid,
                     jnp.take_along_axis(tables, positions // BS, axis=1),
                     0)                                             # [B,S]
     off = jnp.where(valid, positions % BS, 0)
-    h, (k_pool, v_pool), _ = _serve_layers(
+    h, pools, _, stats = _serve_layers(
         params, tok, positions, cfg, mesh,
-        _paged_attend(cfg, mesh, tables, blk, off, mask), (k_pool, v_pool))
+        model.paged_attend(cfg, mesh, tables, blk, off, mask), tuple(pools))
     return (_head(params, h, cfg, ("batch", None, "vocab"), mesh,
-                  shard_rules(cfg, mesh)), k_pool, v_pool)
+                  model.shard_rules(cfg, mesh)), pools, stats)
 
 
 def _use_blockwise_ce(cfg: LlamaConfig, mesh: Optional[Mesh]) -> bool:
@@ -1274,7 +1355,9 @@ def make_train_step(cfg, mesh: Mesh, tx, *,
     ``loss_fn(params, batch, cfg, mesh=)``, ``param_shardings(cfg, mesh)``
     and ``init_params(cfg, key)`` (for the optimizer state's shapes).
     The default is this module with a :class:`LlamaConfig`;
-    :mod:`horovod_tpu.models.kimi_linear` is the other.  Where the model
+    :mod:`horovod_tpu.models.kimi_linear` is the other; a model whose
+    objective is not written refuses by its ``check_trainable(cfg)``
+    (:mod:`horovod_tpu.models.glm_moe_lite`).  Where the model
     sets ``LOSS_HAS_AUX`` its loss returns ``(loss, aux)`` and so does
     the step, as its third output.
 
@@ -1289,6 +1372,8 @@ def make_train_step(cfg, mesh: Mesh, tx, *,
             "objective, each pass's loss weighed by a learned exit "
             "distribution with an entropy term, which is not here; plain "
             "cross-entropy on the last pass would train another model")
+    if hasattr(model, "check_trainable"):
+        model.check_trainable(cfg)       # raises, naming what is missing
     if mesh.shape.get("pp", 1) > 1 and model is not _THIS:
         raise NotImplementedError(
             f"{model.__name__} has no pipelined forward; use a pp=1 mesh")

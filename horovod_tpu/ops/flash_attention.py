@@ -136,9 +136,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
 # Scoped VMEM a Mosaic kernel gets on the v5e without asking; a kernel
 # that needs more asks for it (``vmem_limit_bytes``) out of the chip's
 # 128 MiB.  :data:`_FLASH_VMEM_BUDGET` is what :func:`supported` lets the
-# flash kernels keep resident, by :func:`_flash_resident`.
+# flash kernels keep resident, by :func:`_flash_resident`: 40 MiB since a
+# 13,312-token prompt at keys and values 256 wide keeps 33 (the forward
+# kernel compiles there for a v5e with its limit raised to 50; a prompt
+# that fell to the dense path would build 12 GB of scores).
 _SCOPED_VMEM = 16 << 20
-_FLASH_VMEM_BUDGET = 24 << 20
+_FLASH_VMEM_BUDGET = 40 << 20
 
 
 def _flash_resident(S: int, D: int, Dv: int, itemsize: int, blk: int) -> int:
@@ -681,6 +684,209 @@ def paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
         name="hvd_paged_decode",
     )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
       lengths.astype(jnp.int32), q, k_pool, v_pool)
+
+
+# ---------------------------------------------------------------------------
+# latent paged decode kernel (serving: one shared entry a token and layer)
+# ---------------------------------------------------------------------------
+
+# Tokens the latent decode kernel takes in one group.  A group's score
+# block is [H, G] with no kv-head fold, so it can be twice the GQA
+# kernel's at a quarter of its VMEM.
+_MLA_GROUP_TOKENS = 512
+
+
+def _mla_paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref,
+                             pool_hbm, o_ref, buf, sem, first_slot, *,
+                             scale: float, v_dim: int):
+    """One stream of latent (MLA) paged decode attention, the key
+    up-projection absorbed into the query: the stream's live pages are
+    walked in groups of ``P`` as :func:`_paged_decode_kernel` walks them
+    (two VMEM slots, group ``g+1`` and the next stream's first group
+    started before group ``g`` is computed, a last group copying the pages
+    it holds, a row of length 0 copying nothing and reading as zeros).
+
+    A page is ``[BS, W]``: a token's row is its normed latent (the first
+    ``v_dim`` columns), its rotated shared key, and padding up to whole
+    lanes.  It is copied ONCE and used for both products: ``q [H, W] .
+    page^T -> [H, G]`` over the whole row (the query's padding columns
+    are zero) and ``p . page[:, :v_dim] -> [H, v_dim]``.  Every head
+    reads the same rows, so there is no kv-head fold and no masked
+    surplus beyond the last group's tail; the buffer's slots are zeroed
+    at the call's first step, so rows no copy reached are finite under
+    ``p == 0``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H = q_ref.shape[0]
+    BS = pool_hbm.shape[2]
+    P = buf.shape[1] // BS
+    G = P * BS
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    li = layer_ref[0]
+    length = lengths_ref[b]
+    n_groups = (length + G - 1) // G
+
+    def pages_of(stream, g):
+        return jnp.minimum((lengths_ref[stream] + BS - 1) // BS - g * P, P)
+
+    def next_stream(row):
+        return jax.lax.while_loop(
+            lambda r: (r < B) & (lengths_ref[jnp.minimum(r, B - 1)] == 0),
+            lambda r: r + 1, row)
+
+    def start(stream, g, slot):
+        def page(p, _):
+            blk = tables_ref[stream, g * P + p]
+            rows = pl.ds(pl.multiple_of(p * BS, BS), BS)
+            pltpu.make_async_copy(pool_hbm.at[li, blk], buf.at[slot, rows],
+                                  sem.at[slot]).start()
+        jax.lax.fori_loop(0, pages_of(stream, g), page, None)
+
+    def wait(g, slot):
+        # The semaphore counts bytes: take exactly what ``start`` sent,
+        # by the binary digits of the page count.
+        pages = pages_of(b, g)
+        for digit in reversed(range(P.bit_length())):
+            rows = pl.ds(0, BS << digit)
+
+            @pl.when((pages >> digit) & 1 == 1)
+            def _take():
+                pltpu.make_async_copy(buf.at[slot, rows], buf.at[slot, rows],
+                                      sem.at[slot]).wait()
+
+    @pl.when(b == 0)
+    def _first():
+        first_slot[0] = 0
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        head = next_stream(0)
+        pl.when(head < B)(lambda: start(head, 0, 0))
+
+    slot0 = first_slot[0]
+    q = q_ref[...]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (H, G), 1)
+
+    def body(g, carry):
+        m, l, acc = carry
+        slot = (slot0 + g) % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _more():
+            start(b, g + 1, 1 - slot)
+
+        @pl.when(g + 1 == n_groups)
+        def _hand_over():
+            nxt = next_stream(b + 1)
+            pl.when(nxt < B)(lambda: start(nxt, 0, 1 - slot))
+
+        wait(g, slot)
+        page = buf[slot]                                  # [G, W]
+        s = jax.lax.dot_general(
+            q, page, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(tok < length - g * G, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(page.dtype), page[:, :v_dim],
+                     preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
+
+    m0 = jnp.full((H, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((H, 1), jnp.float32)
+    acc0 = jnp.zeros((H, v_dim), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_groups, body, (m0, l0, acc0))
+    first_slot[0] = (slot0 + n_groups) % 2
+    o_ref[...] = jnp.where(length > 0, acc / l, 0.0).astype(o_ref.dtype)
+
+
+def _mla_resident(pages: int, block_size: int, width: int, heads: int,
+                  itemsize: int) -> int:
+    """VMEM bytes of the latent decode kernel at ``pages`` pages a group:
+    the group in two slots, the float32 score block three times over."""
+    rows = pages * block_size
+    return 2 * rows * width * itemsize + 3 * heads * rows * 4
+
+
+def mla_group_pages(block_size: int, width: int, heads: int, itemsize: int,
+                    n_cols: int) -> int:
+    """Pages the latent decode kernel takes at once: a power of two (the
+    waits go by binary digits), :data:`_MLA_GROUP_TOKENS` tokens' worth,
+    no more than the table is wide, halved until the resident set fits."""
+    pages = 1
+    while pages * 2 <= min(max(1, _MLA_GROUP_TOKENS // block_size), n_cols):
+        pages *= 2
+    while pages > 1 and _mla_resident(pages, block_size, width, heads,
+                                      itemsize) > _VMEM_BUDGET:
+        pages //= 2
+    return pages
+
+
+def mla_paged_supported(block_size: int, width: int, heads: int,
+                        itemsize: int) -> bool:
+    """Latent pool geometries :func:`mla_paged_attention` compiles for
+    (interpret mode runs any): a page ``[block_size, width]`` is sliced
+    out of the pool in HBM whole, so its rows fill whole sublane tiles (16
+    rows of bf16, 8 of float32) and its width whole 128-lane tiles, which
+    the published 576 (latent 512, rope key 64) does not: the pool pads a
+    token's row to 640.  Compiled ahead of time for a v5e at block sizes
+    16 to 64, width 640, 20 heads, bf16 (PERF.md, PR 35)."""
+    return (width % 128 == 0 and block_size % (32 // itemsize) == 0
+            and _mla_resident(1, block_size, width, heads, itemsize)
+            <= _VMEM_BUDGET)
+
+
+def mla_paged_attention(q, pool, layer, tables, lengths, *, v_dim: int,
+                        scale: float, interpret: bool = False):
+    """Decode-step latent attention over a block-paged pool.
+
+    q [B, H, W]: one token a request, every head's query in the pool's
+    row space (the key up-projection absorbed: ``q_nope W_uk^T`` over the
+    latent columns, the rotated ``q_pe`` over the rope-key columns, zeros
+    over the padding); pool the WHOLE pool [L, num_blocks, block_size, W]
+    and ``layer`` the int32 scalar index of the layer to read; tables
+    [B, n_cols] int32 (rows padded with the scratch block 0); lengths
+    [B]: positions ``< lengths[b]`` are live, a row of length 0 has no
+    stream and reads as zeros.  Returns ``softmax(q . row) . row[:v_dim]``,
+    [B, H, v_dim]: the caller takes it through the value up-projection."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, W = q.shape
+    L, NB, BS, _ = pool.shape
+    itemsize = pool.dtype.itemsize
+    # whole sublane tiles of query heads (20 heads of bf16 become 32)
+    sub = 32 // itemsize
+    Hp = -(-H // sub) * sub
+    if Hp != H:
+        q = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
+    P = mla_group_pages(BS, W, Hp, itemsize, tables.shape[1])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((None, Hp, W),
+                               lambda b, li, tbl, ln: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, Hp, v_dim),
+                               lambda b, li, tbl, ln: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, P * BS, W), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_mla_paged_decode_kernel, scale=scale,
+                          v_dim=v_dim),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hp, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="hvd_mla_paged_decode",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
+      lengths.astype(jnp.int32), q, pool)
+    return out[:, :H]
 
 
 # ---------------------------------------------------------------------------

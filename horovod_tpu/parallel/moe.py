@@ -418,7 +418,8 @@ _grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
                    experts_held: dict, held_range: tuple[int, int],
                    shared: Any = None, *, k: int, renormalize: bool = True,
-                   scale: float = 1.0, tile: int = 512):
+                   scale: float = 1.0, tile: int = 512, picks: bool = False,
+                   first_row=None):
     """This chip's part of a dropless top-k expert layer.
 
     ``tokens [T, D]``; ``router [D, E]`` keeps every expert's column and
@@ -435,19 +436,35 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
     expert and run as grouped products (:func:`_grouped_experts`) under
     the static bound ``T * min(k, E_held)`` that routing cannot pass.
 
+    ``experts_held`` may be a stack of several layers' experts flattened
+    on the leading axis (``[L * E_held, ...]``), ``first_row`` (a traced
+    scalar) the row of this layer's first: each tile then picks its
+    expert's matrices out of the whole stack where they lie, and no
+    layer's ``[E_held, D, F]`` slice is copied out for the loop first
+    (1.2 GB a layer and tick at 64 experts of 2048 x 1536, which XLA does
+    copy when a scan over layers hands the loop its slice).
+
+    ``tile`` is the rows of one grouped product: a caller with few rows
+    (a decode tick) passes few, since an expert's run is padded to whole
+    tiles and every tile reads its expert's three matrices.
+
     Returns ``(out [T, D], stats)`` with ``stats = {"pairs_held": int32
     scalar, "expert_counts": int32 [E_held]}``, traced values for
-    :func:`record_held_pairs`.
+    :func:`record_held_pairs`; with ``picks`` also ``"experts" [T, k]``
+    and ``"scores" [T, E]``, the float32 gate activations the choice was
+    made from (for a comparison with a reference's own choice).
     """
     T, D = tokens.shape
     first, last = held_range
     E_held = last - first
-    assert experts_held["gate"].shape[0] == E_held, (held_range,
-                                                     experts_held["gate"].shape)
+    assert first_row is not None or \
+        experts_held["gate"].shape[0] == E_held, (
+            held_range, experts_held["gate"].shape)
     logits = jnp.einsum("td,de->te", tokens.astype(jnp.float32),
                         router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    experts, weights = topk_route(jax.nn.sigmoid(logits), bias, k,
+    scores = jax.nn.sigmoid(logits)
+    experts, weights = topk_route(scores, bias, k,
                                   renormalize=renormalize, scale=scale)
 
     # Sort the pairs by held expert; a pair held elsewhere sorts last.
@@ -474,6 +491,8 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
     tile_expert = jnp.clip(jnp.searchsorted(
         jnp.cumsum(padded), jnp.arange(M // tile) * tile, side="right"),
         0, E_held - 1).astype(jnp.int32)
+    if first_row is not None:
+        tile_expert = tile_expert + first_row
 
     out = _grouped_experts(tokens, rows, pair_w, tile_expert, n_tiles,
                            experts_held, tile)
@@ -481,4 +500,7 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
         hidden = jax.nn.silu(tokens @ shared["w_gate"]) * \
             (tokens @ shared["w_up"])
         out = out + hidden @ shared["w_down"]
-    return out, {"pairs_held": jnp.sum(counts), "expert_counts": counts}
+    stats = {"pairs_held": jnp.sum(counts), "expert_counts": counts}
+    if picks:
+        stats.update(experts=experts, scores=scores)
+    return out, stats

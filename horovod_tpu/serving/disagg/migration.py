@@ -51,8 +51,10 @@ def export_request(engine, req: Request):
     Must run while the pager still holds the request's table (i.e.
     before ``scheduler.finish`` releases the blocks).  Returns
     ``(manifest, k_bytes, v_bytes)`` — the payloads are C-contiguous
-    ``[L, nb, BS, KV, Dh]`` dumps, one whole block per page, so the
-    importer can attach any prefix of them shared and scatter the rest.
+    ``[L, nb, BS, *row]`` dumps of the engine's first and second pool
+    (K and V; a model whose cache is one pool, the latent rows, sends
+    an empty second payload), one whole block per page, so the importer
+    can attach any prefix of them shared and scatter the rest.
     """
     if not req.generated:
         raise ValueError(f"request {req.req_id} has no prefill emission "
@@ -65,12 +67,13 @@ def export_request(engine, req: Request):
         idx = np.asarray(blocks, np.int32)
         # Device-side gather of just this request's pages, then one host
         # copy — never the whole pool.
-        k = np.ascontiguousarray(np.asarray(engine.k_pool[:, idx]))
-        v = np.ascontiguousarray(np.asarray(engine.v_pool[:, idx]))
+        dumps = [np.ascontiguousarray(np.asarray(pool[:, idx]))
+                 for pool in engine.pools]
     except Exception:
         _m_exports.labels(outcome="error").inc()
         raise
-    k_bytes, v_bytes = k.tobytes(), v.tobytes()
+    assert len(dumps) <= 2, "the transport carries two payloads"
+    k_bytes, v_bytes = ([d.tobytes() for d in dumps] + [b""])[:2]
     manifest = {
         "schema": MANIFEST_SCHEMA,
         # Torn-read sentinel: the transport re-checks this + the payload
@@ -89,9 +92,8 @@ def export_request(engine, req: Request):
         "n_blocks": int(nb),
         "block_size": cache.block_size,
         "n_layers": cache.n_layers,
-        "kv_heads": cache.kv_heads,
-        "head_dim": cache.head_dim,
-        "dtype": str(k.dtype),
+        "rows": [list(r) for r in cache.rows],
+        "dtype": str(dumps[0].dtype),
         "k_len": len(k_bytes),
         "v_len": len(v_bytes),
         # Trace context rides the manifest so the decode-side import
@@ -112,8 +114,7 @@ def _check_geometry(engine, manifest: dict) -> None:
             f"supported {MANIFEST_SCHEMA}")
     for field, want in (("block_size", cache.block_size),
                         ("n_layers", cache.n_layers),
-                        ("kv_heads", cache.kv_heads),
-                        ("head_dim", cache.head_dim)):
+                        ("rows", [list(r) for r in cache.rows])):
         if manifest.get(field) != want:
             raise ValueError(
                 f"migration geometry mismatch: manifest {field}="
@@ -184,25 +185,23 @@ def import_request(engine, manifest: dict, k_bytes: bytes,
     table = engine.pager.table(req_id)
     ncb = len(cached_blocks)
     dtype = np.dtype(manifest["dtype"])
-    shape = (cache.n_layers, nb, cache.block_size,
-             cache.kv_heads, cache.head_dim)
     if ncb < nb:
-        k_arr = np.frombuffer(k_bytes, dtype).reshape(shape)
-        v_arr = np.frombuffer(v_bytes, dtype).reshape(shape)
         tail_nb = nb - ncb
-        # [L, tail_nb, BS, KV, Dh] -> [L, 1, tail_nb*BS, KV, Dh]: the
-        # scatter step's pad-and-reshape is then an exact identity, so
-        # the prefill-path jit is reused unchanged.
         L = cache.n_layers
-        ks = np.ascontiguousarray(k_arr[:, ncb:]).reshape(
-            L, 1, tail_nb * cache.block_size, cache.kv_heads,
-            cache.head_dim)
-        vs = np.ascontiguousarray(v_arr[:, ncb:]).reshape(
-            L, 1, tail_nb * cache.block_size, cache.kv_heads,
-            cache.head_dim)
-        engine.k_pool, engine.v_pool = engine._scatter(
-            engine.k_pool, engine.v_pool, jnp.asarray(ks),
-            jnp.asarray(vs), jnp.asarray(table[ncb:nb], jnp.int32))
+
+        def tail(payload, row):
+            # [L, nb, BS, *row] -> [L, 1, tail_nb*BS, *row]: the scatter
+            # step's pad-and-reshape is then an exact identity, so the
+            # prefill-path jit is reused unchanged.
+            arr = np.frombuffer(payload, dtype).reshape(
+                (L, nb, cache.block_size) + tuple(row))
+            return jnp.asarray(np.ascontiguousarray(arr[:, ncb:]).reshape(
+                (L, 1, tail_nb * cache.block_size) + tuple(row)))
+
+        engine.pools = engine._scatter(
+            engine.pools,
+            tuple(tail(b, r) for b, r in zip((k_bytes, v_bytes), cache.rows)),
+            jnp.asarray(table[ncb:nb], jnp.int32))
     _m_blocks_attached.labels(source="payload").inc(nb - ncb)
     _m_blocks_attached.labels(source="prefix_cache").inc(ncb)
 

@@ -3,10 +3,12 @@
 Transport half of the policy/transport split (the scheduler decides what
 runs; this owns how it runs on devices):
 
-- **Page pool** — ``[L, num_blocks, block_size, KV, Dh]`` K and V arrays
-  (``L`` the model's ``cache_layers``: its layers times its passes),
-  allocated once, donated through every jitted step so writes land in
-  place.  On a mesh the pool is constrained ``kv_heads`` over tp (the
+- **Page pools** — ``[L, num_blocks, block_size, *row]`` arrays, as many
+  and of such rows as the model's cache has (K and V ``[KV, Dh]`` for
+  the Llama family, one pool of latent rows for GLM-4.7-Flash; ``L`` the
+  model's ``cache_layers``: its layers times its passes), allocated
+  once, donated through every jitted step so writes land in place.  On
+  a mesh a K/V pool is constrained ``kv_heads`` over tp (the
   round-5 never-replicate-the-cache rule) and activations ``batch`` over
   dp·fsdp, via :mod:`horovod_tpu.parallel.sharding` logical rules.
 - **Bucketed shapes** — prompts are right-padded to a bucket length and
@@ -32,10 +34,10 @@ from typing import Any, Optional
 import numpy as np
 
 from ..models import llama
-from ..models.layers import POOL_DIMS
 from .. import chaos
 from ..obs import REGISTRY as _obs
 from ..obs import trace as _trace
+from ..parallel.moe import record_held_pairs
 from ..utils import logging as hvd_logging
 from .kv_pager import KVPager, OutOfBlocks, PagedKVCache
 from .scheduler import Request, RequestState, Scheduler
@@ -84,7 +86,8 @@ _m_cache_layers = _obs.gauge(
     "K/V layers of the page pool: the model's layers x its passes")
 _m_kv_bytes_per_token = _obs.gauge(
     "hvd_serving_kv_bytes_per_token",
-    "bytes of K and V one token holds in the pool, all cache layers")
+    "bytes one token holds in the pools (K and V, or its latent rows as "
+    "the pool pads them), all cache layers")
 
 _span = _trace.profiler_span
 
@@ -108,7 +111,8 @@ def _bucket_pow2(n: int, floor: int = 1) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
-    """Serving-engine knobs (model geometry comes from ``LlamaConfig``)."""
+    """Serving-engine knobs (model geometry comes from the model's
+    configuration)."""
 
     #: tokens per KV block (pool page size)
     block_size: int = 16
@@ -144,7 +148,7 @@ class ServingEngine:
     per-token callbacks the API layer wires in.
     """
 
-    def __init__(self, params: Any, cfg: llama.LlamaConfig, *,
+    def __init__(self, params: Any, cfg: Any, *,
                  engine_cfg: EngineConfig = EngineConfig(),
                  mesh=None, timeline=None,
                  draft_params: Any = None,
@@ -153,8 +157,10 @@ class ServingEngine:
         #: request with QUEUE->PREFILL->DECODE flow arrows); None keeps
         #: traces JSON/flight-recorder-only.
         self.timeline = timeline
-        if cfg.use_moe:
-            raise NotImplementedError("serving does not support MoE configs")
+        #: the module of ``cfg``'s class: what the serving steps ask for
+        #: the layer runs, the cache's geometry and the attention paths
+        self.model = llama.model_of(cfg)
+        self.model.check_servable(cfg, mesh)
         self.params = params
         self.cfg = cfg
         self.ecfg = engine_cfg
@@ -176,8 +182,8 @@ class ServingEngine:
 
         self.cache = PagedKVCache(
             n_layers=cfg.cache_layers, num_blocks=engine_cfg.num_blocks,
-            block_size=engine_cfg.block_size, kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim)
+            block_size=engine_cfg.block_size,
+            rows=self.model.cache_rows(cfg))
         self.pager = KVPager(self.cache)
         _m_cache_layers.set(self.cache.n_layers)
         _m_kv_bytes_per_token.set(
@@ -194,16 +200,17 @@ class ServingEngine:
             prefill_token_budget=engine_cfg.prefill_token_budget,
             prefix_cache=self.prefix_cache)
 
-        def fresh_pool():
-            pool = jnp.zeros(self.cache.shape, cfg.dtype)
+        def fresh_pool(shape):
+            pool = jnp.zeros(shape, cfg.dtype)
             if mesh is not None:
                 from ..parallel import sharding as shd
                 pool = jax.device_put(pool, shd.logical_sharding(
-                    mesh, POOL_DIMS, llama.shard_rules(cfg, mesh)))
+                    mesh, self.model.pool_dims(cfg),
+                    self.model.shard_rules(cfg, mesh)))
             return pool
 
-        self.k_pool = fresh_pool()
-        self.v_pool = fresh_pool()
+        #: the page pools, one array for each of ``cache.rows``
+        self.pools = tuple(fresh_pool(shape) for shape in self.cache.shapes)
 
         #: what the prefill and decode spans say of the model's depth
         self._depth = dict(loops=cfg.loops, cache_layers=cfg.cache_layers)
@@ -216,12 +223,12 @@ class ServingEngine:
         self._interpret = flash == "interpret"
         wanted = flash == "interpret" or (
             flash == "auto" and jax.default_backend() == "tpu")
-        self._use_flash = wanted and llama.paged_kernel_ok(
+        self._use_flash = wanted and self.model.paged_kernel_ok(
             cfg, mesh, engine_cfg.block_size, self._interpret)
         log.info("serving decode attention path: %s (use_flash=%s, "
-                 "backend %s, block_size %d, head_dim %d, mesh %s)",
+                 "backend %s, block_size %d, cache rows %s, mesh %s)",
                  self.attention_path, flash, jax.default_backend(),
-                 engine_cfg.block_size, cfg.head_dim,
+                 engine_cfg.block_size, self.cache.rows,
                  dict(mesh.shape) if mesh is not None else None)
 
         # One jit per step kind; bucketing keeps the traced shape set
@@ -230,11 +237,11 @@ class ServingEngine:
         self._prefill = jax.jit(_named(
             "hvd_serve_prefill", self._prefill_impl))
         self._scatter = jax.jit(_named(
-            "hvd_serve_scatter", self._scatter_impl), donate_argnums=(0, 1))
+            "hvd_serve_scatter", self._scatter_impl), donate_argnums=(0,))
         self._decode = jax.jit(_named(
-            "hvd_serve_decode", self._decode_impl), donate_argnums=(1, 2))
+            "hvd_serve_decode", self._decode_impl), donate_argnums=(1,))
         self._extend = jax.jit(_named(
-            "hvd_serve_extend", self._extend_impl), donate_argnums=(1, 2))
+            "hvd_serve_extend", self._extend_impl), donate_argnums=(1,))
 
         self.spec = None
         if engine_cfg.spec_k:
@@ -246,57 +253,63 @@ class ServingEngine:
                                     k=engine_cfg.spec_k)
 
     # -- jitted step bodies ---------------------------------------------
+    # Each returns ``(tokens, stats)`` first: what the host fetches, in
+    # one transfer (``stats`` the expert layers' counts, or nothing).
     def _prefill_impl(self, params, tokens, last_pos):
         jnp = self._jnp
-        logits, ks, vs = llama.prefill_step(
+        logits, kept, stats = llama.prefill_step(
             params, tokens, self.cfg, mesh=self.mesh, last_pos=last_pos)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), ks, vs
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), stats), kept
 
-    def _scatter_impl(self, kp, vp, ks, vs, blocks):
-        """Write one request's prefill K/V ([L, 1, P, KV, Dh]) into its
-        ``nb`` pool blocks.  P is the prefill bucket: it is cut to the
-        blocks' positions where it is longer (here and not by the caller,
-        whose eager slices would be two more programs, each a copy of K
-        or V) and padded up to them where it is shorter; the tail slots
-        hold pad-token K/V, masked by position until decode overwrites
-        them one at a time."""
+    def _scatter_impl(self, pools, kept, blocks):
+        """Write one request's prefill entries (each ``[L, 1, P, *row]``)
+        into its ``nb`` blocks of each pool.  P is the prefill bucket: it
+        is cut to the blocks' positions where it is longer (here and not
+        by the caller, whose eager slices would be more programs, each a
+        copy) and padded up to them where it is shorter; the tail slots
+        hold pad-token entries, masked by position until decode
+        overwrites them one at a time."""
         jnp = self._jnp
-        L = ks.shape[0]
         BS = self.cache.block_size
         nb = blocks.shape[0]
-        P = min(ks.shape[2], nb * BS)
-        pad = nb * BS - P
-        ks = jnp.pad(ks[:, 0, :P], ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vs = jnp.pad(vs[:, 0, :P], ((0, 0), (0, pad), (0, 0), (0, 0)))
-        ks = ks.reshape(L, nb, BS, *ks.shape[2:])
-        vs = vs.reshape(L, nb, BS, *vs.shape[2:])
-        return kp.at[:, blocks].set(ks), vp.at[:, blocks].set(vs)
 
-    def _decode_impl(self, params, kp, vp, tok, pos, tables):
+        def put(pool, new):
+            L = new.shape[0]
+            P = min(new.shape[2], nb * BS)
+            pad = [(0, 0), (0, nb * BS - P)] + [(0, 0)] * (new.ndim - 3)
+            new = jnp.pad(new[:, 0, :P], pad)
+            return pool.at[:, blocks].set(
+                new.reshape(L, nb, BS, *new.shape[2:]))
+
+        return tuple(put(pool, new) for pool, new in zip(pools, kept))
+
+    def _decode_impl(self, params, pools, tok, pos, tables):
         jnp = self._jnp
-        logits, kp, vp = llama.decode_step_paged(
-            params, tok, pos, kp, vp, tables, self.cfg, mesh=self.mesh,
+        logits, pools, stats = llama.decode_step_paged(
+            params, tok, pos, pools, tables, self.cfg, mesh=self.mesh,
             use_flash=self._use_flash, interpret=self._interpret)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kp, vp
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), stats), pools
 
-    def _extend_impl(self, params, kp, vp, tok, pos, valid, tables):
+    def _extend_impl(self, params, pools, tok, pos, valid, tables):
         """Multi-token paged forward ([B, S] at arbitrary positions):
         the prefix-hit tail prefill and the speculative verify step."""
         jnp = self._jnp
-        logits, kp, vp = llama.extend_step_paged(
-            params, tok, pos, valid, kp, vp, tables, self.cfg,
+        logits, pools, stats = llama.extend_step_paged(
+            params, tok, pos, valid, pools, tables, self.cfg,
             mesh=self.mesh)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kp, vp
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), stats), pools
 
     # -- public surface --------------------------------------------------
     @property
     def attention_path(self) -> str:
-        """What the decode tick reads the pool through: ``"pallas"`` (the
-        paged kernel, compiled), ``"pallas-interpret"`` or ``"gather"``
-        (XLA)."""
+        """What the decode tick reads the pool through: the model's paged
+        kernel, compiled (``"pallas"`` for the Llama family,
+        ``"pallas-mla"`` for the latent pool), the same with
+        ``"-interpret"``, or ``"gather"`` (XLA)."""
         if not self._use_flash:
             return "gather"
-        return "pallas-interpret" if self._interpret else "pallas"
+        return self.model.PAGED_KERNEL + (
+            "-interpret" if self._interpret else "")
 
     def lower_decode(self, n_cols: int):
         """The decode tick, lowered (``jax.stages.Lowered``) at a block
@@ -306,7 +319,7 @@ class ServingEngine:
         jax, jnp = self._jax, self._jnp
         R = self.ecfg.max_active
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-        return self._decode.lower(self.params, self.k_pool, self.v_pool,
+        return self._decode.lower(self.params, self.pools,
                                   i32(R), i32(R), i32(R, n_cols))
 
     def submit(self, prompt, max_new_tokens: int, *, eos_token=None,
@@ -449,7 +462,8 @@ class ServingEngine:
         Pb = self._bucket_len(P)
         sp = req.open_phase("prefill", tokens=P, bucket=Pb)
         with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=0,
-                   resumed=P if req.preemptions else 0, **self._depth):
+                   resumed=P if req.preemptions else 0,
+                   **self._depth) as span:
             # The span is the context's current span while the prefill
             # dispatches, so nested layers (collectives the model
             # enqueues) attach their events to this request's chain.
@@ -459,16 +473,15 @@ class ServingEngine:
                 # Small integer inputs go in as numpy arrays: jnp.asarray
                 # of a list, like an index into a device array, is an
                 # eager program of its own (jit_convert_element_type).
-                tok, ks, vs = self._prefill(
+                out, kept = self._prefill(
                     self.params, jnp.asarray(padded),
                     np.asarray([P - 1], np.int32))
                 blocks = self.pager.table(req.req_id)
                 # Only the blocks the P real positions span are written;
                 # the +1 slot block (for the emitted token) is untouched.
                 nb = self.cache.blocks_for(P)
-                self.k_pool, self.v_pool = self._scatter(
-                    self.k_pool, self.v_pool, ks, vs,
-                    np.asarray(blocks[:nb], np.int32))
+                self.pools = self._scatter(
+                    self.pools, kept, np.asarray(blocks[:nb], np.int32))
                 if self.spec is not None:
                     self.spec.mirror_prefill(req, padded, P)
             if self.prefix_cache is not None:
@@ -476,7 +489,7 @@ class ServingEngine:
                                          self.pager.table(req.req_id))
             req.close_phase("prefill")
             with _span("hvd.serve.prefill.fetch"):
-                first = int(np.asarray(tok)[0])
+                first = int(self._fetch(out, span)[0])
             token = self._emit(req, first)
             if req.state == RequestState.RUNNING:
                 # The decode phase opens once and spans every tick until
@@ -497,7 +510,8 @@ class ServingEngine:
         Sb = _bucket_pow2(S)
         sp = req.open_phase("prefill", tokens=P, cached=C, bucket=Sb)
         with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=C,
-                   resumed=S if req.preemptions else 0, **self._depth):
+                   resumed=S if req.preemptions else 0,
+                   **self._depth) as span:
             with sp.use(), _span("hvd.serve.prefill.dispatch"):
                 req.trace.event("prefill_skip", cached_tokens=C)
                 tok2 = np.zeros((1, Sb), np.int32)
@@ -512,10 +526,10 @@ class ServingEngine:
                 n_cols = min(_bucket_pow2(self.cache.blocks_for(P)),
                              self.cache.num_blocks)
                 tables = self.pager.table_matrix([req.req_id], n_cols)
-                nxt, self.k_pool, self.v_pool = self._extend(
-                    self.params, self.k_pool, self.v_pool,
-                    jnp.asarray(tok2), jnp.asarray(pos2),
-                    jnp.asarray(val2), jnp.asarray(tables))
+                out, self.pools = self._extend(
+                    self.params, self.pools, jnp.asarray(tok2),
+                    jnp.asarray(pos2), jnp.asarray(val2),
+                    jnp.asarray(tables))
                 if self.spec is not None:
                     self.spec.mirror_extend(tok2, pos2, val2, tables)
             if self.prefix_cache is not None:
@@ -526,16 +540,35 @@ class ServingEngine:
             _m_prefill_skipped.inc(C)
             req.close_phase("prefill")
             with _span("hvd.serve.prefill.fetch"):
-                first = int(np.asarray(nxt)[0, S - 1])
+                first = int(self._fetch(out, span)[0, S - 1])
             token = self._emit(req, first)
             if req.state == RequestState.RUNNING:
                 req.open_phase("decode")
         return token
 
-    def _count_table(self, tick, tables: np.ndarray) -> None:
+    def _fetch(self, out, span, touched: bool = False):
+        """A step's tokens and stats in the one transfer the host makes.
+        Where the model has expert layers, their pairs by expert go onto
+        ``span`` (``moe_pairs``; with ``touched`` also how many of the
+        ``experts_held`` (layer, expert) pairs the step sent any row to)
+        and into the per-layer routing metrics."""
+        tok, stats = self._jax.device_get(out)
+        counts = stats.get("expert_counts")
+        if counts is not None:
+            attrs = dict(moe_pairs=int(counts.sum()))
+            if touched:
+                attrs.update(experts_touched=int(np.count_nonzero(counts)),
+                             experts_held=counts.size)
+            span.set_metadata(**attrs)
+            for layer, c in zip(self.model.moe_layer_names(self.cfg),
+                                counts):
+                record_held_pairs(c, layer=layer)
+        return tok
+
+    def _count_table(self, tick, tables: np.ndarray, **more) -> None:
         """What one decode step's block table holds and how full the
-        pool stands, onto the step's profiler span and the cumulative
-        counters.  Block 0 is scratch and never in a request's table, so
+        pool stands (and ``more``), onto the step's profiler span and the
+        cumulative counters.  Block 0 is scratch and never in a request's table, so
         the non-zero entries are the real pages and a row whose first
         entry is non-zero has a stream (the decode program tells by the
         same)."""
@@ -547,7 +580,7 @@ class ServingEngine:
         usable = self.cache.num_blocks - 1
         tick.set_metadata(n_cols=tables.shape[1], blocks=blocks, rows=rows,
                           blocks_held=usable - self.pager.free_blocks,
-                          blocks_usable=usable)
+                          blocks_usable=usable, **more)
 
     def _decode_tick(self, tick) -> list[tuple[Request, int]]:
         """One decode step for the running set, under ``tick``, the
@@ -586,13 +619,15 @@ class ServingEngine:
                 pos[i] = r.context_len
                 ids[i] = r.req_id
             tables = self.pager.table_matrix(ids, n_cols)
-            self._count_table(tick, tables)
+            # kv_tokens: the cached tokens this tick's queries read, the
+            # one being written included
+            self._count_table(tick, tables,
+                              kv_tokens=int(pos.sum()) + len(active))
             args = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables))
         with _span("hvd.serve.decode.dispatch"):
-            nxt, self.k_pool, self.v_pool = self._decode(
-                self.params, self.k_pool, self.v_pool, *args)
+            out, self.pools = self._decode(self.params, self.pools, *args)
         with _span("hvd.serve.decode.fetch"):
-            nxt = np.asarray(nxt)
+            nxt = self._fetch(out, tick, touched=True)
         emitted = []
         with _span("hvd.serve.decode.emit"):
             for i, r in enumerate(list(self._slots)):

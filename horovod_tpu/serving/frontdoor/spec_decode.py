@@ -60,8 +60,11 @@ class SpecDecoder:
                  *, k: int) -> None:
         if k < 1:
             raise ValueError(f"spec_k must be >= 1, got {k}")
-        if draft_cfg.use_moe:
-            raise NotImplementedError("draft model must be dense")
+        if not isinstance(draft_cfg, llama.LlamaConfig):
+            raise NotImplementedError(
+                "the draft is a separate dense model of the Llama family "
+                f"(LlamaConfig), not a {type(draft_cfg).__name__}")
+        llama.check_servable(draft_cfg, None)
         if draft_cfg.vocab_size != engine.cfg.vocab_size:
             raise ValueError(
                 f"draft vocab {draft_cfg.vocab_size} != target vocab "
@@ -77,11 +80,11 @@ class SpecDecoder:
             n_layers=draft_cfg.cache_layers,
             num_blocks=engine.cache.num_blocks,
             block_size=engine.cache.block_size,
-            kv_heads=draft_cfg.n_kv_heads, head_dim=draft_cfg.head_dim)
+            rows=llama.cache_rows(draft_cfg))
         # The draft pools stay replicated on a mesh: the draft is small
         # by design and its kv_heads need not divide tp.
-        self.dk_pool = jnp.zeros(self.cache.shape, draft_cfg.dtype)
-        self.dv_pool = jnp.zeros(self.cache.shape, draft_cfg.dtype)
+        self.pools = tuple(jnp.zeros(shape, draft_cfg.dtype)
+                           for shape in self.cache.shapes)
         self._drafted_total = 0
         self._accepted_total = 0
 
@@ -90,29 +93,29 @@ class SpecDecoder:
             "hvd_serve_draft_prefill", self._prefill_impl))
         self._decode = jax.jit(_named(
             "hvd_serve_draft_decode", self._decode_impl),
-            donate_argnums=(1, 2))
+            donate_argnums=(1,))
         self._extend = jax.jit(_named(
             "hvd_serve_draft_extend", self._extend_impl),
-            donate_argnums=(1, 2))
+            donate_argnums=(1,))
 
     # -- draft-model jitted bodies (target mesh rules do not apply) ------
     def _prefill_impl(self, params, tokens, last_pos):
-        _, ks, vs = llama.prefill_step(
+        _, kept, _ = llama.prefill_step(
             params, tokens, self.draft_cfg, mesh=None, last_pos=last_pos)
-        return ks, vs
+        return kept
 
-    def _decode_impl(self, params, kp, vp, tok, pos, tables):
+    def _decode_impl(self, params, pools, tok, pos, tables):
         jnp = self._jnp
-        logits, kp, vp = llama.decode_step_paged(
-            params, tok, pos, kp, vp, tables, self.draft_cfg, mesh=None,
+        logits, pools, _ = llama.decode_step_paged(
+            params, tok, pos, pools, tables, self.draft_cfg, mesh=None,
             use_flash=False)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kp, vp
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pools
 
-    def _extend_impl(self, params, kp, vp, tok, pos, valid, tables):
-        _, kp, vp = llama.extend_step_paged(
-            params, tok, pos, valid, kp, vp, tables, self.draft_cfg,
+    def _extend_impl(self, params, pools, tok, pos, valid, tables):
+        _, pools, _ = llama.extend_step_paged(
+            params, tok, pos, valid, pools, tables, self.draft_cfg,
             mesh=None)
-        return kp, vp
+        return pools
 
     # -- context mirroring ----------------------------------------------
     def mirror_prefill(self, req, padded: np.ndarray, n_tokens: int
@@ -122,24 +125,22 @@ class SpecDecoder:
         draft-side twin of the engine's prefill+scatter."""
         jnp = self._jnp
         eng = self.eng
-        ks, vs = self._prefill(
+        kept = self._prefill(
             self.draft_params, jnp.asarray(padded),
             np.asarray([n_tokens - 1], np.int32))
         blocks = eng.pager.table(req.req_id)
         nb = self.cache.blocks_for(n_tokens)
-        self.dk_pool, self.dv_pool = eng._scatter(
-            self.dk_pool, self.dv_pool, ks, vs,
-            np.asarray(blocks[:nb], np.int32))
+        self.pools = eng._scatter(
+            self.pools, kept, np.asarray(blocks[:nb], np.int32))
 
     def mirror_extend(self, tok2, pos2, val2, tables) -> None:
         """Mirror a prefix-hit tail prefill into the draft pools (the
         cached head's draft K/V is already there from the insert-time
         request — pinned block ids are never reallocated)."""
         jnp = self._jnp
-        self.dk_pool, self.dv_pool = self._extend(
-            self.draft_params, self.dk_pool, self.dv_pool,
-            jnp.asarray(tok2), jnp.asarray(pos2), jnp.asarray(val2),
-            jnp.asarray(tables))
+        self.pools = self._extend(
+            self.draft_params, self.pools, jnp.asarray(tok2),
+            jnp.asarray(pos2), jnp.asarray(val2), jnp.asarray(tables))
 
     # -- the round -------------------------------------------------------
     def tick(self, span) -> list:
@@ -191,28 +192,27 @@ class SpecDecoder:
         # 1. draft k tokens sequentially with the small model.
         drafts = np.zeros((R, k), np.int32)
         cur = jnp.asarray(tok)
-        dk, dv = self.dk_pool, self.dv_pool
+        pools = self.pools
         for j in range(k):
-            cur, dk, dv = self._decode(
-                self.draft_params, dk, dv, cur,
+            cur, pools = self._decode(
+                self.draft_params, pools, cur,
                 jnp.asarray(pos + j, jnp.int32), tables)
             drafts[:, j] = np.asarray(cur)
         # Write d_k's K/V too (output discarded): a fully-accepted round
         # keeps position C+k in context, and without this write that
         # position would stay a hole the draft attends over forever.
-        _, dk, dv = self._decode(
-            self.draft_params, dk, dv, cur,
+        _, self.pools = self._decode(
+            self.draft_params, pools, cur,
             jnp.asarray(pos + k, jnp.int32), tables)
-        self.dk_pool, self.dv_pool = dk, dv
 
         # 2. verify all k+1 positions in one target forward.
         vtok = np.concatenate([tok[:, None], drafts], axis=1)
         vpos = pos[:, None] + np.arange(k + 1, dtype=np.int32)[None, :]
         valid = np.repeat(act[:, None], k + 1, axis=1)
-        g, eng.k_pool, eng.v_pool = eng._extend(
-            eng.params, eng.k_pool, eng.v_pool, jnp.asarray(vtok),
-            jnp.asarray(vpos), jnp.asarray(valid), tables)
-        g = np.asarray(g)                                    # [R, k+1]
+        out, eng.pools = eng._extend(
+            eng.params, eng.pools, jnp.asarray(vtok), jnp.asarray(vpos),
+            jnp.asarray(valid), tables)
+        g = eng._fetch(out, span)                            # [R, k+1]
 
         # 3./4. accept the agreeing prefix + bonus token, roll back rest.
         _m_rounds.inc()

@@ -6,8 +6,11 @@ dimension is welded shut.  Here the same grouped layout is cut into
 fixed-size blocks pooled across requests (PagedAttention, Kwon et al.;
 vLLM's central idea):
 
-- device pool: ``[L, num_blocks, block_size, KV, D]`` per K and V —
-  one allocation for the whole serving session, never resized;
+- device pools: ``[L, num_blocks, block_size, *row]``, as many and of
+  such rows as the model's cache has (``PagedKVCache.rows``: K and V of
+  ``[KV, D]`` for a grouped-query model, one pool of one latent row a
+  token for latent attention), allocated once for the whole serving
+  session, never resized;
 - host allocator (:class:`KVPager`): a free list of block ids with
   per-request block tables mapping logical position ``p`` to physical
   block ``table[p // block_size]``;
@@ -37,6 +40,7 @@ needed.
 from __future__ import annotations
 
 import dataclasses
+import math
 from collections import Counter
 from typing import Sequence
 
@@ -62,22 +66,25 @@ class PagedKVCache:
     n_layers: int
     num_blocks: int
     block_size: int
-    kv_heads: int
-    head_dim: int
+    #: per-token shape of each pool, from the model (its ``cache_rows``):
+    #: grouped keys and values are two pools of ``(kv_heads, head_dim)``,
+    #: a latent cache is one pool of one row a token
+    rows: tuple
 
     @property
-    def shape(self) -> tuple[int, int, int, int, int]:
-        return (self.n_layers, self.num_blocks, self.block_size,
-                self.kv_heads, self.head_dim)
+    def shapes(self) -> tuple:
+        """Each pool's array shape ``[L, num_blocks, block_size, *row]``."""
+        return tuple((self.n_layers, self.num_blocks, self.block_size)
+                     + tuple(r) for r in self.rows)
 
     def blocks_for(self, n_tokens: int) -> int:
         """Blocks needed to hold ``n_tokens`` positions."""
         return -(-n_tokens // self.block_size)
 
     def bytes_per_block(self, itemsize: int) -> int:
-        # x2: K and V pools.
-        return (2 * self.n_layers * self.block_size * self.kv_heads
-                * self.head_dim * itemsize)
+        """A block's bytes in all pools and cache layers."""
+        return (self.n_layers * self.block_size * itemsize
+                * sum(math.prod(r) for r in self.rows))
 
 
 class KVPager:

@@ -83,8 +83,8 @@ def _counter_value(name, **labels):
 
 def _pager(num_blocks=16, block_size=4):
     return KVPager(PagedKVCache(n_layers=2, num_blocks=num_blocks,
-                                block_size=block_size, kv_heads=2,
-                                head_dim=8))
+                                block_size=block_size,
+                                rows=((2, 8), (2, 8))))
 
 
 def test_pager_import_attach_bumps_refcounts():

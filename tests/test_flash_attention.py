@@ -115,4 +115,7 @@ def test_default_blocks_divisibility():
 def test_supported_gating():
     assert supported((1, 1024, 8, 64))
     assert not supported((1, 100, 8, 64))     # not block-divisible
-    assert not supported((1, 2048, 8, 512))   # resident set over budget
+    assert not supported((1, 4096, 8, 512))   # resident set over budget
+    # a 13,312-token prefill at keys and values 256 wide in bf16 (33 MiB
+    # resident: the latent-attention cell's longest bucket) is inside it
+    assert supported((1, 13312, 20, 256), 2, 256)

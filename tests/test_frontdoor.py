@@ -49,8 +49,8 @@ def _oracle(params, cfg, prompt, max_new):
 
 def _pager(num_blocks=16, block_size=4):
     return KVPager(PagedKVCache(n_layers=2, num_blocks=num_blocks,
-                                block_size=block_size, kv_heads=2,
-                                head_dim=8))
+                                block_size=block_size,
+                                rows=((2, 8), (2, 8))))
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,607 @@
+"""GLM-4.7-Flash (``models/glm_moe_lite.py``) and what serving it added:
+the one latent-attention mixer, the latent paged pool and its decode
+kernel, the expert layer inside the serving steps, and a page pool whose
+geometry comes from the model.  On the CPU, at tiny widths in float32:
+one dense and two expert layers, 8 experts, 2 a token, seeded.
+
+The reference is the benchmark's own (``chipbench/reference_glm.py``:
+plain ``jax.numpy``, expanded attention, every expert on every token),
+on the benchmark's seeded weights.  Logits are compared, not tokens.
+Tolerances: 2e-4 on logits of unit scale is float32 round-off through
+three layers summed in another order (the grouped experts, the absorbed
+query, the kernel's online softmax); a planted fault reads 1e-2 and more.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import reference_glm as ref
+from chipbench import weights_glm as wts
+from horovod_tpu import serving
+from horovod_tpu.models import glm_moe_lite as G
+from horovod_tpu.models import kimi_linear as KL
+from horovod_tpu.models import layers, llama
+from horovod_tpu.obs import REGISTRY
+from horovod_tpu.ops import flash_attention as FA
+from horovod_tpu.parallel.moe import moe_layer_held
+from horovod_tpu.serving import EngineConfig, ServingEngine
+from horovod_tpu.serving.kv_pager import KVPager, OutOfBlocks, PagedKVCache
+from horovod_tpu.serving.scheduler import Request, Scheduler
+
+PUBLISHED = dict(
+    attention_bias=False, hidden_act="silu", hidden_size=64,
+    intermediate_size=128, moe_intermediate_size=32, topk_method="noaux_tc",
+    norm_topk_prob=True, num_attention_heads=2, n_group=1, topk_group=1,
+    n_routed_experts=8, n_shared_experts=1, routed_scaling_factor=1.8,
+    num_experts_per_tok=2, first_k_dense_replace=1, num_hidden_layers=3,
+    partial_rotary_factor=1, rms_norm_eps=1e-5, rope_scaling=None,
+    rope_theta=10000.0, q_lora_rank=24, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, vocab_size=128)
+DIMS = wts.dims_of(PUBLISHED)
+CFG = G.GlmMoeLiteConfig.from_published(PUBLISHED, dtype=jnp.float32)
+KEY = wts.root_key(2 ** 31 + 35)
+TOL = 2e-4
+ENGINE = EngineConfig(block_size=4, num_blocks=40, max_active=3,
+                      use_flash="interpret", prefill_buckets=(8, 16, 24))
+PROMPTS = [np.asarray(jax.random.randint(jax.random.PRNGKey(i), (n,), 0,
+                                         CFG.vocab_size), np.int32)
+           for i, n in enumerate((5, 9, 14, 7))]
+NEW = 6
+
+
+@pytest.fixture(scope="module")
+def params():
+    return jax.jit(lambda k: wts.stacked(k, DIMS, jnp.float32))(KEY)
+
+
+def _reference_logits(seq):
+    """The reference's logits and picks on ``seq``, padded to a length
+    its query blocks take (causality keeps the tail inert)."""
+    S = len(seq)
+    tok = np.zeros((-(-S // 8) * 8,), np.int32)
+    tok[:S] = seq
+    old, ref.Q_BLOCK = ref.Q_BLOCK, 8
+    try:
+        logits, experts, scores = ref.forward(KEY, tok, DIMS, jnp.float32)
+    finally:
+        ref.Q_BLOCK = old
+    return np.asarray(logits)[:S], np.asarray(experts)[:, :S], \
+        np.asarray(scores)[:, :S]
+
+
+# -- (1) forward against the reference ------------------------------------------
+
+def test_config_is_the_published_one_and_the_tiny_one_matches_the_leaves():
+    assert CFG == G.GlmMoeLiteConfig.tiny(vocab_size=128, rope_theta=10000.0)
+    full = G.GlmMoeLiteConfig()
+    assert (full.cache_values, full.cache_row, full.qk_dim) == (576, 640, 256)
+    assert G.layer_runs(full) == [("dense", 1), ("moe", 46)]
+    shapes = jax.eval_shape(lambda: G.init_params(CFG, jax.random.PRNGKey(0)))
+    made = jax.eval_shape(lambda: wts.stacked(KEY, DIMS, jnp.float32))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), shapes) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), made)
+    with pytest.raises(NotImplementedError, match="n_group"):
+        G.GlmMoeLiteConfig.from_published(dict(PUBLISHED, n_group=2))
+
+
+def test_forward_matches_the_reference_logits_and_picks(params):
+    seq = PROMPTS[2]
+    logits, stats = G.forward(params, jnp.asarray(seq)[None], CFG, picks=True)
+    want, experts, scores = _reference_logits(seq)
+    np.testing.assert_allclose(np.asarray(logits[0]), want, atol=TOL)
+    assert stats["experts"].shape == (2, len(seq), 2)
+    got = ref.compare_picks(experts, scores, np.asarray(stats["experts"]),
+                            len(seq))
+    assert got["agree_share"] == 1.0 and got["clean_flips"] == 0
+    assert got["clean_places"] == 2 * len(seq)
+    np.testing.assert_array_equal(
+        np.asarray(stats["expert_counts"]).sum(-1), [2 * len(seq)] * 2)
+
+
+def test_compare_picks_judges_clean_places_only():
+    """A flip at layer 0, token 3 leaves layer 1 judged on tokens 0 to 2
+    alone; a flip there with a wide margin is a fault, one past it is
+    not judged."""
+    S, E = 6, 4
+    scores = np.tile(np.asarray([0.9, 0.8, 0.5, 0.1]), (2, S, 1))
+    scores[0, 3] = [0.9, 0.8, 0.7999, 0.1]
+    refp = np.tile(np.asarray([0, 1]), (2, S, 1))
+    prog = refp.copy()
+    prog[0, 3] = [0, 2]                     # near tie, clean
+    prog[1, 5] = [0, 3]                     # after a flip: not judged
+    got = ref.compare_picks(refp, scores, prog, S)
+    assert got["clean_flips"] == 1 and got["clean_places"] == S + 3
+    assert got["clean_flip_margin"] == pytest.approx(1e-4)
+    assert got["agree_share"] == pytest.approx(10 / 12)
+    prog[1, 1] = [0, 3]                     # clean, and no near tie
+    assert ref.compare_picks(refp, scores, prog, S)["clean_flip_margin"] \
+        == pytest.approx(0.7)
+
+
+# -- (2) prefill then decode through the engine ------------------------------------
+
+def _serve(params, engine_cfg=ENGINE):
+    """The prompts through a ``ServingEngine`` whose prefill and decode
+    programs hand back their logits: ``(engine, requests, served)`` with
+    ``served[req_id][position]`` the logits the token after ``position``
+    was picked from."""
+    eng = ServingEngine(params, CFG, engine_cfg=engine_cfg)
+    served: dict = {}
+    prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
+        p, tok, CFG, last_pos=last))
+    decode = jax.jit(lambda p, pools, tok, pos, tables:
+                     llama.decode_step_paged(
+                         p, tok, pos, pools, tables, CFG,
+                         use_flash=eng._use_flash, interpret=eng._interpret))
+
+    def spy_prefill(p, tokens, last_pos):
+        logits, kept, stats = prefill(p, tokens, last_pos)
+        req = next(r for r in eng._slots if r is not None and np.array_equal(
+            r.prefill_tokens, np.asarray(tokens)[0, :int(last_pos[0]) + 1]))
+        served.setdefault(req.req_id, {})[int(last_pos[0])] = \
+            np.asarray(logits[0])
+        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
+
+    def spy_decode(p, pools, tok, pos, tables):
+        logits, pools, stats = decode(p, pools, tok, pos, tables)
+        for i, r in enumerate(eng._slots):
+            if r is not None:
+                served[r.req_id][int(pos[i])] = np.asarray(logits[i])
+        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), pools
+
+    eng._prefill, eng._decode = spy_prefill, spy_decode
+    reqs = [eng.submit(p, NEW) for p in PROMPTS]
+    eng.run()
+    return eng, reqs, served
+
+
+@pytest.mark.parametrize("use_flash", ["interpret", "never"])
+def test_served_logits_match_the_reference(params, use_flash):
+    """Four prompts over three slots: bucketed prefill through the
+    expanded form, the rows scattered into the latent pool, decode ticks
+    through the absorbed form (the kernel interpreted, or the gather)."""
+    import dataclasses
+    eng, reqs, served = _serve(params, dataclasses.replace(
+        ENGINE, use_flash=use_flash))
+    assert eng.attention_path == {"interpret": "pallas-mla-interpret",
+                                  "never": "gather"}[use_flash]
+    assert len(eng.pools) == 1 and eng.pools[0].shape == (3, 40, 4, 128)
+    for r in reqs:
+        assert len(r.generated) == NEW
+        seq = np.concatenate([r.prompt, np.asarray(r.generated, np.int32)])
+        want, _, _ = _reference_logits(seq)
+        P = len(r.prompt)
+        for j in range(NEW):
+            np.testing.assert_allclose(served[r.req_id][P - 1 + j],
+                                       want[P - 1 + j], atol=TOL)
+    eng.pager.check_invariants()
+
+
+def test_a_planted_fault_is_over_the_tolerance(params, monkeypatch):
+    """The rope key left unrotated in the cache row reads far over TOL."""
+    real = layers.mla_row
+    monkeypatch.setattr(G, "mla_row", lambda c, k_pe, width: real(
+        c, jnp.zeros_like(k_pe), width))
+    _, reqs, served = _serve(params)
+    worst = 0.0
+    for r in reqs:
+        seq = np.concatenate([r.prompt, np.asarray(r.generated, np.int32)])
+        want, _, _ = _reference_logits(seq)
+        P = len(r.prompt)
+        worst = max(worst, max(
+            float(np.abs(served[r.req_id][P - 1 + j] - want[P - 1 + j]).max())
+            for j in range(1, NEW)))
+    assert worst > 1e-2
+
+
+# -- (3) absorbed equals expanded ------------------------------------------------
+
+def test_absorbed_attention_equals_expanded_in_float32():
+    B, T, H, C, nope, R, Dv, W = 2, 12, 3, 32, 16, 8, 16, 128
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (B, 1, H, nope + R))
+    c = jax.random.normal(ks[1], (B, T, C))
+    k_pe = jax.random.normal(ks[2], (B, T, R))
+    w_kvb = jax.random.normal(ks[3], (C, H, nope + Dv)) / np.sqrt(C)
+    scale = (nope + R) ** -0.5
+    mask = (jnp.arange(T)[None, :] < jnp.asarray([T, 7])[:, None])[:, None]
+    k, v = layers.mla_expand(c, k_pe, w_kvb, nope)
+    want = layers.cached_attend(q, k, v, mask, scale)
+    rows = layers.mla_row(c, k_pe, W)[:, :, None]
+    assert rows.shape == (B, T, 1, W)
+    assert not np.asarray(rows[..., C + R:]).any()
+    o = layers.cached_attend(layers.mla_absorb(q, w_kvb, nope, W), rows,
+                             rows[..., :C], mask, scale)
+    got = jnp.einsum("bshc,chv->bshv", o, w_kvb[..., nope:])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# -- (4) the kernel against the gather -------------------------------------------
+
+KERNEL_CASES = {
+    # lengths, table columns, block size
+    "row-of-length-0": ([9, 0, 17], 5, 4),
+    "last-group-of-one-page": ([33, 36, 1], 9, 4),      # groups of 8 pages
+    "table-wider-than-the-stream": ([5, 3, 2], 16, 4),
+    "whole-groups": ([32, 64, 16], 16, 4),
+    "all-rows-idle-but-the-last": ([0, 0, 11], 4, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_mla_kernel_matches_the_gather(case, monkeypatch):
+    lengths, n_cols, BS = KERNEL_CASES[case]
+    monkeypatch.setattr(FA, "_MLA_GROUP_TOKENS", 32)     # 8 pages a group
+    B, H, W, C, L, li = len(lengths), 3, 128, 32, 3, 2
+    NB = 1 + B * n_cols
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 2)
+    q = jax.random.normal(ks[0], (B, H, W))
+    pool = jax.random.normal(ks[1], (L, NB, BS, W))
+    # scratch block 0 holds NaN: nothing of it may be read
+    pool = pool.at[:, 0].set(jnp.nan)
+    tables = np.zeros((B, n_cols), np.int32)
+    for b, n in enumerate(lengths):
+        live = -(-n // BS)
+        tables[b, :live] = 1 + b * n_cols + np.arange(live)
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+    got = FA.mla_paged_attention(q, pool, li, tables, lengths, v_dim=C,
+                                 scale=0.2, interpret=True)
+    assert got.shape == (B, H, C) and np.isfinite(np.asarray(got)).all()
+    rows = layers.gather_blocks(jnp.nan_to_num(pool[li]), tables)[:, :, None]
+    mask = (jnp.arange(n_cols * BS)[None, :] < lengths[:, None])[:, None]
+    want = layers.cached_attend(q[:, None], rows, rows[..., :C], mask,
+                                0.2)[:, 0]
+    want = jnp.where((lengths > 0)[:, None, None], want, 0.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_mla_kernel_geometries_and_group_pages():
+    ok = FA.mla_paged_supported
+    assert ok(32, 640, 20, 2) and ok(16, 640, 20, 2) and ok(8, 128, 4, 4)
+    assert not ok(32, 576, 20, 2)          # not whole lanes
+    assert not ok(8, 640, 20, 2)           # half a sublane tile of bf16
+    assert FA.mla_group_pages(32, 640, 32, 2, 512) == 16     # 512 tokens
+    assert FA.mla_group_pages(32, 640, 32, 2, 6) == 4        # a power of two
+    assert G.paged_kernel_ok(G.GlmMoeLiteConfig(), None, 32)
+    assert not G.paged_kernel_ok(G.GlmMoeLiteConfig(), None, 8)
+    assert G.paged_kernel_ok(CFG, None, 3, interpret=True)
+
+
+# -- (5) pager, scheduler and the pool's geometry ---------------------------------
+
+def test_pool_geometry_comes_from_the_model():
+    full = G.GlmMoeLiteConfig(n_layers=7)
+    cache = PagedKVCache(n_layers=full.cache_layers, num_blocks=18432,
+                         block_size=32, rows=G.cache_rows(full))
+    assert cache.shapes == ((7, 18432, 32, 640),)
+    # what the configuration file states: 8,960 B a token as the pool pads
+    # it (8,064 as published), 286,720 B a block
+    assert cache.bytes_per_block(2) == 286_720 == 32 * 8960
+    assert full.n_layers * full.cache_values * 2 == 8064
+    gqa = llama.LlamaConfig.tiny()
+    two = PagedKVCache(n_layers=2, num_blocks=8, block_size=4,
+                       rows=llama.cache_rows(gqa))
+    assert two.shapes == ((2, 8, 4, 2, 16),) * 2
+    assert two.bytes_per_block(4) == 2 * 2 * 4 * 2 * 16 * 4
+
+
+def test_engine_gauges_read_the_latent_geometry(params):
+    ServingEngine(params, CFG, engine_cfg=ENGINE)
+    assert REGISTRY.get("hvd_serving_cache_layers").value == 3
+    assert REGISTRY.get("hvd_serving_kv_bytes_per_token").value == \
+        3 * 128 * 4
+
+
+def test_preempt_and_resume_on_the_latent_pool(params):
+    """A pool of 12 usable blocks under three streams of 20 and more
+    tokens: requests are preempted and prefilled again, and still say
+    what an unpressed engine says."""
+    import dataclasses
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, CFG.vocab_size, size=n).astype(np.int32)
+               for n in (13, 11, 14, 9)]
+    outs = []
+    for blocks in (40, 13):
+        eng = ServingEngine(params, CFG, engine_cfg=dataclasses.replace(
+            ENGINE, num_blocks=blocks, prefill_buckets=(8, 16, 24, 32)))
+        before = REGISTRY.get("hvd_serving_preemptions_total").value
+        reqs = [eng.submit(p, 10) for p in prompts]
+        eng.run()
+        outs.append([r.generated for r in reqs])
+        eng.pager.check_invariants()
+        assert eng.pager.free_blocks == blocks - 1
+        preempted = REGISTRY.get("hvd_serving_preemptions_total").value \
+            - before
+        assert (preempted > 0) == (blocks == 13)
+    assert outs[0] == outs[1]
+
+
+def test_scheduler_admits_by_blocks_of_the_latent_pool():
+    pager = KVPager(PagedKVCache(n_layers=3, num_blocks=6, block_size=4,
+                                 rows=((128,),)))
+    sched = Scheduler(pager, max_active=4, prefill_token_budget=64)
+    for i, n in enumerate((7, 9, 5)):
+        sched.submit(Request(req_id=i, prompt=np.zeros(n, np.int32),
+                             max_new_tokens=4))
+    admitted = sched.admit()
+    assert [r.req_id for r in admitted] == [0, 1]    # 2 + 3 blocks of 5 free
+    pager.check_invariants()
+    with pytest.raises(OutOfBlocks):
+        pager.allocate(9, 40)
+
+
+# -- (6) prefix cache, migration, speculative verify, by pool geometry -------------
+
+@pytest.fixture(scope="module")
+def families(params):
+    gqa = llama.LlamaConfig.tiny(vocab_size=CFG.vocab_size)
+    return {"gqa": (gqa, llama.init_params(gqa, jax.random.PRNGKey(1))),
+            "latent": (CFG, params)}
+
+
+def _plain_tokens(cfg, p, prompt, n):
+    """What an engine with no front door says: the oracle of the three."""
+    sess = serving.serve(p, cfg, num_blocks=64, block_size=8, max_active=4,
+                         use_flash="never")
+    fut = sess.submit(prompt, n)
+    sess.drain()
+    sess.close()
+    return list(fut.result().tokens)
+
+
+@pytest.mark.parametrize("family", ["gqa", "latent"])
+def test_prefix_hit_tail_prefill_by_geometry(families, family):
+    cfg, p = families[family]
+    sess = serving.serve(p, cfg, num_blocks=64, block_size=8, max_active=4,
+                         use_flash="never", prefix_cache=True)
+    rng = np.random.RandomState(3)
+    head = rng.randint(0, cfg.vocab_size, size=(24,)).astype(np.int32)
+    tail = rng.randint(0, cfg.vocab_size, size=(7,)).astype(np.int32)
+    first = sess.submit(head, 8)
+    sess.drain()
+    second = sess.submit(np.concatenate([head, tail]), 8)
+    sess.drain()
+    assert second.result().metrics["cached_tokens"] == 24
+    assert first.result().metrics["cached_tokens"] == 0
+    assert list(second.result().tokens) == _plain_tokens(
+        cfg, p, np.concatenate([head, tail]), 8)
+    sess.engine.pager.check_invariants()
+    sess.close()
+
+
+@pytest.mark.parametrize("family", ["gqa", "latent"])
+def test_migrated_request_continues_by_geometry(families, family):
+    cfg, p = families[family]
+    kw = dict(num_blocks=32, block_size=4, max_active=4, use_flash="never")
+    a, b = serving.serve(p, cfg, **kw), serving.serve(p, cfg, **kw)
+    prompt = np.random.RandomState(21).randint(
+        0, cfg.vocab_size, size=(9,)).astype(np.int32)
+    box = {}
+    fut = a.submit(prompt, 10, migrate_cb=lambda *mig: box.update(mig=mig))
+    a.drain()
+    assert fut.result(timeout=5).metrics["finish_reason"] == "migrated"
+    manifest, k_bytes, v_bytes = box["mig"]
+    rows = [list(r) for r in a.engine.cache.rows]
+    assert manifest["rows"] == rows
+    per_block = [cfg.cache_layers * 4 * int(np.prod(r)) * 4 for r in rows]
+    assert [len(k_bytes), len(v_bytes)] == \
+        ([3 * n for n in per_block] + [0])[:2]       # 9 tokens: 3 blocks
+    got = b.import_migrated(manifest, k_bytes, v_bytes)
+    b.drain()
+    assert list(got.result(timeout=5).tokens) == _plain_tokens(
+        cfg, p, prompt, 10)
+    with pytest.raises(ValueError, match="geometry"):
+        b.import_migrated(dict(manifest, rows=[[1, 2]]), k_bytes, v_bytes)
+    for s in (a, b):
+        s.engine.pager.check_invariants()
+        s.close()
+
+
+@pytest.mark.parametrize("family", ["gqa", "latent"])
+def test_speculative_verify_by_geometry(families, family):
+    """The target of either geometry behind a dense draft of the same
+    vocabulary: the emitted tokens are the target's own."""
+    cfg, p = families[family]
+    draft_cfg, draft = families["gqa"]
+    sess = serving.serve(p, cfg, num_blocks=64, block_size=8, max_active=4,
+                         use_flash="never", spec_k=2, draft_params=draft,
+                         draft_cfg=draft_cfg)
+    prompts = [np.random.RandomState(4 + i).randint(
+        0, cfg.vocab_size, size=(n,)).astype(np.int32)
+        for i, n in enumerate((5, 9))]
+    futs = [sess.submit(q, 9) for q in prompts]
+    sess.drain()
+    for q, f in zip(prompts, futs):
+        assert list(f.result().tokens) == _plain_tokens(cfg, p, q, 9)
+    assert sess.engine.spec._drafted_total > 0
+    assert len(sess.engine.spec.pools) == 2          # the draft's own K, V
+    sess.engine.pager.check_invariants()
+    sess.close()
+
+
+def test_the_draft_is_a_dense_model_of_the_llama_family(families):
+    cfg, p = families["latent"]
+    with pytest.raises(NotImplementedError, match="draft"):
+        serving.serve(p, cfg, num_blocks=16, block_size=4, max_active=2,
+                      use_flash="never", spec_k=2, draft_params=p,
+                      draft_cfg=cfg)
+
+
+# -- (7) one mixer for two models ---------------------------------------------------
+
+def _kimi_mla_mixer_as_it_stood(x, lp, cfg, mesh):
+    """``kimi_linear._mla_mixer`` of PR 34, verbatim."""
+    B, S, _ = x.shape
+    C, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
+    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
+    kva = jnp.einsum("bsd,dc->bsc", x, lp["w_kva"])
+    c = layers.rmsnorm(kva[..., :C], lp["kv_norm"], cfg.rms_eps)
+    kv = jnp.einsum("bsc,chk->bshk", c, lp["w_kvb"])
+    k_pe = jnp.broadcast_to(kva[:, :, None, C:],
+                            (B, S, cfg.n_heads, cfg.qk_rope_dim))
+    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    o = layers.attention(q, k, kv[..., nope:], mesh, True)
+    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), None
+
+
+@pytest.mark.parametrize("path", ["dense", "flash"])
+def test_the_one_mixer_gives_kimi_what_its_own_gave(path, monkeypatch):
+    monkeypatch.setattr(layers, "_FORCE_FLASH_INTERPRET", path == "flash")
+    kcfg = KL.KimiLinearConfig.tiny()
+    shapes = KL.leaf_shapes(kcfg, "mla_moe")
+    ks = jax.random.split(jax.random.PRNGKey(7), len(shapes) + 1)
+    lp = {n: jax.random.normal(k, s) / np.sqrt(s[0])
+          for k, (n, (s, _)) in zip(ks, sorted(shapes.items()))}
+    x = jax.random.normal(ks[-1], (2, 128, kcfg.d_model))
+    want, _ = _kimi_mla_mixer_as_it_stood(x, lp, kcfg, None)
+    got, kept = KL.layer_pair("mla_moe", kcfg, None)[0](x, lp)
+    assert kept is None
+    assert np.array_equal(np.asarray(got), np.asarray(want))     # bitwise
+    # and its gradient, which the trainer takes
+    g = lambda f: jax.grad(lambda x: jnp.sum(f(x)[0] ** 2))(x)
+    assert np.array_equal(
+        np.asarray(g(lambda x: _kimi_mla_mixer_as_it_stood(x, lp, kcfg,
+                                                           None))),
+        np.asarray(g(lambda x: KL.layer_pair("mla_moe", kcfg, None)[0](
+            x, lp))))
+
+
+# -- (8) the expert layer at a tick's rows and at a prompt's --------------------------
+
+@pytest.mark.parametrize("rows", [64, 4096])
+def test_expert_layer_equals_the_per_token_sum(rows):
+    D, E, F, k = 32, 8, 16, 2
+    ks = jax.random.split(jax.random.PRNGKey(rows), 8)
+    x = jax.random.normal(ks[0], (rows, D))
+    router = jax.random.normal(ks[1], (D, E)) / np.sqrt(D)
+    experts = {"gate": jax.random.normal(ks[2], (E, D, F)) / np.sqrt(D),
+               "up": jax.random.normal(ks[3], (E, D, F)) / np.sqrt(D),
+               "down": jax.random.normal(ks[4], (E, F, D)) / np.sqrt(F)}
+    shared = {"w_gate": jax.random.normal(ks[5], (D, F)) / np.sqrt(D),
+              "w_up": jax.random.normal(ks[6], (D, F)) / np.sqrt(D),
+              "w_down": jax.random.normal(ks[7], (F, D)) / np.sqrt(F)}
+    tile = G.moe_tile(rows)
+    assert tile == {64: 64, 4096: 256}[rows]
+    out, stats = moe_layer_held(x, router, jnp.zeros((E,)), experts, (0, E),
+                                shared, k=k, scale=1.8, tile=tile, picks=True)
+    w = {"router": router, "router_bias": jnp.zeros((E,)),
+         "e_gate": experts["gate"], "e_up": experts["up"],
+         "e_down": experts["down"], "s_gate": shared["w_gate"],
+         "s_up": shared["w_up"], "s_down": shared["w_down"]}
+    dims = dict(n_experts=E, experts_per_token=k, renormalize=True,
+                routed_scale=1.8)
+    want, picked, _ = ref.expert_mlp(w, x, dims)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    np.testing.assert_array_equal(np.sort(np.asarray(stats["experts"]), -1),
+                                  np.sort(np.asarray(picked), -1))
+    assert int(stats["expert_counts"].sum()) == rows * k       # dropless
+    # a stack of two layers' experts, this layer's from row E on
+    stack = {n: jnp.concatenate([jnp.zeros_like(a), a]) for n, a in
+             experts.items()}
+    again, _ = moe_layer_held(x, router, jnp.zeros((E,)), stack, (0, E),
+                              shared, k=k, scale=1.8, tile=tile,
+                              first_row=jnp.asarray(E))
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(out))
+
+
+def test_moe_tile_follows_the_rows():
+    assert [G.moe_tile(n) for n in (1, 64, 65, 320, 2048, 13312)] == \
+        [16, 64, 80, 256, 256, 256]
+
+
+# -- what is refused, by name ---------------------------------------------------------
+
+def test_training_is_refused_naming_the_missing_objective():
+    from jax.sharding import Mesh
+    import optax
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    with pytest.raises(NotImplementedError, match="multi-token prediction"):
+        llama.make_train_step(CFG, mesh, optax.sgd(0.1), model=G)
+    assert not hasattr(G, "loss_fn")
+
+
+def test_the_engine_refuses_what_it_cannot_run_by_name(params):
+    moe = llama.LlamaConfig.tiny(use_moe=True, n_experts=4)
+    with pytest.raises(NotImplementedError, match="Switch expert layer"):
+        ServingEngine(llama.init_params(moe, jax.random.PRNGKey(0)), moe)
+    kcfg = KL.KimiLinearConfig.tiny()
+    with pytest.raises(NotImplementedError, match="kimi_linear"):
+        ServingEngine({}, kcfg)
+    from jax.sharding import Mesh
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 2), ("dp", "tp"))
+    with pytest.raises(NotImplementedError, match="one chip"):
+        ServingEngine(params, CFG, mesh=mesh)
+
+
+def test_steps_return_each_expert_layers_counts(params):
+    """The counts ride the step's own outputs: every row's k pairs, a
+    layer a row of the result, for the prefill, the tick and extend."""
+    tok = jnp.asarray(PROMPTS[1])[None]
+    _, kept, stats = llama.prefill_step(params, tok, CFG)
+    assert kept[0].shape == (3, 1, 9, 128)
+    assert np.asarray(stats["expert_counts"]).sum(-1).tolist() == [18, 18]
+    pool = jnp.zeros((3, 8, 4, 128))
+    tables = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
+    args = (jnp.asarray([9, 7, 0]), jnp.asarray([9, 5, 0]), (pool,), tables)
+    _, pools, stats = llama.decode_step_paged(params, *args, CFG)
+    assert len(pools) == 1
+    assert np.asarray(stats["expert_counts"]).sum(-1).tolist() == [6, 6]
+    _, _, stats = llama.extend_step_paged(
+        params, jnp.zeros((3, 2), jnp.int32), jnp.zeros((3, 2), jnp.int32),
+        jnp.ones((3, 2), bool), (pool,), tables, CFG)
+    assert np.asarray(stats["expert_counts"]).shape == (2, 8)
+    assert llama.serve_stats(llama.LlamaConfig.tiny(), [None]) == {}
+
+
+# -- compiled for a v5e, no chip needed ---------------------------------------------
+
+def _v5e_spec(monkeypatch):
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this machine
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        tuple(shape), dtype, sharding=chip)
+
+
+def test_the_cells_decode_tick_compiles_for_a_v5e(monkeypatch):
+    """At the cell's size (seven layers, 64 rows, a table of 512 blocks of
+    32, the pool of 18,432): one Mosaic call a layer run by the name the
+    benchmark finds it by, the donated pool updated in place, and no
+    layer's experts copied out for the loop over tiles (1.2 GB where a
+    scan hands the loop its slice)."""
+    spec = _v5e_spec(monkeypatch)
+    monkeypatch.setattr(layers, "_flash_backend", lambda: True)
+    cfg = G.GlmMoeLiteConfig(n_layers=7)
+    assert all(G.prefill_path(cfg, b) == "flash"
+               for b in (2048, 6144, 12288, 13312))
+    assert G.paged_kernel_ok(cfg, None, 32)
+    p = jax.tree.map(lambda s: spec(s.shape, s.dtype), jax.eval_shape(
+        lambda: G.init_params(cfg, jax.random.PRNGKey(0))))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(p)) == \
+        4_530_936_960
+    pool = spec((7, 18432, 32, cfg.cache_row))
+    i32 = lambda *shape: spec(shape, jnp.int32)
+    step = jax.jit(
+        lambda p, tok, pos, pool, tables: llama.decode_step_paged(
+            p, tok, pos, (pool,), tables, cfg, use_flash=True),
+        donate_argnums=(3,))
+    compiled = step.lower(p, i32(64), i32(64), pool, i32(64, 512)).compile()
+    names = [ln.split(" = ")[0].strip()
+             for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(names) == 2 and all("hvd_mla_paged_decode" in n
+                                   for n in names), names
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 7 * 18432 * 32 * 640 * 2
+    assert mem.temp_size_in_bytes < 64 << 20, mem

@@ -265,7 +265,7 @@ def test_mla_block_matches_the_reference(path, monkeypatch):
     w = _layer_weights(3)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, DIMS["d_model"]))
     assert llama.attention_path((2, 128, 2, 24), 4, None, v_dim=16) == path
-    got, _ = KL._mla_mixer(x, w, KCFG, None)
+    got, _ = KL.layer_pair("mla_moe", KCFG, None)[0](x, w)
     want = jax.vmap(lambda h: ref.mla_mixer(w, h, DIMS))(x)
     _close(got, want, 1e-4)
 

@@ -669,7 +669,7 @@ def test_trace_queue_wait_after_preemption_counts_requeue_only():
 
     now = [0.0]
     pager = KVPager(PagedKVCache(n_layers=1, num_blocks=16, block_size=4,
-                                 kv_heads=1, head_dim=4))
+                                 rows=((1, 4), (1, 4))))
     s = Scheduler(pager, max_active=2, prefill_token_budget=1000,
                   clock=lambda: now[0])
     old_rate = trace.TRACER.sample_rate

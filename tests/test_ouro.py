@@ -77,24 +77,24 @@ def _serve(params, cfg=CFG, engine_cfg=ENGINE):
     served: dict = {}
     prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
         p, tok, cfg, last_pos=last))
-    decode = jax.jit(lambda p, kp, vp, tok, pos, tables:
-                     llama.decode_step_paged(p, tok, pos, kp, vp, tables,
+    decode = jax.jit(lambda p, pools, tok, pos, tables:
+                     llama.decode_step_paged(p, tok, pos, pools, tables,
                                              cfg))
 
     def spy_prefill(p, tokens, last_pos):
-        logits, ks, vs = prefill(p, tokens, last_pos)
+        logits, kept, stats = prefill(p, tokens, last_pos)
         req = next(r for r in eng._slots if r is not None and np.array_equal(
             r.prefill_tokens, np.asarray(tokens)[0, :int(last_pos[0]) + 1]))
         served.setdefault(req.req_id, {})[int(last_pos[0])] = \
             np.asarray(logits[0])
-        return jnp.argmax(logits, -1).astype(jnp.int32), ks, vs
+        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
 
-    def spy_decode(p, kp, vp, tok, pos, tables):
-        logits, kp, vp = decode(p, kp, vp, tok, pos, tables)
+    def spy_decode(p, pools, tok, pos, tables):
+        logits, pools, stats = decode(p, pools, tok, pos, tables)
         for i, r in enumerate(eng._slots):
             if r is not None:
                 served[r.req_id][int(pos[i])] = np.asarray(logits[i])
-        return jnp.argmax(logits, -1).astype(jnp.int32), kp, vp
+        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), pools
 
     eng._prefill, eng._decode = spy_prefill, spy_decode
     reqs = [eng.submit(p, NEW) for p in PROMPTS]
@@ -123,7 +123,7 @@ def test_served_logits_match_the_reference_and_generate(params):
     preempted and prefilled again: the reference's logits at every served
     position, and ``generate``'s tokens."""
     eng, reqs, served = _serve(params)
-    assert eng.k_pool.shape == (LOOPS * LAYERS, 12, 4, 4, 16)
+    assert eng.pools[0].shape == (LOOPS * LAYERS, 12, 4, 4, 16)
     assert sum(r.preemptions for r in reqs) >= 1
     assert all(len(r.generated) == NEW for r in reqs)
     assert _worst_gap(params, reqs, served) < 1e-4
@@ -137,13 +137,13 @@ def test_served_logits_match_the_reference_and_generate(params):
 
 
 def _shared_cache(monkeypatch):
-    real = llama._paged_attend
+    real = llama.paged_attend
 
     def one_cache(cfg, *a, **k):
         attend = real(cfg, *a, **k)
         return lambda q, k1, v1, li, state: attend(
             q, k1, v1, li % cfg.n_layers, state)
-    monkeypatch.setattr(llama, "_paged_attend", one_cache)
+    monkeypatch.setattr(llama, "paged_attend", one_cache)
 
 
 def _norm_after_last_pass_only(monkeypatch):
@@ -198,7 +198,7 @@ def _plain_serve_layers(params, tok, positions, cfg, mesh, attend,
 
     (h, state), outs = lax.scan(
         layer, (h, state), (params["layers"], jnp.arange(cfg.n_layers)))
-    return h, state, outs
+    return h, state, outs, {}
 
 
 def test_one_pass_without_post_norms_is_the_plain_decoder_bit_for_bit(
@@ -211,7 +211,7 @@ def test_one_pass_without_post_norms_is_the_plain_decoder_bit_for_bit(
     pool = jax.random.normal(jax.random.PRNGKey(4), (2, 6, 4, 2, 16))
     tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0]], jnp.int32)
     args = (jnp.asarray([9, 77], jnp.int32), jnp.asarray([10, 5], jnp.int32),
-            pool, pool + 1, tables, cfg)
+            (pool, pool + 1), tables, cfg)
 
     new = (llama.prefill_step(p, tok, cfg), llama.decode_step_paged(p, *args))
     monkeypatch.setattr(llama, "_serve_layers", _plain_serve_layers)
@@ -239,10 +239,10 @@ def test_paged_kernel_reads_a_cache_layer_past_the_weights_depth(params):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
     tok, pos = jnp.asarray([9, 77], jnp.int32), lengths - 1
-    outs = [llama.decode_step_paged(params, tok, pos, kp, vp, tables, CFG,
+    outs = [llama.decode_step_paged(params, tok, pos, (kp, vp), tables, CFG,
                                     use_flash=flash, interpret=flash)
             for flash in (False, True)]
-    for a, b in zip(*outs):
+    for a, b in zip(*map(jax.tree.leaves, outs)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
@@ -254,7 +254,7 @@ def test_cache_depth_follows_loops_times_layers(params):
     eng = ServingEngine(params, CFG, engine_cfg=roomy)
     depth = LOOPS * LAYERS
     assert CFG.cache_layers == eng.cache.n_layers == depth
-    assert eng.k_pool.shape[0] == eng.v_pool.shape[0] == depth
+    assert [p.shape[0] for p in eng.pools] == [depth, depth]
     assert eng.cache.bytes_per_block(4) == 2 * depth * 4 * 4 * 16 * 4
     req = eng.submit(PROMPTS[1], NEW)
     eng.step()

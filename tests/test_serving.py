@@ -46,8 +46,8 @@ def _generate_oracle(params, cfg, prompt, max_new):
 
 def _pager(num_blocks=8, block_size=4):
     return KVPager(PagedKVCache(n_layers=2, num_blocks=num_blocks,
-                                block_size=block_size, kv_heads=2,
-                                head_dim=8))
+                                block_size=block_size,
+                                rows=((2, 8), (2, 8))))
 
 
 def test_pager_allocate_free_invariants():
@@ -268,11 +268,11 @@ def test_decode_tick_is_extend_with_one_token_a_row(tiny, use_flash):
                          jnp.int32)
     tok = jnp.asarray(rng.randint(0, cfg.vocab_size, size=(B,)), jnp.int32)
     pos = jnp.asarray([5, 2, 9], jnp.int32)
-    logits_d, kp_d, vp_d = llama.decode_step_paged(
-        params, tok, pos, kp, vp, tables, cfg, use_flash=use_flash,
+    logits_d, (kp_d, vp_d), _ = llama.decode_step_paged(
+        params, tok, pos, (kp, vp), tables, cfg, use_flash=use_flash,
         interpret=use_flash)
-    logits_e, kp_e, vp_e = llama.extend_step_paged(
-        params, tok[:, None], pos[:, None], jnp.ones((B, 1), bool), kp, vp,
+    logits_e, (kp_e, vp_e), _ = llama.extend_step_paged(
+        params, tok[:, None], pos[:, None], jnp.ones((B, 1), bool), (kp, vp),
         tables, cfg)
     assert logits_d.shape == (B, cfg.vocab_size)
     np.testing.assert_allclose(logits_d, logits_e[:, 0], rtol=1e-4,
@@ -307,11 +307,11 @@ def test_decode_tick_skips_rows_with_no_stream(tiny):
     pos = np.where(live, [0, 6, 0, 0, 9], 0)
     args = [jnp.asarray(a, jnp.int32) for a in (tok, pos)]
     step = lambda k, v, flash: llama.decode_step_paged(
-        params, *args, jnp.asarray(k), jnp.asarray(v),
+        params, *args, (jnp.asarray(k), jnp.asarray(v)),
         jnp.asarray(tables), cfg, use_flash=flash, interpret=flash)
-    logits_g, kp_g, vp_g = step(kp, vp, False)
+    logits_g, (kp_g, vp_g), _ = step(kp, vp, False)
     kp[:, 0, 1:] = vp[:, 0, 1:] = np.nan      # offset 0 is written first
-    logits_k, kp_k, vp_k = step(kp, vp, True)
+    logits_k, (kp_k, vp_k), _ = step(kp, vp, True)
     assert np.isfinite(np.asarray(logits_k)).all()
     np.testing.assert_allclose(logits_k[live], logits_g[live], rtol=1e-4,
                                atol=1e-4)

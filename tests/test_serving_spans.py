@@ -51,7 +51,7 @@ DEPTH = {"loops", "cache_layers"}            # the model's, on both (PR 33)
 ATTRS = {"hvd.serve.step": {"step"},
          "hvd.serve.prefill": {"req", "tokens", "cached", "resumed"} | DEPTH,
          "hvd.serve.decode": {"n_cols", "blocks", "rows", "blocks_held",
-                              "blocks_usable"} | DEPTH}
+                              "blocks_usable", "kv_tokens"} | DEPTH}
 
 
 @pytest.fixture(scope="module")
@@ -100,7 +100,9 @@ def test_spans_attributes_and_counters_of_a_traced_run(tiny, use_flash,
             blocks=sum(len(engine.pager.table(i)) for i in ids if i >= 0),
             rows=sum(i >= 0 for i in ids),
             n_cols=n_cols, blocks_held=usable - engine.pager.free_blocks,
-            blocks_usable=usable, loops=1, cache_layers=2))
+            blocks_usable=usable, loops=1, cache_layers=2,
+            kv_tokens=sum(r.context_len + 1 for r in engine._slots
+                          if r is not None)))
         return real_tables(ids, n_cols)
     engine.pager.table_matrix = table_matrix
 
@@ -172,17 +174,61 @@ def test_prefix_hit_and_speculative_rounds_carry_the_same_spans(tiny,
                           for r in rounds)
 
 
+def test_expert_layers_counts_reach_the_spans_and_the_metrics(tmp_path):
+    """A model with expert layers: the decode span says how many (layer,
+    expert) pairs the tick touched of those held and how many (row,
+    expert) pairs it ran, the prefill span its pairs, and the per-layer
+    routing metrics advance by the same counts; a dense model's spans
+    carry none of the three."""
+    from horovod_tpu.models import glm_moe_lite as G
+    cfg = G.GlmMoeLiteConfig.tiny()          # 1 dense + 2 expert layers
+    params = G.init_params(cfg, jax.random.PRNGKey(0))
+    session = serving.serve(params, cfg, block_size=4, num_blocks=64,
+                            max_active=4, use_flash="interpret",
+                            prefill_buckets=(8, 16))
+    rng = np.random.RandomState(5)
+    submit = lambda n: session.submit(
+        rng.randint(0, cfg.vocab_size, size=(n,)).astype(np.int32), 4)
+    submit(9)
+    session.drain()                           # warm
+    held = REGISTRY.get("hvd_moe_held_pairs_total")
+    before = [held.labels(layer=l).value for l in ("2", "3")]
+    with jax.profiler.trace(str(tmp_path)):
+        for n in (5, 9, 13):
+            submit(n)
+        session.drain()
+    session.close()
+    spans = _hvd_spans(tmp_path)
+    prefills = [s[3] for s in spans if s[0] == "hvd.serve.prefill"]
+    decodes = [s[3] for s in spans if s[0] == "hvd.serve.decode"]
+    assert set(prefills[0]) == ATTRS["hvd.serve.prefill"] | {"moe_pairs"}
+    assert set(decodes[0]) == ATTRS["hvd.serve.decode"] | {
+        "moe_pairs", "experts_touched", "experts_held"}
+    # a prefill routes every row of its bucket, a tick all four slots'
+    assert [p["moe_pairs"] for p in prefills] == [
+        2 * 2 * b for b in (8, 16, 16)]
+    assert all(d["moe_pairs"] == 2 * 2 * 4 and d["experts_held"] == 2 * 8
+               and 2 <= d["experts_touched"] <= 16 for d in decodes)
+    assert all(d["kv_tokens"] >= d["rows"] for d in decodes)
+    pairs = sum(s["moe_pairs"] for s in prefills + decodes)
+    after = [held.labels(layer=l).value for l in ("2", "3")]
+    assert [b - a for a, b in zip(before, after)] == [pairs / 2] * 2
+    load = REGISTRY.get("hvd_moe_expert_load_max_over_mean")
+    assert load.labels(layer="3").value >= 1.0
+    assert session.engine.attention_path == "pallas-mla-interpret"
+
+
 def test_jitted_steps_are_named(tiny):
     engine = _session(tiny, "never").engine
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     kv = jax.ShapeDtypeStruct((2, 1, 8, 2, 16), jnp.float32)
-    pools = engine.k_pool, engine.v_pool
+    pools = engine.pools
     lowered = {
         "prefill": engine._prefill.lower(engine.params, i32(1, 8), i32(1)),
-        "scatter": engine._scatter.lower(*pools, kv, kv, i32(2)),
+        "scatter": engine._scatter.lower(pools, (kv, kv), i32(2)),
         "decode": engine.lower_decode(2),
         "extend": engine._extend.lower(
-            engine.params, *pools, i32(1, 4), i32(1, 4),
+            engine.params, pools, i32(1, 4), i32(1, 4),
             jax.ShapeDtypeStruct((1, 4), jnp.bool_), i32(1, 2)),
     }
     for what, low in lowered.items():
@@ -197,7 +243,7 @@ def test_scatter_cuts_the_bucket_to_the_blocks_itself(tiny):
     ks = jnp.arange(L * 8 * KV * Dh, dtype=jnp.float32).reshape(
         L, 1, 8, KV, Dh)
     blocks = jnp.asarray([3], jnp.int32)       # one block of 4 positions
-    kp, vp = engine._scatter(engine.k_pool, engine.v_pool, ks, -ks, blocks)
+    kp, vp = engine._scatter(engine.pools, (ks, -ks), blocks)
     np.testing.assert_array_equal(kp[:, 3], ks[:, 0, :4])
     np.testing.assert_array_equal(vp[:, 3], -ks[:, 0, :4])
     assert not np.asarray(kp[:, 4]).any()
@@ -311,7 +357,7 @@ def test_decode_step_compiles_to_one_paged_kernel_and_no_pool_copy(
     i32 = lambda *shape: spec(shape, jnp.int32)
     step = jax.jit(
         lambda p, tok, pos, kp, vp, tables: llama.decode_step_paged(
-            p, tok, pos, kp, vp, tables, cfg, use_flash=True),
+            p, tok, pos, (kp, vp), tables, cfg, use_flash=True),
         donate_argnums=(3, 4))
     compiled = step.lower(params, i32(R), i32(R), pool, pool,
                           i32(R, n_cols)).compile()
