@@ -22,7 +22,9 @@ and scanned:
   :func:`horovod_tpu.parallel.moe.moe_layer_held` with every expert held:
   sigmoid scores in float32, a selection bias, the ``experts_per_token``
   largest, renormalised, scaled, and a shared expert; dropless, the
-  grouped products' tile following the step's rows (:func:`moe_tile`).
+  grouped products' tile following the step's rows (:func:`moe_tile`),
+  their results back to the rows through the list (every scored expert
+  is held: :func:`horovod_tpu.parallel.moe.combine_form`).
 
 Served by :class:`horovod_tpu.serving.ServingEngine` through the three
 steps of :mod:`horovod_tpu.models.llama`, which find this module by the
@@ -392,6 +394,12 @@ def serve_stats(cfg: GlmMoeLiteConfig, extras: list) -> dict:
 def moe_layer_names(cfg: GlmMoeLiteConfig) -> list:
     """The 1-based numbers of the expert layers, as metric labels."""
     return [str(l) for l in range(cfg.first_k_dense + 1, cfg.n_layers + 1)]
+
+
+def moe_experts_scored(cfg: GlmMoeLiteConfig) -> int:
+    """How many experts an expert layer's router scores: all of which
+    this chip holds (:func:`_moe_mlp`)."""
+    return cfg.n_experts
 
 
 def prefill_attend(cfg: GlmMoeLiteConfig, mesh, P: int):

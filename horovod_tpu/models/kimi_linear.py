@@ -373,9 +373,10 @@ def loss_fn(params: dict, batch: dict, cfg: KimiLinearConfig, *,
 def record_routing(cfg: KimiLinearConfig, stats: dict) -> None:
     """Host side, after a step: count the step's routing (``stats`` as
     :func:`forward` returns them, fetched) into the per-layer metrics
-    ``hvd_moe_held_pairs_total`` and ``hvd_moe_expert_load_max_over_mean``."""
+    ``hvd_moe_held_pairs_total``, ``hvd_moe_expert_load_max_over_mean``
+    and ``hvd_moe_combine_steps_total``."""
     from ..parallel.moe import record_held_pairs
     moe_layers = [l for l in range(1, cfg.n_layers + 1)
                   if layer_kind(cfg, l).endswith("_moe")]
     for layer, counts in zip(moe_layers, np.asarray(stats["expert_counts"])):
-        record_held_pairs(counts, layer=str(layer))
+        record_held_pairs(counts, layer=str(layer), scored=cfg.n_experts)

@@ -855,7 +855,9 @@ def model_of(cfg):
     ``prefill_attend`` and ``paged_attend`` (where a layer's cache
     entries go and what a query reads), ``cache_rows`` and ``pool_dims``
     (the pools' geometry), ``serve_stats``, ``check_servable``,
-    ``paged_kernel_ok`` and ``PAGED_KERNEL``."""
+    ``paged_kernel_ok`` and ``PAGED_KERNEL``; where ``serve_stats``
+    returns expert layers' counts, the engine also asks for
+    ``moe_layer_names`` and ``moe_experts_scored``."""
     model = sys.modules[type(cfg).__module__]
     if not hasattr(model, "serve_runs"):
         raise NotImplementedError(

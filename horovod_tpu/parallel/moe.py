@@ -311,16 +311,28 @@ _m_load = _obs.gauge(
     "expert-parallel step waits for)", ("layer",))
 
 
-def record_held_pairs(expert_counts, layer: str = "0") -> None:
+_m_combine = _obs.counter(
+    "hvd_moe_combine_steps_total",
+    "recorded steps of the dropless expert layer by the form in which its "
+    "grouped products' results got back to the tokens' rows (list: every "
+    "scored expert is held here; add: a share of them)", ("layer", "form"))
+
+
+def record_held_pairs(expert_counts, layer: str = "0", *,
+                      scored: int) -> None:
     """Count one step's routed load into the per-layer metrics.
 
-    ``expert_counts``: pairs per held expert, ``[E_held]``.  Host-side,
-    after the step, as :func:`record_dropped_tokens`."""
+    ``expert_counts``: pairs per held expert, ``[E_held]``; ``scored``:
+    how many experts the layer's router scores, by which the step is
+    counted under its :func:`combine_form`.  Host-side, after the step,
+    as :func:`record_dropped_tokens`."""
     import numpy as np
     c = np.asarray(expert_counts, np.float64)
     _m_held.labels(layer=str(layer)).inc(float(c.sum()))
     if c.sum() > 0:
         _m_load.labels(layer=str(layer)).set(float(c.max() / c.mean()))
+    _m_combine.labels(layer=str(layer),
+                      form=combine_form(c.size, scored)).inc()
 
 
 def topk_route(scores: jax.Array, bias: jax.Array, k: int, *,
@@ -360,9 +372,22 @@ def _tile_operands(i, tile, tokens, rows, weights, tile_expert, experts):
     return idx, x, wt, w
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def combine_form(held: int, scored: int) -> str:
+    """How :func:`_grouped_experts` gets its tiles' results back to the
+    tokens' rows, from what the layer's caller holds: ``"list"`` where it
+    holds every expert the router scores (each token's ``k`` pairs are
+    all computed here, so the padded list is as full as routing makes
+    it), ``"add"`` where it holds a share (the list's static bound is
+    mostly bound: 16 of 256 experts fill a twentieth of it).  The one
+    rule, for the program and for what the host says of it
+    (``moe_combine`` on the serving spans,
+    ``hvd_moe_combine_steps_total``)."""
+    return "list" if held == scored else "add"
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _grouped_experts(tokens, rows, weights, tile_expert, n_tiles, experts,
-                     tile: int):
+                     pair_slot, tile: int):
     """Sum over the listed (row, expert) pairs of weight x SwiGLU_expert.
 
     The pairs lie sorted by expert, each expert's run padded to whole
@@ -370,21 +395,52 @@ def _grouped_experts(tokens, rows, weights, tile_expert, n_tiles, experts,
     names each slot's token (``T`` for padding), ``weights [M]`` its
     routing weight, ``tile_expert [M / tile]`` each tile's expert and
     ``n_tiles`` how many tiles are in use.  A loop over the tiles in use
-    gathers a tile's rows, runs them through that expert's three
-    matrices and adds the result back to the tokens' rows: the work is
-    that of the pairs held (to a tile), whatever the static bound ``M``,
-    and no buffer of ``M`` rows of activations exists."""
-    def body(i, out):
+    gathers a tile's rows and runs them through that expert's three
+    matrices: the work is that of the pairs held (to a tile), whatever
+    the static bound ``M``.  The results reach the tokens' rows in one of
+    two forms (:func:`combine_form` picks; the backward is the same):
+
+    - ``pair_slot`` is ``None``, the add form: every tile adds its rows
+      into a carried ``[T, D]`` at the places routing chose, a
+      scatter-add of ``tile`` rows a tile, one rounding to the tokens'
+      type a pair.  No buffer of ``M`` rows of activations exists.
+    - ``pair_slot [T, k]`` names the slot of each of a token's pairs
+      (``M - 1`` for a pair held elsewhere), the list form: every tile
+      writes its rows where it lies in a carried ``[M, D]`` list, a
+      contiguous block, and after the loop every token gathers its ``k``
+      rows, one column of ``pair_slot`` at a time, and sums them in
+      float32, rounded once.  It costs the list: ``M x D`` of the
+      tokens' type, uninitialised but for its last tile, which no tile
+      in use reaches (the bound keeps ``E_held`` rows over what routing
+      can fill): zeros for the pairs that are not here.  Every other row
+      a token reads, a tile has written."""
+    def body(i, acc):
         idx, x, wt, w = _tile_operands(i, tile, tokens, rows, weights,
                                        tile_expert, experts)
         y = _swiglu_tile(x, w["gate"], w["up"], w["down"], wt)
-        return out.at[idx].add(y, mode="drop", unique_indices=True)
-    return lax.fori_loop(0, n_tiles, body, jnp.zeros_like(tokens))
+        if pair_slot is None:
+            return acc.at[idx].add(y, mode="drop", unique_indices=True)
+        # The barrier keeps the write out of the down product's fusion:
+        # fused, XLA's product writes the list's rows itself and takes
+        # 18.2 us a tile of 256 for 12.4 (v5e); apart, the write is a
+        # copy of 1.8 us.
+        return lax.dynamic_update_slice(acc, lax.optimization_barrier(y),
+                                        (i * tile, 0))
+    if pair_slot is None:
+        return lax.fori_loop(0, n_tiles, body, jnp.zeros_like(tokens))
+    M, D = rows.shape[0], tokens.shape[1]
+    lst = lax.dynamic_update_slice(
+        lax.empty((M, D), tokens.dtype), jnp.zeros((tile, D), tokens.dtype),
+        (M - tile, 0))
+    lst = lax.fori_loop(0, n_tiles, body, lst)
+    return sum(lst.at[pair_slot[:, j]].get(mode="promise_in_bounds").astype(
+        jnp.float32) for j in range(pair_slot.shape[1])).astype(tokens.dtype)
 
 
-def _grouped_fwd(tokens, rows, weights, tile_expert, n_tiles, experts, tile):
+def _grouped_fwd(tokens, rows, weights, tile_expert, n_tiles, experts,
+                 pair_slot, tile):
     out = _grouped_experts(tokens, rows, weights, tile_expert, n_tiles,
-                           experts, tile)
+                           experts, pair_slot, tile)
     return out, (tokens, rows, weights, tile_expert, n_tiles, experts)
 
 
@@ -409,7 +465,7 @@ def _grouped_bwd(tile, res, d_out):
     zeros = (jnp.zeros_like(tokens), jnp.zeros_like(weights),
              jax.tree.map(jnp.zeros_like, experts))
     d_tok, d_wt, d_exp = lax.fori_loop(0, n_tiles, body, zeros)
-    return d_tok, None, d_wt, None, None, d_exp
+    return d_tok, None, d_wt, None, None, d_exp, None
 
 
 _grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
@@ -435,6 +491,9 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
     No capacity and no dropped token: the pairs held are sorted by
     expert and run as grouped products (:func:`_grouped_experts`) under
     the static bound ``T * min(k, E_held)`` that routing cannot pass.
+    Where every expert the router scores is held (``E_held == E``) the
+    products' results come back through a list of that bound's rows,
+    else by an add a tile (:func:`combine_form`).
 
     ``experts_held`` may be a stack of several layers' experts flattened
     on the leading axis (``[L * E_held, ...]``), ``first_row`` (a traced
@@ -494,8 +553,14 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
     if first_row is not None:
         tile_expert = tile_expert + first_row
 
+    # Each pair's own slot, in pair order, where the results come back
+    # through the list; a pair held elsewhere reads the list's last row.
+    pair_slot = None
+    if combine_form(E_held, router.shape[1]) == "list":
+        pair_slot = jnp.zeros_like(order).at[order].set(
+            jnp.minimum(slot, M - 1)).reshape(T, k)
     out = _grouped_experts(tokens, rows, pair_w, tile_expert, n_tiles,
-                           experts_held, tile)
+                           experts_held, pair_slot, tile)
     if shared is not None:
         hidden = jax.nn.silu(tokens @ shared["w_gate"]) * \
             (tokens @ shared["w_up"])

@@ -37,7 +37,7 @@ from ..models import llama
 from .. import chaos
 from ..obs import REGISTRY as _obs
 from ..obs import trace as _trace
-from ..parallel.moe import record_held_pairs
+from ..parallel.moe import combine_form, record_held_pairs
 from ..utils import logging as hvd_logging
 from .kv_pager import KVPager, OutOfBlocks, PagedKVCache
 from .scheduler import Request, RequestState, Scheduler
@@ -549,20 +549,24 @@ class ServingEngine:
     def _fetch(self, out, span, touched: bool = False):
         """A step's tokens and stats in the one transfer the host makes.
         Where the model has expert layers, their pairs by expert go onto
-        ``span`` (``moe_pairs``; with ``touched`` also how many of the
+        ``span`` (``moe_pairs``, and ``moe_combine``, the form in which
+        the program's grouped products came back to the rows, by the rule
+        the program itself goes by; with ``touched`` also how many of the
         ``experts_held`` (layer, expert) pairs the step sent any row to)
         and into the per-layer routing metrics."""
         tok, stats = self._jax.device_get(out)
         counts = stats.get("expert_counts")
         if counts is not None:
-            attrs = dict(moe_pairs=int(counts.sum()))
+            scored = self.model.moe_experts_scored(self.cfg)
+            attrs = dict(moe_pairs=int(counts.sum()),
+                         moe_combine=combine_form(counts.shape[-1], scored))
             if touched:
                 attrs.update(experts_touched=int(np.count_nonzero(counts)),
                              experts_held=counts.size)
             span.set_metadata(**attrs)
             for layer, c in zip(self.model.moe_layer_names(self.cfg),
                                 counts):
-                record_held_pairs(c, layer=layer)
+                record_held_pairs(c, layer=layer, scored=scored)
         return tok
 
     def _count_table(self, tick, tables: np.ndarray, **more) -> None:

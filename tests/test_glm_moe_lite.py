@@ -27,6 +27,7 @@ from horovod_tpu.models import kimi_linear as KL
 from horovod_tpu.models import layers, llama
 from horovod_tpu.obs import REGISTRY
 from horovod_tpu.ops import flash_attention as FA
+from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.moe import moe_layer_held
 from horovod_tpu.serving import EngineConfig, ServingEngine
 from horovod_tpu.serving.kv_pager import KVPager, OutOfBlocks, PagedKVCache
@@ -508,6 +509,189 @@ def test_expert_layer_equals_the_per_token_sum(rows):
     np.testing.assert_array_equal(np.asarray(again), np.asarray(out))
 
 
+def _reference_share(x, router, bias, experts, held, k, scale):
+    """The benchmark's reference layer (float32, every expert on every
+    token) with the experts held elsewhere, and the shared one, zero."""
+    D, E = router.shape
+    F = experts["gate"].shape[-1]
+    full = lambda a: jnp.zeros((E,) + a.shape[1:]).at[held[0]:held[1]].set(
+        a.astype(jnp.float32))
+    w = {"router": router, "router_bias": bias, "e_gate": full(
+        experts["gate"]), "e_up": full(experts["up"]), "e_down": full(
+        experts["down"]), "s_gate": jnp.zeros((D, F)),
+        "s_up": jnp.zeros((D, F)), "s_down": jnp.zeros((F, D))}
+    return ref.expert_mlp(w, x.astype(jnp.float32), dict(
+        n_experts=E, experts_per_token=k, renormalize=True,
+        routed_scale=scale))[0]
+
+
+# rows, tile, experts held of the 8, an expert no token picks, a stack
+_COMBINE_CASES = {
+    "tile-16": (40, 16, (0, 8), None, False),
+    "tile-256-a-prompt": (1000, 256, (0, 8), None, False),
+    "a-pair-held-elsewhere": (40, 16, (2, 6), None, False),
+    "an-expert-with-no-token": (40, 16, (0, 8), 3, False),
+    "first-row-into-a-stack": (40, 16, (0, 8), None, True),
+    "one-tile-an-expert-a-tick": (64, 64, (0, 8), None, False),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", _COMBINE_CASES)
+def test_the_list_form_is_the_add_form_and_the_per_token_sum(
+        case, dtype, monkeypatch):
+    """The two ways the grouped products' results get back to the rows
+    (``moe.combine_form``) against each other and against the plain sum,
+    outputs and gradients: every case has padding rows (no expert's run
+    is whole tiles), and each adds what its name says."""
+    rows, tile, held, starved, stacked = _COMBINE_CASES[case]
+    D, E, F, k = 32, 8, 16, 2
+    n = held[1] - held[0]
+    ks = jax.random.split(jax.random.PRNGKey(rows + tile), 6)
+    x = jax.random.normal(ks[0], (rows, D)).astype(dtype)
+    router = jax.random.normal(ks[1], (D, E)) / np.sqrt(D)
+    bias = jnp.zeros((E,))
+    if starved is not None:
+        bias = bias.at[starved].set(-10.0)
+    experts = {
+        "gate": (jax.random.normal(ks[2], (n, D, F)) / np.sqrt(D)),
+        "up": (jax.random.normal(ks[3], (n, D, F)) / np.sqrt(D)),
+        "down": (jax.random.normal(ks[4], (n, F, D)) / np.sqrt(F))}
+    experts = jax.tree.map(lambda a: a.astype(dtype), experts)
+    cot = jax.random.normal(ks[5], (rows, D)).astype(dtype)
+    first_row = jnp.asarray(n) if stacked else None
+
+    def layer(form):
+        def f(x, router, experts):
+            held_experts = jax.tree.map(
+                lambda a: jnp.concatenate([jnp.zeros_like(a), a]),
+                experts) if stacked else experts
+            out, stats = moe_layer_held(
+                x, router, bias, held_experts, held, None, k=k, scale=1.8,
+                tile=tile, first_row=first_row)
+            return out, stats
+        monkeypatch.setattr(moe, "combine_form", lambda *a: form)
+        (out, stats), vjp = jax.vjp(f, x, router, experts)
+        return out, stats, vjp((cot, jax.tree.map(jnp.zeros_like, stats)))
+
+    out_list, stats, g_list = layer("list")
+    out_add, _, g_add = layer("add")
+    want, vjp = jax.vjp(lambda *a: _reference_share(
+        a[0], a[1], bias, a[2], held, k, 1.8), x, router, experts)
+    g_want = vjp(cot.astype(jnp.float32))
+    counts = np.asarray(stats["expert_counts"])
+    assert (counts % tile).any()                          # padding rows
+    assert (int(counts.sum()) < rows * k) == (n < E)      # held elsewhere
+    if starved is not None:
+        assert counts[starved] == 0
+    # a bf16 step at the outputs' size; float32 to its own round-off
+    step = 2.0 ** -8 * float(jnp.max(jnp.abs(want))) \
+        if dtype == jnp.bfloat16 else 2e-5
+    f32 = lambda a: np.asarray(a, np.float32)
+    np.testing.assert_allclose(f32(out_list), f32(out_add), atol=step)
+    np.testing.assert_allclose(f32(out_list), f32(want), atol=2 * step)
+    # the backward is the one function, on the same residuals
+    for a, b in zip(jax.tree.leaves(g_list), jax.tree.leaves(g_add)):
+        np.testing.assert_array_equal(f32(a), f32(b))
+    for a, b in zip(jax.tree.leaves(g_list), jax.tree.leaves(g_want)):
+        scale = float(np.max(np.abs(f32(b)))) + 1e-6
+        np.testing.assert_allclose(
+            f32(a), f32(b), atol=scale * (2.0 ** -5 if dtype == jnp.bfloat16
+                                          else 1e-4))
+
+
+def test_the_form_follows_what_the_caller_holds():
+    assert moe.combine_form(64, 64) == "list"
+    assert moe.combine_form(16, 256) == "add"
+
+
+def _eqns(jaxpr, in_loop=False):
+    """Every equation under ``jaxpr`` with whether a ``while`` encloses
+    it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_loop
+        inner = in_loop or eqn.primitive.name == "while"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, inner)
+
+
+def combine_ops(fn, *args, width):
+    """(list writes, row adds) in the loops of ``fn``'s jaxpr: the
+    ``dynamic_update_slice`` of ``width``-wide rows and the
+    ``scatter-add`` whose update is ``width`` wide."""
+    writes = adds = 0
+    for eqn, in_loop in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if not in_loop:
+            continue
+        if eqn.primitive.name == "dynamic_update_slice":
+            upd = eqn.invars[1].aval
+            writes += upd.ndim == 2 and upd.shape[1] == width
+        if eqn.primitive.name == "scatter-add":
+            upd = eqn.invars[2].aval
+            adds += upd.ndim == 2 and upd.shape[1] == width
+    return writes, adds
+
+
+def test_the_prefill_steps_tile_loop_writes_a_list_and_adds_no_rows(params):
+    """A count, not a time: the prefill program's loop over tiles puts a
+    tile's rows into the list and holds no scatter-add of rows
+    ``d_model`` wide; the tick's and the extend step's neither."""
+    tok = jnp.asarray(PROMPTS[2])[None]
+    assert combine_ops(lambda p, t: llama.prefill_step(p, t, CFG)[0],
+                       params, tok, width=CFG.d_model) == (1, 0)
+    pool = jnp.zeros((3, 8, 4, 128))
+    tables = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
+    assert combine_ops(
+        lambda p, pool: llama.decode_step_paged(
+            p, jnp.asarray([9, 7, 0]), jnp.asarray([9, 5, 0]), (pool,),
+            tables, CFG)[0], params, pool, width=CFG.d_model) == (1, 0)
+    assert combine_ops(
+        lambda p, pool: llama.extend_step_paged(
+            p, jnp.zeros((3, 2), jnp.int32), jnp.zeros((3, 2), jnp.int32),
+            jnp.ones((3, 2), bool), (pool,), tables, CFG)[0],
+        params, pool, width=CFG.d_model) == (1, 0)
+
+
+def _grouped_experts_as_it_stood(tokens, rows, weights, tile_expert, n_tiles,
+                                 experts, pair_slot, tile):
+    """``moe._grouped_experts`` before it had a list form (its forward;
+    the backward rule was and is ``moe._grouped_bwd``)."""
+    assert pair_slot is None
+
+    def body(i, out):
+        idx, x, wt, w = moe._tile_operands(i, tile, tokens, rows, weights,
+                                           tile_expert, experts)
+        y = moe._swiglu_tile(x, w["gate"], w["up"], w["down"], wt)
+        return out.at[idx].add(y, mode="drop", unique_indices=True)
+    return jax.lax.fori_loop(0, n_tiles, body, jnp.zeros_like(tokens))
+
+
+def test_a_share_of_the_experts_still_adds_its_rows_as_it_did(monkeypatch):
+    """The trainer's layer holds a share of the experts its router scores
+    (Kimi-Linear: 16 of 256; here 4 of 16): its loop keeps the add of
+    rows ``d_model`` wide, no list, and gives bitwise what it gave."""
+    kcfg = KL.KimiLinearConfig.tiny()
+    D, E, F, k = kcfg.d_model, kcfg.n_experts, kcfg.moe_d_ff, 2
+    ks = jax.random.split(jax.random.PRNGKey(36), 8)
+    x = jax.random.normal(ks[0], (96, D))
+    router = jax.random.normal(ks[1], (D, E)) / np.sqrt(D)
+    bias = 0.1 * jax.random.normal(ks[2], (E,))
+    experts = {"gate": jax.random.normal(ks[3], (4, D, F)) / np.sqrt(D),
+               "up": jax.random.normal(ks[4], (4, D, F)) / np.sqrt(D),
+               "down": jax.random.normal(ks[5], (4, F, D)) / np.sqrt(F)}
+    layer = lambda x, experts: moe_layer_held(
+        x, router, bias, experts, (4, 8), None, k=k, scale=2.446, tile=8)[0]
+    assert combine_ops(layer, x, experts, width=D) == (0, 1)
+    got = layer(x, experts)
+    assert float(jnp.max(jnp.abs(got))) > 0
+    monkeypatch.setattr(moe, "_grouped_experts", _grouped_experts_as_it_stood)
+    assert np.array_equal(np.asarray(got), np.asarray(layer(x, experts)))
+
+
 def test_moe_tile_follows_the_rows():
     assert [G.moe_tile(n) for n in (1, 64, 65, 320, 2048, 13312)] == \
         [16, 64, 80, 256, 256, 256]
@@ -605,3 +789,66 @@ def test_the_cells_decode_tick_compiles_for_a_v5e(monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 7 * 18432 * 32 * 640 * 2
     assert mem.temp_size_in_bytes < 64 << 20, mem
+
+
+def _loop_ops(text, opcode):
+    """Result shapes of the compiled ops in a loop body whose ``op_name``
+    ends in ``opcode``."""
+    import re
+    return re.findall(
+        r"= (\w+\[[\d,]*\])[^\n]*op_name=\"[^\"]*while/body[^\"]*/"
+        + opcode + r"\"", text)
+
+
+def test_the_cells_longest_prefill_compiles_for_a_v5e(monkeypatch):
+    """The 13,312-token bucket at the cell's size: beside the weights and
+    the pool the device holds, the program's temporaries and results stay
+    under what the device lends (the list of 69,632 rows is 285 MB of
+    them), a tile's rows go into the list, and no loop body adds rows
+    into a ``[13312, 2048]``."""
+    spec = _v5e_spec(monkeypatch)
+    monkeypatch.setattr(layers, "_flash_backend", lambda: True)
+    cfg = G.GlmMoeLiteConfig(n_layers=7)
+    P, M = 13312, 13312 * 4 + 64 * 256
+    p = jax.tree.map(lambda s: spec(s.shape, s.dtype), jax.eval_shape(
+        lambda: G.init_params(cfg, jax.random.PRNGKey(0))))
+
+    def prefill(p, tok, last):
+        logits, kept, stats = llama.prefill_step(p, tok, cfg, last_pos=last)
+        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
+    compiled = jax.jit(prefill).lower(
+        p, spec((1, P), jnp.int32), spec((1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    pool = 7 * 18432 * 32 * cfg.cache_row * 2
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes + \
+        mem.output_size_in_bytes + pool
+    assert held < 15.6e9 < 16.9e9, (mem, pool)
+    assert mem.temp_size_in_bytes < 1.0e9, mem
+    text = compiled.as_text()
+    assert f"bf16[{M},2048]" in _loop_ops(text, "dynamic_update_slice")
+    assert not [s for s in _loop_ops(text, "scatter-add")
+                if s.endswith(",2048]")], _loop_ops(text, "scatter-add")
+
+
+def test_the_trainers_share_still_adds_compiled_for_a_v5e(monkeypatch):
+    """The Kimi-Linear cell's layer (32,768 rows, 16 held of the 256
+    scored, 8 a token, tiles of 512): the loop body adds its rows into
+    the ``[T, D]`` result and no list of the bound's 270,336 rows (1.25
+    GB) exists: 0.04 GB of temporaries, where the list form would keep
+    3.36."""
+    spec = _v5e_spec(monkeypatch)
+    T, D, F, held, E, k, tile = 32768, 2304, 1024, 16, 256, 8, 512
+    experts = {"gate": spec((held, D, F)), "up": spec((held, D, F)),
+               "down": spec((held, F, D))}
+    compiled = jax.jit(lambda x, router, bias, experts: moe_layer_held(
+        x, router, bias, experts, (0, held), None, k=k, scale=2.446,
+        tile=tile)[0]).lower(
+            spec((T, D)), spec((D, E), jnp.float32), spec((E,), jnp.float32),
+            experts).compile()
+    text = compiled.as_text()
+    assert f"bf16[{T},{D}]" in _loop_ops(text, "scatter-add")
+    assert T * k + held * tile == 270336
+    assert f"[270336,{D}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9, \
+        compiled.memory_analysis()
+

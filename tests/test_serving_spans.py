@@ -193,6 +193,9 @@ def test_expert_layers_counts_reach_the_spans_and_the_metrics(tmp_path):
     session.drain()                           # warm
     held = REGISTRY.get("hvd_moe_held_pairs_total")
     before = [held.labels(layer=l).value for l in ("2", "3")]
+    combine = REGISTRY.get("hvd_moe_combine_steps_total")
+    steps_before = [combine.labels(layer="3", form=f).value
+                    for f in ("list", "add")]
     with jax.profiler.trace(str(tmp_path)):
         for n in (5, 9, 13):
             submit(n)
@@ -201,9 +204,16 @@ def test_expert_layers_counts_reach_the_spans_and_the_metrics(tmp_path):
     spans = _hvd_spans(tmp_path)
     prefills = [s[3] for s in spans if s[0] == "hvd.serve.prefill"]
     decodes = [s[3] for s in spans if s[0] == "hvd.serve.decode"]
-    assert set(prefills[0]) == ATTRS["hvd.serve.prefill"] | {"moe_pairs"}
+    assert set(prefills[0]) == ATTRS["hvd.serve.prefill"] | {
+        "moe_pairs", "moe_combine"}
     assert set(decodes[0]) == ATTRS["hvd.serve.decode"] | {
-        "moe_pairs", "experts_touched", "experts_held"}
+        "moe_pairs", "moe_combine", "experts_touched", "experts_held"}
+    # every scored expert is held, so the results come back through the
+    # list, and the steps are counted under that form alone
+    assert {s["moe_combine"] for s in prefills + decodes} == {"list"}
+    steps = [combine.labels(layer="3", form=f).value - b
+             for f, b in zip(("list", "add"), steps_before)]
+    assert steps == [len(prefills) + len(decodes), 0]
     # a prefill routes every row of its bucket, a tick all four slots'
     assert [p["moe_pairs"] for p in prefills] == [
         2 * 2 * b for b in (8, 16, 16)]
