@@ -48,6 +48,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.trace import region
 from ..ops import flash_attention as FA
 from ..parallel.moe import moe_layer_held
 from .layers import (
@@ -309,11 +310,12 @@ def forward(params: dict, tokens: jax.Array, cfg: GlmMoeLiteConfig, *,
             stats.append(st)
     stats = jax.tree.map(lambda *a: jnp.concatenate(a), *stats) \
         if stats else {}
-    h = rmsnorm(h, params["final_norm"], cfg.rms_eps)
-    if return_hidden:
-        return h, stats
-    logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
-    return logits.astype(jnp.float32), stats
+    with region("head"):
+        h = rmsnorm(h, params["final_norm"], cfg.rms_eps)
+        if return_hidden:
+            return h, stats
+        logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
+        return logits.astype(jnp.float32), stats
 
 
 def check_trainable(cfg: GlmMoeLiteConfig) -> None:
@@ -358,7 +360,8 @@ def serve_embed(embed, tokens, dtype):
     its partitioning, neither of which is here) is 7.8 TFLOP for a
     12,288-token prompt, as much as the layers it feeds, and reads the
     whole 0.63 GB table every decode tick."""
-    return jnp.take(embed, tokens, axis=0).astype(dtype)
+    with region("embed"):
+        return jnp.take(embed, tokens, axis=0).astype(dtype)
 
 
 def serve_runs(params: dict, cfg: GlmMoeLiteConfig, positions, mesh) -> list:

@@ -48,6 +48,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.trace import region
 from ..ops.kda import CHUNK, chunk_kda
 from ..parallel.moe import moe_layer_held
 from .layers import (
@@ -353,11 +354,12 @@ def forward(params: dict, tokens: jax.Array, cfg: KimiLinearConfig, *,
             stats.append(st)
     stats = jax.tree.map(lambda *a: jnp.concatenate(a), *stats) \
         if stats else {}
-    h = rmsnorm(h, params["final_norm"], cfg.rms_eps)
-    if return_hidden:
-        return h, stats
-    logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
-    return logits.astype(jnp.float32), stats
+    with region("head"):
+        h = rmsnorm(h, params["final_norm"], cfg.rms_eps)
+        if return_hidden:
+            return h, stats
+        logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
+        return logits.astype(jnp.float32), stats
 
 
 def loss_fn(params: dict, batch: dict, cfg: KimiLinearConfig, *,

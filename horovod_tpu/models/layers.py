@@ -35,6 +35,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..obs.trace import region
 from ..ops import flash_attention as FA
 from ..parallel.ring_attention import (
     ring_attention_local,
@@ -121,8 +122,9 @@ def embed_lookup(embed: jax.Array, tokens: jax.Array, dtype) -> jax.Array:
     matmul on the MXU instead of a scatter-add, and it partitions cleanly
     under the vocab_rows (tp, fsdp) sharding — a sharded gather lowers
     to per-shard lookup + select + psum anyway."""
-    onehot = jax.nn.one_hot(tokens, embed.shape[0], dtype=dtype)
-    return jnp.einsum("bsv,vd->bsd", onehot, embed.astype(dtype))
+    with region("embed"):
+        onehot = jax.nn.one_hot(tokens, embed.shape[0], dtype=dtype)
+        return jnp.einsum("bsv,vd->bsd", onehot, embed.astype(dtype))
 
 
 def dense_mlp(x2, lp):
@@ -139,15 +141,19 @@ def block(h, lp, mixer, mlp, eps: float = 1e-5):
     mlp(norm(h))``.  ``mixer`` and ``mlp`` are ``(x, lp) -> (y, extra)``;
     returns ``(h, the mixer's extra, the mlp's extra)``.  A layer that
     holds ``attn_post_norm`` and ``mlp_post_norm`` (sandwich norm) norms
-    each branch's output with them before the residual takes it."""
-    y, kept = mixer(rmsnorm(h, lp["attn_norm"], eps), lp)
-    if "attn_post_norm" in lp:
-        y = rmsnorm(y, lp["attn_post_norm"], eps)
-    h = h + y
-    y, aux = mlp(rmsnorm(h, lp["mlp_norm"], eps), lp)
-    if "mlp_post_norm" in lp:
-        y = rmsnorm(y, lp["mlp_post_norm"], eps)
-    return h + y, kept, aux
+    each branch's output with them before the residual takes it.  The
+    two halves are the regions ``hvd.block.mixer`` and ``hvd.block.mlp``
+    of whatever program runs the layer."""
+    with region("block.mixer"):
+        y, kept = mixer(rmsnorm(h, lp["attn_norm"], eps), lp)
+        if "attn_post_norm" in lp:
+            y = rmsnorm(y, lp["attn_post_norm"], eps)
+        h = h + y
+    with region("block.mlp"):
+        y, aux = mlp(rmsnorm(h, lp["mlp_norm"], eps), lp)
+        if "mlp_post_norm" in lp:
+            y = rmsnorm(y, lp["mlp_post_norm"], eps)
+        return h + y, kept, aux
 
 
 def looped(layer, carry, stacked, loops: int, renorm, xs=None,
@@ -170,7 +176,8 @@ def looped(layer, carry, stacked, loops: int, renorm, xs=None,
     def step(carry, ix):
         i, x = ix
         h, rest = carry
-        h = jax.lax.cond((i % L == 0) & (i > 0), renorm, lambda h: h, h)
+        with region("renorm"):
+            h = jax.lax.cond((i % L == 0) & (i > 0), renorm, lambda h: h, h)
         lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
             a, i % L, keepdims=False), stacked)
         return layer((h, rest), (lp, x))
@@ -399,14 +406,17 @@ def causal_lm_loss(forward, lm_head, tokens, blockwise: bool):
     if blockwise:
         from ..ops.losses import blockwise_cross_entropy
         h, extra = forward(inputs, True)
-        nll = blockwise_cross_entropy(
-            h.reshape(-1, h.shape[-1]), lm_head,
-            targets.reshape(-1).astype(jnp.int32))
-        return nll.mean(), extra
+        with region("loss"):       # the head's product is in the blocks
+            nll = blockwise_cross_entropy(
+                h.reshape(-1, h.shape[-1]), lm_head,
+                targets.reshape(-1).astype(jnp.int32))
+            return nll.mean(), extra
     logits, extra = forward(inputs, False)
     # logsumexp form of the CE — identical math to log_softmax + gather,
     # but the [B,S,V] fp32 log-prob tensor is never materialized, only
     # its row reduction.
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return (lse - picked).mean(), extra
+    with region("loss"):
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, targets[..., None],
+                                     axis=-1)[..., 0]
+        return (lse - picked).mean(), extra

@@ -33,6 +33,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.trace import region
 from ..ops import flash_attention as FA
 from ..parallel import sharding as shd
 from ..parallel.moe import moe_layer_local
@@ -319,11 +320,17 @@ def _moe_mlp(h2, lp, cfg: LlamaConfig, mesh: Optional[Mesh]):
         from ..parallel.moe import switch_route
         E = cfg.n_experts
         cap = max(1, int(flat.shape[0] * cfg.capacity_factor / E))
-        logits = flat.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
-        dispatch, combine, aux, _drops = switch_route(logits, cap)
-        einputs = jnp.einsum("tec,td->ecd", dispatch.astype(flat.dtype), flat)
-        eouts = jax.vmap(_expert_swiglu)(eparams, einputs)
-        out = jnp.einsum("tec,ecd->td", combine.astype(flat.dtype), eouts)
+        with region("moe.route"):
+            logits = flat.astype(jnp.float32) @ \
+                lp["router"].astype(jnp.float32)
+            dispatch, combine, aux, _drops = switch_route(logits, cap)
+        with region("moe.experts"):
+            einputs = jnp.einsum("tec,td->ecd", dispatch.astype(flat.dtype),
+                                 flat)
+            eouts = jax.vmap(_expert_swiglu)(eparams, einputs)
+        with region("moe.combine"):
+            out = jnp.einsum("tec,ecd->td", combine.astype(flat.dtype),
+                             eouts)
     return out.reshape(B, S, D), aux
 
 
@@ -485,12 +492,13 @@ def _head(params, h, cfg: LlamaConfig, dims=None,
           mesh: Optional[Mesh] = None, rules=None):
     """Final norm and lm_head on ``h [..., D]``: float32 logits, pinned to
     the logical ``dims`` under a mesh."""
-    logits = jnp.einsum(
-        "...d,dv->...v", rmsnorm(h, params["final_norm"], cfg.rms_eps),
-        params["lm_head"])
-    if mesh is not None:
-        logits = shd.constrain(logits, dims, mesh, rules)
-    return logits.astype(jnp.float32)
+    with region("head"):
+        logits = jnp.einsum(
+            "...d,dv->...v", rmsnorm(h, params["final_norm"], cfg.rms_eps),
+            params["lm_head"])
+        if mesh is not None:
+            logits = shd.constrain(logits, dims, mesh, rules)
+        return logits.astype(jnp.float32)
 
 
 def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
@@ -607,16 +615,18 @@ def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
                          (h, jnp.zeros((), jnp.float32)), params["layers"],
                          cfg.loops, final_norm, unroll=cfg.scan_unroll)
     if return_hidden:
-        return final_norm(h), aux
+        with region("head"):
+            return final_norm(h), aux
     return _head(params, h, cfg, ("batch", "seq", "vocab"), mesh), aux
 
 
 def _pick_token(logits, step_key, temperature, dtype):
     """Greedy or temperature sampling from [B, V] fp32 logits."""
-    if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(dtype)
-    return jax.random.categorical(
-        step_key, logits / temperature, axis=-1).astype(dtype)
+    with region("head"):
+        if temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1).astype(dtype)
+        return jax.random.categorical(
+            step_key, logits / temperature, axis=-1).astype(dtype)
 
 
 def _pin_kv(cfg: LlamaConfig, mesh: Optional[Mesh]):
@@ -986,11 +996,12 @@ def prefill_step(params, tokens: jax.Array, cfg, *,
     h, _, kept, stats = _serve_layers(
         params, tokens, jnp.broadcast_to(jnp.arange(P), (B, P)), cfg, mesh,
         model.prefill_attend(cfg, mesh, P))
-    if last_pos is None:
-        h_last = h[:, -1]
-    else:
-        h_last = jnp.take_along_axis(
-            h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    with region("head"):
+        if last_pos is None:
+            h_last = h[:, -1]
+        else:
+            h_last = jnp.take_along_axis(
+                h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return (_head(params, h_last, cfg, ("batch", "vocab"), mesh,
                   model.shard_rules(cfg, mesh)), kept, stats)
 
@@ -1282,28 +1293,30 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
             }
 
             def loss_head(head, y, m):
-                h2 = rmsnorm(y, head["final_norm"], cfg.rms_eps)
-                logits = jnp.einsum("bsd,dv->bsv", h2, head["lm_head"]
-                                    ).astype(jnp.float32)
-                # CE over the tp-sharded vocab.  The max shift is taken on
-                # stopped gradients (exact: the shift cancels in the lse
-                # derivative) and reduced with all_gather+max — pmax has
-                # no AD rule even on zero tangents.
-                mloc = jnp.max(jax.lax.stop_gradient(logits), axis=-1)
-                mx = jnp.max(
-                    lax.all_gather(mloc, "tp", axis=0, tiled=False), axis=0)
-                lse = jnp.log(lax.psum(
-                    jnp.sum(jnp.exp(logits - mx[..., None]), axis=-1),
-                    "tp")) + mx
-                t = tgts[m]
-                vloc = logits.shape[-1]
-                vstart = lax.axis_index("tp") * vloc
-                within = (t >= vstart) & (t < vstart + vloc)
-                pl = jnp.take_along_axis(
-                    logits, jnp.clip(t - vstart, 0, vloc - 1)[..., None],
-                    axis=-1)[..., 0]
-                picked = lax.psum(jnp.where(within, pl, 0.0), "tp")
-                return (lse - picked).mean()
+                with region("head"):
+                    h2 = rmsnorm(y, head["final_norm"], cfg.rms_eps)
+                    logits = jnp.einsum("bsd,dv->bsv", h2, head["lm_head"]
+                                        ).astype(jnp.float32)
+                with region("loss"):
+                    # CE over the tp-sharded vocab.  The max shift is taken on
+                    # stopped gradients (exact: the shift cancels in the lse
+                    # derivative) and reduced with all_gather+max — pmax has
+                    # no AD rule even on zero tangents.
+                    mloc = jnp.max(jax.lax.stop_gradient(logits), axis=-1)
+                    mx = jnp.max(lax.all_gather(
+                        mloc, "tp", axis=0, tiled=False), axis=0)
+                    lse = jnp.log(lax.psum(
+                        jnp.sum(jnp.exp(logits - mx[..., None]), axis=-1),
+                        "tp")) + mx
+                    t = tgts[m]
+                    vloc = logits.shape[-1]
+                    vstart = lax.axis_index("tp") * vloc
+                    within = (t >= vstart) & (t < vstart + vloc)
+                    pl = jnp.take_along_axis(
+                        logits, jnp.clip(t - vstart, 0, vloc - 1)[..., None],
+                        axis=-1)[..., 0]
+                    picked = lax.psum(jnp.where(within, pl, 0.0), "tp")
+                    return (lse - picked).mean()
 
             loss, aux, dmbs, dlayers, dhead = pipeline_train_local(
                 make_stage_fn(mb_loc), layers_loc, mbs, loss_head, head_full,
@@ -1338,8 +1351,9 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
                  "lm_head": dhead["lm_head"],
                  "final_norm": dhead["final_norm"]}
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = jax.tree.map(jnp.add, params, updates)
+        with region("optim"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = jax.tree.map(jnp.add, params, updates)
         return params, opt_state, loss + cfg.moe_aux_weight * aux
 
     opt_shard = _opt_shardings(tx, cfg, mesh)
@@ -1402,8 +1416,9 @@ def make_train_step(cfg, mesh: Mesh, tx, *,
         # only an XLA fusion barrier, so it is skipped.)
         if multi_device:
             grads = jax.lax.with_sharding_constraint(grads, pshard)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = jax.tree.map(jnp.add, params, updates)
+        with region("optim"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = jax.tree.map(jnp.add, params, updates)
         return params, opt_state, loss
 
     opt_shard = _opt_shardings(tx, cfg, mesh, model)

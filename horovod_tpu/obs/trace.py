@@ -308,6 +308,18 @@ def profiler_span(name: str, **attrs: int):
     return jax.profiler.TraceAnnotation(name, **attrs)
 
 
+def region(name: str):
+    """A named part of a compiled program: ``with region("block.mlp"):``
+    around the code that writes the work, while jax traces it.  Every
+    instruction traced inside carries ``hvd.<name>`` in its ``op_name``
+    (the innermost region last), through ``scan``, ``checkpoint`` and
+    autodiff, and a profile's own copy of the module maps a device op
+    back to it.  :func:`profiler_span` names what the host does, this
+    what a program does.  Metadata only: the compiled instructions are
+    the same with or without it, so it is always on."""
+    return sys.modules["jax"].named_scope("hvd." + name)
+
+
 def _coerce_context(parent) -> Optional[dict]:
     """Normalize a ``parent=`` value to a context dict (or None).
     Accepts a :class:`Span`/:data:`NULL_SPAN` (uses its ``context()``),

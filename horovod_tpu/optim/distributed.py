@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
+from ..obs.trace import region
 from ..ops import collectives as C
 from ..ops.compression import Compression, Compressor, routes_engine_side
 
@@ -159,15 +160,21 @@ def DistributedGradientTransformation(
         raise ValueError("backward_passes_per_step must be >= 1")
 
     def reduce_grads(grads):
-        return jax.tree.map(
-            lambda g: _reduce_in_context(g, axis_name, op, compression), grads)
+        with region("exchange"):
+            return jax.tree.map(
+                lambda g: _reduce_in_context(g, axis_name, op, compression),
+                grads)
+
+    def inner_update(grads, state, params):
+        with region("optim"):
+            return inner.update(grads, state, params)
 
     if backward_passes_per_step == 1:
         def init(params):
             return inner.init(params)
 
         def update(grads, state, params=None):
-            return inner.update(reduce_grads(grads), state, params)
+            return inner_update(reduce_grads(grads), state, params)
 
         return optax.GradientTransformation(init, update)
 
@@ -198,7 +205,7 @@ def DistributedGradientTransformation(
             else:
                 scaled = acc_
             reduced = reduce_grads(scaled)
-            updates, new_inner = inner.update(reduced, inner_state, params)
+            updates, new_inner = inner_update(reduced, inner_state, params)
             return updates, new_inner, jax.tree.map(jnp.zeros_like, acc_), \
                 jnp.zeros((), jnp.int32)
 

@@ -27,6 +27,7 @@ from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs import REGISTRY as _obs
+from ..obs.trace import region
 
 _m_dropped = _obs.counter(
     "hvd_moe_dropped_tokens_total",
@@ -112,30 +113,35 @@ def moe_layer_local(tokens: jax.Array,
     E_local = E_total // n
     capacity = max(1, int(T * capacity_factor / E_total))
 
-    logits = tokens @ router_kernel                           # [T, E]
-    dispatch, combine, aux, dropped = switch_route(logits, capacity)
+    with region("moe.route"):
+        logits = tokens @ router_kernel                       # [T, E]
+        dispatch, combine, aux, dropped = switch_route(logits, capacity)
 
-    # Gather tokens into expert buffers: [E, C, D].
-    expert_inputs = buffer_constraint(
-        jnp.einsum("tec,td->ecd", dispatch, tokens))
-    # Exchange: send each expert's buffer to its owner device.
-    # [E, C, D] -> [n, E_local, C, D] -> a2a -> [n, E_local, C, D] where the
-    # leading dim now indexes source rank.
-    shaped = expert_inputs.reshape(n, E_local, capacity, D)
-    received = lax.all_to_all(shaped, axis_name, split_axis=0, concat_axis=0,
-                              tiled=False)
-    # received: [n, E_local, C, D] — tokens from every rank for my experts.
-    per_expert = buffer_constraint(received.transpose(1, 0, 2, 3).reshape(
-        E_local, n * capacity, D))
-    expert_out = buffer_constraint(jax.vmap(expert_fn)(
-        expert_params, per_expert))                           # [E_local, n*C, D]
-    # Route back: inverse exchange.
-    back = expert_out.reshape(E_local, n, capacity, D).transpose(1, 0, 2, 3)
-    returned = lax.all_to_all(back, axis_name, split_axis=0, concat_axis=0,
-                              tiled=False)
-    # returned: [n(expert-owner), E_local, C, D] == my tokens' results.
-    results = buffer_constraint(returned.reshape(E_total, capacity, D))
-    out = jnp.einsum("tec,ecd->td", combine, results)
+    with region("moe.experts"):
+        # Gather tokens into expert buffers: [E, C, D].
+        expert_inputs = buffer_constraint(
+            jnp.einsum("tec,td->ecd", dispatch, tokens))
+        # Exchange: send each expert's buffer to its owner device.
+        # [E, C, D] -> [n, E_local, C, D] -> a2a -> [n, E_local, C, D]
+        # where the leading dim now indexes source rank.
+        shaped = expert_inputs.reshape(n, E_local, capacity, D)
+        received = lax.all_to_all(shaped, axis_name, split_axis=0,
+                                  concat_axis=0, tiled=False)
+        # received: [n, E_local, C, D] — tokens from every rank for my
+        # experts.
+        per_expert = buffer_constraint(received.transpose(1, 0, 2, 3).reshape(
+            E_local, n * capacity, D))
+        expert_out = buffer_constraint(jax.vmap(expert_fn)(
+            expert_params, per_expert))                 # [E_local, n*C, D]
+        # Route back: inverse exchange.
+        back = expert_out.reshape(E_local, n, capacity, D).transpose(
+            1, 0, 2, 3)
+        returned = lax.all_to_all(back, axis_name, split_axis=0,
+                                  concat_axis=0, tiled=False)
+    with region("moe.combine"):
+        # returned: [n(expert-owner), E_local, C, D] == my tokens' results.
+        results = buffer_constraint(returned.reshape(E_total, capacity, D))
+        out = jnp.einsum("tec,ecd->td", combine, results)
     if return_drops:
         return (out.astype(tokens.dtype), aux,
                 jnp.sum(dropped.astype(jnp.float32)))
@@ -427,14 +433,19 @@ def _grouped_experts(tokens, rows, weights, tile_expert, n_tiles, experts,
         return lax.dynamic_update_slice(acc, lax.optimization_barrier(y),
                                         (i * tile, 0))
     if pair_slot is None:
-        return lax.fori_loop(0, n_tiles, body, jnp.zeros_like(tokens))
+        with region("moe.experts"):
+            return lax.fori_loop(0, n_tiles, body, jnp.zeros_like(tokens))
     M, D = rows.shape[0], tokens.shape[1]
-    lst = lax.dynamic_update_slice(
-        lax.empty((M, D), tokens.dtype), jnp.zeros((tile, D), tokens.dtype),
-        (M - tile, 0))
-    lst = lax.fori_loop(0, n_tiles, body, lst)
-    return sum(lst.at[pair_slot[:, j]].get(mode="promise_in_bounds").astype(
-        jnp.float32) for j in range(pair_slot.shape[1])).astype(tokens.dtype)
+    with region("moe.experts"):
+        lst = lax.dynamic_update_slice(
+            lax.empty((M, D), tokens.dtype),
+            jnp.zeros((tile, D), tokens.dtype), (M - tile, 0))
+        lst = lax.fori_loop(0, n_tiles, body, lst)
+    with region("moe.combine"):
+        return sum(
+            lst.at[pair_slot[:, j]].get(mode="promise_in_bounds").astype(
+                jnp.float32)
+            for j in range(pair_slot.shape[1])).astype(tokens.dtype)
 
 
 def _grouped_fwd(tokens, rows, weights, tile_expert, n_tiles, experts,
@@ -462,9 +473,10 @@ def _grouped_bwd(tile, res, d_out):
                  for k, d in (("gate", dg), ("up", du), ("down", dd))}
         return d_tok, d_wt, d_exp
 
-    zeros = (jnp.zeros_like(tokens), jnp.zeros_like(weights),
-             jax.tree.map(jnp.zeros_like, experts))
-    d_tok, d_wt, d_exp = lax.fori_loop(0, n_tiles, body, zeros)
+    with region("moe.experts"):
+        zeros = (jnp.zeros_like(tokens), jnp.zeros_like(weights),
+                 jax.tree.map(jnp.zeros_like, experts))
+        d_tok, d_wt, d_exp = lax.fori_loop(0, n_tiles, body, zeros)
     return d_tok, None, d_wt, None, None, d_exp, None
 
 
@@ -519,52 +531,54 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
     assert first_row is not None or \
         experts_held["gate"].shape[0] == E_held, (
             held_range, experts_held["gate"].shape)
-    logits = jnp.einsum("td,de->te", tokens.astype(jnp.float32),
-                        router.astype(jnp.float32),
-                        precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    experts, weights = topk_route(scores, bias, k,
-                                  renormalize=renormalize, scale=scale)
+    with region("moe.route"):
+        logits = jnp.einsum("td,de->te", tokens.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        experts, weights = topk_route(scores, bias, k,
+                                      renormalize=renormalize, scale=scale)
 
-    # Sort the pairs by held expert; a pair held elsewhere sorts last.
-    local = experts.reshape(-1) - first
-    held = (local >= 0) & (local < E_held)
-    key = jnp.where(held, local, E_held)
-    order = jnp.argsort(key, stable=True)
-    counts = jnp.zeros((E_held + 1,), jnp.int32).at[key].add(1)[:E_held]
-    # Expert e's run starts at a tile boundary of the padded list.
-    padded = -(-counts // tile) * tile
-    starts = jnp.cumsum(padded) - padded
-    begins = jnp.cumsum(counts) - counts               # in the sorted list
-    M = -(-T * min(k, E_held) // tile) * tile + E_held * tile
-    sorted_key = key[order]
-    rank = jnp.arange(order.shape[0]) - begins[jnp.minimum(sorted_key,
-                                                           E_held - 1)]
-    slot = jnp.where(sorted_key < E_held,
-                     starts[jnp.minimum(sorted_key, E_held - 1)] + rank, M)
-    rows = jnp.full((M,), T, jnp.int32).at[slot].set(
-        (order // k).astype(jnp.int32), mode="drop")
-    pair_w = jnp.zeros((M,), weights.dtype).at[slot].set(
-        weights.reshape(-1)[order], mode="drop")
-    n_tiles = jnp.sum(padded) // tile
-    tile_expert = jnp.clip(jnp.searchsorted(
-        jnp.cumsum(padded), jnp.arange(M // tile) * tile, side="right"),
-        0, E_held - 1).astype(jnp.int32)
-    if first_row is not None:
-        tile_expert = tile_expert + first_row
+        # Sort the pairs by held expert; a pair held elsewhere sorts last.
+        local = experts.reshape(-1) - first
+        held = (local >= 0) & (local < E_held)
+        key = jnp.where(held, local, E_held)
+        order = jnp.argsort(key, stable=True)
+        counts = jnp.zeros((E_held + 1,), jnp.int32).at[key].add(1)[:E_held]
+        # Expert e's run starts at a tile boundary of the padded list.
+        padded = -(-counts // tile) * tile
+        starts = jnp.cumsum(padded) - padded
+        begins = jnp.cumsum(counts) - counts               # in the sorted list
+        M = -(-T * min(k, E_held) // tile) * tile + E_held * tile
+        sorted_key = key[order]
+        rank = jnp.arange(order.shape[0]) - begins[jnp.minimum(sorted_key,
+                                                               E_held - 1)]
+        slot = jnp.where(sorted_key < E_held,
+                         starts[jnp.minimum(sorted_key, E_held - 1)] + rank, M)
+        rows = jnp.full((M,), T, jnp.int32).at[slot].set(
+            (order // k).astype(jnp.int32), mode="drop")
+        pair_w = jnp.zeros((M,), weights.dtype).at[slot].set(
+            weights.reshape(-1)[order], mode="drop")
+        n_tiles = jnp.sum(padded) // tile
+        tile_expert = jnp.clip(jnp.searchsorted(
+            jnp.cumsum(padded), jnp.arange(M // tile) * tile, side="right"),
+            0, E_held - 1).astype(jnp.int32)
+        if first_row is not None:
+            tile_expert = tile_expert + first_row
 
-    # Each pair's own slot, in pair order, where the results come back
-    # through the list; a pair held elsewhere reads the list's last row.
-    pair_slot = None
-    if combine_form(E_held, router.shape[1]) == "list":
-        pair_slot = jnp.zeros_like(order).at[order].set(
-            jnp.minimum(slot, M - 1)).reshape(T, k)
+        # Each pair's own slot, in pair order, where the results come back
+        # through the list; a pair held elsewhere reads the list's last row.
+        pair_slot = None
+        if combine_form(E_held, router.shape[1]) == "list":
+            pair_slot = jnp.zeros_like(order).at[order].set(
+                jnp.minimum(slot, M - 1)).reshape(T, k)
     out = _grouped_experts(tokens, rows, pair_w, tile_expert, n_tiles,
                            experts_held, pair_slot, tile)
     if shared is not None:
-        hidden = jax.nn.silu(tokens @ shared["w_gate"]) * \
-            (tokens @ shared["w_up"])
-        out = out + hidden @ shared["w_down"]
+        with region("moe.shared"):
+            hidden = jax.nn.silu(tokens @ shared["w_gate"]) * \
+                (tokens @ shared["w_up"])
+            out = out + hidden @ shared["w_down"]
     stats = {"pairs_held": jnp.sum(counts), "expert_counts": counts}
     if picks:
         stats.update(experts=experts, scores=scores)

@@ -255,11 +255,15 @@ class ServingEngine:
     # -- jitted step bodies ---------------------------------------------
     # Each returns ``(tokens, stats)`` first: what the host fetches, in
     # one transfer (``stats`` the expert layers' counts, or nothing).
+    def _pick(self, logits):
+        """The token pick, the last of the program's ``hvd.head``."""
+        with _trace.region("head"):
+            return self._jnp.argmax(logits, axis=-1).astype(self._jnp.int32)
+
     def _prefill_impl(self, params, tokens, last_pos):
-        jnp = self._jnp
         logits, kept, stats = llama.prefill_step(
             params, tokens, self.cfg, mesh=self.mesh, last_pos=last_pos)
-        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), stats), kept
+        return (self._pick(logits), stats), kept
 
     def _scatter_impl(self, pools, kept, blocks):
         """Write one request's prefill entries (each ``[L, 1, P, *row]``)
@@ -284,20 +288,18 @@ class ServingEngine:
         return tuple(put(pool, new) for pool, new in zip(pools, kept))
 
     def _decode_impl(self, params, pools, tok, pos, tables):
-        jnp = self._jnp
         logits, pools, stats = llama.decode_step_paged(
             params, tok, pos, pools, tables, self.cfg, mesh=self.mesh,
             use_flash=self._use_flash, interpret=self._interpret)
-        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), stats), pools
+        return (self._pick(logits), stats), pools
 
     def _extend_impl(self, params, pools, tok, pos, valid, tables):
         """Multi-token paged forward ([B, S] at arbitrary positions):
         the prefix-hit tail prefill and the speculative verify step."""
-        jnp = self._jnp
         logits, pools, stats = llama.extend_step_paged(
             params, tok, pos, valid, pools, tables, self.cfg,
             mesh=self.mesh)
-        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), stats), pools
+        return (self._pick(logits), stats), pools
 
     # -- public surface --------------------------------------------------
     @property

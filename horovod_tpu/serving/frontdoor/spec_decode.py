@@ -105,11 +105,10 @@ class SpecDecoder:
         return kept
 
     def _decode_impl(self, params, pools, tok, pos, tables):
-        jnp = self._jnp
         logits, pools, _ = llama.decode_step_paged(
             params, tok, pos, pools, tables, self.draft_cfg, mesh=None,
             use_flash=False)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pools
+        return self.eng._pick(logits), pools
 
     def _extend_impl(self, params, pools, tok, pos, valid, tables):
         _, pools, _ = llama.extend_step_paged(

@@ -8,6 +8,14 @@ set here; otherwise the cache is ``<checkout>/.jax_cache``.  The path is
 part of the cache key's neighbourhood (entries are only found again under
 the same directory), so it is never derived from ``tempfile``, a pid or
 the clock.
+
+A program's names are part of its entry's key.  What a program calls its
+parts (``obs.trace.region``) lives in its instructions' metadata, which
+JAX leaves out of the key by default; a program that differs from an
+older tree's in metadata alone would then be handed that tree's
+executable, and a profile of it, whose copy of the module is the
+executable's, would read the older tree's names (none, for a tree from
+before the regions).  So the key holds the metadata too.
 """
 
 from __future__ import annotations
@@ -25,10 +33,11 @@ def ensure_compile_cache() -> str:
     Call before the first compilation: JAX decides once per process,
     at its first compile, whether a cache is in use.
     """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR

@@ -72,6 +72,8 @@ def test_compile_cache_env_wins(monkeypatch, tmp_path):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert ensure_compile_cache() == str(tmp_path)
     assert jax.config.jax_compilation_cache_dir == before
+    # either way a program is keyed by its names too (obs.trace.region)
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_compile_cache_default_is_the_checkout():
