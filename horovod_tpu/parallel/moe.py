@@ -352,19 +352,65 @@ def topk_route(scores: jax.Array, bias: jax.Array, k: int, *,
     experts a token takes are the top k of ``scores + bias``; their
     weights are ``scores`` at those experts, divided by their sum under
     ``renormalize``, times ``scale``.  Returns ``(experts [T, k] int32,
-    weights [T, k])``; the weights carry the gradient, the choice none.
+    weights [T, k])``, best first, ties to the lower index, as
+    ``lax.top_k`` gives them; the weights carry the gradient, the choice
+    none.
+
+    The choice is ``k`` passes of first-maximum-and-mask over ``[T, E]``
+    and a weight the masked sum of its pass, dense work of the vector
+    unit: at 32,768 tokens of 256 scores on a v5e the eight passes take
+    0.2 ms and the weights 0.04, where ``lax.top_k``, a stable sort of
+    every token's scores with an iota beside them, took 0.96 and
+    ``take_along_axis``, a gather of ``T x k`` scalars one at a time,
+    2.7; the gather's transpose is a scatter-add into ``[T, E]``, the
+    sum's a select.
     """
-    _, experts = lax.top_k(lax.stop_gradient(scores + bias), k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    choose = lax.stop_gradient(scores + bias)
+    column = lax.broadcasted_iota(jnp.int32, choose.shape, 1)
+    experts, weights = [], []
+    for _ in range(k):
+        best = jnp.argmax(choose, axis=-1).astype(jnp.int32)
+        taken = column == best[:, None]
+        experts.append(best)
+        weights.append(jnp.sum(jnp.where(taken, scores, 0), axis=-1))
+        choose = jnp.where(taken, -jnp.inf, choose)
+    experts, weights = jnp.stack(experts, -1), jnp.stack(weights, -1)
     if renormalize:
+        # The barrier keeps the stack an array and the sum a reduction
+        # over its k: XLA otherwise turns the sum of a concatenation into
+        # a chain of adds, which rounds otherwise (v5e: with it the
+        # weights are bit for bit what the gather's were, without it a
+        # last bit here and there, and the step's rounding noise with it).
+        weights = lax.optimization_barrier(weights)
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
-    return experts.astype(jnp.int32), weights * scale
+    return experts, weights * scale
 
 
 def _swiglu_tile(x, wg, wu, wd, wt):
     """One tile's rows through one expert, weighted: ``[tile, D]``."""
     hidden = jax.nn.silu(x @ wg) * (x @ wu)
     return (hidden @ wd) * wt[:, None].astype(x.dtype)
+
+
+def _padded_lists(place, weights):
+    """The loop's lists from one sort.  ``place [L]`` gives every entry
+    its row among the rows of all tiles: the ``T * k`` pairs first, in
+    pair order, then ``L - T * k`` fillers, of which an expert's run
+    takes as many as its last tile lacks; a pair held elsewhere and a
+    filler not needed have ``L``, past every tile in use.  Sorted by
+    ``place`` the entries are the tiles' rows as they lie, and the pair's
+    own index (``T * k`` for a filler) and weight (0) ride along as the
+    sort's operands.  Returns each row's token (``T`` for padding), pair
+    and weight, ``[L]`` each."""
+    with region("moe.route"):
+        n, fill = weights.size, place.shape[0] - weights.size
+        pairs = jnp.concatenate([jnp.arange(n, dtype=jnp.int32),
+                                 jnp.full((fill,), n, jnp.int32)])
+        w = jnp.concatenate([weights.reshape(-1),
+                             jnp.zeros((fill,), weights.dtype)])
+        _, pairs, w = lax.sort((place, pairs, w), num_keys=1,
+                               is_stable=False)
+        return pairs // weights.shape[1], pairs, w
 
 
 def _tile_operands(i, tile, tokens, rows, weights, tile_expert, experts):
@@ -392,19 +438,26 @@ def combine_form(held: int, scored: int) -> str:
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _grouped_experts(tokens, rows, weights, tile_expert, n_tiles, experts,
+def _grouped_experts(tokens, weights, place, tile_expert, n_tiles, experts,
                      pair_slot, tile: int):
-    """Sum over the listed (row, expert) pairs of weight x SwiGLU_expert.
+    """Sum over the held (row, expert) pairs of weight x SwiGLU_expert.
 
     The pairs lie sorted by expert, each expert's run padded to whole
-    tiles of ``tile`` rows, so a tile belongs to one expert: ``rows [M]``
-    names each slot's token (``T`` for padding), ``weights [M]`` its
-    routing weight, ``tile_expert [M / tile]`` each tile's expert and
-    ``n_tiles`` how many tiles are in use.  A loop over the tiles in use
-    gathers a tile's rows and runs them through that expert's three
-    matrices: the work is that of the pairs held (to a tile), whatever
-    the static bound ``M``.  The results reach the tokens' rows in one of
-    two forms (:func:`combine_form` picks; the backward is the same):
+    tiles of ``tile`` rows, so a tile belongs to one expert: ``weights
+    [T, k]`` are the pairs' routing weights in pair order, ``place`` each
+    pair's row among the rows of all tiles and the padding's
+    (:func:`_padded_lists`: one sort, the only pass over the pairs that
+    is not dense vector work, yields each row's pair and weight),
+    ``tile_expert [M / tile]`` each tile's expert and ``n_tiles`` how
+    many tiles are in use.  A loop over the tiles in use gathers a tile's
+    rows and runs them through that expert's three matrices: the work is
+    that of the pairs held (to a tile), whatever the static bound ``M``.
+    Nothing moves the ``T * k`` pairs one scalar at a time: on a v5e a
+    scatter or gather of 262,144 scalars takes 1.2 to 2.7 ms (the two
+    lists were two such scatters behind two such gathers), the sort of
+    270,336 keys with both operands 0.39.  The results reach the tokens'
+    rows in one of two forms (:func:`combine_form` picks; the backward is
+    the same):
 
     - ``pair_slot`` is ``None``, the add form: every tile adds its rows
       into a carried ``[T, D]`` at the places routing chose, a
@@ -419,9 +472,23 @@ def _grouped_experts(tokens, rows, weights, tile_expert, n_tiles, experts,
       tokens' type, uninitialised but for its last tile, which no tile
       in use reaches (the bound keeps ``E_held`` rows over what routing
       can fill): zeros for the pairs that are not here.  Every other row
-      a token reads, a tile has written."""
+      a token reads, a tile has written.
+
+    The backward walks the same tiles; a tile's weight gradients go
+    straight to their pairs' places in ``[T, k]``, ``tile`` scalars a
+    tile in use (3 us at 512), and not through the sort's transpose,
+    which would gather and scatter all ``T * k``."""
+    return _grouped_fwd(tokens, weights, place, tile_expert, n_tiles,
+                        experts, pair_slot, tile)[0]
+
+
+def _grouped_fwd(tokens, weights, place, tile_expert, n_tiles, experts,
+                 pair_slot, tile):
+    rows, pairs, row_w = _padded_lists(place, weights)
+    res = (tokens, weights, rows, pairs, row_w, tile_expert, n_tiles, experts)
+
     def body(i, acc):
-        idx, x, wt, w = _tile_operands(i, tile, tokens, rows, weights,
+        idx, x, wt, w = _tile_operands(i, tile, tokens, rows, row_w,
                                        tile_expert, experts)
         y = _swiglu_tile(x, w["gate"], w["up"], w["down"], wt)
         if pair_slot is None:
@@ -434,8 +501,9 @@ def _grouped_experts(tokens, rows, weights, tile_expert, n_tiles, experts,
                                         (i * tile, 0))
     if pair_slot is None:
         with region("moe.experts"):
-            return lax.fori_loop(0, n_tiles, body, jnp.zeros_like(tokens))
-    M, D = rows.shape[0], tokens.shape[1]
+            return lax.fori_loop(0, n_tiles, body,
+                                 jnp.zeros_like(tokens)), res
+    M, D = tile_expert.shape[0] * tile, tokens.shape[1]
     with region("moe.experts"):
         lst = lax.dynamic_update_slice(
             lax.empty((M, D), tokens.dtype),
@@ -445,39 +513,35 @@ def _grouped_experts(tokens, rows, weights, tile_expert, n_tiles, experts,
         return sum(
             lst.at[pair_slot[:, j]].get(mode="promise_in_bounds").astype(
                 jnp.float32)
-            for j in range(pair_slot.shape[1])).astype(tokens.dtype)
-
-
-def _grouped_fwd(tokens, rows, weights, tile_expert, n_tiles, experts,
-                 pair_slot, tile):
-    out = _grouped_experts(tokens, rows, weights, tile_expert, n_tiles,
-                           experts, pair_slot, tile)
-    return out, (tokens, rows, weights, tile_expert, n_tiles, experts)
+            for j in range(pair_slot.shape[1])).astype(tokens.dtype), res
 
 
 def _grouped_bwd(tile, res, d_out):
-    tokens, rows, weights, tile_expert, n_tiles, experts = res
+    tokens, weights, rows, pairs, row_w, tile_expert, n_tiles, experts = res
 
     def body(i, carry):
         d_tok, d_wt, d_exp = carry
-        idx, x, wt, w = _tile_operands(i, tile, tokens, rows, weights,
+        idx, x, wt, w = _tile_operands(i, tile, tokens, rows, row_w,
                                        tile_expert, experts)
         dy = jnp.take(d_out, idx, axis=0, mode="fill", fill_value=0)
         _, vjp = jax.vjp(_swiglu_tile, x, w["gate"], w["up"], w["down"], wt)
         dx, dg, du, dd, dwt = vjp(dy)
         d_tok = d_tok.at[idx].add(dx, mode="drop", unique_indices=True)
-        d_wt = lax.dynamic_update_slice(d_wt, dwt.astype(d_wt.dtype),
-                                        (i * tile,))
+        # a padding row names pair T * k, past the end: dropped
+        pair = lax.dynamic_slice(pairs, (i * tile,), (tile,))
+        d_wt = d_wt.at[pair].set(dwt.astype(d_wt.dtype), mode="drop",
+                                 unique_indices=True)
         e = tile_expert[i]
         d_exp = {k: d_exp[k].at[e].add(d.astype(d_exp[k].dtype))
                  for k, d in (("gate", dg), ("up", du), ("down", dd))}
         return d_tok, d_wt, d_exp
 
     with region("moe.experts"):
-        zeros = (jnp.zeros_like(tokens), jnp.zeros_like(weights),
+        zeros = (jnp.zeros_like(tokens), jnp.zeros_like(weights).reshape(-1),
                  jax.tree.map(jnp.zeros_like, experts))
         d_tok, d_wt, d_exp = lax.fori_loop(0, n_tiles, body, zeros)
-    return d_tok, None, d_wt, None, None, d_exp, None
+    return (d_tok, d_wt.reshape(weights.shape), None, None, None, d_exp,
+            None)
 
 
 _grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
@@ -506,6 +570,17 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
     Where every expert the router scores is held (``E_held == E``) the
     products' results come back through a list of that bound's rows,
     else by an add a tile (:func:`combine_form`).
+
+    From the gate's scores to the loop's lists everything but one sort
+    is dense work over ``[T, E]``, ``[T, E_held]`` or ``[T, k]``: the
+    choice by passes of first maximum (:func:`topk_route`), the counts
+    and every pair's place in its expert's run by a running sum over the
+    tokens of who took which held expert, each tile's expert by a
+    comparison with the runs' ends; the sort then lays the pairs and
+    their weights out by place (:func:`_padded_lists`).  A scatter or a
+    gather of the ``T * k`` pairs, a scalar at a time, cost this chip
+    1.2 to 2.7 ms each at 262,144 pairs and there were six or seven of
+    them (13.4 ms a layer's routing, 1.3 now; 2.7 and 0.25 at 49,152).
 
     ``experts_held`` may be a stack of several layers' experts flattened
     on the leading axis (``[L * E_held, ...]``), ``first_row`` (a traced
@@ -539,40 +614,50 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
         experts, weights = topk_route(scores, bias, k,
                                       renormalize=renormalize, scale=scale)
 
-        # Sort the pairs by held expert; a pair held elsewhere sorts last.
-        local = experts.reshape(-1) - first
+        # A pair's key is its held expert; a pair held elsewhere gets
+        # E_held.  A token takes an expert once, so a pair's place in its
+        # expert's run is how many earlier tokens took that expert: a
+        # running sum over the tokens of [T, E_held] zeros and ones.
+        local = experts - first
         held = (local >= 0) & (local < E_held)
-        key = jnp.where(held, local, E_held)
-        order = jnp.argsort(key, stable=True)
-        counts = jnp.zeros((E_held + 1,), jnp.int32).at[key].add(1)[:E_held]
-        # Expert e's run starts at a tile boundary of the padded list.
+        key = jnp.where(held, local, E_held)                   # [T, k]
+        bins = jnp.arange(E_held, dtype=jnp.int32)
+        took = [key[:, j, None] == bins for j in range(k)]
+        taken = sum(t.astype(jnp.int32) for t in took)         # [T, E_held]
+        seen = jnp.cumsum(taken, axis=0)
+        counts = seen[-1]
+        # Expert e's run starts at a tile boundary of the M rows of all
+        # tiles; tile i is of the expert whose run holds row i * tile: as
+        # many runs as end at or before it.
         padded = -(-counts // tile) * tile
-        starts = jnp.cumsum(padded) - padded
-        begins = jnp.cumsum(counts) - counts               # in the sorted list
+        ends = jnp.cumsum(padded)
+        starts = ends - padded
         M = -(-T * min(k, E_held) // tile) * tile + E_held * tile
-        sorted_key = key[order]
-        rank = jnp.arange(order.shape[0]) - begins[jnp.minimum(sorted_key,
-                                                               E_held - 1)]
-        slot = jnp.where(sorted_key < E_held,
-                         starts[jnp.minimum(sorted_key, E_held - 1)] + rank, M)
-        rows = jnp.full((M,), T, jnp.int32).at[slot].set(
-            (order // k).astype(jnp.int32), mode="drop")
-        pair_w = jnp.zeros((M,), weights.dtype).at[slot].set(
-            weights.reshape(-1)[order], mode="drop")
-        n_tiles = jnp.sum(padded) // tile
-        tile_expert = jnp.clip(jnp.searchsorted(
-            jnp.cumsum(padded), jnp.arange(M // tile) * tile, side="right"),
-            0, E_held - 1).astype(jnp.int32)
+        n_tiles = ends[-1] // tile
+        tile_expert = jnp.minimum(jnp.sum(
+            jnp.arange(M // tile, dtype=jnp.int32)[:, None] * tile >= ends,
+            axis=1, dtype=jnp.int32), E_held - 1)
         if first_row is not None:
             tile_expert = tile_expert + first_row
-
-        # Each pair's own slot, in pair order, where the results come back
-        # through the list; a pair held elsewhere reads the list's last row.
+        # Each pair's row among the M, in pair order, and the rows that
+        # pad an expert's last tile, for the one sort that lays out the
+        # loop's lists (_padded_lists), whose length is past every tile
+        # in use.
+        n_list = T * k + E_held * tile
+        before = seen - taken + starts
+        slot = jnp.stack([jnp.sum(jnp.where(t, before, 0), axis=1)
+                          for t in took], axis=1)
+        spare = jnp.arange(tile, dtype=jnp.int32)
+        place = jnp.concatenate([
+            jnp.where(held, slot, n_list).reshape(-1),
+            jnp.where(spare < (padded - counts)[:, None],
+                      (starts + counts)[:, None] + spare, n_list).reshape(-1)])
+        # Where the results come back through the list a pair reads its
+        # own row of it; a pair held elsewhere the list's last row.
         pair_slot = None
         if combine_form(E_held, router.shape[1]) == "list":
-            pair_slot = jnp.zeros_like(order).at[order].set(
-                jnp.minimum(slot, M - 1)).reshape(T, k)
-    out = _grouped_experts(tokens, rows, pair_w, tile_expert, n_tiles,
+            pair_slot = jnp.where(held, slot, M - 1)
+    out = _grouped_experts(tokens, weights, place, tile_expert, n_tiles,
                            experts_held, pair_slot, tile)
     if shared is not None:
         with region("moe.shared"):

@@ -14,6 +14,8 @@ query, the kernel's online softmax); a planted fault reads 1e-2 and more.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -656,14 +658,15 @@ def test_the_prefill_steps_tile_loop_writes_a_list_and_adds_no_rows(params):
         params, pool, width=CFG.d_model) == (1, 0)
 
 
-def _grouped_experts_as_it_stood(tokens, rows, weights, tile_expert, n_tiles,
+def _grouped_experts_as_it_stood(tokens, weights, place, tile_expert, n_tiles,
                                  experts, pair_slot, tile):
     """``moe._grouped_experts`` before it had a list form (its forward;
     the backward rule was and is ``moe._grouped_bwd``)."""
     assert pair_slot is None
+    rows, _, row_w = moe._padded_lists(place, weights)
 
     def body(i, out):
-        idx, x, wt, w = moe._tile_operands(i, tile, tokens, rows, weights,
+        idx, x, wt, w = moe._tile_operands(i, tile, tokens, rows, row_w,
                                            tile_expert, experts)
         y = moe._swiglu_tile(x, w["gate"], w["up"], w["down"], wt)
         return out.at[idx].add(y, mode="drop", unique_indices=True)
@@ -690,6 +693,230 @@ def test_a_share_of_the_experts_still_adds_its_rows_as_it_did(monkeypatch):
     assert float(jnp.max(jnp.abs(got))) > 0
     monkeypatch.setattr(moe, "_grouped_experts", _grouped_experts_as_it_stood)
     assert np.array_equal(np.asarray(got), np.asarray(layer(x, experts)))
+
+
+# -- routing as it stood before it became dense work (PR 38) ----------------------------
+# ``lax.top_k`` (a sort of every token's scores), ``argsort``, a histogram
+# by scatter-add, the padded ``rows`` and ``pair_w`` lists filled by two
+# scatters and ``pair_slot`` by a third: the reference that
+# ``moe.moe_layer_held`` has to equal bit for bit.  A tile's operands and
+# its products are the program's own, which read the lists as they did.
+
+def _topk_route_as_it_stood(scores, bias, k, *, renormalize=True, scale=1.0):
+    _, experts = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if renormalize:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scale
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _grouped_as_it_stood(tokens, rows, weights, tile_expert, n_tiles, experts,
+                         pair_slot, tile):
+    def body(i, acc):
+        idx, x, wt, w = moe._tile_operands(i, tile, tokens, rows, weights,
+                                           tile_expert, experts)
+        y = moe._swiglu_tile(x, w["gate"], w["up"], w["down"], wt)
+        if pair_slot is None:
+            return acc.at[idx].add(y, mode="drop", unique_indices=True)
+        return jax.lax.dynamic_update_slice(
+            acc, jax.lax.optimization_barrier(y), (i * tile, 0))
+    if pair_slot is None:
+        return jax.lax.fori_loop(0, n_tiles, body, jnp.zeros_like(tokens))
+    M, D = rows.shape[0], tokens.shape[1]
+    lst = jax.lax.dynamic_update_slice(
+        jax.lax.empty((M, D), tokens.dtype),
+        jnp.zeros((tile, D), tokens.dtype), (M - tile, 0))
+    lst = jax.lax.fori_loop(0, n_tiles, body, lst)
+    return sum(
+        lst.at[pair_slot[:, j]].get(mode="promise_in_bounds").astype(
+            jnp.float32)
+        for j in range(pair_slot.shape[1])).astype(tokens.dtype)
+
+
+def _grouped_fwd_as_it_stood(tokens, rows, weights, tile_expert, n_tiles,
+                             experts, pair_slot, tile):
+    out = _grouped_as_it_stood(tokens, rows, weights, tile_expert, n_tiles,
+                               experts, pair_slot, tile)
+    return out, (tokens, rows, weights, tile_expert, n_tiles, experts)
+
+
+def _grouped_bwd_as_it_stood(tile, res, d_out):
+    tokens, rows, weights, tile_expert, n_tiles, experts = res
+
+    def body(i, carry):
+        d_tok, d_wt, d_exp = carry
+        idx, x, wt, w = moe._tile_operands(i, tile, tokens, rows, weights,
+                                           tile_expert, experts)
+        dy = jnp.take(d_out, idx, axis=0, mode="fill", fill_value=0)
+        _, vjp = jax.vjp(moe._swiglu_tile, x, w["gate"], w["up"], w["down"],
+                         wt)
+        dx, dg, du, dd, dwt = vjp(dy)
+        d_tok = d_tok.at[idx].add(dx, mode="drop", unique_indices=True)
+        d_wt = jax.lax.dynamic_update_slice(d_wt, dwt.astype(d_wt.dtype),
+                                            (i * tile,))
+        e = tile_expert[i]
+        d_exp = {k: d_exp[k].at[e].add(d.astype(d_exp[k].dtype))
+                 for k, d in (("gate", dg), ("up", du), ("down", dd))}
+        return d_tok, d_wt, d_exp
+
+    zeros = (jnp.zeros_like(tokens), jnp.zeros_like(weights),
+             jax.tree.map(jnp.zeros_like, experts))
+    d_tok, d_wt, d_exp = jax.lax.fori_loop(0, n_tiles, body, zeros)
+    return d_tok, None, d_wt, None, None, d_exp, None
+
+
+_grouped_as_it_stood.defvjp(_grouped_fwd_as_it_stood,
+                            _grouped_bwd_as_it_stood)
+
+
+def _moe_layer_held_as_it_stood(tokens, router, bias, experts_held,
+                                held_range, shared=None, *, k,
+                                renormalize=True, scale=1.0, tile=512,
+                                picks=False, first_row=None):
+    T, D = tokens.shape
+    first, last = held_range
+    E_held = last - first
+    logits = jnp.einsum("td,de->te", tokens.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    experts, weights = _topk_route_as_it_stood(
+        scores, bias, k, renormalize=renormalize, scale=scale)
+    local = experts.reshape(-1) - first
+    held = (local >= 0) & (local < E_held)
+    key = jnp.where(held, local, E_held)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros((E_held + 1,), jnp.int32).at[key].add(1)[:E_held]
+    padded = -(-counts // tile) * tile
+    starts = jnp.cumsum(padded) - padded
+    begins = jnp.cumsum(counts) - counts
+    M = -(-T * min(k, E_held) // tile) * tile + E_held * tile
+    sorted_key = key[order]
+    rank = jnp.arange(order.shape[0]) - begins[jnp.minimum(sorted_key,
+                                                           E_held - 1)]
+    slot = jnp.where(sorted_key < E_held,
+                     starts[jnp.minimum(sorted_key, E_held - 1)] + rank, M)
+    rows = jnp.full((M,), T, jnp.int32).at[slot].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    pair_w = jnp.zeros((M,), weights.dtype).at[slot].set(
+        weights.reshape(-1)[order], mode="drop")
+    n_tiles = jnp.sum(padded) // tile
+    tile_expert = jnp.clip(jnp.searchsorted(
+        jnp.cumsum(padded), jnp.arange(M // tile) * tile, side="right"),
+        0, E_held - 1).astype(jnp.int32)
+    if first_row is not None:
+        tile_expert = tile_expert + first_row
+    pair_slot = None
+    if moe.combine_form(E_held, router.shape[1]) == "list":
+        pair_slot = jnp.zeros_like(order).at[order].set(
+            jnp.minimum(slot, M - 1)).reshape(T, k)
+    out = _grouped_as_it_stood(tokens, rows, pair_w, tile_expert, n_tiles,
+                               experts_held, pair_slot, tile)
+    if shared is not None:
+        hidden = jax.nn.silu(tokens @ shared["w_gate"]) * \
+            (tokens @ shared["w_up"])
+        out = out + hidden @ shared["w_down"]
+    stats = {"pairs_held": jnp.sum(counts), "expert_counts": counts}
+    if picks:
+        stats.update(experts=experts, scores=scores)
+    return out, stats
+
+
+def _planted_runs(T, D, tile, key):
+    """Tokens, a router over 8 experts and a bias under which the first
+    ``tile`` tokens take experts 0 and 1, the next ``tile + 1`` experts 2
+    and 3, the rest 5 and 6, and no one takes 4 or 7."""
+    group = np.repeat(np.arange(3), [tile, tile + 1, T - 2 * tile - 1])
+    x = 0.1 * jax.random.normal(key, (T, D)) + \
+        2.0 * jax.nn.one_hot(group, D)
+    router = np.zeros((D, 8), np.float32)
+    for g, (a, b) in enumerate(((0, 1), (2, 3), (5, 6))):
+        router[g, a], router[g, b] = 2.0, 1.5
+    return x, jnp.asarray(router), jnp.zeros((8,)).at[
+        jnp.asarray([4, 7])].set(-10.0)
+
+
+# T, E, held, k, tile, what is planted, a stack with first_row
+_ROUTING_CASES = {
+    "a-share-held-k2": (96, 16, (4, 8), 2, 8, None, False),
+    "a-share-held-k8": (96, 16, (4, 8), 8, 8, None, False),
+    "all-held-k2-first-row": (96, 8, (0, 8), 2, 8, None, True),
+    "all-held-k8": (40, 16, (0, 16), 8, 16, None, False),
+    "ties-a-share-held": (96, 16, (4, 8), 2, 8, "ties", False),
+    "ties-all-held": (96, 8, (0, 8), 2, 8, "ties", True),
+    "runs-of-0-tile-tile+1-a-share-held": (40, 8, (1, 5), 2, 8, "runs",
+                                          False),
+    "runs-of-0-tile-tile+1-all-held": (40, 8, (0, 8), 2, 8, "runs", False),
+    "below-one-tile-a-tick-all-held": (3, 8, (0, 8), 2, 16, None, True),
+    "below-one-tile-a-share-held": (3, 8, (2, 6), 2, 16, None, False),
+}
+
+
+@pytest.mark.parametrize("case", _ROUTING_CASES)
+def test_routing_is_bitwise_what_the_sorts_and_scatters_gave(case):
+    """``moe.moe_layer_held`` against the layer as it stood, on the CPU,
+    bit for bit: the output, the counts, every token's experts in their
+    order, and the gradients to tokens, router and experts."""
+    T, E, held, k, tile, planted, stacked = _ROUTING_CASES[case]
+    D, F, n = 32, 16, held[1] - held[0]
+    ks = jax.random.split(jax.random.PRNGKey(38 + T + k), 8)
+    x = jax.random.normal(ks[0], (T, D))
+    router = jax.random.normal(ks[1], (D, E)) / np.sqrt(D)
+    bias = 0.1 * jax.random.normal(ks[2], (E,))
+    if planted == "ties":
+        # experts 5 and 6 score alike on every token, 1 and 2 on the sum
+        router = router.at[:, 6].set(router[:, 5])
+        bias = bias.at[6].set(bias[5]).at[1].set(0.0).at[2].set(0.0)
+        router = router.at[:, 2].set(router[:, 1])
+    if planted == "runs":
+        x, router, bias = _planted_runs(T, D, tile, ks[0])
+    experts = {"gate": jax.random.normal(ks[3], (n, D, F)) / np.sqrt(D),
+               "up": jax.random.normal(ks[4], (n, D, F)) / np.sqrt(D),
+               "down": jax.random.normal(ks[5], (n, F, D)) / np.sqrt(F)}
+    shared = {"w_gate": jax.random.normal(ks[6], (D, F)) / np.sqrt(D),
+              "w_up": jax.random.normal(ks[6], (D, F)) / np.sqrt(D),
+              "w_down": jax.random.normal(ks[7], (F, D)) / np.sqrt(F)}
+    cot = jax.random.normal(ks[7], (T, D))
+    first_row = jnp.asarray(n) if stacked else None
+
+    def run(layer):
+        def f(x, router, experts):
+            held_experts = jax.tree.map(
+                lambda a: jnp.concatenate([jnp.zeros_like(a), a]),
+                experts) if stacked else experts
+            return layer(x, router, bias, held_experts, held, shared, k=k,
+                         scale=1.8, tile=tile, picks=True,
+                         first_row=first_row)
+
+        def both(x, router, experts):
+            (out, stats), vjp = jax.vjp(f, x, router, experts)
+            zero = lambda a: np.zeros(a.shape, jax.dtypes.float0) \
+                if jnp.issubdtype(a.dtype, jnp.integer) else jnp.zeros_like(a)
+            return out, stats, vjp((cot, jax.tree.map(zero, stats)))
+        return jax.jit(both)(x, router, experts)
+
+    out, stats, grads = run(moe_layer_held)
+    want, want_stats, want_grads = run(_moe_layer_held_as_it_stood)
+    form = moe.combine_form(n, E)
+    assert form == ("list" if n == E else "add")
+    counts = np.asarray(stats["expert_counts"])
+    if planted == "runs":
+        assert {0, tile, tile + 1} <= set(counts.tolist()), counts
+    if planted == "ties":
+        picked = np.asarray(stats["experts"])
+        assert ((picked == 5).any(-1) & (picked == 6).any(-1)).any()
+    if case.startswith("below-one-tile"):
+        assert T * k < tile
+    assert float(jnp.max(jnp.abs(out))) > 0
+    for name in ("expert_counts", "pairs_held", "experts", "scores"):
+        assert np.array_equal(np.asarray(stats[name]),
+                              np.asarray(want_stats[name])), name
+    assert np.array_equal(np.asarray(out), np.asarray(want))
+    for got_leaf, want_leaf in zip(jax.tree.leaves(grads),
+                                   jax.tree.leaves(want_grads)):
+        assert float(jnp.max(jnp.abs(want_leaf))) > 0
+        assert np.array_equal(np.asarray(got_leaf), np.asarray(want_leaf))
 
 
 def test_moe_tile_follows_the_rows():
@@ -800,6 +1027,22 @@ def _loop_ops(text, opcode):
         + opcode + r"\"", text)
 
 
+def _route_ops(text, *opcodes):
+    """``(opcode, result shapes, sorted dimension)`` of the compiled ops of
+    ``opcodes`` (a prefix: ``scatter`` finds ``scatter-add`` too) whose
+    ``op_name`` lies in the region ``hvd.moe.route``, fused ones too."""
+    import re
+    found = []
+    for m in re.finditer(
+            r"= (\(.*?\)|\S+) ((?:" + "|".join(opcodes) + r")[\w\-]*)\("
+            r"[^\n]*op_name=\"[^\"]*hvd\.moe\.route[^\"]*\"", text):
+        line = text[m.start():text.index("\n", m.start())]
+        dim = re.search(r"dimensions=\{(\d+)\}", line)
+        found.append((m.group(2), re.findall(r"\w+\[[\d,]*\]", m.group(1)),
+                      int(dim.group(1)) if dim else None))
+    return found
+
+
 def test_the_cells_longest_prefill_compiles_for_a_v5e(monkeypatch):
     """The 13,312-token bucket at the cell's size: beside the weights and
     the pool the device holds, the program's temporaries and results stay
@@ -828,6 +1071,13 @@ def test_the_cells_longest_prefill_compiles_for_a_v5e(monkeypatch):
     assert f"bf16[{M},2048]" in _loop_ops(text, "dynamic_update_slice")
     assert not [s for s in _loop_ops(text, "scatter-add")
                 if s.endswith(",2048]")], _loop_ops(text, "scatter-add")
+    # Routing's passes over the T x k pairs that are not dense vector
+    # work, counted: one sort, of each pair's row among the M with the
+    # pair and its weight beside it.  Before PR 38: a sort of every
+    # token's 64 scores along the experts' axis and an argsort, three
+    # gathers and four scatters of T x k or M scalars.
+    assert _route_ops(text, "sort", "gather", "scatter") == [
+        ("sort", [f"s32[{M}]", f"s32[{M}]", f"f32[{M}]"], 0)]
 
 
 def test_the_trainers_share_still_adds_compiled_for_a_v5e(monkeypatch):
@@ -849,6 +1099,13 @@ def test_the_trainers_share_still_adds_compiled_for_a_v5e(monkeypatch):
     assert f"bf16[{T},{D}]" in _loop_ops(text, "scatter-add")
     assert T * k + held * tile == 270336
     assert f"[270336,{D}]" not in text
+    # Routing's passes over the T x k pairs that are not dense vector
+    # work, counted: one sort, of each pair's row among the 270,336 with
+    # the pair and its weight beside it.  Before PR 38: a sort of every
+    # token's 256 scores along the experts' axis and an argsort, three
+    # gathers and three scatters of T x k or M scalars.
+    assert _route_ops(text, "sort", "gather", "scatter") == [
+        ("sort", ["s32[270336]", "s32[270336]", "f32[270336]"], 0)]
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9, \
         compiled.memory_analysis()
 
