@@ -346,14 +346,20 @@ def shard_rules(cfg: GlmMoeLiteConfig, mesh: Optional[Mesh]):
     return None
 
 
-def check_servable(cfg: GlmMoeLiteConfig, mesh: Optional[Mesh]) -> None:
+def state_rows(cfg: GlmMoeLiteConfig) -> tuple:
+    """No state slots: the latent rows are all a token leaves."""
+    return ()
+
+
+def check_servable(cfg: GlmMoeLiteConfig, mesh: Optional[Mesh],
+                   engine_cfg=None, path: Optional[str] = None) -> None:
     if mesh is not None and any(s > 1 for s in mesh.shape.values()):
         raise NotImplementedError(
             "glm_moe_lite is served whole on one chip: its leaves carry no "
             "logical dims to split over a mesh")
 
 
-def serve_embed(embed, tokens, dtype):
+def serve_embed(embed, tokens, cfg: GlmMoeLiteConfig):
     """The serving steps' embedding, a lookup: over 154,880 rows the
     one-hot product of :func:`~horovod_tpu.models.layers.embed_lookup`
     (which training wants for its backward and a sharded vocabulary for
@@ -361,7 +367,7 @@ def serve_embed(embed, tokens, dtype):
     12,288-token prompt, as much as the layers it feeds, and reads the
     whole 0.63 GB table every decode tick."""
     with region("embed"):
-        return jnp.take(embed, tokens, axis=0).astype(dtype)
+        return jnp.take(embed, tokens, axis=0).astype(cfg.dtype)
 
 
 def serve_runs(params: dict, cfg: GlmMoeLiteConfig, positions, mesh) -> list:
@@ -405,7 +411,7 @@ def moe_experts_scored(cfg: GlmMoeLiteConfig) -> int:
     return cfg.n_experts
 
 
-def prefill_attend(cfg: GlmMoeLiteConfig, mesh, P: int):
+def prefill_attend(cfg: GlmMoeLiteConfig, mesh, P: int, last_pos=None):
     """``attend`` of the prompt prefill: the expanded form through the
     attention dispatch (the flash forward kernel at keys and values
     ``qk_dim`` and ``v_head_dim`` wide: a ``[P, P]`` mask and a float32

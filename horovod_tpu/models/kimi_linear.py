@@ -53,6 +53,7 @@ from ..ops.kda import CHUNK, chunk_kda
 from ..parallel.moe import moe_layer_held
 from .layers import (
     block,
+    causal_conv,
     causal_lm_loss,
     dense_mlp,
     embed_lookup,
@@ -275,24 +276,13 @@ def optimizer(tx):
 
 # -- the layers ---------------------------------------------------------------
 
-def _short_conv(y, w):
-    """Causal depthwise convolution along the sequence and SiLU.  ``y [B,
-    S, H, K]``, ``w [k, H, K]``: ``out[t] = sum_j w[j] y[t - (k-1) + j]``
-    (``w[k-1]`` meets the current token), float32 inside."""
-    kk, S = w.shape[0], y.shape[1]
-    yp = jnp.pad(y.astype(jnp.float32),
-                 ((0, 0), (kk - 1, 0), (0, 0), (0, 0)))
-    out = sum(w[j].astype(jnp.float32) * yp[:, j:j + S] for j in range(kk))
-    return jax.nn.silu(out)
-
-
 def _l2norm(x, eps):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
 def _kda_mixer(x, lp, cfg: KimiLinearConfig):
     dt = x.dtype
-    proj = lambda w, conv: _short_conv(
+    proj = lambda w, conv: causal_conv(
         jnp.einsum("bsd,dhk->bshk", x, lp[w]), lp[conv])
     q = _l2norm(proj("wq", "conv_q"), cfg.l2_eps) * cfg.kda_head_dim ** -0.5
     k = _l2norm(proj("wk", "conv_k"), cfg.l2_eps)

@@ -127,6 +127,42 @@ def embed_lookup(embed: jax.Array, tokens: jax.Array, dtype) -> jax.Array:
         return jnp.einsum("bsv,vd->bsd", onehot, embed.astype(dtype))
 
 
+def causal_conv(y, w, bias=None, tail=None):
+    """Causal depthwise convolution along the sequence, then SiLU: ``y
+    [B, S, *C]``, ``w [k, *C]``, ``out[t] = bias + sum_j w[j] y[t - (k-1)
+    + j]`` (``w[k-1]`` meets the current token), float32 inside.  ``tail
+    [B, k-1, *C]`` holds the ``k - 1`` inputs before ``y[0]`` (zeros where
+    None: the sequence starts here); a decode tick hands it over with one
+    token (:func:`conv_tail` gives the next one)."""
+    kk, S = w.shape[0], y.shape[1]
+    if tail is None:
+        yp = jnp.pad(y.astype(jnp.float32),
+                     ((0, 0), (kk - 1, 0)) + ((0, 0),) * (y.ndim - 2))
+    else:
+        yp = jnp.concatenate([tail.astype(jnp.float32),
+                              y.astype(jnp.float32)], axis=1)
+    out = sum(w[j].astype(jnp.float32) * yp[:, j:j + S] for j in range(kk))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return jax.nn.silu(out)
+
+
+def conv_tail(y, k: int, tail=None, last=None):
+    """The ``k - 1`` inputs that :func:`causal_conv` of what follows ``y``
+    needs as its ``tail``: the last ``k - 1`` rows of ``tail`` then ``y``
+    (zeros before the sequence where ``tail`` is None), ending at row
+    ``last [B]`` of ``y`` where given (a right-padded prompt's last real
+    token)."""
+    B, S = y.shape[:2]
+    prev = jnp.zeros((B, k - 1) + y.shape[2:], y.dtype) if tail is None \
+        else tail.astype(y.dtype)
+    yp = jnp.concatenate([prev, y], axis=1)
+    end = jnp.full((B,), S, jnp.int32) if last is None else last + 1
+    idx = end[:, None] + jnp.arange(k - 1)[None, :]   # rows of yp
+    idx = idx.reshape(idx.shape + (1,) * (y.ndim - 2))
+    return jnp.take_along_axis(yp, idx, axis=1)
+
+
 def dense_mlp(x2, lp):
     """SwiGLU MLP, in the frame's mlp form: ``(out, None)``."""
     g = jax.nn.silu(jnp.einsum("bsd,df->bsf", x2, lp["w_gate"]))

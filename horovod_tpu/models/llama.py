@@ -865,9 +865,11 @@ def model_of(cfg):
     ``prefill_attend`` and ``paged_attend`` (where a layer's cache
     entries go and what a query reads), ``cache_rows`` and ``pool_dims``
     (the pools' geometry), ``serve_stats``, ``check_servable``,
-    ``paged_kernel_ok`` and ``PAGED_KERNEL``; where ``serve_stats``
-    returns expert layers' counts, the engine also asks for
-    ``moe_layer_names`` and ``moe_experts_scored``."""
+    ``paged_kernel_ok`` and ``PAGED_KERNEL``, ``state_rows`` (the
+    per-row shapes of the state slots an engine row holds beside the
+    pools: none for a model whose layers keep no recurrent state); where
+    ``serve_stats`` returns expert layers' counts, the engine also asks
+    for ``moe_layer_names`` and ``moe_experts_scored``."""
     model = sys.modules[type(cfg).__module__]
     if not hasattr(model, "serve_runs"):
         raise NotImplementedError(
@@ -880,9 +882,11 @@ def model_of(cfg):
 PAGED_KERNEL = "pallas"
 
 
-def check_servable(cfg: LlamaConfig, mesh: Optional[Mesh]) -> None:
+def check_servable(cfg: LlamaConfig, mesh: Optional[Mesh],
+                   engine_cfg=None, path: Optional[str] = None) -> None:
     """Raise, by name, for what of a :class:`LlamaConfig` the serving
-    steps cannot run."""
+    steps cannot run (``engine_cfg`` and ``path``, the engine's knobs and
+    a path it is about to take, are for models that refuse some)."""
     if cfg.use_moe:
         raise NotImplementedError(
             "serving does not run the capacity-routed Switch expert layer "
@@ -901,6 +905,11 @@ def pool_dims(cfg: LlamaConfig) -> tuple:
     return POOL_DIMS
 
 
+def state_rows(cfg: LlamaConfig) -> tuple:
+    """No state slots: attention keeps everything in the pools."""
+    return ()
+
+
 def serve_runs(params, cfg: LlamaConfig, positions, mesh) -> list:
     """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack."""
     tables = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
@@ -913,10 +922,11 @@ def serve_stats(cfg: LlamaConfig, extras: list) -> dict:
     return {}
 
 
-#: the serving steps' embedding: the one-hot product of training, which
-#: partitions under the vocab_rows sharding (a model served on one chip
-#: with a large vocabulary brings a lookup: glm_moe_lite.serve_embed)
-serve_embed = embed_lookup
+def serve_embed(embed, tokens, cfg: LlamaConfig):
+    """The serving steps' embedding: the one-hot product of training,
+    which partitions under the vocab_rows sharding (a model served on one
+    chip with a large vocabulary brings a lookup: glm_moe_lite's)."""
+    return embed_lookup(embed, tokens, cfg.dtype)
 
 
 def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
@@ -932,11 +942,13 @@ def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
     layer's cache entries go and what q attends over; ``li`` is the cache
     layer, ``t * n_layers + l`` in pass ``t``; ``state`` (the pools)
     rides the scan's carry, ``out`` (a layer's entries) is stacked,
-    ``cache_layers`` deep.  Returns ``(h, state, outs, stats)``, ``stats``
+    ``cache_layers`` deep (a model with state slots hands them on beside
+    its pools in ``state``, and its prefill's ``out`` holds a row's state
+    beside its entries).  Returns ``(h, state, outs, stats)``, ``stats``
     what the model makes of its mlps' extras (the expert layers' counts,
     or nothing)."""
     model = model_of(cfg)
-    h = model.serve_embed(params["embed"], tok, cfg.dtype)
+    h = model.serve_embed(params["embed"], tok, cfg)
     if mesh is not None:
         h = shd.constrain(h, ("batch", None, None), mesh,
                           model.shard_rules(cfg, mesh))
@@ -964,9 +976,10 @@ def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
     return h, state, outs, model.serve_stats(cfg, extras)
 
 
-def prefill_attend(cfg: LlamaConfig, mesh, P: int):
+def prefill_attend(cfg: LlamaConfig, mesh, P: int, last_pos=None):
     """``attend`` of the prompt prefill: causal attention of the prompt
-    over its own keys; the layer's K and V are what it leaves."""
+    over its own keys; the layer's K and V are what it leaves (causality
+    keeps the padding after ``last_pos`` out of every real position)."""
     scale = 1.0 / np.sqrt(cfg.head_dim)
     mask = jnp.tril(jnp.ones((P, P), bool))
     pin = _pin_kv(cfg, mesh)
@@ -985,7 +998,9 @@ def prefill_step(params, tokens: jax.Array, cfg, *,
     tokens [B, P] int32 → (next-token logits [B, V] fp32, the prompt's
     cache entries, one array ``[cache_layers, B, P, *row]`` for each pool
     of the model's cache (K and V for the Llama family, the latent rows
-    for GLM-4.7-Flash), the step's stats).
+    for GLM-4.7-Flash), then for a model with state slots one array
+    ``[cache_layers, B, *row]`` for each of them (the state at each row's
+    ``last_pos``), the step's stats).
     ``last_pos`` [B] selects the logits position per row (bucketed
     prompts are right-padded: the real last token sits at ``len-1``, not
     ``P-1``); None means ``P-1``.
@@ -995,7 +1010,7 @@ def prefill_step(params, tokens: jax.Array, cfg, *,
     B, P = tokens.shape
     h, _, kept, stats = _serve_layers(
         params, tokens, jnp.broadcast_to(jnp.arange(P), (B, P)), cfg, mesh,
-        model.prefill_attend(cfg, mesh, P))
+        model.prefill_attend(cfg, mesh, P, last_pos))
     with region("head"):
         if last_pos is None:
             h_last = h[:, -1]
@@ -1085,7 +1100,7 @@ def paged_attend(cfg: LlamaConfig, mesh, tables, blk, off, mask,
 def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
                       pools: tuple, tables: jax.Array, cfg, *,
                       mesh: Optional[Mesh] = None, use_flash: bool = False,
-                      interpret: bool = False) -> tuple:
+                      interpret: bool = False, slots: tuple = ()) -> tuple:
     """One decode tick for the serving engine against the paged pools.
 
     tok [B] int32 (this tick's input token per slot); positions [B] its
@@ -1100,21 +1115,26 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
     path, GSPMD-shardable) or in the model's Pallas paged kernel, which
     copies each stream's live pages in by the scalar-prefetched table
     (``use_flash``; callers check the model's ``paged_kernel_ok``).
-    Returns (logits [B, V] fp32, pools, stats) — pass the pools donated
-    so the writes land in place."""
+    ``slots``: the model's state slot arrays ``[cache_layers, B, *row]``
+    (:func:`model_of`'s ``state_rows``; none for attention alone), row b
+    read and written by row b's token.
+    Returns (logits [B, V] fp32, pools, slots, stats) — pass the pools
+    and slots donated so the writes land in place."""
     model = model_of(cfg)
     B = tok.shape[0]
     BS = pools[0].shape[2]
     T = tables.shape[1] * BS
     mask = (jnp.arange(T)[None, :] <= positions[:, None])[:, None, :]
     blk = tables[jnp.arange(B), positions // BS]                # [B]
-    h, pools, _, stats = _serve_layers(
+    n = len(pools)
+    h, state, _, stats = _serve_layers(
         params, tok[:, None], positions[:, None], cfg, mesh,
         model.paged_attend(cfg, mesh, tables, blk, positions % BS, mask,
                            positions if use_flash else None, interpret),
-        tuple(pools))
-    return (_head(params, h[:, 0], cfg, ("batch", "vocab"), mesh,
-                  model.shard_rules(cfg, mesh)), pools, stats)
+        tuple(pools) + tuple(slots))
+    logits = _head(params, h[:, 0], cfg, ("batch", "vocab"), mesh,
+                   model.shard_rules(cfg, mesh))
+    return logits, state[:n], state[n:], stats
 
 
 def extend_step_paged(params, tok: jax.Array, positions: jax.Array,
@@ -1144,8 +1164,10 @@ def extend_step_paged(params, tok: jax.Array, positions: jax.Array,
     pool), exactly the visibility a monolithic prefill gives it.  Reads
     go through the contiguous-gather path (GSPMD-shardable); the Pallas
     decode kernels are single-query and do not apply here.  Returns
-    (logits [B, S, V] fp32, pools, stats) — donate the pools."""
+    (logits [B, S, V] fp32, pools, stats) — donate the pools.  A model
+    with state slots refuses it (``check_servable``'s ``"extend"``)."""
     model = model_of(cfg)
+    model.check_servable(cfg, mesh, path="extend")
     BS = pools[0].shape[2]
     T = tables.shape[1] * BS
     mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]  # [B,S,T]

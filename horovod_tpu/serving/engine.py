@@ -7,7 +7,12 @@ runs; this owns how it runs on devices):
   and of such rows as the model's cache has (K and V ``[KV, Dh]`` for
   the Llama family, one pool of latent rows for GLM-4.7-Flash; ``L`` the
   model's ``cache_layers``: its layers times its passes), allocated
-  once, donated through every jitted step so writes land in place.  On
+  once, donated through every jitted step so writes land in place.
+  Beside them, for a model whose layers keep a recurrent state
+  (Falcon-H1's Mamba-2 mixer), **state slots**: one ``[L, max_active,
+  *row]`` array for each of the model's ``state_rows``, row ``i`` the
+  state of the request in decode slot ``i``, written whole by its prefill
+  and read and written in place by every tick; none for the others.  On
   a mesh a K/V pool is constrained ``kv_heads`` over tp (the
   round-5 never-replicate-the-cache rule) and activations ``batch`` over
   dp·fsdp, via :mod:`horovod_tpu.parallel.sharding` logical rules.
@@ -88,6 +93,15 @@ _m_kv_bytes_per_token = _obs.gauge(
     "hvd_serving_kv_bytes_per_token",
     "bytes one token holds in the pools (K and V, or its latent rows as "
     "the pool pads them), all cache layers")
+# Fed only for a model with state slots.
+_m_state_bytes_per_stream = _obs.gauge(
+    "hvd_serving_state_bytes_per_stream",
+    "bytes one stream's state slot holds, all cache layers (a model whose "
+    "layers keep a recurrent state: its size does not grow with context)")
+_m_state_writes = _obs.counter(
+    "hvd_serving_state_slot_writes_total",
+    "state slot rows written: one by each prefill, one by each tick for "
+    "every row with a stream")
 
 _span = _trace.profiler_span
 
@@ -100,6 +114,18 @@ def _named(name: str, fn):
         return fn(*args)
     call.__name__ = call.__qualname__ = name
     return call
+
+
+def _scatters_in_place(pool) -> bool:
+    """Whether XLA writes a prefill's blocks into ``pool [L, NB, BS, *row]``
+    in place with one scatter.  Where a token's row has a second-minor
+    dimension under 16 bytes (K/V of 4 heads in bf16), the chip lays the
+    pool out in tiles of that many rows and the scatter wants tiles of 8:
+    compiled for a v5e it copies the whole pool out and back (1.41 GB for
+    the Falcon-H1 cell's K pool), so such a pool is written a block at a
+    time instead."""
+    row = pool.shape[3:]
+    return len(row) < 2 or row[-2] * pool.dtype.itemsize >= 16
 
 
 def _bucket_pow2(n: int, floor: int = 1) -> int:
@@ -160,7 +186,7 @@ class ServingEngine:
         #: the module of ``cfg``'s class: what the serving steps ask for
         #: the layer runs, the cache's geometry and the attention paths
         self.model = llama.model_of(cfg)
-        self.model.check_servable(cfg, mesh)
+        self.model.check_servable(cfg, mesh, engine_cfg)
         self.params = params
         self.cfg = cfg
         self.ecfg = engine_cfg
@@ -211,6 +237,16 @@ class ServingEngine:
 
         #: the page pools, one array for each of ``cache.rows``
         self.pools = tuple(fresh_pool(shape) for shape in self.cache.shapes)
+        #: the state slots, one array ``[L, max_active, *row]`` for each
+        #: of the model's ``state_rows`` (none for attention alone)
+        self.slots = tuple(
+            jnp.zeros((cfg.cache_layers, engine_cfg.max_active) + row,
+                      cfg.dtype) for row in self.model.state_rows(cfg))
+        #: bytes one row of the slots holds, over all layers
+        self._state_row_bytes = sum(
+            int(a.nbytes) // engine_cfg.max_active for a in self.slots)
+        if self.slots:
+            _m_state_bytes_per_stream.set(self._state_row_bytes)
 
         #: what the prefill and decode spans say of the model's depth
         self._depth = dict(loops=cfg.loops, cache_layers=cfg.cache_layers)
@@ -237,9 +273,9 @@ class ServingEngine:
         self._prefill = jax.jit(_named(
             "hvd_serve_prefill", self._prefill_impl))
         self._scatter = jax.jit(_named(
-            "hvd_serve_scatter", self._scatter_impl), donate_argnums=(0,))
+            "hvd_serve_scatter", self._scatter_impl), donate_argnums=(0, 3))
         self._decode = jax.jit(_named(
-            "hvd_serve_decode", self._decode_impl), donate_argnums=(1,))
+            "hvd_serve_decode", self._decode_impl), donate_argnums=(1, 5))
         self._extend = jax.jit(_named(
             "hvd_serve_extend", self._extend_impl), donate_argnums=(1,))
 
@@ -265,14 +301,17 @@ class ServingEngine:
             params, tokens, self.cfg, mesh=self.mesh, last_pos=last_pos)
         return (self._pick(logits), stats), kept
 
-    def _scatter_impl(self, pools, kept, blocks):
+    def _scatter_impl(self, pools, kept, blocks, slots=(), row=None):
         """Write one request's prefill entries (each ``[L, 1, P, *row]``)
-        into its ``nb`` blocks of each pool.  P is the prefill bucket: it
-        is cut to the blocks' positions where it is longer (here and not
-        by the caller, whose eager slices would be more programs, each a
-        copy) and padded up to them where it is shorter; the tail slots
-        hold pad-token entries, masked by position until decode
-        overwrites them one at a time."""
+        into its ``nb`` blocks of each pool, and the state it left (the
+        rest of ``kept``, each ``[L, 1, *row]``) into row ``row`` of each
+        state slot array (none for a model without, whose ``row`` goes
+        unused).  P is the prefill bucket: it is cut to the blocks'
+        positions where it is longer (here and not by the caller, whose
+        eager slices would be more programs, each a copy) and padded up to
+        them where it is shorter; the tail slots hold pad-token entries,
+        masked by position until decode overwrites them one at a time.
+        Returns the pools then the slots, in one tuple."""
         jnp = self._jnp
         BS = self.cache.block_size
         nb = blocks.shape[0]
@@ -282,16 +321,26 @@ class ServingEngine:
             P = min(new.shape[2], nb * BS)
             pad = [(0, 0), (0, nb * BS - P)] + [(0, 0)] * (new.ndim - 3)
             new = jnp.pad(new[:, 0, :P], pad)
-            return pool.at[:, blocks].set(
-                new.reshape(L, nb, BS, *new.shape[2:]))
+            new = new.reshape(L, nb, BS, *new.shape[2:])
+            if not _scatters_in_place(pool):
+                lax = self._jax.lax
+                return lax.fori_loop(
+                    0, nb, lambda i, p: lax.dynamic_update_slice_in_dim(
+                        p, lax.dynamic_slice_in_dim(new, i, 1, 1),
+                        blocks[i], 1), pool)
+            return pool.at[:, blocks].set(new)
 
-        return tuple(put(pool, new) for pool, new in zip(pools, kept))
+        n = len(pools)
+        return tuple(put(pool, new) for pool, new in zip(
+            pools, kept[:n])) + tuple(slot.at[:, row].set(new[:, 0])
+                                      for slot, new in zip(slots, kept[n:]))
 
-    def _decode_impl(self, params, pools, tok, pos, tables):
-        logits, pools, stats = llama.decode_step_paged(
+    def _decode_impl(self, params, pools, tok, pos, tables, slots):
+        logits, pools, slots, stats = llama.decode_step_paged(
             params, tok, pos, pools, tables, self.cfg, mesh=self.mesh,
-            use_flash=self._use_flash, interpret=self._interpret)
-        return (self._pick(logits), stats), pools
+            use_flash=self._use_flash, interpret=self._interpret,
+            slots=slots)
+        return (self._pick(logits), stats), pools, slots
 
     def _extend_impl(self, params, pools, tok, pos, valid, tables):
         """Multi-token paged forward ([B, S] at arbitrary positions):
@@ -322,7 +371,7 @@ class ServingEngine:
         R = self.ecfg.max_active
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
         return self._decode.lower(self.params, self.pools,
-                                  i32(R), i32(R), i32(R, n_cols))
+                                  i32(R), i32(R), i32(R, n_cols), self.slots)
 
     def submit(self, prompt, max_new_tokens: int, *, eos_token=None,
                stream_cb=None, migrate_cb=None, trace_ctx=None) -> Request:
@@ -330,6 +379,9 @@ class ServingEngine:
         # queues (the caller sees the raise, nothing leaks into the
         # scheduler); delay throttles intake.
         chaos.fire("serving_admit")
+        if migrate_cb is not None:
+            self.model.check_servable(self.cfg, self.mesh, self.ecfg,
+                                      path="migration")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -463,9 +515,10 @@ class ServingEngine:
         P = int(toks.shape[0])
         Pb = self._bucket_len(P)
         sp = req.open_phase("prefill", tokens=P, bucket=Pb)
+        state = dict(state_bytes=self._state_row_bytes) if self.slots else {}
         with _span("hvd.serve.prefill", req=req.req_id, tokens=P, cached=0,
-                   resumed=P if req.preemptions else 0,
-                   **self._depth) as span:
+                   resumed=P if req.preemptions else 0, **self._depth,
+                   **state) as span:
             # The span is the context's current span while the prefill
             # dispatches, so nested layers (collectives the model
             # enqueues) attach their events to this request's chain.
@@ -482,8 +535,13 @@ class ServingEngine:
                 # Only the blocks the P real positions span are written;
                 # the +1 slot block (for the emitted token) is untouched.
                 nb = self.cache.blocks_for(P)
-                self.pools = self._scatter(
-                    self.pools, kept, np.asarray(blocks[:nb], np.int32))
+                blocks = np.asarray(blocks[:nb], np.int32)
+                n = len(self.pools)
+                written = self._scatter(self.pools, kept, blocks, self.slots,
+                                        np.int32(self._slots.index(req)))
+                self.pools, self.slots = written[:n], written[n:]
+                if self.slots:
+                    _m_state_writes.inc()
                 if self.spec is not None:
                     self.spec.mirror_prefill(req, padded, P)
             if self.prefix_cache is not None:
@@ -594,9 +652,12 @@ class ServingEngine:
         jnp = self._jnp
         with _span("hvd.serve.decode.grow"):
             # Reserve the write position for every running request
-            # first — growth can preempt, shrinking the running set.
+            # first — growth can preempt, shrinking the running set.  A
+            # preempted or failed request leaves RUNNING as it leaves the
+            # set; asking the list instead compares Request's fields
+            # against every earlier stream, O(streams^2) a tick.
             for req in list(self.scheduler.running):
-                if req in self.scheduler.running:
+                if req.state is RequestState.RUNNING:
                     try:
                         self.scheduler.grow(req)
                     except OutOfBlocks as e:
@@ -626,12 +687,20 @@ class ServingEngine:
                 ids[i] = r.req_id
             tables = self.pager.table_matrix(ids, n_cols)
             # kv_tokens: the cached tokens this tick's queries read, the
-            # one being written included
+            # one being written included; with state slots, the rows whose
+            # state the tick reads and writes, and those bytes
+            state = {}
+            if self.slots:
+                state = dict(state_rows=len(active), state_bytes=2 * len(
+                    active) * self._state_row_bytes)
+                _m_state_writes.inc(len(active))
             self._count_table(tick, tables,
-                              kv_tokens=int(pos.sum()) + len(active))
+                              kv_tokens=int(pos.sum()) + len(active),
+                              **state)
             args = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables))
         with _span("hvd.serve.decode.dispatch"):
-            out, self.pools = self._decode(self.params, self.pools, *args)
+            out, self.pools, self.slots = self._decode(
+                self.params, self.pools, *args, self.slots)
         with _span("hvd.serve.decode.fetch"):
             nxt = self._fetch(out, tick, touched=True)
         emitted = []
@@ -693,6 +762,8 @@ class ServingEngine:
         identical to a local prefill (greedy decode).  See
         :mod:`horovod_tpu.serving.disagg.migration`."""
         from .disagg import migration
+        self.model.check_servable(self.cfg, self.mesh, self.ecfg,
+                                  path="migration")
         return migration.import_request(self, manifest, k_bytes, v_bytes,
                                         stream_cb=stream_cb)
 

@@ -105,7 +105,7 @@ class SpecDecoder:
         return kept
 
     def _decode_impl(self, params, pools, tok, pos, tables):
-        logits, pools, _ = llama.decode_step_paged(
+        logits, pools, _, _ = llama.decode_step_paged(
             params, tok, pos, pools, tables, self.draft_cfg, mesh=None,
             use_flash=False)
         return self.eng._pick(logits), pools

@@ -203,6 +203,39 @@ def test_kimi_kernels_compile_for_v5e(monkeypatch):
         <= kda._SCOPED_VMEM == 16 << 20
 
 
+def test_ssd_decode_kernel_compiles_for_v5e(monkeypatch):
+    """``hvd_ssd_decode`` through Mosaic at the Falcon-H1 cell's shape (6
+    layers of 128 rows of ``[32, 256, 128]`` bf16 state): one custom call,
+    the slot array aliased to the output (written in place, no copy of
+    its 1.6 GB), no temporaries."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from horovod_tpu.ops import ssd
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this machine
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    sharding = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    L, R, H, N, Pd, G = 6, 128, 32, 256, 128, 2
+    state = spec((L, R, H, N, Pd), jnp.bfloat16)
+    compiled = jax.jit(ssd.ssd_decode, donate_argnums=(0,)).lower(
+        state, spec((), jnp.int32), spec((R,), jnp.int32), spec((R, H, Pd)),
+        spec((R, H)), spec((R, G, N)), spec((R, G, N))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "hvd_ssd_decode" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == L * R * H * N * Pd * 2
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 # (S, heads, kv heads, key width, value width, asks for VMEM).  Latent
 # attention at the Kimi-Linear cell's shape; a Llama block at 8k, which
 # stays under the default scoped VMEM and hands the compiler no limit;

@@ -20,18 +20,3 @@ for _file in sorted(os.listdir(_DIR)):
         # the tests and the fixtures they name
         globals().update({k: v for k, v in vars(_mod).items()
                           if not k.startswith("_")})
-
-# ``test_manifest_config_keeps_published_widths`` is parametrised over every
-# configuration of the manifest and knows one family's published numbers
-# (Mistral-7B-v0.3's).  The file is the benchmark's and is edited only by a
-# ``benchmark`` PR, so here its cases are held to that family's
-# configurations; a configuration of another family brings the same check
-# against its own published numbers (``test_kimi_config_keeps_every_
-# published_number``).  PERF.md section 7 asks the next ``benchmark`` issue
-# to make the test read each configuration's own ``published``.
-import pytest as _pytest  # noqa: E402
-
-test_manifest_config_keeps_published_widths.pytestmark = [
-    _pytest.mark.parametrize("config", [
-        c["name"] for c in MANIFEST["configs"]
-        if c["source"].startswith("https://huggingface.co/mistralai/")])]

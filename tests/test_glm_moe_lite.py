@@ -136,10 +136,11 @@ def _serve(params, engine_cfg=ENGINE):
     served: dict = {}
     prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
         p, tok, CFG, last_pos=last))
-    decode = jax.jit(lambda p, pools, tok, pos, tables:
+    decode = jax.jit(lambda p, pools, tok, pos, tables, slots:
                      llama.decode_step_paged(
                          p, tok, pos, pools, tables, CFG,
-                         use_flash=eng._use_flash, interpret=eng._interpret))
+                         use_flash=eng._use_flash, interpret=eng._interpret,
+                         slots=slots))
 
     def spy_prefill(p, tokens, last_pos):
         logits, kept, stats = prefill(p, tokens, last_pos)
@@ -149,12 +150,14 @@ def _serve(params, engine_cfg=ENGINE):
             np.asarray(logits[0])
         return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
 
-    def spy_decode(p, pools, tok, pos, tables):
-        logits, pools, stats = decode(p, pools, tok, pos, tables)
+    def spy_decode(p, pools, tok, pos, tables, slots):
+        logits, pools, slots, stats = decode(p, pools, tok, pos, tables,
+                                             slots)
         for i, r in enumerate(eng._slots):
             if r is not None:
                 served[r.req_id][int(pos[i])] = np.asarray(logits[i])
-        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), pools
+        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), pools, \
+            slots
 
     eng._prefill, eng._decode = spy_prefill, spy_decode
     reqs = [eng.submit(p, NEW) for p in PROMPTS]
@@ -958,7 +961,7 @@ def test_steps_return_each_expert_layers_counts(params):
     pool = jnp.zeros((3, 8, 4, 128))
     tables = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
     args = (jnp.asarray([9, 7, 0]), jnp.asarray([9, 5, 0]), (pool,), tables)
-    _, pools, stats = llama.decode_step_paged(params, *args, CFG)
+    _, pools, _, stats = llama.decode_step_paged(params, *args, CFG)
     assert len(pools) == 1
     assert np.asarray(stats["expert_counts"]).sum(-1).tolist() == [6, 6]
     _, _, stats = llama.extend_step_paged(

@@ -77,9 +77,9 @@ def _serve(params, cfg=CFG, engine_cfg=ENGINE):
     served: dict = {}
     prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
         p, tok, cfg, last_pos=last))
-    decode = jax.jit(lambda p, pools, tok, pos, tables:
+    decode = jax.jit(lambda p, pools, tok, pos, tables, slots:
                      llama.decode_step_paged(p, tok, pos, pools, tables,
-                                             cfg))
+                                             cfg, slots=slots))
 
     def spy_prefill(p, tokens, last_pos):
         logits, kept, stats = prefill(p, tokens, last_pos)
@@ -89,12 +89,14 @@ def _serve(params, cfg=CFG, engine_cfg=ENGINE):
             np.asarray(logits[0])
         return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
 
-    def spy_decode(p, pools, tok, pos, tables):
-        logits, pools, stats = decode(p, pools, tok, pos, tables)
+    def spy_decode(p, pools, tok, pos, tables, slots):
+        logits, pools, slots, stats = decode(p, pools, tok, pos, tables,
+                                             slots)
         for i, r in enumerate(eng._slots):
             if r is not None:
                 served[r.req_id][int(pos[i])] = np.asarray(logits[i])
-        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), pools
+        return (jnp.argmax(logits, -1).astype(jnp.int32), stats), pools, \
+            slots
 
     eng._prefill, eng._decode = spy_prefill, spy_decode
     reqs = [eng.submit(p, NEW) for p in PROMPTS]

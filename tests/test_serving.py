@@ -219,6 +219,27 @@ def test_engine_parity_under_preemption_pressure(tiny):
     assert preemptions > 0, "pool was sized to force preemption"
 
 
+def test_decode_tick_compares_no_two_requests(tiny, monkeypatch):
+    """A tick that admits and finishes nothing never calls
+    ``Request.__eq__``: the engine tells a stream that ``grow()``
+    preempted by its state, not by asking the running list, whose
+    field-by-field test is O(streams^2) a tick."""
+    cfg, params = tiny
+    rng = np.random.RandomState(5)
+    eng = serving.ServingEngine(params, cfg, engine_cfg=serving.EngineConfig(
+        block_size=4, num_blocks=32, max_active=4))
+    for p in _prompts(rng, [5, 6, 7, 8]):
+        eng.submit(p, 8)
+    while eng.scheduler.waiting:
+        eng.step()
+    calls = []
+    eq = Request.__eq__
+    monkeypatch.setattr(Request, "__eq__",
+                        lambda a, b: calls.append(1) or eq(a, b))
+    assert len(eng.step()) == 4
+    assert calls == []
+
+
 def test_engine_bucketed_prefill_matches_exact(tiny):
     """Right-padded bucketed prefill must emit the same tokens as
     exact-length compiles (causality makes the padded tail inert)."""
@@ -268,7 +289,7 @@ def test_decode_tick_is_extend_with_one_token_a_row(tiny, use_flash):
                          jnp.int32)
     tok = jnp.asarray(rng.randint(0, cfg.vocab_size, size=(B,)), jnp.int32)
     pos = jnp.asarray([5, 2, 9], jnp.int32)
-    logits_d, (kp_d, vp_d), _ = llama.decode_step_paged(
+    logits_d, (kp_d, vp_d), _, _ = llama.decode_step_paged(
         params, tok, pos, (kp, vp), tables, cfg, use_flash=use_flash,
         interpret=use_flash)
     logits_e, (kp_e, vp_e), _ = llama.extend_step_paged(
@@ -309,9 +330,9 @@ def test_decode_tick_skips_rows_with_no_stream(tiny):
     step = lambda k, v, flash: llama.decode_step_paged(
         params, *args, (jnp.asarray(k), jnp.asarray(v)),
         jnp.asarray(tables), cfg, use_flash=flash, interpret=flash)
-    logits_g, (kp_g, vp_g), _ = step(kp, vp, False)
+    logits_g, (kp_g, vp_g), _, _ = step(kp, vp, False)
     kp[:, 0, 1:] = vp[:, 0, 1:] = np.nan      # offset 0 is written first
-    logits_k, (kp_k, vp_k), _ = step(kp, vp, True)
+    logits_k, (kp_k, vp_k), _, _ = step(kp, vp, True)
     assert np.isfinite(np.asarray(logits_k)).all()
     np.testing.assert_allclose(logits_k[live], logits_g[live], rtol=1e-4,
                                atol=1e-4)
