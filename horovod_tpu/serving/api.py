@@ -244,6 +244,12 @@ class ServingSession:
             fut = self._futures.pop(req.req_id, None)
             if fut is not None and not fut.done():
                 fut.set_exception(exc)
+        self._deliver(emissions)
+
+    def _deliver(self, emissions) -> None:
+        """The engine's ``(request, token)`` emissions to their clients:
+        TTFT/ITL observes, stream callbacks, and the futures of the
+        requests they finished."""
         with _trace.profiler_span("hvd.serve.deliver"):
             now = time.monotonic()
             for req, token in emissions:
@@ -308,7 +314,9 @@ class ServingSession:
         _frec.RECORDER.record("serving_abort", error=repr(exc),
                               recovery=self.recoveries)
         with self._lock:
-            aborted = self.engine.abort_inflight(exc)
+            emitted, aborted = self.engine.abort_inflight(exc)
+        self._deliver(emitted)
+        with self._lock:
             futs = [(req, self._futures.pop(req.req_id, None))
                     for req in aborted]
         for req, fut in futs:

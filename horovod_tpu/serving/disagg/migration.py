@@ -49,7 +49,10 @@ def export_request(engine, req: Request):
     """Snapshot ``req``'s KV blocks out of ``engine``'s pool.
 
     Must run while the pager still holds the request's table (i.e.
-    before ``scheduler.finish`` releases the blocks).  Returns
+    before ``scheduler.finish`` releases the blocks).  The context sent
+    is the prompt's and every fetched token's but the last (a tick still
+    in flight may have written one position more: the importer's first
+    tick writes it again).  Returns
     ``(manifest, k_bytes, v_bytes)`` — the payloads are C-contiguous
     ``[L, nb, BS, *row]`` dumps of the engine's first and second pool
     (K and V; a model whose cache is one pool, the latent rows, sends
@@ -60,7 +63,7 @@ def export_request(engine, req: Request):
         raise ValueError(f"request {req.req_id} has no prefill emission "
                          "yet; export runs after the first token")
     cache = engine.cache
-    ctx = req.context_len
+    ctx = int(req.prompt.shape[0]) + len(req.generated) - 1
     nb = cache.blocks_for(ctx)
     blocks = engine.pager.table(req.req_id)[:nb]
     try:
@@ -229,6 +232,7 @@ def import_request(engine, manifest: dict, k_bytes: bytes,
     req.open_phase("decode", migrated=True)
     engine.scheduler.running.append(req)
     engine._assign_slot(req)
+    engine._put_token(req, req.generated[-1])
     if engine.prefix_cache is not None:
         # The migrated prompt's pages are now first-class local pages;
         # share them so future local admissions (or re-imports of the

@@ -28,6 +28,18 @@ runs; this owns how it runs on devices):
   :func:`~horovod_tpu.models.llama.generate` on the same prompts (the
   model-side steps reuse its math op for op); asserted in
   ``tests/test_serving.py``.
+- **One tick in flight** — each row's last token stays on the device
+  (``ServingEngine.toks``: the tick reads it and its picks are the next
+  one, a prefill's pick is scattered into its row), so a turn dispatches
+  tick k and only then fetches and emits tick k-1: the host's turn runs
+  while the device runs.  A prefill's first token is fetched in its own
+  turn; the turn's last prefill waits for it only after the tick is
+  dispatched behind it.  A request whose last budgeted token is
+  dispatched leaves the batch and frees its blocks at that dispatch.
+  Where growth may preempt (which folds a request's tokens into its
+  prefill) or an abort ends the batch, the engine first fetches what is
+  in flight: it *drains*.  A request with an ``eos_token`` may be
+  dispatched one tick past its stop; that pick is dropped, never emitted.
 """
 
 from __future__ import annotations
@@ -102,8 +114,31 @@ _m_state_writes = _obs.counter(
     "hvd_serving_state_slot_writes_total",
     "state slot rows written: one by each prefill, one by each tick for "
     "every row with a stream")
+# How often the host ran one tick ahead of the device, and what stopped it.
+_m_decode_ahead = _obs.counter(
+    "hvd_serving_decode_ahead_total",
+    "decode ticks dispatched while the previous tick's tokens were still "
+    "on the device (the host a turn ahead)")
+_m_drains = _obs.counter(
+    "hvd_serving_lookahead_drains_total",
+    "fetches of everything in flight before a path that needs every token "
+    "on the host: preempt (a growth that may preempt), abort", ("reason",))
+_m_dropped_rows = _obs.counter(
+    "hvd_serving_lookahead_dropped_rows_total",
+    "picks fetched for a request that had stopped after they were "
+    "dispatched (an EOS one tick earlier): never emitted")
 
 _span = _trace.profiler_span
+
+
+@dataclasses.dataclass(eq=False)
+class _Flight:
+    """A program dispatched and not yet fetched: ``out`` the device's
+    ``(tokens, stats)``, ``rows`` the ``(index, request)`` pairs whose
+    picks it holds, ``tick`` a decode tick (else a prefill)."""
+    out: Any
+    rows: list
+    tick: bool
 
 
 def _named(name: str, fn):
@@ -247,6 +282,12 @@ class ServingEngine:
             int(a.nbytes) // engine_cfg.max_active for a in self.slots)
         if self.slots:
             _m_state_bytes_per_stream.set(self._state_row_bytes)
+        #: each row's last token, on the device: what the next tick reads
+        #: (the last tick's picks, a prefill's pick scattered into its row)
+        self.toks = jnp.zeros((engine_cfg.max_active,), jnp.int32)
+        #: dispatched and not yet fetched, in dispatch order: between
+        #: turns the last tick alone
+        self._flights: list[_Flight] = []
 
         #: what the prefill and decode spans say of the model's depth
         self._depth = dict(loops=cfg.loops, cache_layers=cfg.cache_layers)
@@ -269,7 +310,9 @@ class ServingEngine:
 
         # One jit per step kind; bucketing keeps the traced shape set
         # small and jax's cache does the rest.  A profile shows each as
-        # the program jit_<name>.
+        # the program jit_<name>.  The token vector is not donated: the
+        # buffer a tick reads is the last tick's picks, which the host
+        # fetches after this tick is dispatched.
         self._prefill = jax.jit(_named(
             "hvd_serve_prefill", self._prefill_impl))
         self._scatter = jax.jit(_named(
@@ -301,17 +344,20 @@ class ServingEngine:
             params, tokens, self.cfg, mesh=self.mesh, last_pos=last_pos)
         return (self._pick(logits), stats), kept
 
-    def _scatter_impl(self, pools, kept, blocks, slots=(), row=None):
+    def _scatter_impl(self, pools, kept, blocks, slots=(), row=None,
+                      toks=None, pick=None):
         """Write one request's prefill entries (each ``[L, 1, P, *row]``)
         into its ``nb`` blocks of each pool, and the state it left (the
         rest of ``kept``, each ``[L, 1, *row]``) into row ``row`` of each
-        state slot array (none for a model without, whose ``row`` goes
-        unused).  P is the prefill bucket: it is cut to the blocks'
+        state slot array (none for a model without).  P is the prefill
+        bucket: it is cut to the blocks'
         positions where it is longer (here and not by the caller, whose
         eager slices would be more programs, each a copy) and padded up to
         them where it is shorter; the tail slots hold pad-token entries,
         masked by position until decode overwrites them one at a time.
-        Returns the pools then the slots, in one tuple."""
+        Returns the pools then the slots, in one tuple, and with ``toks``
+        (the engine's token vector) that vector with the prefill's
+        ``pick [1]`` in row ``row`` last."""
         jnp = self._jnp
         BS = self.cache.block_size
         nb = blocks.shape[0]
@@ -331,9 +377,12 @@ class ServingEngine:
             return pool.at[:, blocks].set(new)
 
         n = len(pools)
-        return tuple(put(pool, new) for pool, new in zip(
+        out = tuple(put(pool, new) for pool, new in zip(
             pools, kept[:n])) + tuple(slot.at[:, row].set(new[:, 0])
                                       for slot, new in zip(slots, kept[n:]))
+        if toks is None:
+            return out
+        return out + (toks.at[row].set(pick[0]),)
 
     def _decode_impl(self, params, pools, tok, pos, tables, slots):
         logits, pools, slots, stats = llama.decode_step_paged(
@@ -414,7 +463,8 @@ class ServingEngine:
         return req
 
     def has_work(self) -> bool:
-        return self.scheduler.has_work()
+        """Requests queued or running, or tokens still to fetch."""
+        return self.scheduler.has_work() or bool(self._flights)
 
     def pop_failed(self) -> list:
         """Requests the scheduler declared unrunnable (e.g. a preempted
@@ -425,7 +475,9 @@ class ServingEngine:
         return failed
 
     def step(self) -> list[tuple[Request, int]]:
-        """One serving round; returns the (request, token) emissions.
+        """One serving round; returns the (request, token) emissions:
+        the first tokens of its prefills, those of the tick the round
+        before dispatched, and whatever a drain fetched.
 
         A raise out of here (device failure, collective abort, injected
         fault) leaves the scheduler/pager bookkeeping consistent enough
@@ -438,16 +490,29 @@ class ServingEngine:
         emitted: list[tuple[Request, int]] = []
         self._steps += 1
         _m_steps.inc()
-        with _span("hvd.serve.step", step=self._steps):
+        with _span("hvd.serve.step", step=self._steps) as turn:
             with _span("hvd.serve.admit"):
                 admitted = self.scheduler.admit()
+            # The turn's last prefill, where a plain one, dispatches the
+            # turn's tick before it waits for its token, so the device
+            # has work queued while the host waits (a speculative round
+            # reads its rows' tokens on the host).
+            last = admitted[-1] if admitted else None
+            if last is not None and (self.spec is not None
+                                     or last.cached_tokens > 0
+                                     or last.migrate_cb is not None):
+                last = None
             for req in admitted:
                 self._assign_slot(req)
                 n = int(req.prefill_tokens.shape[0]) - req.cached_tokens
                 _m_prefill_tokens.inc(n)
                 if req.preemptions:
                     _m_reprefill_tokens.inc(n)
-                emitted.append((req, self._prefill_one(req)))
+                if req.cached_tokens > 0:
+                    emitted.append((req, self._prefill_cached(req)))
+                else:
+                    emitted += self._prefill_one(
+                        req, turn if req is last else None)
                 if req.migrate_cb is not None \
                         and req.state == RequestState.RUNNING:
                     # Disaggregated handoff: this replica's job ends at
@@ -455,14 +520,27 @@ class ServingEngine:
                     # the pager table is still held and let a decode
                     # replica continue the request (serving/disagg).
                     self._migrate_out(req)
-            if self.scheduler.running:
-                with _span("hvd.serve.decode", **self._depth) as tick:
-                    ticked = (self.spec.tick(tick) if self.spec is not None
-                              else self._decode_tick(tick))
-                _m_decode_tokens.inc(len(ticked))
-                emitted.extend(ticked)
+            if last is None:
+                emitted += self._tick(turn)
             self._sample_gauges()
         return emitted
+
+    def _tick(self, turn) -> list[tuple[Request, int]]:
+        """The turn's decode tick under its ``hvd.serve.decode`` span,
+        and the emissions of the tick before; with no stream to run, the
+        tick before's alone (its stats onto ``turn``)."""
+        if not self.scheduler.running:
+            return self._fetch_emit(
+                [f for f in self._flights if f.tick], turn)
+        with _span("hvd.serve.decode", **self._depth) as tick:
+            if self.spec is None:
+                return self._decode_tick(tick)
+            # A round fetches its own tokens and the prefills theirs, so
+            # nothing is in flight under speculation.
+            tick.set_metadata(ahead=0)
+            ticked = self.spec.tick(tick)
+            _m_decode_tokens.inc(len(ticked))
+            return ticked
 
     def _sample_gauges(self) -> None:
         """Pool/queue health after a step: queue depth, compiled-batch
@@ -490,8 +568,15 @@ class ServingEngine:
         i = self._slots.index(None)
         self._slots[i] = req
 
+    def _put_token(self, req: Request, token: int) -> None:
+        """``token`` as the last token of ``req``'s row on the device:
+        for a path that has it on the host (a prefix-hit prefill, a
+        migrated request)."""
+        self.toks = self.toks.at[self._slots.index(req)].set(np.int32(token))
+
     def _drop_slot(self, req: Request) -> None:
-        self._slots[self._slots.index(req)] = None
+        if not req.released:        # else its row went at its last dispatch
+            self._slots[self._slots.index(req)] = None
 
     def _sync_slots(self) -> None:
         """Preemption inside scheduler.grow() removes requests from the
@@ -507,9 +592,13 @@ class ServingEngine:
                 return b
         return n
 
-    def _prefill_one(self, req: Request) -> int:
-        if req.cached_tokens > 0:
-            return self._prefill_cached(req)
+    def _prefill_one(self, req: Request, turn=None
+                     ) -> list[tuple[Request, int]]:
+        """``req``'s prefill: dispatched (its pick goes into the
+        request's row of the token vector on the device), and its first
+        token fetched and emitted.  With ``turn``, the turn's tick is
+        dispatched between the two (:meth:`_tick`), and its emissions
+        come first."""
         jnp = self._jnp
         toks = req.prefill_tokens
         P = int(toks.shape[0])
@@ -538,8 +627,10 @@ class ServingEngine:
                 blocks = np.asarray(blocks[:nb], np.int32)
                 n = len(self.pools)
                 written = self._scatter(self.pools, kept, blocks, self.slots,
-                                        np.int32(self._slots.index(req)))
-                self.pools, self.slots = written[:n], written[n:]
+                                        np.int32(self._slots.index(req)),
+                                        self.toks, out[0])
+                self.pools, self.slots = written[:n], written[n:-1]
+                self.toks = written[-1]
                 if self.slots:
                     _m_state_writes.inc()
                 if self.spec is not None:
@@ -548,20 +639,28 @@ class ServingEngine:
                 self.prefix_cache.insert(toks,
                                          self.pager.table(req.req_id))
             req.close_phase("prefill")
-            with _span("hvd.serve.prefill.fetch"):
-                first = int(self._fetch(out, span)[0])
-            token = self._emit(req, first)
-            if req.state == RequestState.RUNNING:
-                # The decode phase opens once and spans every tick until
-                # the terminal state (scheduler.finish/preempt closes it).
-                req.open_phase("decode")
-        return token
+            flight = _Flight(out, [(0, req)], tick=False)
+            self._dispatched(flight)
+            emitted = self._tick(turn) if turn is not None else []
+            if flight in self._flights:     # else the tick's drain had it
+                self._flights.remove(flight)
+                with _span("hvd.serve.prefill.fetch"):
+                    first = int(self._fetch(out, span)[0])
+                emitted.append((req, self._emit(req, first)))
+                if req.state == RequestState.RUNNING:
+                    # The decode phase opens once and spans every tick
+                    # until the terminal state (scheduler.finish/preempt
+                    # closes it).
+                    req.open_phase("decode")
+        return emitted
 
     def _prefill_cached(self, req: Request) -> int:
         """Prefix-hit prefill: the cached head's K/V is already in the
         pool under the shared table head, so only the ``P - C`` tail
         tokens run — through the multi-token extend step, attending over
-        the cached blocks via the request's table."""
+        the cached blocks via the request's table.  Its first token is
+        fetched at once and written into its row of the token vector (the
+        tick in flight holds no row of this request)."""
         jnp = self._jnp
         toks = req.prefill_tokens
         P = int(toks.shape[0])
@@ -603,31 +702,92 @@ class ServingEngine:
                 first = int(self._fetch(out, span)[0, S - 1])
             token = self._emit(req, first)
             if req.state == RequestState.RUNNING:
+                self._put_token(req, first)
                 req.open_phase("decode")
         return token
 
-    def _fetch(self, out, span, touched: bool = False):
-        """A step's tokens and stats in the one transfer the host makes.
-        Where the model has expert layers, their pairs by expert go onto
-        ``span`` (``moe_pairs``, and ``moe_combine``, the form in which
-        the program's grouped products came back to the rows, by the rule
-        the program itself goes by; with ``touched`` also how many of the
-        ``experts_held`` (layer, expert) pairs the step sent any row to)
-        and into the per-layer routing metrics."""
-        tok, stats = self._jax.device_get(out)
+    def _record(self, stats, span, touched: bool = False) -> None:
+        """A step's expert counts, where the model has expert layers:
+        into the per-layer routing metrics, and onto ``span`` (None: not
+        on a span) as ``moe_pairs`` and ``moe_combine``, the form in
+        which the program's grouped products came back to the rows, by
+        the rule the program itself goes by; with ``touched`` also how
+        many of the ``experts_held`` (layer, expert) pairs the step sent
+        any row to."""
         counts = stats.get("expert_counts")
-        if counts is not None:
-            scored = self.model.moe_experts_scored(self.cfg)
+        if counts is None:
+            return
+        scored = self.model.moe_experts_scored(self.cfg)
+        if span is not None:
             attrs = dict(moe_pairs=int(counts.sum()),
                          moe_combine=combine_form(counts.shape[-1], scored))
             if touched:
                 attrs.update(experts_touched=int(np.count_nonzero(counts)),
                              experts_held=counts.size)
             span.set_metadata(**attrs)
-            for layer, c in zip(self.model.moe_layer_names(self.cfg),
-                                counts):
-                record_held_pairs(c, layer=layer, scored=scored)
+        for layer, c in zip(self.model.moe_layer_names(self.cfg), counts):
+            record_held_pairs(c, layer=layer, scored=scored)
+
+    def _fetch(self, out, span, touched: bool = False):
+        """A step's tokens and stats in the one transfer the host makes;
+        the stats go where :meth:`_record` puts them."""
+        tok, stats = self._jax.device_get(out)
+        self._record(stats, span, touched)
         return tok
+
+    def _dispatched(self, flight: _Flight) -> None:
+        """Book ``flight`` as in flight.  A tick advances its rows'
+        contexts (it writes each row's position).  A request whose last
+        budgeted token is now on the device leaves the batch and frees
+        its blocks and row: no later tick writes for it, and the next
+        program that takes them runs after this one on the device."""
+        for _, r in flight.rows:
+            if flight.tick:
+                r.context_len += 1
+            # tokens made so far: the prefill's pick, then one a tick
+            if r.context_len - int(r.prompt.shape[0]) + 1 \
+                    >= r.max_new_tokens:
+                self._drop_slot(r)
+                self.scheduler.release(r)
+        self._flights.append(flight)
+
+    def _fetch_emit(self, flights: list, span) -> list[tuple[Request, int]]:
+        """Fetch ``flights`` (of those in flight) in one transfer and emit
+        their picks in the order they were dispatched (a prefill's pick
+        before its request's first tick's); a tick's stats go onto
+        ``span``.  A pick of a request that has stopped since its
+        dispatch is dropped."""
+        if not flights:
+            return []
+        self._flights = [f for f in self._flights if f not in flights]
+        with _span("hvd.serve.decode.fetch"):
+            got = self._jax.device_get([f.out for f in flights])
+            for f, (_, stats) in zip(flights, got):
+                self._record(stats, span if f.tick else None, touched=True)
+        emitted = []
+        with _span("hvd.serve.decode.emit"):
+            for f, (tok, _) in zip(flights, got):
+                n = len(emitted)
+                for i, r in f.rows:
+                    if r.state is not RequestState.RUNNING:
+                        _m_dropped_rows.inc()
+                        continue
+                    emitted.append((r, self._emit(r, int(tok[i]))))
+                    if not f.tick and r.state is RequestState.RUNNING:
+                        # The decode phase opens once and spans every
+                        # tick until the terminal state
+                        # (scheduler.finish/preempt closes it).
+                        r.open_phase("decode")
+                if f.tick:
+                    _m_decode_tokens.inc(len(emitted) - n)
+        return emitted
+
+    def _drain(self, reason: str, span) -> list[tuple[Request, int]]:
+        """Fetch and emit everything in flight, before a path that needs
+        every token on the host; counted under ``reason``."""
+        if self._flights:
+            _m_drains.labels(reason=reason).inc()
+        return self._fetch_emit(list(self._flights), span)
 
     def _count_table(self, tick, tables: np.ndarray, **more) -> None:
         """What one decode step's block table holds and how full the
@@ -648,15 +808,22 @@ class ServingEngine:
 
     def _decode_tick(self, tick) -> list[tuple[Request, int]]:
         """One decode step for the running set, under ``tick``, the
-        round's ``hvd.serve.decode`` profiler span."""
-        jnp = self._jnp
+        round's ``hvd.serve.decode`` profiler span: dispatched, and then
+        the turn before's tick fetched and emitted."""
+        emitted: list[tuple[Request, int]] = []
         with _span("hvd.serve.decode.grow"):
+            running = self.scheduler.running
+            if sum(self.pager.blocks_to_extend(r.req_id, r.context_len + 1)
+                   for r in running) > self.pager.free_blocks:
+                # growth may preempt, which folds a request's tokens
+                # into its prefill: have them all first
+                emitted = self._drain("preempt", tick)
             # Reserve the write position for every running request
             # first — growth can preempt, shrinking the running set.  A
             # preempted or failed request leaves RUNNING as it leaves the
             # set; asking the list instead compares Request's fields
             # against every earlier stream, O(streams^2) a tick.
-            for req in list(self.scheduler.running):
+            for req in list(running):
                 if req.state is RequestState.RUNNING:
                     try:
                         self.scheduler.grow(req)
@@ -668,21 +835,19 @@ class ServingEngine:
                         # the engine.
                         self.scheduler.fail_running(req, e)
             self._sync_slots()
-        active = [r for r in self._slots if r is not None]
-        if not active:
-            return []
+        # the tick before, still on the device unless a drain fetched it
+        behind = [f for f in self._flights if f.tick]
+        rows = [(i, r) for i, r in enumerate(self._slots) if r is not None]
+        if not rows:
+            return emitted + self._fetch_emit(behind, tick)
         with _span("hvd.serve.decode.tables"):
             R = self.ecfg.max_active
             need_cols = max(
-                self.cache.blocks_for(r.context_len + 1) for r in active)
+                self.cache.blocks_for(r.context_len + 1) for _, r in rows)
             n_cols = min(_bucket_pow2(need_cols), self.cache.num_blocks)
-            tok = np.zeros((R,), np.int32)
             pos = np.zeros((R,), np.int32)
             ids = [-1] * R
-            for i, r in enumerate(self._slots):
-                if r is None:
-                    continue
-                tok[i] = r.generated[-1]
+            for i, r in rows:
                 pos[i] = r.context_len
                 ids[i] = r.req_id
             tables = self.pager.table_matrix(ids, n_cols)
@@ -691,26 +856,21 @@ class ServingEngine:
             # state the tick reads and writes, and those bytes
             state = {}
             if self.slots:
-                state = dict(state_rows=len(active), state_bytes=2 * len(
-                    active) * self._state_row_bytes)
-                _m_state_writes.inc(len(active))
+                state = dict(state_rows=len(rows), state_bytes=2 * len(
+                    rows) * self._state_row_bytes)
+                _m_state_writes.inc(len(rows))
             self._count_table(tick, tables,
-                              kv_tokens=int(pos.sum()) + len(active),
-                              **state)
-            args = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables))
+                              kv_tokens=int(pos.sum()) + len(rows),
+                              ahead=int(bool(behind)), **state)
+            args = (self._jnp.asarray(pos), self._jnp.asarray(tables))
         with _span("hvd.serve.decode.dispatch"):
             out, self.pools, self.slots = self._decode(
-                self.params, self.pools, *args, self.slots)
-        with _span("hvd.serve.decode.fetch"):
-            nxt = self._fetch(out, tick, touched=True)
-        emitted = []
-        with _span("hvd.serve.decode.emit"):
-            for i, r in enumerate(list(self._slots)):
-                if r is None:
-                    continue
-                r.context_len += 1          # this tick wrote pos[i]
-                emitted.append((r, self._emit(r, int(nxt[i]))))
-        return emitted
+                self.params, self.pools, self.toks, *args, self.slots)
+            self.toks = out[0]
+            self._dispatched(_Flight(out, rows, tick=True))
+        if behind:
+            _m_decode_ahead.inc()
+        return emitted + self._fetch_emit(behind, tick)
 
     def _emit(self, req: Request, token: int) -> int:
         req.generated.append(token)
@@ -767,18 +927,34 @@ class ServingEngine:
         return migration.import_request(self, manifest, k_bytes, v_bytes,
                                         stream_cb=stream_cb)
 
-    def abort_inflight(self, exc: BaseException) -> list[Request]:
-        """Graceful-degradation half of a step failure: finish every
-        queued and running request NOW with ``finish_reason="error"``
-        (partial tokens preserved — streamed clients already hold
-        them), release their pool blocks, and leave the engine empty
-        and reusable.  Returns the aborted requests; the session layer
-        resolves their futures and owns the /healthz + rejoin story."""
+    def abort_inflight(self, exc: BaseException
+                       ) -> tuple[list[tuple[Request, int]], list[Request]]:
+        """Graceful-degradation half of a step failure: fetch and emit
+        the tokens in flight (a request whose last one was among them
+        finishes as it would have; where the device failed too, they are
+        lost), then finish every queued and running request NOW with
+        ``finish_reason="error"`` (partial tokens preserved — streamed
+        clients already hold them), release their pool blocks, and leave
+        the engine empty and reusable.  Returns ``(emitted, aborted)``:
+        the emissions to deliver first and the aborted requests; the
+        session layer resolves their futures and owns the /healthz +
+        rejoin story."""
+        # a request whose last token is in flight has left the running
+        # set and its blocks already
+        flying = {id(r): r for f in self._flights for _, r in f.rows}
+        try:
+            emitted = self._drain("abort", None)
+        except Exception as e:  # the device failed with the step
+            log.warning("serving: the tokens in flight are lost (%s: %s)",
+                        type(e).__name__, e, exc_info=True)
+            emitted, self._flights = [], []
         aborted: list[Request] = []
         for req in list(self.scheduler.running):
             self.scheduler.running.remove(req)
             self.pager.release(req.req_id)
             aborted.append(req)
+        aborted += [r for r in flying.values()
+                    if r.released and r.state is RequestState.RUNNING]
         while self.scheduler.waiting:
             aborted.append(self.scheduler.waiting.popleft())
         for req in aborted:
@@ -788,4 +964,4 @@ class ServingEngine:
             req.close_trace("aborted", error=str(exc))
         self._slots = [None] * self.ecfg.max_active
         self._sample_gauges()
-        return aborted
+        return emitted, aborted

@@ -195,6 +195,11 @@ class KVPager:
         table.extend(self._take(need))
         return list(table)
 
+    def blocks_to_extend(self, req_id: int, n_tokens: int) -> int:
+        """Free blocks that ``extend(req_id, n_tokens)`` would take."""
+        return max(0, self.cache.blocks_for(n_tokens)
+                   - len(self._tables[req_id]))
+
     def truncate(self, req_id: int, n_tokens: int) -> list[int]:
         """Shrink ``req_id``'s table to the blocks covering ``n_tokens``
         positions, releasing the tail (speculative-decode rollback: the

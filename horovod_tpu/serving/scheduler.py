@@ -76,6 +76,10 @@ class Request:
     #: preemption — re-admission re-matches
     cached_tokens: int = 0
     preemptions: int = 0
+    #: its last budgeted token is dispatched: it left the decode batch and
+    #: its blocks then (:meth:`Scheduler.release`), and finishes when that
+    #: token is fetched
+    released: bool = False
     # metrics timestamps (time.monotonic)
     t_submit: float = 0.0
     t_admitted: Optional[float] = None
@@ -206,11 +210,21 @@ class Scheduler:
         return bool(self.waiting or self.running)
 
     # -- per-step phases -------------------------------------------------
+    def release(self, req: Request) -> None:
+        """Take a running request out of the decode batch and free its
+        blocks ahead of its finish: its last token is on the device, so
+        no tick writes for it again.  It stays RUNNING until
+        :meth:`finish`, when that token has been fetched."""
+        self.running.remove(req)
+        self.pager.release(req.req_id)
+        req.released = True
+
     def finish(self, req: Request) -> None:
         req.state = RequestState.FINISHED
         req.t_finished = self._clock()
-        self.running.remove(req)
-        self.pager.release(req.req_id)
+        if not req.released:
+            self.running.remove(req)
+            self.pager.release(req.req_id)
         m = req.metrics()
         req.close_trace("finished",
                         ttft_s=m["ttft_s"],

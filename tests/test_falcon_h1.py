@@ -224,11 +224,13 @@ def test_bucketed_prefill_leaves_the_exact_prefills_row(params):
 
 # -- (3) the engine against the reference -----------------------------------------
 
-def _serve(params, engine_cfg=ENGINE, prompts=PROMPTS, new=NEW):
+def _serve(params, engine_cfg=ENGINE, prompts=PROMPTS, new=NEW,
+           ticks=None):
     """The prompts through a ``ServingEngine`` whose prefill and decode
     programs hand back their logits: ``(engine, requests, served)`` with
     ``served[req_id][position]`` the logits the token after ``position``
-    was picked from, the head's multiplier in."""
+    was picked from, the head's multiplier in; ``ticks`` counts the
+    decode dispatches."""
     eng = ServingEngine(params, CFG, engine_cfg=engine_cfg)
     served: dict = {}
     prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
@@ -249,6 +251,8 @@ def _serve(params, engine_cfg=ENGINE, prompts=PROMPTS, new=NEW):
         return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
 
     def spy_decode(p, pools, tok, pos, tables, slots):
+        if ticks is not None:
+            ticks.append(1)
         logits, pools, slots, stats = decode(p, pools, tok, pos, tables,
                                              slots)
         for i, r in enumerate(eng._slots):
@@ -291,6 +295,25 @@ def test_served_logits_match_the_reference(params, use_flash):
     assert [s.shape for s in eng.slots] == [(2, 2, 4, 8, 16), (2, 2, 3, 96)]
     assert max(r.req_id for r in reqs) == 2
     _check_against_reference(reqs, served)
+
+
+@pytest.mark.parametrize("max_active", [1, 3])
+def test_the_tick_runs_ahead_over_the_state_slots(params, max_active):
+    """One tick in flight over the state slots: a request whose last
+    token is in flight gives its row up, and the next request's prefill
+    writes that row's state while the tick still runs; every tick but the
+    first is dispatched ahead and the served logits are the
+    reference's."""
+    ticks: list = []
+    before = REGISTRY.get("hvd_serving_decode_ahead_total").value
+    eng, reqs, served = _serve(params, dataclasses.replace(
+        ENGINE, max_active=max_active), ticks=ticks)
+    assert REGISTRY.get("hvd_serving_decode_ahead_total").value - before \
+        == len(ticks) - 1
+    assert all(len(r.generated) == NEW for r in reqs)
+    _check_against_reference(reqs, served)
+    assert not eng.has_work()
+    eng.pager.check_invariants()
 
 
 def test_a_preempted_request_is_prefilled_again_and_agrees(params):

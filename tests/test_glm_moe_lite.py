@@ -127,11 +127,11 @@ def test_compare_picks_judges_clean_places_only():
 
 # -- (2) prefill then decode through the engine ------------------------------------
 
-def _serve(params, engine_cfg=ENGINE):
+def _serve(params, engine_cfg=ENGINE, ticks=None):
     """The prompts through a ``ServingEngine`` whose prefill and decode
     programs hand back their logits: ``(engine, requests, served)`` with
     ``served[req_id][position]`` the logits the token after ``position``
-    was picked from."""
+    was picked from; ``ticks`` counts the decode dispatches."""
     eng = ServingEngine(params, CFG, engine_cfg=engine_cfg)
     served: dict = {}
     prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
@@ -151,6 +151,8 @@ def _serve(params, engine_cfg=ENGINE):
         return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
 
     def spy_decode(p, pools, tok, pos, tables, slots):
+        if ticks is not None:
+            ticks.append(1)
         logits, pools, slots, stats = decode(p, pools, tok, pos, tables,
                                              slots)
         for i, r in enumerate(eng._slots):
@@ -185,6 +187,32 @@ def test_served_logits_match_the_reference(params, use_flash):
             np.testing.assert_allclose(served[r.req_id][P - 1 + j],
                                        want[P - 1 + j], atol=TOL)
     eng.pager.check_invariants()
+
+
+def test_the_tick_ahead_records_each_steps_pairs_once(params):
+    """The expert counts come with each program's tokens, one turn
+    after its dispatch: every prefill and tick is recorded once, under
+    the list form (every scored expert held), with every row of its
+    bucket or of the batch routed to k experts in each expert layer; the
+    tokens are the reference's."""
+    held = REGISTRY.get("hvd_moe_held_pairs_total")
+    combine = REGISTRY.get("hvd_moe_combine_steps_total")
+    layers_ = ("2", "3")
+    pairs0 = [held.labels(layer=l).value for l in layers_]
+    steps0 = [combine.labels(layer=l, form="list").value for l in layers_]
+    ticks: list = []
+    eng, reqs, served = _serve(params, ticks=ticks)
+    steps = len(PROMPTS) + len(ticks)
+    assert [combine.labels(layer=l, form="list").value - b
+            for l, b in zip(layers_, steps0)] == [steps] * 2
+    rows = sum((8, 16, 16, 8)) + len(ticks) * ENGINE.max_active
+    assert [held.labels(layer=l).value - b
+            for l, b in zip(layers_, pairs0)] == [rows * 2] * 2
+    for r in reqs:
+        seq = np.concatenate([r.prompt, np.asarray(r.generated, np.int32)])
+        want, _, _ = _reference_logits(seq)
+        assert r.generated == [int(want[len(r.prompt) - 1 + j].argmax())
+                               for j in range(NEW)]
 
 
 def test_a_planted_fault_is_over_the_tolerance(params, monkeypatch):

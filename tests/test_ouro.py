@@ -261,7 +261,10 @@ def test_cache_depth_follows_loops_times_layers(params):
     req = eng.submit(PROMPTS[1], NEW)
     eng.step()
     manifest, k_bytes, v_bytes = migration.export_request(eng, req)
-    nb = eng.cache.blocks_for(req.context_len)
+    # the prompt and every fetched token but the last: the tick that
+    # writes that one is still in flight
+    assert manifest["context_len"] == req.context_len - 1
+    nb = eng.cache.blocks_for(req.context_len - 1)
     assert manifest["n_layers"] == depth
     assert len(k_bytes) == len(v_bytes) == depth * nb * 4 * 4 * 16 * 4
     eng.run()

@@ -21,7 +21,7 @@ from horovod_tpu.models import llama
 from horovod_tpu.parallel import MeshConfig, build_mesh
 from horovod_tpu.serving.kv_pager import (KVPager, OutOfBlocks,
                                           PagedKVCache, gather_blocks)
-from horovod_tpu.serving.scheduler import Request, Scheduler
+from horovod_tpu.serving.scheduler import Request, RequestState, Scheduler
 
 
 @pytest.fixture(scope="module")
@@ -639,3 +639,167 @@ def test_eos_token_stops_early(tiny):
     res = fut.result()
     assert res.tokens == list(ref[len(prompt):len(prompt) + 3]), \
         "generation must stop AT the eos token"
+
+
+# ---------------------------------------------------------------------------
+# one tick in flight: each turn dispatches its tick before it fetches the
+# last turn's, and drains where growth may preempt and on an abort
+# ---------------------------------------------------------------------------
+
+def _counter(name, **labels):
+    from horovod_tpu.obs import REGISTRY
+    m = REGISTRY.get(name)
+    return (m.labels(**labels) if labels else m).value
+
+
+_DRAINS = ("preempt", "abort")
+
+
+def _lookahead_counts():
+    return dict(ahead=_counter("hvd_serving_decode_ahead_total"),
+                dropped=_counter("hvd_serving_lookahead_dropped_rows_total"),
+                **{r: _counter("hvd_serving_lookahead_drains_total",
+                               reason=r) for r in _DRAINS})
+
+
+def _counting_ticks(eng):
+    """``eng``'s decode program, counting its dispatches."""
+    ticks, real = [], eng._decode
+    eng._decode = lambda *a: ticks.append(1) or real(*a)
+    return ticks
+
+
+@pytest.mark.parametrize("max_active", [1, 2, 5])
+def test_ticks_run_ahead_and_keep_generates_tokens(tiny, max_active):
+    """Mixed prompts and budgets over 1, 2 and 5 rows: every tick but
+    the first is dispatched with the one before still in flight, nothing
+    drains, and the tokens are generate()'s; a request whose last token
+    is in flight hands its row to the next at once."""
+    cfg, params = tiny
+    rng = np.random.RandomState(12)
+    lens, mx = [5, 11, 3, 16, 9, 7], [4, 7, 2, 3, 6, 5]
+    prompts = _prompts(rng, lens)
+    sess = serving.serve(params, cfg, block_size=4, num_blocks=64,
+                         max_active=max_active)
+    ticks = _counting_ticks(sess.engine)
+    before = _lookahead_counts()
+    futs = [sess.submit(p, m) for p, m in zip(prompts, mx)]
+    sess.drain()
+    got = {k: v - before[k] for k, v in _lookahead_counts().items()}
+    assert got == dict(ahead=len(ticks) - 1, dropped=0,
+                       **{r: 0 for r in _DRAINS})
+    for p, m, f in zip(prompts, mx, futs):
+        assert list(f.result().full_sequence) == list(
+            _generate_oracle(params, cfg, p, m))
+    assert not sess.engine.has_work()
+    sess.engine.pager.check_invariants()
+
+
+def test_a_last_token_in_flight_gives_up_the_row_at_dispatch(tiny):
+    """A budget of two: the first tick holds the request's last token,
+    so the request leaves the batch, its blocks and its row as that tick
+    is dispatched, the next turn prefills another request into the row
+    while the tick still runs, and the request finishes when that turn
+    fetches the tick."""
+    cfg, params = tiny
+    rng = np.random.RandomState(13)
+    eng = serving.ServingEngine(params, cfg, engine_cfg=serving.EngineConfig(
+        block_size=4, num_blocks=16, max_active=1))
+    a, b = (eng.submit(p, m) for p, m in zip(_prompts(rng, [6, 4]),
+                                               [2, 3]))
+    emitted = eng.step()
+    assert [(r.req_id, t) for r, t in emitted] == [(0, a.generated[0])]
+    assert eng.scheduler.running == [] and eng._slots == [None]
+    assert eng.pager.free_blocks == 15 and a.released
+    assert a.state is RequestState.RUNNING and eng.has_work()
+    emitted = eng.step()                 # b prefilled into a's row
+    assert [(r.req_id, t) for r, t in emitted] == [
+        (0, a.generated[1]), (1, b.generated[0])]
+    assert a.finish_reason == "length" and eng._slots == [b]
+    eng.run()
+    for r in (a, b):
+        assert list(r.prompt) + r.generated == list(
+            _generate_oracle(params, cfg, r.prompt, r.max_new_tokens))
+
+
+def test_preemption_drains_first_and_keeps_the_tokens(tiny):
+    """A pool that forces preemptions: each ``grow`` that may preempt
+    first fetches the tick in flight (its tokens fold into the
+    re-prefill), the re-prefilled outputs are generate()'s, and every
+    tick but the first and those after a drain ran ahead."""
+    cfg, params = tiny
+    rng = np.random.RandomState(3)
+    prompts = _prompts(rng, [6, 6, 6])
+    sess = serving.serve(params, cfg, block_size=2, num_blocks=12,
+                         max_active=3)
+    ticks = _counting_ticks(sess.engine)
+    before = _lookahead_counts()
+    futs = [sess.submit(p, 10) for p in prompts]
+    sess.drain()
+    got = {k: v - before[k] for k, v in _lookahead_counts().items()}
+    assert got["preempt"] > 0
+    assert got["ahead"] == len(ticks) - 1 - got["preempt"]
+    assert sum(f.result().metrics["preemptions"] for f in futs) > 0
+    for p, f in zip(prompts, futs):
+        assert list(f.result().full_sequence) == list(
+            _generate_oracle(params, cfg, p, 10))
+
+
+def test_a_pick_past_eos_is_dropped(tiny):
+    """The tick after the one that picked EOS is already dispatched when
+    EOS is fetched: its pick for that row is counted and dropped, never
+    emitted or streamed, and the other row's stream goes on unchanged."""
+    cfg, params = tiny
+    rng = np.random.RandomState(9)
+    prompts = _prompts(rng, [6, 5])
+    ref = [_generate_oracle(params, cfg, p, 8) for p in prompts]
+    eos = int(ref[0][len(prompts[0]) + 2])          # its 3rd token
+    assert eos not in list(ref[0][len(prompts[0]):len(prompts[0]) + 2])
+    events: list = []
+    sess = serving.serve(params, cfg, block_size=4, num_blocks=64,
+                         max_active=2)
+    before = _counter("hvd_serving_lookahead_dropped_rows_total")
+    futs = [sess.submit(p, 8, eos_token=eos if i == 0 else None,
+                        stream_cb=lambda rid, t: events.append((rid, t)))
+            for i, p in enumerate(prompts)]
+    sess.drain()
+    stopped, other = (f.result() for f in futs)
+    assert stopped.tokens == list(ref[0][len(prompts[0]):][:3])
+    assert stopped.metrics["finish_reason"] == "stop"
+    assert other.tokens == list(ref[1][len(prompts[1]):])
+    for res in (stopped, other):
+        assert [t for rid, t in events if rid == res.req_id] == res.tokens
+    assert _counter("hvd_serving_lookahead_dropped_rows_total") - before \
+        == 1
+
+
+def test_abort_fetches_the_tick_in_flight_first(tiny):
+    """``abort_inflight`` with a tick in flight: its tokens are emitted
+    first (a request whose last token was among them finishes by its
+    length), the rest end as errors with every token they had, and the
+    engine serves again from empty."""
+    cfg, params = tiny
+    rng = np.random.RandomState(14)
+    prompts = _prompts(rng, [5, 7, 4])
+    eng = serving.ServingEngine(params, cfg, engine_cfg=serving.EngineConfig(
+        block_size=4, num_blocks=32, max_active=2))
+    a, b = (eng.submit(p, m) for p, m in zip(prompts, [2, 8]))
+    first = eng.step()                    # a's last token is in tick 1
+    assert [(r.req_id, t) for r, t in first] == [
+        (0, a.generated[0]), (1, b.generated[0])]
+    before = _counter("hvd_serving_lookahead_drains_total", reason="abort")
+    emitted, aborted = eng.abort_inflight(RuntimeError("device lost"))
+    assert [(r.req_id, t) for r, t in emitted] == [
+        (0, a.generated[1]), (1, b.generated[1])]
+    assert aborted == [b] and b.finish_reason == "error"
+    assert a.finish_reason == "length"
+    for r in (a, b):
+        assert list(r.prompt) + r.generated == list(
+            _generate_oracle(params, cfg, r.prompt, 2))
+    assert _counter("hvd_serving_lookahead_drains_total",
+                    reason="abort") - before == 1
+    assert not eng.has_work() and eng.pager.free_blocks == 31
+    c = eng.submit(prompts[2], 3)
+    eng.run()
+    assert list(c.prompt) + c.generated == list(
+        _generate_oracle(params, cfg, prompts[2], 3))

@@ -43,6 +43,12 @@ PARENT = {
     "hvd.serve.decode.emit": "hvd.serve.decode",
     "hvd.serve.deliver": None,
 }
+#: a turn's last prefill holds its tick, dispatched before the prefill's
+#: token is fetched; the turn after the last tick fetches it with no tick
+#: of its own
+ALSO_UNDER = {"hvd.serve.decode": "hvd.serve.prefill",
+              "hvd.serve.decode.fetch": "hvd.serve.step",
+              "hvd.serve.decode.emit": "hvd.serve.step"}
 COUNTERS = ("hvd_serving_decode_table_slots_total",
             "hvd_serving_decode_table_blocks_total",
             "hvd_serving_decode_idle_rows_total")
@@ -51,7 +57,7 @@ DEPTH = {"loops", "cache_layers"}            # the model's, on both (PR 33)
 ATTRS = {"hvd.serve.step": {"step"},
          "hvd.serve.prefill": {"req", "tokens", "cached", "resumed"} | DEPTH,
          "hvd.serve.decode": {"n_cols", "blocks", "rows", "blocks_held",
-                              "blocks_usable", "kv_tokens"} | DEPTH}
+                              "blocks_usable", "kv_tokens", "ahead"} | DEPTH}
 
 
 @pytest.fixture(scope="module")
@@ -107,6 +113,7 @@ def test_spans_attributes_and_counters_of_a_traced_run(tiny, use_flash,
     engine.pager.table_matrix = table_matrix
 
     before = [REGISTRY.get(c).value for c in COUNTERS]
+    ahead_before = REGISTRY.get("hvd_serving_decode_ahead_total").value
     lens = (5, 9, 13, 7, 11, 6)               # 6 requests, 4 slots
     with jax.profiler.trace(str(tmp_path)):
         futs = [submit(n) for n in lens]
@@ -121,10 +128,12 @@ def test_spans_attributes_and_counters_of_a_traced_run(tiny, use_flash,
     assert set(by_name) == set(PARENT)
     assert all(set(s[3]) == ATTRS.get(s[0], set()) for s in spans)
     for name, parent in PARENT.items():
+        parents = [parent] + ([ALSO_UNDER[name]] if name in ALSO_UNDER
+                              else [])
         for _, t0, t1, _ in by_name[name]:
             if parent is not None:
-                assert any(p0 <= t0 and t1 <= p1
-                           for _, p0, p1, _ in by_name[parent]), name
+                assert any(p0 <= t0 and t1 <= p1 for p in parents
+                           for _, p0, p1, _ in by_name[p]), name
     # a turn is one step and one deliver, and the deliver comes after it
     steps, delivers = by_name["hvd.serve.step"], by_name["hvd.serve.deliver"]
     assert len(steps) == len(delivers)
@@ -140,6 +149,11 @@ def test_spans_attributes_and_counters_of_a_traced_run(tiny, use_flash,
 
     decodes = by_name["hvd.serve.decode"]
     assert len(decodes) == len(ticks) > 0
+    # every tick but the first is dispatched with the one before in flight
+    assert [d[3].pop("ahead") for d in decodes] == [0] + [1] * (
+        len(decodes) - 1)
+    assert REGISTRY.get("hvd_serving_decode_ahead_total").value \
+        - ahead_before == len(decodes) - 1
     assert [d[3] for d in decodes] == ticks
 
     after = [REGISTRY.get(c).value for c in COUNTERS]
@@ -204,13 +218,18 @@ def test_expert_layers_counts_reach_the_spans_and_the_metrics(tmp_path):
     spans = _hvd_spans(tmp_path)
     prefills = [s[3] for s in spans if s[0] == "hvd.serve.prefill"]
     decodes = [s[3] for s in spans if s[0] == "hvd.serve.decode"]
+    # a tick's counts come with its tokens, onto the span of the turn
+    # that fetches them: the next tick's, or the last turn's own
+    fetched = [s[3] for s in spans if "experts_touched" in s[3]]
     assert set(prefills[0]) == ATTRS["hvd.serve.prefill"] | {
         "moe_pairs", "moe_combine"}
-    assert set(decodes[0]) == ATTRS["hvd.serve.decode"] | {
+    assert set(decodes[0]) == ATTRS["hvd.serve.decode"]
+    assert set(decodes[1]) == ATTRS["hvd.serve.decode"] | {
         "moe_pairs", "moe_combine", "experts_touched", "experts_held"}
+    assert len(fetched) == len(decodes) and fetched[:-1] == decodes[1:]
     # every scored expert is held, so the results come back through the
     # list, and the steps are counted under that form alone
-    assert {s["moe_combine"] for s in prefills + decodes} == {"list"}
+    assert {s["moe_combine"] for s in prefills + fetched} == {"list"}
     steps = [combine.labels(layer="3", form=f).value - b
              for f, b in zip(("list", "add"), steps_before)]
     assert steps == [len(prefills) + len(decodes), 0]
@@ -218,9 +237,9 @@ def test_expert_layers_counts_reach_the_spans_and_the_metrics(tmp_path):
     assert [p["moe_pairs"] for p in prefills] == [
         2 * 2 * b for b in (8, 16, 16)]
     assert all(d["moe_pairs"] == 2 * 2 * 4 and d["experts_held"] == 2 * 8
-               and 2 <= d["experts_touched"] <= 16 for d in decodes)
+               and 2 <= d["experts_touched"] <= 16 for d in fetched)
     assert all(d["kv_tokens"] >= d["rows"] for d in decodes)
-    pairs = sum(s["moe_pairs"] for s in prefills + decodes)
+    pairs = sum(s["moe_pairs"] for s in prefills + fetched)
     after = [held.labels(layer=l).value for l in ("2", "3")]
     assert [b - a for a, b in zip(before, after)] == [pairs / 2] * 2
     load = REGISTRY.get("hvd_moe_expert_load_max_over_mean")
