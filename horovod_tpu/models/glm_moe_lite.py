@@ -52,6 +52,7 @@ from ..obs.trace import region
 from ..ops import flash_attention as FA
 from ..parallel.moe import moe_layer_held
 from .layers import (
+    Yarn,
     attention,
     attention_path,
     block,
@@ -93,6 +94,8 @@ class GlmMoeLiteConfig:
     rope_theta: float = 1e6
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    #: the rope columns' scaling (None: as published here, plain)
+    yarn: Optional[Yarn] = None
 
     #: a plain stack: one pass, a cache layer a layer
     loops = 1
@@ -117,6 +120,13 @@ class GlmMoeLiteConfig:
     @property
     def qk_dim(self) -> int:
         return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        """``qk_dim ** -0.5``, times YaRN's factor where the rope is
+        scaled."""
+        scale = self.qk_dim ** -0.5
+        return scale * self.yarn.attention_factor if self.yarn else scale
 
     @staticmethod
     def from_published(c: dict, **kw) -> "GlmMoeLiteConfig":
@@ -373,7 +383,7 @@ def serve_embed(embed, tokens, cfg: GlmMoeLiteConfig):
 def serve_runs(params: dict, cfg: GlmMoeLiteConfig, positions, mesh) -> list:
     """``[(stacked leaves, attend -> mixer, mlp)]``, a run of one layer
     kind each, for :func:`horovod_tpu.models.llama._serve_layers`."""
-    tables = rope_tables(positions, cfg.rope_theta, cfg.qk_rope_dim)
+    tables = rope_tables(positions, cfg.rope_theta, cfg.qk_rope_dim, cfg.yarn)
     runs = []
     for (kind, n), stack in zip(layer_runs(cfg), params["runs"]):
         kw = {}
@@ -421,8 +431,8 @@ def prefill_attend(cfg: GlmMoeLiteConfig, mesh, P: int, last_pos=None):
 
     def attend(q, c, k_pe, w_kvb, li, state):
         k, v = mla_expand(c, k_pe, w_kvb, cfg.qk_nope_dim)
-        return attention(q, k, v, mesh, True), (state,
-                                                (mla_row(c, k_pe, W),))
+        return attention(q, k, v, mesh, True, scale=cfg.softmax_scale), (
+            state, (mla_row(c, k_pe, W),))
     return attend
 
 
@@ -450,7 +460,7 @@ def paged_attend(cfg: GlmMoeLiteConfig, mesh, tables, blk, off, mask,
     query a row), else through the contiguous gather under ``mask``; the
     result goes through the value up-projection."""
     C, nope, W = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.cache_row
-    scale = cfg.qk_dim ** -0.5
+    scale = cfg.softmax_scale
     lengths = None if last is None else jnp.where(
         tables[:, 0] != 0, last + 1, 0)
 

@@ -2,8 +2,11 @@
 
 A decoder layer is :func:`block`: ``h + mixer(norm(h))`` then ``h +
 mlp(norm(h))``, each branch normed once more before it is added where the
-layer has the gains for it (sandwich norm).  A stack whose weights are
-used several times over is :func:`looped`.  A layer *kind* is a ``(mixer,
+layer has the gains for it (sandwich norm).  A layer whose residual is
+several streams mixed by per-token maps (manifold-constrained
+hyper-connections) goes through :func:`hc_block` instead, with the same
+``(mixer, mlp)`` pair.  A stack whose weights are used several times
+over is :func:`looped`.  A layer *kind* is a ``(mixer,
 mlp)`` pair of callables, each ``(x, lp) -> (y, extra)``; what differs
 between training, prefill and a paged decode tick of one model is passed
 in, never branched on:
@@ -26,6 +29,8 @@ Nothing here imports a model, ``serving`` or ``context``.
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from functools import lru_cache, partial
 from typing import Optional
 
@@ -95,15 +100,73 @@ def _rmsnorm_bwd(eps, res, dy):
 rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
 
 
-def rope_tables(positions: jax.Array, theta: float, head_dim: int
+def _yarn_mscale(factor: float, m: float) -> float:
+    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's scaled rotary embedding (arXiv:2309.00071) in DeepSeek-V3's
+    form, the published ``rope_scaling`` of ``type: yarn``: the
+    frequencies that turn fewer than ``beta_slow`` times over the
+    original context are divided by ``factor``, those that turn more than
+    ``beta_fast`` times are kept, a linear ramp between; the softmax scale
+    gains ``mscale(factor, mscale_all_dim)**2``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def correction_range(self, theta: float, dim: int) -> tuple:
+        """The first and last frequency index of the ramp (floor and
+        ceil of the dimension at which ``beta`` turns fit the original
+        context)."""
+        at = lambda turns: dim * math.log(
+            self.original_max_position_embeddings / (turns * 2 * math.pi)
+        ) / (2 * math.log(theta))
+        return (max(math.floor(at(self.beta_fast)), 0),
+                min(math.ceil(at(self.beta_slow)), dim - 1))
+
+    def inv_freq(self, theta: float, dim: int) -> np.ndarray:
+        """The ``dim // 2`` rotary frequencies, float64."""
+        base = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        lo, hi = self.correction_range(theta, dim)
+        ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3),
+                       0.0, 1.0)
+        return base / self.factor * ramp + base * (1.0 - ramp)
+
+    @property
+    def table_factor(self) -> float:
+        """What cos and sin are multiplied by."""
+        return _yarn_mscale(self.factor, self.mscale) / _yarn_mscale(
+            self.factor, self.mscale_all_dim)
+
+    @property
+    def attention_factor(self) -> float:
+        """What the softmax scale is multiplied by."""
+        return _yarn_mscale(self.factor, self.mscale_all_dim) ** 2 \
+            if self.mscale_all_dim else 1.0
+
+
+def rope_tables(positions: jax.Array, theta: float, head_dim: int,
+                yarn: Optional[Yarn] = None
                 ) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables [B, S, half] for these positions.  Computed once per
     forward and threaded through the layer scan as loop invariants rather
-    than re-deriving the transcendentals per layer."""
+    than re-deriving the transcendentals per layer.  ``yarn``: the
+    frequencies and the tables' factor of :class:`Yarn`."""
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn.inv_freq(theta, head_dim), jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,half]
-    return jnp.cos(angles), jnp.sin(angles)
+    if yarn is None or yarn.table_factor == 1.0:
+        return jnp.cos(angles), jnp.sin(angles)
+    return (jnp.cos(angles) * yarn.table_factor,
+            jnp.sin(angles) * yarn.table_factor)
 
 
 def rope(x: jax.Array, tables: tuple[jax.Array, jax.Array]) -> jax.Array:
@@ -192,6 +255,104 @@ def block(h, lp, mixer, mlp, eps: float = 1e-5):
         return h + y, kept, aux
 
 
+@dataclasses.dataclass(frozen=True)
+class HyperConnections:
+    """The constants of manifold-constrained hyper-connections (mHC,
+    arXiv:2512.24880): a residual of ``streams`` copies, mixed by a matrix
+    that ``sinkhorn_iters`` rounds of Sinkhorn-Knopp bring near the doubly
+    stochastic ones, ``eps`` in the stream's norm and in each divisor,
+    the residual map's logits clamped to ``clamp``."""
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp: tuple = (-30.0, 30.0)
+
+
+def sinkhorn(m, iters: int, eps: float):
+    """``iters`` rounds of dividing each row of ``m [..., n, n]`` by its
+    sum, then each column by its sum (``+ eps`` in each divisor).  A
+    loop, not the rounds written out: written out, XLA fuses the whole
+    chain into the streams' mixing that consumes it, and the compile of
+    one sub-layer at 1,024 tokens took over five minutes (a v5e target,
+    ahead of time); the loop's body is two small fusions."""
+    def round_(_, m):
+        m = m / (m.sum(-1, keepdims=True) + eps)
+        return m / (m.sum(-2, keepdims=True) + eps)
+    return jax.lax.fori_loop(0, iters, round_, m)
+
+
+def _hc_logits(X, phi, eps: float):
+    """``x~ phi`` in float32: ``x~`` a token's streams ``X [..., n, D]``
+    flattened and divided by their root mean square (no gain)."""
+    x = X.astype(jnp.float32).reshape(X.shape[:-2] + (-1,))
+    r = jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+    return jnp.einsum("...k,km->...m", x, phi,
+                      precision=jax.lax.Precision.HIGHEST) * r
+
+
+def _hc_read(X, weights):
+    """``sum_i weights_i X_i`` ``[..., D]`` in float32."""
+    return sum(weights[..., i, None] * X[..., i, :].astype(jnp.float32)
+               for i in range(X.shape[-2]))
+
+
+def hc_maps(X, phi, bias, alpha, hc: HyperConnections):
+    """A sub-layer's maps from the streams ``X [..., n, D]``: ``(pre
+    [..., n], post [..., n], res [..., n, n])``, float32.  ``h = x~ phi``
+    splits into ``n``, ``n`` and ``n * n`` columns; ``pre =
+    sigmoid(alpha_0 h + b)``, ``post = 2 sigmoid(alpha_1 h + b)``, ``res =
+    sinkhorn(exp(clamp(alpha_2 h + b)))``, row-major."""
+    n = hc.streams
+    h = _hc_logits(X, phi, hc.eps)
+    pre = jax.nn.sigmoid(alpha[0] * h[..., :n] + bias[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * h[..., n:2 * n] + bias[n:2 * n])
+    res = jnp.exp(jnp.clip(alpha[2] * h[..., 2 * n:] + bias[2 * n:],
+                           *hc.clamp))
+    return pre, post, sinkhorn(res.reshape(res.shape[:-1] + (n, n)),
+                               hc.sinkhorn_iters, hc.eps)
+
+
+def _hc_branch(X, lp, name: str, norm: str, f, eps: float,
+               hc: HyperConnections):
+    """One sub-layer ``f`` in the stream frame: ``X <- res X + post^T
+    f(norm(pre X))``; the maps and the mixing are the region ``hvd.hc``."""
+    with region("hc"):
+        pre, post, res = hc_maps(X, lp[name + "_phi"], lp[name + "_bias"],
+                                 lp[name + "_alpha"], hc)
+        u = _hc_read(X, pre).astype(X.dtype)
+    y, extra = f(rmsnorm(u, lp[norm], eps), lp)
+    with region("hc"):
+        y = y.astype(jnp.float32)
+        X = jnp.stack([_hc_read(X, res[..., i, :]) + post[..., i, None] * y
+                       for i in range(hc.streams)], -2).astype(X.dtype)
+    return X, extra
+
+
+def hc_block(X, lp, mixer, mlp, eps: float, hc: HyperConnections):
+    """The frame of a layer with hyper-connections: the residual is
+    ``X [..., n, D]``, ``n`` streams, and each of the two sub-layers reads
+    a mix of them and writes into every one (:func:`hc_maps`; the
+    leaves ``hc_attn_*`` and ``hc_mlp_*``, ``phi [n D, n^2 + 2n]``, ``bias
+    [n^2 + 2n]``, ``alpha [3]``).  ``mixer`` and ``mlp`` as
+    :func:`block`'s, and so is what it returns; the halves are the
+    regions ``hvd.block.mixer`` and ``hvd.block.mlp``, the maps and the
+    mixing ``hvd.hc`` inside each."""
+    with region("block.mixer"):
+        X, kept = _hc_branch(X, lp, "hc_attn", "attn_norm", mixer, eps, hc)
+    with region("block.mlp"):
+        X, aux = _hc_branch(X, lp, "hc_mlp", "mlp_norm", mlp, eps, hc)
+        return X, kept, aux
+
+
+def hc_readout(X, phi, bias, alpha, hc: HyperConnections):
+    """The one stream the head reads after the last layer, ``sum_i
+    sigmoid(alpha x~ phi + b)_i X_i`` (``phi [n D, n]``), in ``X``'s type;
+    the region ``hvd.hc``."""
+    with region("hc"):
+        w = jax.nn.sigmoid(alpha[0] * _hc_logits(X, phi, hc.eps) + bias)
+        return _hc_read(X, w).astype(X.dtype)
+
+
 def looped(layer, carry, stacked, loops: int, renorm, xs=None,
            unroll: int = 1):
     """The scan of a stack run ``loops`` times over the same weights (a
@@ -274,13 +435,14 @@ def mla_expand(c, k_pe, w_kvb, nope: int):
     return jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
 
 
-def mla_expanded_attend(nope: int, mesh):
+def mla_expanded_attend(nope: int, mesh, scale: Optional[float] = None):
     """The ``attend`` of :func:`mla_mixer` in the expanded form, for a
     whole sequence (training, the tests' forward): every head's keys and
-    values through the attention dispatch (the flash kernels)."""
+    values through the attention dispatch (the flash kernels), at the
+    softmax ``scale`` (None: the key width's)."""
     def attend(q, c, k_pe, w_kvb):
         k, v = mla_expand(c, k_pe, w_kvb, nope)
-        return attention(q, k, v, mesh, True), None
+        return attention(q, k, v, mesh, True, scale=scale), None
     return attend
 
 
@@ -354,17 +516,22 @@ def attention_path(q_shape: tuple, itemsize: int, mesh: Optional[Mesh],
 
 
 def attention(q, k, v, mesh: Optional[Mesh], causal: bool,
-              sp_mode: str = "ring") -> jax.Array:
+              sp_mode: str = "ring", scale: Optional[float] = None
+              ) -> jax.Array:
     """Dispatch per :func:`attention_path`.  Under a mesh the sp paths and
     the flash kernel are shard_mapped so each chip works on its own
     batch/head shard (a bare pallas_call has no GSPMD partitioning rule
-    and would be replicated)."""
+    and would be replicated).  ``scale``: the softmax scale where it is
+    not the key width's ``D ** -0.5`` (the sp paths take no other)."""
     path = attention_path(q.shape, q.dtype.itemsize, mesh, sp_mode,
                           v.shape[-1])
     _log_attention_path(path, q.shape,
                         tuple(mesh.shape.items()) if mesh is not None
                         else None)
     if path in ("ring", "ulysses"):
+        if scale is not None:
+            raise NotImplementedError(
+                f"{path} attention takes the key width's softmax scale only")
         k, v = FA.gqa_expand(q, k, v)   # ring/Ulysses rotate full head sets
         # Manual over every mesh axis: the batch/head dims are explicitly
         # dp·fsdp / tp sliced instead of left to GSPMD, and the body only
@@ -380,7 +547,7 @@ def attention(q, k, v, mesh: Optional[Mesh], causal: bool,
         return fn(q, k, v)
     if path == "flash":
         flash = lambda q_, k_, v_: FA.flash_attention(
-            q_, k_, v_, None, causal, None, None, _FORCE_FLASH_INTERPRET)
+            q_, k_, v_, scale, causal, None, None, _FORCE_FLASH_INTERPRET)
         if mesh is None:
             return flash(q, k, v)
         if k.shape[2] % mesh.shape.get("tp", 1):
@@ -392,7 +559,9 @@ def attention(q, k, v, mesh: Optional[Mesh], causal: bool,
         spec = P(("dp", "fsdp"), None, "tp", None)
         return shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
-    return FA.dense_attention(q, k, v, 1.0 / np.sqrt(q.shape[-1]), causal)
+    return FA.dense_attention(
+        q, k, v, 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
+        causal)
 
 
 def cached_attend(q, keys, vals, mask, scale):

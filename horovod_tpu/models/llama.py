@@ -869,7 +869,15 @@ def model_of(cfg):
     per-row shapes of the state slots an engine row holds beside the
     pools: none for a model whose layers keep no recurrent state); where
     ``serve_stats`` returns expert layers' counts, the engine also asks
-    for ``moe_layer_names`` and ``moe_experts_scored``."""
+    for ``moe_layer_names`` and ``moe_experts_scored``.  Two hooks a model
+    may leave out, found through :func:`serve_hook`, whose defaults are
+    this module's: ``serve_frame(cfg)``, the frame every layer goes
+    through, called as ``frame(h, lp, mixer, mlp, eps) -> (h, kept,
+    extra)`` (:func:`~horovod_tpu.models.layers.block`; a model with
+    hyper-connections brings ``layers.hc_block``, and its ``serve_embed``
+    gives the streams ``[B, S, n, D]``), and ``serve_readout(params, h,
+    cfg)``, what the final norm reads of the last layer's residual (``h``
+    itself; with hyper-connections the one mix of the streams)."""
     model = sys.modules[type(cfg).__module__]
     if not hasattr(model, "serve_runs"):
         raise NotImplementedError(
@@ -922,6 +930,24 @@ def serve_stats(cfg: LlamaConfig, extras: list) -> dict:
     return {}
 
 
+def serve_frame(cfg):
+    """The frame of every served layer: the pre-norm block."""
+    return block
+
+
+def serve_readout(params, h, cfg):
+    """What the final norm reads: the residual as the last layer left
+    it."""
+    return h
+
+
+def serve_hook(model, name: str):
+    """``model``'s ``serve_frame`` or ``serve_readout``, else this
+    module's, which is every model's that has one residual stream."""
+    assert name in ("serve_frame", "serve_readout"), name
+    return getattr(model, name, getattr(_THIS, name))
+
+
 def serve_embed(embed, tokens, cfg: LlamaConfig):
     """The serving steps' embedding: the one-hot product of training,
     which partitions under the vocab_rows sharding (a model served on one
@@ -944,10 +970,12 @@ def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
     rides the scan's carry, ``out`` (a layer's entries) is stacked,
     ``cache_layers`` deep (a model with state slots hands them on beside
     its pools in ``state``, and its prefill's ``out`` holds a row's state
-    beside its entries).  Returns ``(h, state, outs, stats)``, ``stats``
-    what the model makes of its mlps' extras (the expert layers' counts,
-    or nothing)."""
+    beside its entries).  The frame and what the final norm reads of the
+    residual are the model's hooks (:func:`serve_hook`).  Returns ``(h,
+    state, outs, stats)``, ``stats`` what the model makes of its mlps'
+    extras (the expert layers' counts, or nothing)."""
     model = model_of(cfg)
+    frame = serve_hook(model, "serve_frame")(cfg)
     h = model.serve_embed(params["embed"], tok, cfg)
     if mesh is not None:
         h = shd.constrain(h, ("batch", None, None), mesh,
@@ -959,7 +987,7 @@ def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
         def layer(carry, xs):    # traced here, with this run's pair
             h, state = carry
             lp, li = xs
-            h, (state, out), extra = block(
+            h, (state, out), extra = frame(
                 h, lp, mixer_of(partial(attend, li=li, state=state)), mlp,
                 cfg.rms_eps)
             return (h, state), (out, extra)
@@ -973,6 +1001,7 @@ def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
         extras.append(extra)
     outs = outs[0] if len(outs) == 1 else jax.tree.map(
         lambda *a: jnp.concatenate(a), *outs)
+    h = serve_hook(model, "serve_readout")(params, h, cfg)
     return h, state, outs, model.serve_stats(cfg, extras)
 
 

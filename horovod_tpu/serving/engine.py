@@ -105,6 +105,10 @@ _m_kv_bytes_per_token = _obs.gauge(
     "hvd_serving_kv_bytes_per_token",
     "bytes one token holds in the pools (K and V, or its latent rows as "
     "the pool pads them), all cache layers")
+_m_residual_streams = _obs.gauge(
+    "hvd_serving_residual_streams",
+    "copies of the residual a layer's frame carries: 1 for the pre-norm "
+    "block, n for hyper-connections (its maps mix them every sub-layer)")
 # Fed only for a model with state slots.
 _m_state_bytes_per_stream = _obs.gauge(
     "hvd_serving_state_bytes_per_stream",
@@ -290,7 +294,12 @@ class ServingEngine:
         self._flights: list[_Flight] = []
 
         #: what the prefill and decode spans say of the model's depth
+        #: (and, for a frame of several residual streams, how many)
         self._depth = dict(loops=cfg.loops, cache_layers=cfg.cache_layers)
+        hc = getattr(cfg, "hc", None)
+        _m_residual_streams.set(hc.streams if hc else 1)
+        if hc:
+            self._depth["hc_streams"] = hc.streams
         self._slots: list[Optional[Request]] = \
             [None] * engine_cfg.max_active
         self._next_id = 0

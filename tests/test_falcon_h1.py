@@ -367,8 +367,11 @@ def test_import_of_a_migrated_request_is_refused(params):
 # -- (5) the other served models' programs, as lowered before the slots ------------
 
 #: sha256 (first 12 hex digits) of the lowered text of each serving
-#: program of the three served kinds at a tiny size, made from the tree
-#: before the state slots: a model without them compiles what it did
+#: program of the served kinds at a tiny size: the three without state
+#: slots as made from the tree before the slots (a model without them
+#: compiles what it did), Falcon-H1's as made from the tree before the
+#: multi-stream residual frame (a model in the plain frame compiles what
+#: it did); Falcon-H1 refuses the extend path
 UNCHANGED = {
     "llama": {"prefill": "674b5f14b9ee", "scatter": "4f94cbfa9d52",
               "decode": "94e8a4ab1f82", "extend": "fef456b62af2",
@@ -379,12 +382,15 @@ UNCHANGED = {
     "glm": {"prefill": "b3588f7e4cd9", "scatter": "a88aac7443a7",
             "decode": "900c50c42809", "extend": "4da655702676",
             "decode.interpret": "b096285590ba"},
+    "falcon": {"prefill": "2134afb5fd09", "scatter": "0cb73678c692",
+               "decode": "69d3b3057a43", "decode.interpret": "89ee5e9def1f"},
 }
 SERVED = {
     "llama": lambda: llama.LlamaConfig.tiny(n_kv_heads=4),
     "ouro": lambda: llama.LlamaConfig.tiny(n_kv_heads=4, loops=2,
                                            sandwich_norm=True),
     "glm": lambda: G.GlmMoeLiteConfig.tiny(),
+    "falcon": lambda: CFG,
 }
 
 
@@ -392,24 +398,27 @@ SERVED = {
 def test_the_other_served_models_programs_are_unchanged(kind):
     cfg = SERVED[kind]()
     model = llama.model_of(cfg)
-    assert model.state_rows(cfg) == ()
+    assert (model.state_rows(cfg) == ()) == (kind != "falcon")
     p = model.init_params(cfg, jax.random.PRNGKey(0))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     got = {}
     for flash in ("never", "interpret"):
         eng = ServingEngine(p, cfg, engine_cfg=EngineConfig(
             block_size=4, num_blocks=16, max_active=2, use_flash=flash))
-        assert eng.slots == ()
+        assert len(eng.slots) == len(model.state_rows(cfg))
         kept = tuple(jax.ShapeDtypeStruct(
-            (cfg.cache_layers, 1, 8) + r, cfg.dtype) for r in eng.cache.rows)
+            (cfg.cache_layers, 1, 8) + r, cfg.dtype) for r in eng.cache.rows
+        ) + tuple(jax.ShapeDtypeStruct((s.shape[0], 1) + s.shape[2:],
+                                       s.dtype) for s in eng.slots)
         low = {"prefill": eng._prefill.lower(p, i32(1, 8), i32(1)),
                "scatter": eng._scatter.lower(eng.pools, kept, i32(2),
                                              eng.slots, i32()),
                "decode": eng._decode.lower(p, eng.pools, i32(2), i32(2),
-                                           i32(2, 4), eng.slots),
-               "extend": eng._extend.lower(
-                   p, eng.pools, i32(1, 4), i32(1, 4),
-                   jax.ShapeDtypeStruct((1, 4), bool), i32(1, 4))}
+                                           i32(2, 4), eng.slots)}
+        if not eng.slots:
+            low["extend"] = eng._extend.lower(
+                p, eng.pools, i32(1, 4), i32(1, 4),
+                jax.ShapeDtypeStruct((1, 4), bool), i32(1, 4))
         for name, lowered in low.items():
             if flash == "never" or name == "decode":
                 key = name if flash == "never" else name + ".interpret"

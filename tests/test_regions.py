@@ -22,6 +22,7 @@ import pytest
 
 from horovod_tpu.models import falcon_h1 as FH, glm_moe_lite as G
 from horovod_tpu.models import kimi_linear as K, llama
+from horovod_tpu.models import xing4 as X4
 from horovod_tpu.obs import trace as obs_trace
 from horovod_tpu.parallel import MeshConfig, build_mesh
 from horovod_tpu.serving.engine import EngineConfig, ServingEngine
@@ -30,12 +31,14 @@ from horovod_tpu.serving.engine import EngineConfig, ServingEngine
 REGIONS = {
     "hvd.embed", "hvd.block.mixer", "hvd.block.mlp", "hvd.moe.route",
     "hvd.moe.experts", "hvd.moe.combine", "hvd.moe.shared", "hvd.head",
-    "hvd.loss", "hvd.renorm", "hvd.optim", "hvd.exchange", "hvd.ssm"}
+    "hvd.loss", "hvd.renorm", "hvd.optim", "hvd.exchange", "hvd.ssm",
+    "hvd.hc"}
 #: every module that writes a region
 WRITERS = ("horovod_tpu.models.layers", "horovod_tpu.models.llama",
            "horovod_tpu.models.kimi_linear",
            "horovod_tpu.models.glm_moe_lite",
-           "horovod_tpu.models.falcon_h1", "horovod_tpu.parallel.moe",
+           "horovod_tpu.models.falcon_h1", "horovod_tpu.models.xing4",
+           "horovod_tpu.parallel.moe",
            "horovod_tpu.optim.distributed", "horovod_tpu.obs.trace")
 
 _INSTR = re.compile(
@@ -85,6 +88,7 @@ CASES = {
     "looped-serve": ("serve", llama, llama.LlamaConfig.tiny(loops=2)),
     "glm-serve": ("serve", G, G.GlmMoeLiteConfig.tiny()),
     "falcon-serve": ("serve", FH, FH.FalconH1Config.tiny()),
+    "xing4-serve": ("serve", X4, X4.Xing4Config.tiny()),
 }
 
 
@@ -162,7 +166,7 @@ def test_every_product_lies_in_one_region_of_the_list(case):
     assert {"hvd.block.mixer", "hvd.block.mlp", "hvd.head"} <= seen
     if CASES[case][0] == "train":
         assert {"hvd.embed", "hvd.loss", "hvd.optim"} <= seen, seen
-    if case in ("kimi-train", "glm-serve"):
+    if case in ("kimi-train", "glm-serve", "xing4-serve"):
         assert {"hvd.moe.route", "hvd.moe.experts", "hvd.moe.shared"} \
             <= seen, seen
     if case == "glm-serve":
@@ -171,6 +175,8 @@ def test_every_product_lies_in_one_region_of_the_list(case):
         assert "hvd.renorm" in seen, seen
     if case == "falcon-serve":
         assert {"hvd.embed", "hvd.ssm"} <= seen, seen
+    if case == "xing4-serve":
+        assert {"hvd.embed", "hvd.hc"} <= seen, seen
 
 
 @pytest.mark.parametrize("case", ["llama-train", "kimi-train"])
