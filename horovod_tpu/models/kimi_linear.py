@@ -339,6 +339,9 @@ def forward(params: dict, tokens: jax.Array, cfg: KimiLinearConfig, *,
             h, _, st = block(h, lp, mixer, mlp, cfg.rms_eps)
             return h, st
 
+        # Plain remat: ``llama.remat_plan``'s estimate is a Llama step's,
+        # blind to the KDA states, and the rule fed this cell's candidates
+        # and the 1.7 GB its step leaves free keeps nothing anyway.
         h, st = lax.scan(remat(body, cfg.remat), h, stack)
         if st is not None:
             stats.append(st)

@@ -40,6 +40,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..obs import REGISTRY as _obs
 from ..obs.trace import region
 from ..ops import flash_attention as FA
 from ..parallel.ring_attention import (
@@ -54,12 +55,81 @@ log = hvd_logging.get_logger()
 POOL_DIMS = (None, None, None, "kv_heads", None)
 
 
-def remat(body, mode):
-    """Apply the configured rematerialization mode to a layer body."""
+# The residuals a training step names (``checkpoint_name``; the flash
+# kernel's forward rule names its two), in the groups
+# :func:`saved_residuals` keeps whole, most recompute saved a byte first:
+# the mixer's (q and k after rope, v, the flash output and its row sums,
+# the mixer's output: nothing of it but its norm runs again), then the
+# MLP's (the gate and up products).  Outside a ``jax.checkpoint`` with a
+# names policy a name lowers to nothing.
+MIXER_RESIDUALS = ("attn_q", "attn_k", "attn_v", "flash_out", "flash_lse",
+                   "attn_out")
+MLP_RESIDUALS = ("mlp_gate", "mlp_up")
+
+# What the step's temporaries grow by, a byte of residual saved over the
+# layers (the Mistral-7B train step compiled for a v5e: 1.97 for the
+# mixer's, 2.02 for the MLP's), and what the caller's estimate of the step
+# must leave free beside them.
+SAVED_GROWTH = 2
+SAVED_MARGIN = 1e9
+
+
+def saved_residuals(groups, free_bytes: float) -> tuple:
+    """The names a remat'd layer keeps: whole ``groups`` of ``(names,
+    bytes over all layers)``, in order, while ``SAVED_GROWTH`` times the
+    bytes taken fit in ``free_bytes`` (the device's memory less the
+    step's own estimate) less ``SAVED_MARGIN``."""
+    names, spent = (), 0
+    for group, nbytes in groups:
+        spent += SAVED_GROWTH * nbytes
+        if spent > free_bytes - SAVED_MARGIN:
+            break
+        names += tuple(group)
+    return names
+
+
+def device_bytes() -> Optional[int]:
+    """The memory the first local device lends a program
+    (``bytes_limit``); None where the backend does not say (the CPU)."""
+    return (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+_m_saved = _obs.gauge(
+    "hvd_train_remat_saved_bytes",
+    "bytes of a named residual that the remat'd layer stack keeps for the "
+    "backward instead of recomputing it, all layers; set when a step is "
+    "traced", ("name",))
+
+
+def record_saved(saved: dict) -> None:
+    """Trace time: ``hvd_train_remat_saved_bytes`` for each residual of
+    ``saved`` (``{name: bytes over all layers}``), and one log line for
+    each set a step keeps."""
+    for name, nbytes in saved.items():
+        _m_saved.labels(name=name).set(nbytes)
+    if saved:
+        _log_saved(tuple(saved.items()))
+
+
+@lru_cache(maxsize=None)
+def _log_saved(saved: tuple) -> None:
+    log.info("remat keeps %s for the backward (%.3f GB over the layers)",
+             ", ".join(n for n, _ in saved), sum(b for _, b in saved) / 1e9)
+
+
+def remat(body, mode, saved: tuple = ()):
+    """Apply the configured rematerialization mode to a layer body:
+    True recomputes the layer in the backward but for the named
+    residuals ``saved`` (none: plain ``jax.checkpoint``), ``"dots"``
+    saves the matmul outputs, False nothing is recomputed."""
     if mode == "dots":
         return jax.checkpoint(
             body,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    if mode and saved:
+        return jax.checkpoint(
+            body, policy=jax.checkpoint_policies.save_only_these_names(
+                *saved))
     return jax.checkpoint(body) if mode else body
 
 
@@ -226,10 +296,14 @@ def conv_tail(y, k: int, tail=None, last=None):
     return jnp.take_along_axis(yp, idx, axis=1)
 
 
-def dense_mlp(x2, lp):
-    """SwiGLU MLP, in the frame's mlp form: ``(out, None)``."""
-    g = jax.nn.silu(jnp.einsum("bsd,df->bsf", x2, lp["w_gate"]))
-    u = jnp.einsum("bsd,df->bsf", x2, lp["w_up"])
+def dense_mlp(x2, lp, name=lambda y, _: y):
+    """SwiGLU MLP, in the frame's mlp form: ``(out, None)``.  ``name(y,
+    what)`` marks the gate product (before the SiLU) and the up product;
+    a training step passes ``checkpoint_name`` for its remat policy, and
+    the serving programs, which a name would renumber, nothing."""
+    g = jax.nn.silu(name(jnp.einsum("bsd,df->bsf", x2, lp["w_gate"]),
+                         "mlp_gate"))
+    u = name(jnp.einsum("bsd,df->bsf", x2, lp["w_up"]), "mlp_up")
     return jnp.einsum("bsf,fd->bsd", g * u, lp["w_down"]), None
 
 

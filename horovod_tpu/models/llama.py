@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.trace import region
@@ -38,6 +39,8 @@ from ..ops import flash_attention as FA
 from ..parallel import sharding as shd
 from ..parallel.moe import moe_layer_local
 from .layers import (  # noqa: F401  (attention_path: re-exported)
+    MIXER_RESIDUALS,
+    MLP_RESIDUALS,
     POOL_DIMS,
     attention,
     attention_path,
@@ -45,13 +48,16 @@ from .layers import (  # noqa: F401  (attention_path: re-exported)
     cached_attend,
     causal_lm_loss,
     dense_mlp,
+    device_bytes,
     embed_lookup,
     gather_blocks,
     gqa_mixer,
     looped,
+    record_saved,
     remat,
     rmsnorm,
     rope_tables,
+    saved_residuals,
     sp_local_attention,
 )
 
@@ -71,12 +77,15 @@ class LlamaConfig:
     use_moe: bool = False
     n_experts: int = 8
     capacity_factor: float = 1.25
-    # Rematerialization of the layer body: True = full per-layer remat
-    # (least memory: the backward runs each layer's forward again),
-    # False = save everything, or "dots" = jax.checkpoint with the
+    # Rematerialization of the layer body: True = per-layer remat (the
+    # backward runs each layer's forward again, but for the named
+    # residuals that :func:`remat_plan` finds room for on the device:
+    # the mixer's q, k, v, flash output, row sums and output, then the
+    # MLP's gate and up products; none where the device does not say its
+    # memory or the step is sharded over several), False = save
+    # everything, or "dots" = jax.checkpoint with the
     # dots_with_no_batch_dims_saveable policy (matmul outputs saved, the
-    # rest recomputed).  What each costs a step is ROADMAP Speed 3's to
-    # measure on the training cells; no ledger line prices it yet.
+    # rest recomputed).
     remat: Any = True
     moe_aux_weight: float = 0.01
     # pp microbatch count (None = auto: most M <= 2*pp dividing the local
@@ -565,6 +574,35 @@ def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
     return _head(params, h, cfg, ("batch", "seq", "vocab"), mesh), aux
 
 
+def remat_plan(cfg: LlamaConfig, B: int, S: int, param_bytes: int,
+               limit: Optional[int]) -> dict:
+    """The residuals a remat'd layer of the ``B x S`` training step keeps,
+    ``{name: bytes over all layers}``, by :func:`layers.saved_residuals`:
+    what the device lends (``limit``) less what the step holds without
+    them, four times the parameters' bytes (weights, gradients, Adam's
+    two moments), the float32 logits and their gradient, and the layer
+    inputs remat keeps (0.16 GB under what the compiler allots the
+    Mistral-7B train step of five layers at B4 x S2048 for a v5e).  Empty
+    unless ``cfg.remat`` is True and ``limit`` is known."""
+    if cfg.remat is not True or limit is None:
+        return {}
+    T, L = B * S, cfg.n_layers
+    item = jnp.dtype(cfg.dtype).itemsize
+    q = T * cfg.n_heads * cfg.head_dim * item
+    kv = T * cfg.n_kv_heads * cfg.head_dim * item
+    size = {"attn_q": q, "attn_k": kv, "attn_v": kv, "flash_out": q,
+            "flash_lse": T * cfg.n_heads * 4,
+            "attn_out": T * cfg.d_model * item,
+            "mlp_gate": T * cfg.d_ff * item, "mlp_up": T * cfg.d_ff * item}
+    held = 4 * param_bytes + 2 * T * cfg.vocab_size * 4 + \
+        L * T * cfg.d_model * item
+    groups = (MIXER_RESIDUALS,) if cfg.use_moe else \
+        (MIXER_RESIDUALS, MLP_RESIDUALS)
+    names = saved_residuals(
+        [(g, L * sum(size[n] for n in g)) for g in groups], limit - held)
+    return {n: L * size[n] for n in names}
+
+
 def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
             mesh: Optional[Mesh] = None, causal: bool = True,
             return_hidden: bool = False
@@ -590,11 +628,20 @@ def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
         layer_dims = _layer_dims(cfg)
         rules = shard_rules(cfg, mesh)
 
-    mixer = partial(
-        gqa_mixer, tables=rope,
-        attend=lambda q, k, v: (attention(q, k, v, mesh, causal,
-                                          cfg.sp_attention), None))
-    mlp = partial(_moe_mlp, cfg=cfg, mesh=mesh) if cfg.use_moe else dense_mlp
+    # The residuals remat_plan may keep are named here, in the training
+    # step's own closures (the serving programs share gqa_mixer and
+    # dense_mlp, and a name there would renumber their lowered text).
+    def attend(q, k, v):
+        q, k, v = (checkpoint_name(a, n) for a, n in
+                   zip((q, k, v), ("attn_q", "attn_k", "attn_v")))
+        return attention(q, k, v, mesh, causal, cfg.sp_attention), None
+
+    def mixer(x, lp):
+        y, kept = gqa_mixer(x, lp, rope, attend)
+        return checkpoint_name(y, "attn_out"), kept
+
+    mlp = partial(_moe_mlp, cfg=cfg, mesh=mesh) if cfg.use_moe else \
+        partial(dense_mlp, name=checkpoint_name)
 
     def layer_body(carry, lp):
         h, aux = carry
@@ -608,7 +655,12 @@ def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
             h = shd.constrain(h, ("batch", "seq", None), mesh)
         return (h, aux), None
 
-    body = remat(layer_body, cfg.remat)
+    saved = {} if mesh is not None and mesh.size > 1 else remat_plan(
+        cfg, B, S, sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(params)),
+        device_bytes())
+    record_saved(saved)
+    body = remat(layer_body, cfg.remat, tuple(saved))
     final_norm = lambda h: rmsnorm(h, params["final_norm"], cfg.rms_eps)
 
     (h, aux), _ = looped(lambda carry, x: body(carry, x[0]),

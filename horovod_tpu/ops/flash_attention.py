@@ -34,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
 
@@ -970,7 +971,11 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 def _fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret):
+    # Named for a remat policy (``layers.remat``): a saved pair is the
+    # backward's, and the forward kernel need not run again.
     out, lse = _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret)
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 

@@ -5,6 +5,7 @@ Pallas kernels compiled ahead of time for a v5e."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -276,3 +277,50 @@ def test_flash_kernels_compile_for_v5e_at_long_sequences(
         spec((B, S, H, D)), spec((B, S, KV, D)), spec((B, S, KV, Dv))
     ).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_mistral_train_step_fits_a_v5e_with_what_remat_keeps(
+        monkeypatch):
+    """The train cell's step (Mistral-7B widths, five layers, B4 x S2048,
+    adamw) compiled for a v5e with the residuals the rule keeps at the
+    chip's limit: the mixer's, so one flash forward and not a second one
+    in the backward, and arguments plus temporaries under the limit with
+    room for what the process holds beside the step."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    import optax
+    from horovod_tpu.models import layers, llama
+    from horovod_tpu.parallel import MeshConfig, build_mesh
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this machine
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    limit = 16_909_336_064              # a v5e's bytes_limit
+    monkeypatch.setattr(layers, "_flash_backend", lambda: True)
+    monkeypatch.setattr(llama, "device_bytes", lambda: limit)
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, d_model=4096, n_layers=5, n_heads=32, n_kv_heads=8,
+        d_ff=14336, rope_theta=1e6, dtype=jnp.bfloat16, remat=True)
+    mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+    tx = optax.adamw(3e-4)
+    aval = jax.eval_shape(lambda: llama.init_params(cfg,
+                                                    jax.random.PRNGKey(0)))
+    put = lambda tree, shard: jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, shard)
+    compiled = llama.make_train_step(cfg, mesh, tx).lower(
+        put(aval, llama.param_shardings(cfg, mesh)),
+        put(jax.eval_shape(tx.init, aval),
+            llama._opt_shardings(tx, cfg, mesh)),
+        {"tokens": jax.ShapeDtypeStruct(
+            (4, 2049), jnp.int32,
+            sharding=NamedSharding(mesh, P(("dp", "fsdp"))))}).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert len(re.findall(r"%hvd_flash_fwd[.\d]* = ", text)) == 1
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= \
+        limit - 1e9
