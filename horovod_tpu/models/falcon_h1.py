@@ -449,8 +449,10 @@ def serve_embed(embed, tokens, cfg: FalconH1Config):
                 * cfg.embedding_multiplier).astype(cfg.dtype)
 
 
-def serve_runs(params: dict, cfg: FalconH1Config, positions, mesh) -> list:
-    """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack."""
+def serve_runs(params: dict, cfg: FalconH1Config, positions, mesh,
+               use_flash: bool = False, interpret: bool = False) -> list:
+    """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack
+    (the tick's kernels are its attend's)."""
     tables = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
     return [(params["layers"], partial(_mixer, cfg, tables),
              partial(falcon_mlp, cfg=cfg))]

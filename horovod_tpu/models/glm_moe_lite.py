@@ -24,7 +24,9 @@ and scanned:
   largest, renormalised, scaled, and a shared expert; dropless, the
   grouped products' tile following the step's rows (:func:`moe_tile`),
   their results back to the rows through the list (every scored expert
-  is held: :func:`horovod_tpu.parallel.moe.combine_form`).
+  is held: :func:`horovod_tpu.parallel.moe.combine_form`); a decode tick
+  under the engine's kernels streams the touched experts through
+  ``hvd_moe_decode`` instead (:mod:`horovod_tpu.ops.moe_decode`).
 
 Served by :class:`horovod_tpu.serving.ServingEngine` through the three
 steps of :mod:`horovod_tpu.models.llama`, which find this module by the
@@ -263,12 +265,15 @@ _EXPERT_LEAVES = {"gate": "e_gate", "up": "e_up", "down": "e_down"}
 
 
 def _moe_mlp(x2, lp, cfg: GlmMoeLiteConfig, picks: bool = False,
-             stack=None):
+             stack=None, use_flash: bool = False, interpret: bool = False):
     """The expert layer as the frame's mlp; its extra is the layer's
     pairs by expert ``[E]`` (with ``picks`` also each token's experts and
     every selection score, for the comparison with the reference).  The
     experts are the layer's own leaves, or with ``stack`` the whole run's
-    (``[layers * E, ...]``) from row ``lp["expert_row"]`` on."""
+    (``[layers * E, ...]``) from row ``lp["expert_row"]`` on.  Under the
+    engine's kernels (``use_flash``, ``interpret``) a step of one position
+    a row, a decode tick, is the kernel's (``moe_layer_held``'s
+    ``kernel``)."""
     B, S, D = x2.shape
     out, stats = moe_layer_held(
         x2.reshape(B * S, D), lp["router"], lp["router_bias"],
@@ -277,7 +282,8 @@ def _moe_mlp(x2, lp, cfg: GlmMoeLiteConfig, picks: bool = False,
         {"w_gate": lp["s_gate"], "w_up": lp["s_up"], "w_down": lp["s_down"]},
         k=cfg.experts_per_token, renormalize=cfg.renormalize,
         scale=cfg.routed_scale, tile=moe_tile(B * S), picks=picks,
-        first_row=lp.get("expert_row"))
+        first_row=lp.get("expert_row"), kernel=use_flash and S == 1,
+        interpret=interpret)
     keep = ("expert_counts", "experts", "scores") if picks \
         else ("expert_counts",)
     return out.reshape(B, S, D), {k: stats[k] for k in keep}
@@ -380,14 +386,17 @@ def serve_embed(embed, tokens, cfg: GlmMoeLiteConfig):
         return jnp.take(embed, tokens, axis=0).astype(cfg.dtype)
 
 
-def serve_runs(params: dict, cfg: GlmMoeLiteConfig, positions, mesh) -> list:
+def serve_runs(params: dict, cfg: GlmMoeLiteConfig, positions, mesh,
+               use_flash: bool = False, interpret: bool = False) -> list:
     """``[(stacked leaves, attend -> mixer, mlp)]``, a run of one layer
-    kind each, for :func:`horovod_tpu.models.llama._serve_layers`."""
+    kind each, for :func:`horovod_tpu.models.llama._serve_layers`; the
+    expert layers take the step's kernel setting (:func:`_moe_mlp`)."""
     tables = rope_tables(positions, cfg.rope_theta, cfg.qk_rope_dim, cfg.yarn)
     runs = []
     for (kind, n), stack in zip(layer_runs(cfg), params["runs"]):
         kw = {}
         if kind == "moe":
+            kw.update(use_flash=use_flash, interpret=interpret)
             # The experts stay out of the scan over layers, whole: a scan
             # hands its body a layer's slice, and XLA copies that slice
             # out (1.2 GB a layer) before the loop over tiles reads it.

@@ -970,8 +970,11 @@ def state_rows(cfg: LlamaConfig) -> tuple:
     return ()
 
 
-def serve_runs(params, cfg: LlamaConfig, positions, mesh) -> list:
-    """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack."""
+def serve_runs(params, cfg: LlamaConfig, positions, mesh,
+               use_flash: bool = False, interpret: bool = False) -> list:
+    """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack.
+    The kernel setting of the step is a model's whose mlp has a kernel;
+    this one's kernel is its attend's."""
     tables = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
     return [(params["layers"],
              lambda attend: partial(gqa_mixer, tables=tables, attend=attend),
@@ -1007,7 +1010,8 @@ def serve_embed(embed, tokens, cfg: LlamaConfig):
     return embed_lookup(embed, tokens, cfg.dtype)
 
 
-def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
+def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None,
+                  use_flash: bool = False, interpret: bool = False):
     """The skeleton under the three serving steps, for every served
     model: embed ``tok [B, S]``, then each of the model's layer runs
     (:func:`model_of`'s ``serve_runs``: one for the Llama family, the
@@ -1023,9 +1027,11 @@ def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
     ``cache_layers`` deep (a model with state slots hands them on beside
     its pools in ``state``, and its prefill's ``out`` holds a row's state
     beside its entries).  The frame and what the final norm reads of the
-    residual are the model's hooks (:func:`serve_hook`).  Returns ``(h,
-    state, outs, stats)``, ``stats`` what the model makes of its mlps'
-    extras (the expert layers' counts, or nothing)."""
+    residual are the model's hooks (:func:`serve_hook`); ``use_flash``
+    and ``interpret``, a decode tick's kernel setting, go to the runs'
+    mlps (``serve_runs``).  Returns ``(h, state, outs, stats)``,
+    ``stats`` what the model makes of its mlps' extras (the expert
+    layers' counts, or nothing)."""
     model = model_of(cfg)
     frame = serve_hook(model, "serve_frame")(cfg)
     h = model.serve_embed(params["embed"], tok, cfg)
@@ -1034,8 +1040,8 @@ def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None):
                           model.shard_rules(cfg, mesh))
     renorm = lambda h: rmsnorm(h, params["final_norm"], cfg.rms_eps)
     first, outs, extras = 0, [], []
-    for stacked, mixer_of, mlp in model.serve_runs(params, cfg, positions,
-                                                   mesh):
+    for stacked, mixer_of, mlp in model.serve_runs(
+            params, cfg, positions, mesh, use_flash, interpret):
         def layer(carry, xs):    # traced here, with this run's pair
             h, state = carry
             lp, li = xs
@@ -1195,7 +1201,9 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
     The attention reads the pool either through a contiguous gather (XLA
     path, GSPMD-shardable) or in the model's Pallas paged kernel, which
     copies each stream's live pages in by the scalar-prefetched table
-    (``use_flash``; callers check the model's ``paged_kernel_ok``).
+    (``use_flash``; callers check the model's ``paged_kernel_ok``); a
+    model's expert layers take the same setting (GLM-4.7-Flash's routed
+    experts go through ``hvd_moe_decode`` under it).
     ``slots``: the model's state slot arrays ``[cache_layers, B, *row]``
     (:func:`model_of`'s ``state_rows``; none for attention alone), row b
     read and written by row b's token.
@@ -1212,7 +1220,7 @@ def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
         params, tok[:, None], positions[:, None], cfg, mesh,
         model.paged_attend(cfg, mesh, tables, blk, positions % BS, mask,
                            positions if use_flash else None, interpret),
-        tuple(pools) + tuple(slots))
+        tuple(pools) + tuple(slots), use_flash, interpret)
     logits = _head(params, h[:, 0], cfg, ("batch", "vocab"), mesh,
                    model.shard_rules(cfg, mesh))
     return logits, state[:n], state[n:], stats

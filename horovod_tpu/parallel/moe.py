@@ -28,6 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs import REGISTRY as _obs
 from ..obs.trace import region
+from ..ops.moe_decode import moe_decode
 
 _m_dropped = _obs.counter(
     "hvd_moe_dropped_tokens_total",
@@ -321,24 +322,27 @@ _m_combine = _obs.counter(
     "hvd_moe_combine_steps_total",
     "recorded steps of the dropless expert layer by the form in which its "
     "grouped products' results got back to the tokens' rows (list: every "
-    "scored expert is held here; add: a share of them)", ("layer", "form"))
+    "scored expert is held here; add: a share of them; kernel: every one "
+    "is held and the step is a decode tick through hvd_moe_decode)",
+    ("layer", "form"))
 
 
 def record_held_pairs(expert_counts, layer: str = "0", *,
-                      scored: int) -> None:
+                      scored: int, kernel: bool = False) -> None:
     """Count one step's routed load into the per-layer metrics.
 
     ``expert_counts``: pairs per held expert, ``[E_held]``; ``scored``:
-    how many experts the layer's router scores, by which the step is
-    counted under its :func:`combine_form`.  Host-side, after the step,
-    as :func:`record_dropped_tokens`."""
+    how many experts the layer's router scores and ``kernel`` whether the
+    step was a tick that ran its kernels, by which the step is counted
+    under its :func:`combine_form`.  Host-side, after the step, as
+    :func:`record_dropped_tokens`."""
     import numpy as np
     c = np.asarray(expert_counts, np.float64)
     _m_held.labels(layer=str(layer)).inc(float(c.sum()))
     if c.sum() > 0:
         _m_load.labels(layer=str(layer)).set(float(c.max() / c.mean()))
     _m_combine.labels(layer=str(layer),
-                      form=combine_form(c.size, scored)).inc()
+                      form=combine_form(c.size, scored, kernel)).inc()
 
 
 def topk_route(scores: jax.Array, bias: jax.Array, k: int, *,
@@ -424,22 +428,28 @@ def _tile_operands(i, tile, tokens, rows, weights, tile_expert, experts):
     return idx, x, wt, w
 
 
-def combine_form(held: int, scored: int) -> str:
-    """How :func:`_grouped_experts` gets its tiles' results back to the
-    tokens' rows, from what the layer's caller holds: ``"list"`` where it
-    holds every expert the router scores (each token's ``k`` pairs are
-    all computed here, so the padded list is as full as routing makes
-    it), ``"add"`` where it holds a share (the list's static bound is
-    mostly bound: 16 of 256 experts fill a twentieth of it).  The one
+def combine_form(held: int, scored: int, kernel: bool = False) -> str:
+    """How :func:`_grouped_experts` gets its experts' results back to the
+    tokens' rows, from what the layer's caller holds and runs:
+    ``"list"`` where it holds every expert the router scores (each
+    token's ``k`` pairs are all computed here, so the padded list is as
+    full as routing makes it), ``"add"`` where it holds a share (the
+    list's static bound is mostly bound: 16 of 256 experts fill a
+    twentieth of it), and ``"kernel"`` where it holds every one and
+    ``kernel`` says the step is a decode tick (one position a row) whose
+    caller runs its kernels: ``hvd_moe_decode`` walks the touched experts
+    and sums the rows' results itself, no tile and no list.  The one
     rule, for the program and for what the host says of it
     (``moe_combine`` on the serving spans,
     ``hvd_moe_combine_steps_total``)."""
-    return "list" if held == scored else "add"
+    if held != scored:
+        return "add"
+    return "kernel" if kernel else "list"
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(7,))
+@partial(jax.custom_vjp, nondiff_argnums=(8, 9))
 def _grouped_experts(tokens, weights, place, tile_expert, n_tiles, experts,
-                     pair_slot, tile: int):
+                     pair_slot, tick, tile: int, interpret):
     """Sum over the held (row, expert) pairs of weight x SwiGLU_expert.
 
     The pairs lie sorted by expert, each expert's run padded to whole
@@ -456,7 +466,7 @@ def _grouped_experts(tokens, weights, place, tile_expert, n_tiles, experts,
     scatter or gather of 262,144 scalars takes 1.2 to 2.7 ms (the two
     lists were two such scatters behind two such gathers), the sort of
     270,336 keys with both operands 0.39.  The results reach the tokens'
-    rows in one of two forms (:func:`combine_form` picks; the backward is
+    rows in one of three forms (:func:`combine_form` picks; the backward is
     the same):
 
     - ``pair_slot`` is ``None``, the add form: every tile adds its rows
@@ -473,17 +483,29 @@ def _grouped_experts(tokens, weights, place, tile_expert, n_tiles, experts,
       in use reaches (the bound keeps ``E_held`` rows over what routing
       can fill): zeros for the pairs that are not here.  Every other row
       a token reads, a tile has written.
+    - ``tick = (choice [T, k], counts [E_held], first_row)`` besides, the
+      kernel form (a decode tick): no tile runs, ``hvd_moe_decode``
+      (:func:`~horovod_tpu.ops.moe_decode.moe_decode`, ``interpret`` its
+      own) walks the experts of nonzero count, each pair's expert
+      ``choice`` among the held and its weight, into a float32 ``[T,
+      D]``.  Only the undifferentiated call takes it: under a gradient
+      the forward rule runs the list form, whose residuals the backward
+      reads, so a differentiated step keeps the tile loop.
 
     The backward walks the same tiles; a tile's weight gradients go
     straight to their pairs' places in ``[T, k]``, ``tile`` scalars a
     tile in use (3 us at 512), and not through the sort's transpose,
     which would gather and scatter all ``T * k``."""
+    if tick is not None:
+        with region("moe.experts"):
+            return moe_decode(tokens, tick[0], weights, tick[1], experts,
+                              tick[2], interpret=interpret)
     return _grouped_fwd(tokens, weights, place, tile_expert, n_tiles,
-                        experts, pair_slot, tile)[0]
+                        experts, pair_slot, tick, tile, interpret)[0]
 
 
 def _grouped_fwd(tokens, weights, place, tile_expert, n_tiles, experts,
-                 pair_slot, tile):
+                 pair_slot, tick, tile, interpret):
     rows, pairs, row_w = _padded_lists(place, weights)
     res = (tokens, weights, rows, pairs, row_w, tile_expert, n_tiles, experts)
 
@@ -516,7 +538,7 @@ def _grouped_fwd(tokens, weights, place, tile_expert, n_tiles, experts,
             for j in range(pair_slot.shape[1])).astype(tokens.dtype), res
 
 
-def _grouped_bwd(tile, res, d_out):
+def _grouped_bwd(tile, interpret, res, d_out):
     tokens, weights, rows, pairs, row_w, tile_expert, n_tiles, experts = res
 
     def body(i, carry):
@@ -541,7 +563,7 @@ def _grouped_bwd(tile, res, d_out):
                  jax.tree.map(jnp.zeros_like, experts))
         d_tok, d_wt, d_exp = lax.fori_loop(0, n_tiles, body, zeros)
     return (d_tok, d_wt.reshape(weights.shape), None, None, None, d_exp,
-            None)
+            None, None)
 
 
 _grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
@@ -551,7 +573,7 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
                    experts_held: dict, held_range: tuple[int, int],
                    shared: Any = None, *, k: int, renormalize: bool = True,
                    scale: float = 1.0, tile: int = 512, picks: bool = False,
-                   first_row=None):
+                   first_row=None, kernel: bool = False, interpret=False):
     """This chip's part of a dropless top-k expert layer.
 
     ``tokens [T, D]``; ``router [D, E]`` keeps every expert's column and
@@ -592,7 +614,15 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
 
     ``tile`` is the rows of one grouped product: a caller with few rows
     (a decode tick) passes few, since an expert's run is padded to whole
-    tiles and every tile reads its expert's three matrices.
+    tiles and every tile reads its expert's three matrices.  ``kernel``
+    says that the step is a decode tick, one position a row, whose caller
+    runs its kernels (``interpret`` as theirs): where every scored expert
+    is held the touched experts then stream through ``hvd_moe_decode``
+    instead of the tiles (:func:`combine_form`'s ``"kernel"``), whose
+    copies of the next expert's matrices run under this one's products;
+    at a tick's rows the products are about 64 FLOPs a byte read, so the
+    read sets the pace, and the tiles ran it at 67 to 73% of the
+    bandwidth (v5e).
 
     Returns ``(out [T, D], stats)`` with ``stats = {"pairs_held": int32
     scalar, "expert_counts": int32 [E_held]}``, traced values for
@@ -653,12 +683,17 @@ def moe_layer_held(tokens: jax.Array, router: jax.Array, bias: jax.Array,
             jnp.where(spare < (padded - counts)[:, None],
                       (starts + counts)[:, None] + spare, n_list).reshape(-1)])
         # Where the results come back through the list a pair reads its
-        # own row of it; a pair held elsewhere the list's last row.
-        pair_slot = None
-        if combine_form(E_held, router.shape[1]) == "list":
+        # own row of it; a pair held elsewhere the list's last row.  The
+        # kernel form keeps the list's operands for a gradient's forward.
+        form = combine_form(E_held, router.shape[1], kernel)
+        pair_slot = tick = None
+        if form != "add":
             pair_slot = jnp.where(held, slot, M - 1)
+        if form == "kernel":
+            tick = (local, counts, jnp.asarray(
+                0 if first_row is None else first_row, jnp.int32))
     out = _grouped_experts(tokens, weights, place, tile_expert, n_tiles,
-                           experts_held, pair_slot, tile)
+                           experts_held, pair_slot, tick, tile, interpret)
     if shared is not None:
         with region("moe.shared"):
             hidden = jax.nn.silu(tokens @ shared["w_gate"]) * \

@@ -715,33 +715,34 @@ class ServingEngine:
                 req.open_phase("decode")
         return token
 
-    def _record(self, stats, span, touched: bool = False) -> None:
+    def _record(self, stats, span, tick: bool = False) -> None:
         """A step's expert counts, where the model has expert layers:
         into the per-layer routing metrics, and onto ``span`` (None: not
         on a span) as ``moe_pairs`` and ``moe_combine``, the form in
-        which the program's grouped products came back to the rows, by
-        the rule the program itself goes by; with ``touched`` also how
-        many of the ``experts_held`` (layer, expert) pairs the step sent
-        any row to."""
+        which the program's experts came back to the rows, by the rule
+        the program itself goes by (a decode ``tick`` under the kernels
+        is the kernel's); a tick's span also says how many of the
+        ``experts_held`` (layer, expert) pairs it sent any row to."""
         counts = stats.get("expert_counts")
         if counts is None:
             return
         scored = self.model.moe_experts_scored(self.cfg)
+        kernel = tick and self._use_flash
         if span is not None:
-            attrs = dict(moe_pairs=int(counts.sum()),
-                         moe_combine=combine_form(counts.shape[-1], scored))
-            if touched:
+            attrs = dict(moe_pairs=int(counts.sum()), moe_combine=combine_form(
+                counts.shape[-1], scored, kernel))
+            if tick:
                 attrs.update(experts_touched=int(np.count_nonzero(counts)),
                              experts_held=counts.size)
             span.set_metadata(**attrs)
         for layer, c in zip(self.model.moe_layer_names(self.cfg), counts):
-            record_held_pairs(c, layer=layer, scored=scored)
+            record_held_pairs(c, layer=layer, scored=scored, kernel=kernel)
 
-    def _fetch(self, out, span, touched: bool = False):
+    def _fetch(self, out, span):
         """A step's tokens and stats in the one transfer the host makes;
         the stats go where :meth:`_record` puts them."""
         tok, stats = self._jax.device_get(out)
-        self._record(stats, span, touched)
+        self._record(stats, span)
         return tok
 
     def _dispatched(self, flight: _Flight) -> None:
@@ -772,7 +773,7 @@ class ServingEngine:
         with _span("hvd.serve.decode.fetch"):
             got = self._jax.device_get([f.out for f in flights])
             for f, (_, stats) in zip(flights, got):
-                self._record(stats, span if f.tick else None, touched=True)
+                self._record(stats, span if f.tick else None, f.tick)
         emitted = []
         with _span("hvd.serve.decode.emit"):
             for f, (tok, _) in zip(flights, got):

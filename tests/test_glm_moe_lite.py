@@ -191,20 +191,22 @@ def test_served_logits_match_the_reference(params, use_flash):
 
 def test_the_tick_ahead_records_each_steps_pairs_once(params):
     """The expert counts come with each program's tokens, one turn
-    after its dispatch: every prefill and tick is recorded once, under
-    the list form (every scored expert held), with every row of its
-    bucket or of the batch routed to k experts in each expert layer; the
-    tokens are the reference's."""
+    after its dispatch: every prefill and tick is recorded once, a
+    prefill under the list form (every scored expert held), a tick under
+    the kernel's (the engine runs its kernels, interpreted), with every
+    row of its bucket or of the batch routed to k experts in each expert
+    layer; the tokens are the reference's."""
     held = REGISTRY.get("hvd_moe_held_pairs_total")
     combine = REGISTRY.get("hvd_moe_combine_steps_total")
     layers_ = ("2", "3")
     pairs0 = [held.labels(layer=l).value for l in layers_]
-    steps0 = [combine.labels(layer=l, form="list").value for l in layers_]
+    steps0 = {f: [combine.labels(layer=l, form=f).value for l in layers_]
+              for f in ("list", "kernel")}
     ticks: list = []
     eng, reqs, served = _serve(params, ticks=ticks)
-    steps = len(PROMPTS) + len(ticks)
-    assert [combine.labels(layer=l, form="list").value - b
-            for l, b in zip(layers_, steps0)] == [steps] * 2
+    for form, steps in (("list", len(PROMPTS)), ("kernel", len(ticks))):
+        assert [combine.labels(layer=l, form=form).value - b
+                for l, b in zip(layers_, steps0[form])] == [steps] * 2
     rows = sum((8, 16, 16, 8)) + len(ticks) * ENGINE.max_active
     assert [held.labels(layer=l).value - b
             for l, b in zip(layers_, pairs0)] == [rows * 2] * 2
@@ -637,6 +639,8 @@ def test_the_list_form_is_the_add_form_and_the_per_token_sum(
 def test_the_form_follows_what_the_caller_holds():
     assert moe.combine_form(64, 64) == "list"
     assert moe.combine_form(16, 256) == "add"
+    assert moe.combine_form(64, 64, True) == "kernel"     # a tick's
+    assert moe.combine_form(16, 256, True) == "add"
 
 
 def _eqns(jaxpr, in_loop=False):
@@ -690,10 +694,11 @@ def test_the_prefill_steps_tile_loop_writes_a_list_and_adds_no_rows(params):
 
 
 def _grouped_experts_as_it_stood(tokens, weights, place, tile_expert, n_tiles,
-                                 experts, pair_slot, tile):
-    """``moe._grouped_experts`` before it had a list form (its forward;
-    the backward rule was and is ``moe._grouped_bwd``)."""
-    assert pair_slot is None
+                                 experts, pair_slot, tick, tile, interpret):
+    """``moe._grouped_experts`` before it had a list form or a kernel
+    form (its forward; the backward rule was and is ``moe._grouped_bwd``),
+    called as it is called now."""
+    assert pair_slot is None and tick is None
     rows, _, row_w = moe._padded_lists(place, weights)
 
     def body(i, out):
@@ -1018,10 +1023,11 @@ def _v5e_spec(monkeypatch):
 
 def test_the_cells_decode_tick_compiles_for_a_v5e(monkeypatch):
     """At the cell's size (seven layers, 64 rows, a table of 512 blocks of
-    32, the pool of 18,432): one Mosaic call a layer run by the name the
-    benchmark finds it by, the donated pool updated in place, and no
-    layer's experts copied out for the loop over tiles (1.2 GB where a
-    scan hands the loop its slice)."""
+    32, the pool of 18,432): one latent Mosaic call a layer run by the
+    name the benchmark finds it by and one ``hvd_moe_decode`` in the
+    expert layers' scan, the donated pool updated in place, and no
+    layer's experts copied out for the kernel (1.2 GB where a scan hands
+    the layer its slice)."""
     spec = _v5e_spec(monkeypatch)
     monkeypatch.setattr(layers, "_flash_backend", lambda: True)
     cfg = G.GlmMoeLiteConfig(n_layers=7)
@@ -1042,8 +1048,9 @@ def test_the_cells_decode_tick_compiles_for_a_v5e(monkeypatch):
     names = [ln.split(" = ")[0].strip()
              for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(names) == 2 and all("hvd_mla_paged_decode" in n
-                                   for n in names), names
+    assert sorted(n.split(".")[0] for n in names) == [
+        "%hvd_mla_paged_decode", "%hvd_mla_paged_decode",
+        "%hvd_moe_decode"], names
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 7 * 18432 * 32 * 640 * 2
     assert mem.temp_size_in_bytes < 64 << 20, mem

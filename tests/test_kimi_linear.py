@@ -7,6 +7,7 @@ which imports nothing from the program)."""
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import os
 import sys
 
@@ -460,3 +461,30 @@ def test_llama_step_lowers_to_the_program_it_lowered_to():
             {"tokens": jnp.zeros((2, 17), jnp.int32)})
     assert llama.make_train_step(cfg, mesh, tx).lower(*args).as_text() == \
         before.lower(*args).as_text()
+
+
+#: sha256 (first 12 hex digits) of the lowered text of the tiny step, as
+#: made from the tree before the decode tick's expert kernel: the
+#: benchmark's tiny cell (a share of the experts held: the add form) and
+#: every expert held (the list form).  The kernel is a decode tick's, so a
+#: trainer's step, which differentiates the layer, lowers as it did.
+STEP_BEFORE_THE_TICK_KERNEL = {"share": "87d5625c0e26", "all": "2e7dcff3a242"}
+
+
+@pytest.mark.parametrize("held", sorted(STEP_BEFORE_THE_TICK_KERNEL))
+def test_the_train_step_lowers_as_before_the_tick_kernel(held):
+    from horovod_tpu.parallel import MeshConfig, build_mesh
+    config = runmod.load_json(os.path.join(TINY_DIR, "configs",
+                                           "tiny-kimi.json"))
+    if held == "all":
+        config = dict(config, num_experts=16, first_expert_held=0)
+    dims = wts.dims_of(config)
+    kcfg = kimi_config(config, dims, jnp.float32)
+    mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
+    tx = KL.optimizer(optax.adamw(1e-3))
+    params = wts.stacked(KEY, dims, jnp.float32)
+    text = llama.make_train_step(kcfg, mesh, tx, model=KL).lower(
+        params, tx.init(params),
+        {"tokens": jnp.zeros((2, 33), jnp.int32)}).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:12] == \
+        STEP_BEFORE_THE_TICK_KERNEL[held]

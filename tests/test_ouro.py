@@ -182,9 +182,11 @@ def test_planted_fault_fails_the_comparison(params, fault, monkeypatch):
 
 
 def _plain_serve_layers(params, tok, positions, cfg, mesh, attend,
-                        state=None):
+                        state=None, use_flash=False, interpret=False):
     """The skeleton as it stood before the loop: one scan over (layers,
-    layer index), eps defaulted."""
+    layer index), eps defaulted; called as the skeleton is now (the
+    tick's kernel setting is for a model whose mlp has a kernel, which a
+    Llama has not)."""
     h = layers.embed_lookup(params["embed"], tok, cfg.dtype)
     tables = layers.rope_tables(positions, cfg.rope_theta, cfg.head_dim)
 

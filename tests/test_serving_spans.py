@@ -209,7 +209,7 @@ def test_expert_layers_counts_reach_the_spans_and_the_metrics(tmp_path):
     before = [held.labels(layer=l).value for l in ("2", "3")]
     combine = REGISTRY.get("hvd_moe_combine_steps_total")
     steps_before = [combine.labels(layer="3", form=f).value
-                    for f in ("list", "add")]
+                    for f in ("list", "add", "kernel")]
     with jax.profiler.trace(str(tmp_path)):
         for n in (5, 9, 13):
             submit(n)
@@ -227,12 +227,14 @@ def test_expert_layers_counts_reach_the_spans_and_the_metrics(tmp_path):
     assert set(decodes[1]) == ATTRS["hvd.serve.decode"] | {
         "moe_pairs", "moe_combine", "experts_touched", "experts_held"}
     assert len(fetched) == len(decodes) and fetched[:-1] == decodes[1:]
-    # every scored expert is held, so the results come back through the
-    # list, and the steps are counted under that form alone
-    assert {s["moe_combine"] for s in prefills + fetched} == {"list"}
+    # every scored expert is held, so a prefill's results come back
+    # through the list, and a tick's, under the engine's kernels, through
+    # hvd_moe_decode; the steps are counted under those forms alone
+    assert {s["moe_combine"] for s in prefills} == {"list"}
+    assert {s["moe_combine"] for s in fetched} == {"kernel"}
     steps = [combine.labels(layer="3", form=f).value - b
-             for f, b in zip(("list", "add"), steps_before)]
-    assert steps == [len(prefills) + len(decodes), 0]
+             for f, b in zip(("list", "add", "kernel"), steps_before)]
+    assert steps == [len(prefills), 0, len(decodes)]
     # a prefill routes every row of its bucket, a tick all four slots'
     assert [p["moe_pairs"] for p in prefills] == [
         2 * 2 * b for b in (8, 16, 16)]

@@ -414,8 +414,9 @@ def test_the_cells_programs_fit_a_v5e(monkeypatch):
     """At the cell's size (seven layers, the pool of 15,000 blocks of 32):
     the 8,704-token prefill's temporaries and results beside the weights
     and the pool stay under the accepted cells' 15.45 GB; the 64-row tick
-    runs the latent kernel once a layer run at 32 heads, and updates the
-    donated pool in place."""
+    runs the latent kernel once a layer run at 32 heads and its routed
+    experts through one ``hvd_moe_decode`` in the expert layers' scan,
+    and updates the donated pool in place."""
     spec = _v5e_spec(monkeypatch)
     monkeypatch.setattr(layers, "_flash_backend", lambda: True)
     cfg = X.Xing4Config(n_layers=7)
@@ -446,8 +447,9 @@ def test_the_cells_programs_fit_a_v5e(monkeypatch):
     names = [ln.split(" = ")[0].strip()
              for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(names) == 2 and all("hvd_mla_paged_decode" in n
-                                   for n in names), names
+    assert sorted(n.split(".")[0] for n in names) == [
+        "%hvd_mla_paged_decode", "%hvd_mla_paged_decode",
+        "%hvd_moe_decode"], names
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == pool
     assert mem.temp_size_in_bytes < 64 << 20, mem
