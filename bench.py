@@ -53,7 +53,7 @@ def main() -> None:
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.models import llama
+    from horovod_tpu.models import llama, steps
     from horovod_tpu.parallel import MeshConfig, build_mesh
     from horovod_tpu.utils.compile_cache import ensure_compile_cache
 
@@ -86,7 +86,7 @@ def main() -> None:
     n_params = sum(int(x.size) for x in jax.tree.leaves(params))
     tx = optax.adam(1e-4)
     opt_state = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
 
     tokens = np.random.RandomState(0).randint(
         0, cfg.vocab_size, size=(B * n_dev, S + 1))

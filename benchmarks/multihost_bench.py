@@ -43,13 +43,13 @@ def _step_loop(mesh, batch, steps, warmup):
     import jax.numpy as jnp
     import optax
 
-    from horovod_tpu.models import llama
+    from horovod_tpu.models import llama, steps
 
     cfg = llama.LlamaConfig(**MODEL_KW, dtype=jnp.dtype(DTYPE))
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     tx = optax.adam(1e-3)
     opt = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
     for _ in range(warmup):
         params, opt, loss = step(params, opt, batch)
     float(loss)

@@ -179,7 +179,7 @@ def phase_train(out: dict, devices, cfg, batch_shape, interpret: bool
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.models import llama
+    from horovod_tpu.models import llama, steps
     from horovod_tpu.parallel import MeshConfig, build_mesh
     from horovod_tpu.utils.checkpoint import Checkpointer
 
@@ -210,7 +210,7 @@ def phase_train(out: dict, devices, cfg, batch_shape, interpret: bool
     opt_state = jax.jit(tx.init)(params)
     batch = jax.device_put({"tokens": jnp.asarray(tokens)},
                            NamedSharding(mesh, P(("dp", "fsdp"))))
-    step = llama.make_train_step(cfg, mesh, tx).lower(
+    step = steps.make_train_step(cfg, mesh, tx).lower(
         params, opt_state, batch).compile()
     if not interpret:
         calls = _pallas_custom_calls(step)
@@ -259,7 +259,7 @@ def phase_serve(out: dict, devices, cfg, serve_kw: dict, lens, budgets,
     import jax.numpy as jnp
 
     from horovod_tpu import serving
-    from horovod_tpu.models import llama
+    from horovod_tpu.models import llama, steps
     from horovod_tpu.parallel import MeshConfig, build_mesh
 
     n = len(devices)
@@ -306,7 +306,7 @@ def phase_serve(out: dict, devices, cfg, serve_kw: dict, lens, budgets,
 
         def tick(use_flash):
             return jax.jit(
-                lambda p, pools: llama.decode_step_paged(
+                lambda p, pools: steps.decode_step_paged(
                     p, jnp.asarray(tok), jnp.asarray(pos), pools,
                     jnp.asarray(tables), cfg, mesh=mesh,
                     use_flash=use_flash, interpret=interpret)[:2],

@@ -20,7 +20,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.models import llama
+from horovod_tpu.models import llama, steps
 from horovod_tpu.parallel import MeshConfig, build_mesh
 from horovod_tpu.utils.checkpoint import Checkpointer
 
@@ -42,7 +42,7 @@ def main():
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     tx = optax.adamw(3e-4, weight_decay=0.01)
     opt_state = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
 
     B, S = 8, 64
     tokens = np.random.RandomState(0).randint(0, cfg.vocab_size,

@@ -25,7 +25,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.models import llama
+from horovod_tpu.models import llama, steps
 from horovod_tpu.parallel import MeshConfig, build_mesh
 
 
@@ -46,7 +46,7 @@ def main():
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     tx = optax.adamw(3e-4, weight_decay=0.01)
     opt_state = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
 
     B, S = 2 * (n // ep), 32   # 2 sequences per dp shard
     tokens = np.random.RandomState(0).randint(0, cfg.vocab_size,

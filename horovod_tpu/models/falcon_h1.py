@@ -22,8 +22,9 @@ With ``u`` the layer's normed input:
   multiplier out: a positive scale moves no greedy pick.
 
 Served by :class:`horovod_tpu.serving.ServingEngine` through the steps of
-:mod:`horovod_tpu.models.llama`, which find this module by the
-configuration's class.  Beside the paged K/V pools each engine row holds a
+:mod:`horovod_tpu.models.steps`, which find this module by the
+configuration's class; its attention's cache facts are the GQA mixer's
+(``layers.gqa_*``).  Beside the paged K/V pools each engine row holds a
 **state slot** (:func:`state_rows`): the SSM state ``[H, N, P]`` and the
 convolution's last ``k - 1`` inputs, one array of each ``[layers,
 max_active, ...]``.  A prompt's prefill runs the chunked scan and leaves
@@ -50,15 +51,17 @@ from jax import lax
 from jax.sharding import Mesh
 
 from ..obs.trace import region
-from ..ops import flash_attention as FA
 from ..ops import ssd
-from .layers import (
-    POOL_DIMS,
+from .layers import (  # noqa: F401  (the GQA cache facts, the model's own)
     attention,
     attention_path,
     block,
     causal_conv,
     conv_tail,
+    gqa_cache_rows as cache_rows,
+    gqa_paged_attend,
+    gqa_paged_kernel_ok as paged_kernel_ok,
+    gqa_pool_dims as pool_dims,
     rmsnorm,
     rope,
     rope_tables,
@@ -210,10 +213,8 @@ def _special_leaf(name: str, shape: tuple, key):
     return jnp.ones(shape, F32)
 
 
-def init_params(cfg: FalconH1Config, key: jax.Array,
-                mesh: Optional[Mesh] = None) -> dict:
-    if mesh is not None:
-        raise NotImplementedError("falcon_h1 is built on one device here")
+def init_params(cfg: FalconH1Config, key: jax.Array) -> dict:
+    """The program's own seeded leaves, whole on one chip."""
     D, V, L = cfg.d_model, cfg.vocab_size, cfg.n_layers
     rnd = lambda k, shape, fan: (jax.random.normal(
         k, shape, F32) / np.sqrt(fan)).astype(cfg.dtype)
@@ -378,24 +379,14 @@ def forward(params: dict, tokens: jax.Array, cfg: FalconH1Config, *,
 
 
 def check_trainable(cfg: FalconH1Config) -> None:
-    """What :func:`horovod_tpu.models.llama.make_train_step` asks first."""
+    """What :func:`horovod_tpu.models.steps.make_train_step` asks first."""
     raise NotImplementedError(
         "falcon_h1 is served here, not trained: no training path (its "
         "shardings, its optimizer state) is written for it, and four of "
         "its layers at 16 bytes a parameter are 27.5 GB")
 
 
-# -- serving: what models.llama's steps ask a model for -----------------------
-
-def cache_rows(cfg: FalconH1Config) -> tuple:
-    """Per-token shape of each pool of the paged cache: keys and values,
-    grouped."""
-    return ((cfg.n_kv_heads, cfg.head_dim),) * 2
-
-
-def pool_dims(cfg: FalconH1Config) -> tuple:
-    return POOL_DIMS
-
+# -- serving: what models.steps asks a model for ----------------------------
 
 def state_rows(cfg: FalconH1Config) -> tuple:
     """Per-row shape of each state slot array ``[L, max_active, ...]``:
@@ -403,10 +394,6 @@ def state_rows(cfg: FalconH1Config) -> tuple:
     inputs."""
     return ((cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim),
             (cfg.conv_kernel - 1, cfg.conv_dim))
-
-
-def shard_rules(cfg: FalconH1Config, mesh: Optional[Mesh]):
-    return None
 
 
 #: what the state slot cannot give, by the name the refusal gives it
@@ -449,17 +436,12 @@ def serve_embed(embed, tokens, cfg: FalconH1Config):
                 * cfg.embedding_multiplier).astype(cfg.dtype)
 
 
-def serve_runs(params: dict, cfg: FalconH1Config, positions, mesh,
-               use_flash: bool = False, interpret: bool = False) -> list:
+def serve_runs(params: dict, cfg: FalconH1Config, step) -> list:
     """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack
     (the tick's kernels are its attend's)."""
-    tables = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
+    tables = rope_tables(step.positions, cfg.rope_theta, cfg.head_dim)
     return [(params["layers"], partial(_mixer, cfg, tables),
              partial(falcon_mlp, cfg=cfg))]
-
-
-def serve_stats(cfg: FalconH1Config, extras: list) -> dict:
-    return {}
 
 
 def prefill_attend(cfg: FalconH1Config, mesh, P: int, last_pos=None):
@@ -481,26 +463,18 @@ def prefill_path(cfg: FalconH1Config, P: int) -> str:
                           jnp.dtype(cfg.dtype).itemsize, None)
 
 
-def paged_kernel_ok(cfg: FalconH1Config, mesh, block_size: int,
-                    interpret: bool = False) -> bool:
-    return interpret or FA.paged_supported(
-        block_size, cfg.head_dim, cfg.n_kv_heads, cfg.n_heads,
-        jnp.dtype(cfg.dtype).itemsize)
-
-
 def paged_attend(cfg: FalconH1Config, mesh, tables, blk, off, mask,
                  last=None, interpret: bool = False):
     """``attend`` of a decode tick: ``state`` is the K and V pools, then
-    the two state slot arrays.  Keys and values as the Llama family's
-    (:func:`horovod_tpu.models.llama.paged_attend`: the paged kernel where
-    each stream's ``last`` position is given, else the gather); the SSM
-    through ``hvd_ssd_decode`` on the same condition, a row whose table
-    starts with scratch block 0 holding no stream."""
-    from . import llama
+    the two state slot arrays.  Keys and values as the GQA mixer's
+    (:func:`horovod_tpu.models.layers.gqa_paged_attend`: the paged kernel
+    where each stream's ``last`` position is given, else the gather); the
+    SSM through ``hvd_ssd_decode`` on the same condition, a row whose
+    table starts with scratch block 0 holding no stream."""
     if blk.ndim != 1:
         check_servable(cfg, mesh, path="extend")
-    kv_attend = llama.paged_attend(cfg, mesh, tables, blk, off, mask, last,
-                                   interpret)
+    kv_attend = gqa_paged_attend(cfg, mesh, tables, blk, off, mask, last,
+                                 interpret)
     live = tables[:, 0] != 0
 
     def attend(q, k1, v1, s, li, state):

@@ -29,7 +29,7 @@ and scanned:
   ``hvd_moe_decode`` instead (:mod:`horovod_tpu.ops.moe_decode`).
 
 Served by :class:`horovod_tpu.serving.ServingEngine` through the three
-steps of :mod:`horovod_tpu.models.llama`, which find this module by the
+steps of :mod:`horovod_tpu.models.steps`, which find this module by the
 configuration's class; the functions under "serving" are what they ask a
 model for.  Not trained here: the published model's objective has a
 second term, a multi-token prediction module
@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 from ..obs.trace import region
 from ..ops import flash_attention as FA
@@ -215,36 +215,24 @@ def _special_leaf(name: str, shape: tuple, key):
     return jnp.ones(shape, jnp.float32)               # norm gains
 
 
-def init_params(cfg: GlmMoeLiteConfig, key: jax.Array,
-                mesh: Optional[Mesh] = None) -> dict:
-    def build(key):
-        D, V = cfg.d_model, cfg.vocab_size
-        rnd = lambda k, shape, fan: (jax.random.normal(
-            k, shape, jnp.float32) / np.sqrt(fan)).astype(cfg.dtype)
-        runs = []
-        for r, (kind, n) in enumerate(layer_runs(cfg)):
-            shapes = leaf_shapes(cfg, kind)
-            ks = jax.random.split(jax.random.fold_in(key, r), len(shapes))
-            runs.append({
-                name: (rnd(k, (n,) + shape, fan) if fan is not None else
-                       jax.vmap(lambda kk: _special_leaf(name, shape, kk)
-                                )(jax.random.split(k, n)))
-                for k, (name, (shape, fan)) in zip(ks, sorted(shapes.items()))})
-        ke, kh = jax.random.split(jax.random.fold_in(key, 1 << 20))
-        return {"embed": rnd(ke, (V, D), D), "runs": runs,
-                "final_norm": jnp.ones((D,), jnp.float32),
-                "lm_head": rnd(kh, (D, V), D)}
-
-    if mesh is None:
-        return build(key)
-    return jax.jit(build, out_shardings=param_shardings(cfg, mesh))(key)
-
-
-def param_shardings(cfg: GlmMoeLiteConfig, mesh: Mesh) -> dict:
-    """Every leaf replicated: a chip holds its whole stage."""
-    repl = NamedSharding(mesh, P())
-    aval = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    return jax.tree.map(lambda _: repl, aval)
+def init_params(cfg: GlmMoeLiteConfig, key: jax.Array) -> dict:
+    """The program's own seeded leaves, whole on one chip."""
+    D, V = cfg.d_model, cfg.vocab_size
+    rnd = lambda k, shape, fan: (jax.random.normal(
+        k, shape, jnp.float32) / np.sqrt(fan)).astype(cfg.dtype)
+    runs = []
+    for r, (kind, n) in enumerate(layer_runs(cfg)):
+        shapes = leaf_shapes(cfg, kind)
+        ks = jax.random.split(jax.random.fold_in(key, r), len(shapes))
+        runs.append({
+            name: (rnd(k, (n,) + shape, fan) if fan is not None else
+                   jax.vmap(lambda kk: _special_leaf(name, shape, kk)
+                            )(jax.random.split(k, n)))
+            for k, (name, (shape, fan)) in zip(ks, sorted(shapes.items()))})
+    ke, kh = jax.random.split(jax.random.fold_in(key, 1 << 20))
+    return {"embed": rnd(ke, (V, D), D), "runs": runs,
+            "final_norm": jnp.ones((D,), jnp.float32),
+            "lm_head": rnd(kh, (D, V), D)}
 
 
 # -- the layers ---------------------------------------------------------------
@@ -335,16 +323,18 @@ def forward(params: dict, tokens: jax.Array, cfg: GlmMoeLiteConfig, *,
 
 
 def check_trainable(cfg: GlmMoeLiteConfig) -> None:
-    """What :func:`horovod_tpu.models.llama.make_train_step` asks first."""
+    """What :func:`horovod_tpu.models.steps.make_train_step` asks first
+    (Xing4.0's too)."""
     raise NotImplementedError(
-        "glm_moe_lite has no training objective here: the published model "
-        "trains a multi-token prediction module (num_nextn_predict_layers) "
-        "beside the next-token loss, a block that reads the last hidden "
-        "state and the next token's embedding, which is neither written "
-        "nor loaded; plain cross-entropy would train another model")
+        f"{type(cfg).__name__} has no training objective here: the "
+        "published model trains a multi-token prediction module "
+        "(num_nextn_predict_layers) beside the next-token loss, a block "
+        "that reads the last hidden state and the next token's embedding, "
+        "which is neither written nor loaded; plain cross-entropy would "
+        "train another model")
 
 
-# -- serving: what models.llama's three steps ask a model for ------------------
+# -- serving: what models.steps asks a model for ----------------------------
 
 def cache_rows(cfg: GlmMoeLiteConfig) -> tuple:
     """Per-token shape of each pool of the paged cache: ONE pool, a row
@@ -358,21 +348,13 @@ def pool_dims(cfg: GlmMoeLiteConfig) -> tuple:
     return (None, None, None, None)
 
 
-def shard_rules(cfg: GlmMoeLiteConfig, mesh: Optional[Mesh]):
-    return None
-
-
-def state_rows(cfg: GlmMoeLiteConfig) -> tuple:
-    """No state slots: the latent rows are all a token leaves."""
-    return ()
-
-
 def check_servable(cfg: GlmMoeLiteConfig, mesh: Optional[Mesh],
                    engine_cfg=None, path: Optional[str] = None) -> None:
+    """Raise for a mesh (Xing4.0's too: its streams' maps are leaves)."""
     if mesh is not None and any(s > 1 for s in mesh.shape.values()):
         raise NotImplementedError(
-            "glm_moe_lite is served whole on one chip: its leaves carry no "
-            "logical dims to split over a mesh")
+            f"{type(cfg).__name__} is served whole on one chip: its leaves "
+            "carry no logical dims to split over a mesh")
 
 
 def serve_embed(embed, tokens, cfg: GlmMoeLiteConfig):
@@ -386,17 +368,17 @@ def serve_embed(embed, tokens, cfg: GlmMoeLiteConfig):
         return jnp.take(embed, tokens, axis=0).astype(cfg.dtype)
 
 
-def serve_runs(params: dict, cfg: GlmMoeLiteConfig, positions, mesh,
-               use_flash: bool = False, interpret: bool = False) -> list:
+def serve_runs(params: dict, cfg: GlmMoeLiteConfig, step) -> list:
     """``[(stacked leaves, attend -> mixer, mlp)]``, a run of one layer
-    kind each, for :func:`horovod_tpu.models.llama._serve_layers`; the
+    kind each, for :func:`horovod_tpu.models.steps._serve_layers`; the
     expert layers take the step's kernel setting (:func:`_moe_mlp`)."""
-    tables = rope_tables(positions, cfg.rope_theta, cfg.qk_rope_dim, cfg.yarn)
+    tables = rope_tables(step.positions, cfg.rope_theta, cfg.qk_rope_dim,
+                         cfg.yarn)
     runs = []
     for (kind, n), stack in zip(layer_runs(cfg), params["runs"]):
         kw = {}
         if kind == "moe":
-            kw.update(use_flash=use_flash, interpret=interpret)
+            kw.update(use_flash=step.use_flash, interpret=step.interpret)
             # The experts stay out of the scan over layers, whole: a scan
             # hands its body a layer's slice, and XLA copies that slice
             # out (1.2 GB a layer) before the loop over tiles reads it.

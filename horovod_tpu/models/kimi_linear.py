@@ -29,11 +29,11 @@ stack is cut into **runs** of consecutive layers of one kind, in the
 published order (:func:`layer_runs`); ``params["runs"]`` holds one
 stacked tree per run and each run is scanned, remat per layer.
 
-Trains through :func:`horovod_tpu.models.llama.make_train_step` (pass
-``model=kimi_linear``); the optimizer goes through :func:`optimizer`,
-because the router's selection bias is a buffer: the published model
-moves it by a balancing rule outside the gradient, so it takes no
-gradient and no update here.
+Trains through :func:`horovod_tpu.models.steps.make_train_step` (which
+finds this module by the configuration's class); the optimizer goes
+through :func:`optimizer`, because the router's selection bias is a
+buffer: the published model moves it by a balancing rule outside the
+gradient, so it takes no gradient and no update here.
 """
 
 from __future__ import annotations
@@ -63,7 +63,7 @@ from .layers import (
     rmsnorm,
 )
 
-# llama.make_train_step: the third output of the step is (loss, stats).
+# steps.make_train_step: the third output of the step is (loss, stats).
 LOSS_HAS_AUX = True
 
 
@@ -225,29 +225,25 @@ def _special_leaf(name: str, shape: tuple, key):
     return jnp.ones(shape, jnp.float32)               # norm gains
 
 
-def init_params(cfg: KimiLinearConfig, key: jax.Array,
-                mesh: Optional[Mesh] = None) -> dict:
-    def build(key):
-        D, V = cfg.d_model, cfg.vocab_rows
-        rnd = lambda k, shape, fan: (jax.random.normal(
-            k, shape, jnp.float32) / np.sqrt(fan)).astype(cfg.dtype)
-        runs = []
-        for r, (kind, _, n) in enumerate(layer_runs(cfg)):
-            shapes = leaf_shapes(cfg, kind)
-            ks = jax.random.split(jax.random.fold_in(key, r), len(shapes))
-            runs.append({
-                name: (rnd(k, (n,) + shape, fan) if fan is not None else
-                       jax.vmap(lambda kk: _special_leaf(name, shape, kk)
-                                )(jax.random.split(k, n)))
-                for k, (name, (shape, fan)) in zip(ks, sorted(shapes.items()))})
-        ke, kh = jax.random.split(jax.random.fold_in(key, 1 << 20))
-        return {"embed": rnd(ke, (V, D), D), "runs": runs,
-                "final_norm": jnp.ones((D,), jnp.float32),
-                "lm_head": rnd(kh, (D, V), D)}
-
-    if mesh is None:
-        return build(key)
-    return jax.jit(build, out_shardings=param_shardings(cfg, mesh))(key)
+def init_params(cfg: KimiLinearConfig, key: jax.Array) -> dict:
+    """The program's own seeded leaves (placed by the caller:
+    :func:`param_shardings`)."""
+    D, V = cfg.d_model, cfg.vocab_rows
+    rnd = lambda k, shape, fan: (jax.random.normal(
+        k, shape, jnp.float32) / np.sqrt(fan)).astype(cfg.dtype)
+    runs = []
+    for r, (kind, _, n) in enumerate(layer_runs(cfg)):
+        shapes = leaf_shapes(cfg, kind)
+        ks = jax.random.split(jax.random.fold_in(key, r), len(shapes))
+        runs.append({
+            name: (rnd(k, (n,) + shape, fan) if fan is not None else
+                   jax.vmap(lambda kk: _special_leaf(name, shape, kk)
+                            )(jax.random.split(k, n)))
+            for k, (name, (shape, fan)) in zip(ks, sorted(shapes.items()))})
+    ke, kh = jax.random.split(jax.random.fold_in(key, 1 << 20))
+    return {"embed": rnd(ke, (V, D), D), "runs": runs,
+            "final_norm": jnp.ones((D,), jnp.float32),
+            "lm_head": rnd(kh, (D, V), D)}
 
 
 def param_shardings(cfg: KimiLinearConfig, mesh: Mesh) -> dict:

@@ -20,11 +20,13 @@ in, never branched on:
 - the **mlp** is :func:`dense_mlp` or a model's expert layer, its
   ``extra`` the auxiliary loss or routing counts.
 
-Beside the frame live the pieces both model files use: RMSNorm with its
+Beside the frame live the pieces the model files share: RMSNorm with its
 hand-written VJP, RoPE, the embedding lookup, the SwiGLU MLP, the remat
 modes, the attention dispatch (sp / flash / dense), the cache-side
-attention and block gather of the serving path, and the causal-LM loss.
-Nothing here imports a model, ``serving`` or ``context``.
+attention and block gather of the serving path, the GQA mixer's paged
+cache (what every model with :func:`gqa_mixer` gives the serving steps),
+the head and the causal-LM loss.  Nothing here imports a model,
+``serving`` or ``context``.
 """
 
 from __future__ import annotations
@@ -43,6 +45,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..obs import REGISTRY as _obs
 from ..obs.trace import region
 from ..ops import flash_attention as FA
+from ..parallel import sharding as shd
 from ..parallel.ring_attention import (
     ring_attention_local,
     ulysses_attention_local,
@@ -470,6 +473,111 @@ def gqa_mixer(x, lp, tables, attend):
     return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), kept
 
 
+# -- the GQA mixer's paged cache: what every model with gqa_mixer gives the
+# serving steps (models.steps) ------------------------------------------------
+
+def gqa_shard_rules(cfg, mesh: Optional[Mesh]) -> Optional[dict]:
+    """Mesh-aware logical-rule overrides for ``cfg``'s heads.
+
+    GQA configs where tp divides ``n_heads`` but not ``n_kv_heads`` (e.g.
+    kv=2 on a tp=4 mesh) degrade the ``kv_heads`` rule to a dividing
+    prefix or replication instead of failing init with an indivisible
+    sharding — the flash path then keeps the kernel by expanding K/V at
+    dispatch (see :func:`attention`)."""
+    if mesh is None:
+        return None
+    return shd.fitted_rules(mesh, {
+        "heads": cfg.n_heads,
+        "kv_heads": cfg.n_kv_heads,
+    })
+
+
+def gqa_cache_rows(cfg) -> tuple:
+    """Per-token shape of each pool of the paged cache: keys and values,
+    grouped."""
+    return ((cfg.n_kv_heads, cfg.head_dim),) * 2
+
+
+def gqa_pool_dims(cfg) -> tuple:
+    return POOL_DIMS
+
+
+def gqa_paged_kernel_ok(cfg, mesh: Optional[Mesh], block_size: int,
+                        interpret: bool = False) -> bool:
+    """Whether the decode tick can take the paged kernel for this model,
+    mesh and page size: tp must split q and kv heads alike (each chip
+    runs the kernel on its own ``kv_heads`` shard), and the per-chip pool
+    geometry must be one the kernel compiles for (any is, for the
+    interpreter)."""
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    if cfg.n_heads % tp or cfg.n_kv_heads % tp:
+        return False
+    return interpret or FA.paged_supported(
+        block_size, cfg.head_dim, cfg.n_kv_heads // tp, cfg.n_heads // tp,
+        jnp.dtype(cfg.dtype).itemsize)
+
+
+def _gqa_paged_kernel(q, kp, vp, layer, tables, lengths, scale, mesh,
+                      rules, interpret):
+    """The Pallas paged-decode kernel on q [B, H, Dh] against layer
+    ``layer`` of the whole pools.  Under a mesh it is shard_mapped —
+    batch rows over dp·fsdp, q heads and the pool's kv_heads over tp —
+    for the same reason as the training kernel in :func:`attention`: a
+    bare pallas_call has no GSPMD partitioning rule and would gather the
+    pool onto every chip."""
+    kernel = partial(FA.paged_attention, scale=scale, interpret=interpret)
+    if mesh is None:
+        return kernel(q, kp, vp, layer, tables, lengths)
+    q_spec = shd.spec_for(("batch", "heads", None), rules)
+    pool_spec = shd.spec_for(POOL_DIMS, rules)
+    return shard_map(
+        kernel, mesh=mesh,
+        in_specs=(q_spec, pool_spec, pool_spec, P(),
+                  shd.spec_for(("batch", None), rules),
+                  shd.spec_for(("batch",), rules)),
+        out_specs=q_spec, check_vma=False)(
+            q, kp, vp, layer, tables, lengths)
+
+
+def gqa_paged_attend(cfg, mesh, tables, blk, off, mask, last=None,
+                     interpret: bool = False):
+    """The ``attend`` of the two paged steps (``models.steps``): each
+    layer writes its fresh K/V rows into page ``blk`` at offset ``off``
+    of its own pages first, then reads the table's logical window back:
+    one query a row through the Pallas paged kernel where each stream's
+    ``last`` position is given (a row whose table is all scratch has no
+    stream, and the kernel skips it), else through the contiguous gather
+    under ``mask`` (stale slots masked)."""
+    scale = 1.0 / np.sqrt(cfg.head_dim)
+    rules = gqa_shard_rules(cfg, mesh)
+
+    def put(pool, li, new):
+        # blk/off are [B] for one token a row, [B, S] for S.
+        pool = pool.at[li, blk, off].set(
+            new.reshape(blk.shape + new.shape[2:]))
+        if mesh is None:
+            return pool
+        return shd.constrain(pool, POOL_DIMS, mesh, rules)
+
+    # Block 0 is scratch and in no stream's table, and a row with no
+    # stream is all block 0: it reaches the kernel as length 0, which the
+    # kernel skips, where ``last + 1`` would read as a stream of one token.
+    lengths = None if last is None else jnp.where(
+        tables[:, 0] != 0, last + 1, 0)
+
+    def attend(q, k1, v1, li, state):
+        kp, vp = put(state[0], li, k1), put(state[1], li, v1)
+        if lengths is not None:
+            o = _gqa_paged_kernel(q[:, 0], kp, vp, li, tables, lengths,
+                                  scale, mesh, rules, interpret)[:, None]
+        else:
+            o = cached_attend(q, gather_blocks(kp[li], tables),
+                              gather_blocks(vp[li], tables), mask, scale)
+        return o, ((kp, vp), None)
+
+    return attend
+
+
 def mla_mixer(x, lp, nope: int, eps: float, tables, attend):
     """The latent-attention mixer (MLA), one function for every model
     that has the layer: queries full-rank (``wq``) or compressed and
@@ -673,7 +781,20 @@ def gather_blocks(pool, table) -> jax.Array:
     return g.reshape(B, n_cols * pool.shape[1], *pool.shape[2:])
 
 
-# -- the loss -----------------------------------------------------------------
+# -- the head and the loss ----------------------------------------------------
+
+def final_logits(params, h, cfg, dims=None, mesh: Optional[Mesh] = None,
+                 rules=None):
+    """Final norm and lm_head on ``h [..., D]``: float32 logits, pinned to
+    the logical ``dims`` under a mesh."""
+    with region("head"):
+        logits = jnp.einsum(
+            "...d,dv->...v", rmsnorm(h, params["final_norm"], cfg.rms_eps),
+            params["lm_head"])
+        if mesh is not None:
+            logits = shd.constrain(logits, dims, mesh, rules)
+        return logits.astype(jnp.float32)
+
 
 def causal_lm_loss(forward, lm_head, tokens, blockwise: bool):
     """Mean next-token cross-entropy of ``tokens [B, S+1]`` and whatever

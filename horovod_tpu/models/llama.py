@@ -18,12 +18,15 @@ story would be "bring your own torch model"), so this is built TPU-first:
   :func:`~horovod_tpu.models.layers.gqa_mixer`.  Training, the pipelined
   regions, :func:`generate` and the three serving steps each pass their
   own ``attend`` (where K and V go, what q attends over) and nothing else.
+
+The train step and the serving steps are every model's
+(:mod:`horovod_tpu.models.steps`); what is here under "serving" and
+"training" is what they ask this model for.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import sys
 from functools import partial
 from typing import Any, Optional
 
@@ -38,10 +41,9 @@ from ..obs.trace import region
 from ..ops import flash_attention as FA
 from ..parallel import sharding as shd
 from ..parallel.moe import moe_layer_local
-from .layers import (  # noqa: F401  (attention_path: re-exported)
+from .layers import (  # noqa: F401  (re-exported; the GQA cache facts)
     MIXER_RESIDUALS,
     MLP_RESIDUALS,
-    POOL_DIMS,
     attention,
     attention_path,
     block,
@@ -50,8 +52,13 @@ from .layers import (  # noqa: F401  (attention_path: re-exported)
     dense_mlp,
     device_bytes,
     embed_lookup,
-    gather_blocks,
+    final_logits,
+    gqa_cache_rows as cache_rows,
     gqa_mixer,
+    gqa_paged_attend as paged_attend,
+    gqa_paged_kernel_ok as paged_kernel_ok,
+    gqa_pool_dims as pool_dims,
+    gqa_shard_rules as shard_rules,
     looped,
     record_saved,
     remat,
@@ -60,8 +67,8 @@ from .layers import (  # noqa: F401  (attention_path: re-exported)
     saved_residuals,
     sp_local_attention,
 )
-
-_THIS = sys.modules[__name__]     # make_train_step's default model
+# The benchmark's training drivers call it by this path.
+from .steps import make_train_step  # noqa: F401
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,22 +194,6 @@ def param_logical_dims(cfg: LlamaConfig) -> dict:
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
     }
-
-
-def shard_rules(cfg: LlamaConfig, mesh: Optional[Mesh]) -> Optional[dict]:
-    """Mesh-aware logical-rule overrides for this config.
-
-    GQA configs where tp divides ``n_heads`` but not ``n_kv_heads`` (e.g.
-    kv=2 on a tp=4 mesh) degrade the ``kv_heads`` rule to a dividing
-    prefix or replication instead of failing init with an indivisible
-    sharding — the flash path then keeps the kernel by expanding K/V at
-    dispatch (see :func:`_attention`)."""
-    if mesh is None:
-        return None
-    return shd.fitted_rules(mesh, {
-        "heads": cfg.n_heads,
-        "kv_heads": cfg.n_kv_heads,
-    })
 
 
 def param_shardings(cfg: LlamaConfig, mesh: Mesh) -> dict:
@@ -497,19 +488,6 @@ def _pp_machinery(cfg: LlamaConfig, mesh: Mesh, causal: bool, S: int) -> dict:
     }
 
 
-def _head(params, h, cfg: LlamaConfig, dims=None,
-          mesh: Optional[Mesh] = None, rules=None):
-    """Final norm and lm_head on ``h [..., D]``: float32 logits, pinned to
-    the logical ``dims`` under a mesh."""
-    with region("head"):
-        logits = jnp.einsum(
-            "...d,dv->...v", rmsnorm(h, params["final_norm"], cfg.rms_eps),
-            params["lm_head"])
-        if mesh is not None:
-            logits = shd.constrain(logits, dims, mesh, rules)
-        return logits.astype(jnp.float32)
-
-
 def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
                        mesh: Mesh, causal: bool
                        ) -> tuple[jax.Array, jax.Array]:
@@ -571,7 +549,7 @@ def _forward_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
                    out_specs=(act_spec, P()), check_vma=False)
     h, aux = fn(params["layers"], h)
     h = shd.constrain(h, ("batch", "seq", None), mesh)
-    return _head(params, h, cfg, ("batch", "seq", "vocab"), mesh), aux
+    return final_logits(params, h, cfg, ("batch", "seq", "vocab"), mesh), aux
 
 
 def remat_plan(cfg: LlamaConfig, B: int, S: int, param_bytes: int,
@@ -669,7 +647,7 @@ def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig, *,
     if return_hidden:
         with region("head"):
             return final_norm(h), aux
-    return _head(params, h, cfg, ("batch", "seq", "vocab"), mesh), aux
+    return final_logits(params, h, cfg, ("batch", "seq", "vocab"), mesh), aux
 
 
 def _pick_token(logits, step_key, temperature, dtype):
@@ -834,7 +812,7 @@ def _generate_pp(params: dict, prompt: jax.Array, cfg: LlamaConfig,
         return h, (ck, cv)
 
     return _sample_loop(
-        prefill, tick, partial(_head, params, cfg=cfg,
+        prefill, tick, partial(final_logits, params, cfg=cfg,
                                dims=("batch", "vocab"), mesh=mesh),
         prompt, max_new_tokens, temperature, key)
 
@@ -896,47 +874,11 @@ def generate(params: dict, prompt: jax.Array, cfg: LlamaConfig, *,
             cfg, embed_lookup(params["embed"], tok[:, None], cfg.dtype),
             params["layers"], *caches, pos, pin, renorm=renorm)
 
-    return _sample_loop(prefill, tick, partial(_head, params, cfg=cfg),
+    return _sample_loop(prefill, tick, partial(final_logits, params, cfg=cfg),
                         prompt, max_new_tokens, temperature, key)
 
 
-# ---------------------------------------------------------------------------
-# Serving entry points (horovod_tpu/serving: continuous batching over a
-# block-paged KV cache).  The math mirrors the batch generate() paths op
-# for op, so greedy decode through the engine reproduces generate()'s
-# tokens; only cache PLACEMENT differs (the engine owns the page pool).
-# ---------------------------------------------------------------------------
-
-def model_of(cfg):
-    """The module a served configuration belongs to, by its class: this
-    one for a :class:`LlamaConfig`,
-    :mod:`horovod_tpu.models.glm_moe_lite` for its own.  The serving
-    steps ask it for what differs between models and nothing else:
-    ``serve_embed``, ``serve_runs`` (the layer runs and their ``(mixer,
-    mlp)`` pairs),
-    ``prefill_attend`` and ``paged_attend`` (where a layer's cache
-    entries go and what a query reads), ``cache_rows`` and ``pool_dims``
-    (the pools' geometry), ``serve_stats``, ``check_servable``,
-    ``paged_kernel_ok`` and ``PAGED_KERNEL``, ``state_rows`` (the
-    per-row shapes of the state slots an engine row holds beside the
-    pools: none for a model whose layers keep no recurrent state); where
-    ``serve_stats`` returns expert layers' counts, the engine also asks
-    for ``moe_layer_names`` and ``moe_experts_scored``.  Two hooks a model
-    may leave out, found through :func:`serve_hook`, whose defaults are
-    this module's: ``serve_frame(cfg)``, the frame every layer goes
-    through, called as ``frame(h, lp, mixer, mlp, eps) -> (h, kept,
-    extra)`` (:func:`~horovod_tpu.models.layers.block`; a model with
-    hyper-connections brings ``layers.hc_block``, and its ``serve_embed``
-    gives the streams ``[B, S, n, D]``), and ``serve_readout(params, h,
-    cfg)``, what the final norm reads of the last layer's residual (``h``
-    itself; with hyper-connections the one mix of the streams)."""
-    model = sys.modules[type(cfg).__module__]
-    if not hasattr(model, "serve_runs"):
-        raise NotImplementedError(
-            f"{model.__name__} has no serving steps (serve_runs and the "
-            "functions beside it)")
-    return model
-
+# -- serving: what models.steps asks a model for ----------------------------
 
 #: what :attr:`ServingEngine.attention_path` calls this model's kernel
 PAGED_KERNEL = "pallas"
@@ -955,52 +897,13 @@ def check_servable(cfg: LlamaConfig, mesh: Optional[Mesh],
             "the dropless expert layer is served (models/glm_moe_lite.py)")
 
 
-def cache_rows(cfg: LlamaConfig) -> tuple:
-    """Per-token shape of each pool of the paged cache: keys and values,
-    grouped."""
-    return ((cfg.n_kv_heads, cfg.head_dim),) * 2
-
-
-def pool_dims(cfg: LlamaConfig) -> tuple:
-    return POOL_DIMS
-
-
-def state_rows(cfg: LlamaConfig) -> tuple:
-    """No state slots: attention keeps everything in the pools."""
-    return ()
-
-
-def serve_runs(params, cfg: LlamaConfig, positions, mesh,
-               use_flash: bool = False, interpret: bool = False) -> list:
-    """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack.
-    The kernel setting of the step is a model's whose mlp has a kernel;
-    this one's kernel is its attend's."""
-    tables = rope_tables(positions, cfg.rope_theta, cfg.head_dim)
+def serve_runs(params, cfg: LlamaConfig, step) -> list:
+    """``[(stacked leaves, attend -> mixer, mlp)]``: one run, the stack
+    (the tick's kernel is its attend's)."""
+    tables = rope_tables(step.positions, cfg.rope_theta, cfg.head_dim)
     return [(params["layers"],
              lambda attend: partial(gqa_mixer, tables=tables, attend=attend),
              dense_mlp)]
-
-
-def serve_stats(cfg: LlamaConfig, extras: list) -> dict:
-    return {}
-
-
-def serve_frame(cfg):
-    """The frame of every served layer: the pre-norm block."""
-    return block
-
-
-def serve_readout(params, h, cfg):
-    """What the final norm reads: the residual as the last layer left
-    it."""
-    return h
-
-
-def serve_hook(model, name: str):
-    """``model``'s ``serve_frame`` or ``serve_readout``, else this
-    module's, which is every model's that has one residual stream."""
-    assert name in ("serve_frame", "serve_readout"), name
-    return getattr(model, name, getattr(_THIS, name))
 
 
 def serve_embed(embed, tokens, cfg: LlamaConfig):
@@ -1008,59 +911,6 @@ def serve_embed(embed, tokens, cfg: LlamaConfig):
     which partitions under the vocab_rows sharding (a model served on one
     chip with a large vocabulary brings a lookup: glm_moe_lite's)."""
     return embed_lookup(embed, tokens, cfg.dtype)
-
-
-def _serve_layers(params, tok, positions, cfg, mesh, attend, state=None,
-                  use_flash: bool = False, interpret: bool = False):
-    """The skeleton under the three serving steps, for every served
-    model: embed ``tok [B, S]``, then each of the model's layer runs
-    (:func:`model_of`'s ``serve_runs``: one for the Llama family, the
-    dense layers and the expert layers for GLM-4.7-Flash) scanned through
-    the frame over (layers, cache layer index), ``cfg.loops`` times over
-    the same stacked weights (:func:`~horovod_tpu.models.layers.looped`).
-    ``attend(..., li, state) -> (o, (state, out))`` is the step's own,
-    handed what the run's mixer hands it (rotated q and grouped k, v; or
-    q, the latent, the shared key and the up-projection): where the
-    layer's cache entries go and what q attends over; ``li`` is the cache
-    layer, ``t * n_layers + l`` in pass ``t``; ``state`` (the pools)
-    rides the scan's carry, ``out`` (a layer's entries) is stacked,
-    ``cache_layers`` deep (a model with state slots hands them on beside
-    its pools in ``state``, and its prefill's ``out`` holds a row's state
-    beside its entries).  The frame and what the final norm reads of the
-    residual are the model's hooks (:func:`serve_hook`); ``use_flash``
-    and ``interpret``, a decode tick's kernel setting, go to the runs'
-    mlps (``serve_runs``).  Returns ``(h, state, outs, stats)``,
-    ``stats`` what the model makes of its mlps' extras (the expert
-    layers' counts, or nothing)."""
-    model = model_of(cfg)
-    frame = serve_hook(model, "serve_frame")(cfg)
-    h = model.serve_embed(params["embed"], tok, cfg)
-    if mesh is not None:
-        h = shd.constrain(h, ("batch", None, None), mesh,
-                          model.shard_rules(cfg, mesh))
-    renorm = lambda h: rmsnorm(h, params["final_norm"], cfg.rms_eps)
-    first, outs, extras = 0, [], []
-    for stacked, mixer_of, mlp in model.serve_runs(
-            params, cfg, positions, mesh, use_flash, interpret):
-        def layer(carry, xs):    # traced here, with this run's pair
-            h, state = carry
-            lp, li = xs
-            h, (state, out), extra = frame(
-                h, lp, mixer_of(partial(attend, li=li, state=state)), mlp,
-                cfg.rms_eps)
-            return (h, state), (out, extra)
-
-        n = cfg.loops * jax.tree.leaves(stacked)[0].shape[0]
-        (h, state), (out, extra) = looped(
-            layer, (h, state), stacked, cfg.loops, renorm,
-            jnp.arange(first, first + n))
-        first += n
-        outs.append(out)
-        extras.append(extra)
-    outs = outs[0] if len(outs) == 1 else jax.tree.map(
-        lambda *a: jnp.concatenate(a), *outs)
-    h = serve_hook(model, "serve_readout")(params, h, cfg)
-    return h, state, outs, model.serve_stats(cfg, extras)
 
 
 def prefill_attend(cfg: LlamaConfig, mesh, P: int, last_pos=None):
@@ -1077,199 +927,7 @@ def prefill_attend(cfg: LlamaConfig, mesh, P: int, last_pos=None):
     return attend
 
 
-def prefill_step(params, tokens: jax.Array, cfg, *,
-                 mesh: Optional[Mesh] = None,
-                 last_pos: Optional[jax.Array] = None) -> tuple:
-    """Prompt prefill for the serving engine.
-
-    tokens [B, P] int32 → (next-token logits [B, V] fp32, the prompt's
-    cache entries, one array ``[cache_layers, B, P, *row]`` for each pool
-    of the model's cache (K and V for the Llama family, the latent rows
-    for GLM-4.7-Flash), then for a model with state slots one array
-    ``[cache_layers, B, *row]`` for each of them (the state at each row's
-    ``last_pos``), the step's stats).
-    ``last_pos`` [B] selects the logits position per row (bucketed
-    prompts are right-padded: the real last token sits at ``len-1``, not
-    ``P-1``); None means ``P-1``.
-    Causality makes the padded tail inert for every real position, so a
-    bucketed prefill emits the same token as an exact-length one."""
-    model = model_of(cfg)
-    B, P = tokens.shape
-    h, _, kept, stats = _serve_layers(
-        params, tokens, jnp.broadcast_to(jnp.arange(P), (B, P)), cfg, mesh,
-        model.prefill_attend(cfg, mesh, P, last_pos))
-    with region("head"):
-        if last_pos is None:
-            h_last = h[:, -1]
-        else:
-            h_last = jnp.take_along_axis(
-                h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return (_head(params, h_last, cfg, ("batch", "vocab"), mesh,
-                  model.shard_rules(cfg, mesh)), kept, stats)
-
-
-def paged_kernel_ok(cfg: LlamaConfig, mesh: Optional[Mesh],
-                    block_size: int, interpret: bool = False) -> bool:
-    """Whether :func:`decode_step_paged` can take ``use_flash=True`` for
-    this model, mesh and page size: tp must split q and kv heads alike
-    (each chip runs the kernel on its own ``kv_heads`` shard), and the
-    per-chip pool geometry must be one the kernel compiles for (any is,
-    for the interpreter)."""
-    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-    if cfg.n_heads % tp or cfg.n_kv_heads % tp:
-        return False
-    return interpret or FA.paged_supported(
-        block_size, cfg.head_dim, cfg.n_kv_heads // tp, cfg.n_heads // tp,
-        jnp.dtype(cfg.dtype).itemsize)
-
-
-def _paged_kernel_attend(q, kp, vp, layer, tables, lengths, scale, mesh,
-                         rules, interpret):
-    """The Pallas paged-decode kernel on q [B, H, Dh] against layer
-    ``layer`` of the whole pools.  Under a mesh it is shard_mapped —
-    batch rows over dp·fsdp, q heads and the pool's kv_heads over tp —
-    for the same reason as the training kernel in :func:`_attention`: a
-    bare pallas_call has no GSPMD partitioning rule and would gather the
-    pool onto every chip."""
-    kernel = partial(FA.paged_attention, scale=scale, interpret=interpret)
-    if mesh is None:
-        return kernel(q, kp, vp, layer, tables, lengths)
-    q_spec = shd.spec_for(("batch", "heads", None), rules)
-    pool_spec = shd.spec_for(POOL_DIMS, rules)
-    return shard_map(
-        kernel, mesh=mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, P(),
-                  shd.spec_for(("batch", None), rules),
-                  shd.spec_for(("batch",), rules)),
-        out_specs=q_spec, check_vma=False)(
-            q, kp, vp, layer, tables, lengths)
-
-
-def paged_attend(cfg: LlamaConfig, mesh, tables, blk, off, mask,
-                 last=None, interpret: bool = False):
-    """The ``attend`` of the two paged steps (see :func:`_serve_layers`):
-    each layer writes its fresh K/V rows into page ``blk`` at offset
-    ``off`` of its own pages first, then reads the table's logical window
-    back: one query a row through the Pallas paged kernel where each
-    stream's ``last`` position is given (a row whose table is all
-    scratch has no stream, and the kernel skips it), else through the
-    contiguous gather under ``mask`` (stale slots masked)."""
-    scale = 1.0 / np.sqrt(cfg.head_dim)
-    rules = shard_rules(cfg, mesh)
-
-    def put(pool, li, new):
-        # blk/off are [B] for one token a row, [B, S] for S.
-        pool = pool.at[li, blk, off].set(
-            new.reshape(blk.shape + new.shape[2:]))
-        if mesh is None:
-            return pool
-        return shd.constrain(pool, POOL_DIMS, mesh, rules)
-
-    # Block 0 is scratch and in no stream's table, and a row with no
-    # stream is all block 0: it reaches the kernel as length 0, which the
-    # kernel skips, where ``last + 1`` would read as a stream of one token.
-    lengths = None if last is None else jnp.where(
-        tables[:, 0] != 0, last + 1, 0)
-
-    def attend(q, k1, v1, li, state):
-        kp, vp = put(state[0], li, k1), put(state[1], li, v1)
-        if lengths is not None:
-            o = _paged_kernel_attend(q[:, 0], kp, vp, li, tables, lengths,
-                                     scale, mesh, rules, interpret)[:, None]
-        else:
-            o = cached_attend(q, gather_blocks(kp[li], tables),
-                              gather_blocks(vp[li], tables), mask, scale)
-        return o, ((kp, vp), None)
-
-    return attend
-
-
-def decode_step_paged(params, tok: jax.Array, positions: jax.Array,
-                      pools: tuple, tables: jax.Array, cfg, *,
-                      mesh: Optional[Mesh] = None, use_flash: bool = False,
-                      interpret: bool = False, slots: tuple = ()) -> tuple:
-    """One decode tick for the serving engine against the paged pools.
-
-    tok [B] int32 (this tick's input token per slot); positions [B] its
-    absolute position; ``pools`` the model's cache, each ``[cache_layers,
-    NB, BS, *row]`` (K and V ``[.., KV, Dh]`` for the Llama family, one
-    pool of latent rows for GLM-4.7-Flash); tables
-    [B, n_cols] int32 block tables (inactive rows all-scratch).  Each
-    layer writes its fresh entries into ``tables[b][positions[b] // BS]``
-    at offset ``positions[b] % BS`` and attends over the table's logical
-    window with a per-request ``<= position`` mask (stale slots masked).
-    The attention reads the pool either through a contiguous gather (XLA
-    path, GSPMD-shardable) or in the model's Pallas paged kernel, which
-    copies each stream's live pages in by the scalar-prefetched table
-    (``use_flash``; callers check the model's ``paged_kernel_ok``); a
-    model's expert layers take the same setting (GLM-4.7-Flash's routed
-    experts go through ``hvd_moe_decode`` under it).
-    ``slots``: the model's state slot arrays ``[cache_layers, B, *row]``
-    (:func:`model_of`'s ``state_rows``; none for attention alone), row b
-    read and written by row b's token.
-    Returns (logits [B, V] fp32, pools, slots, stats) — pass the pools
-    and slots donated so the writes land in place."""
-    model = model_of(cfg)
-    B = tok.shape[0]
-    BS = pools[0].shape[2]
-    T = tables.shape[1] * BS
-    mask = (jnp.arange(T)[None, :] <= positions[:, None])[:, None, :]
-    blk = tables[jnp.arange(B), positions // BS]                # [B]
-    n = len(pools)
-    h, state, _, stats = _serve_layers(
-        params, tok[:, None], positions[:, None], cfg, mesh,
-        model.paged_attend(cfg, mesh, tables, blk, positions % BS, mask,
-                           positions if use_flash else None, interpret),
-        tuple(pools) + tuple(slots), use_flash, interpret)
-    logits = _head(params, h[:, 0], cfg, ("batch", "vocab"), mesh,
-                   model.shard_rules(cfg, mesh))
-    return logits, state[:n], state[n:], stats
-
-
-def extend_step_paged(params, tok: jax.Array, positions: jax.Array,
-                      valid: jax.Array, pools: tuple, tables: jax.Array,
-                      cfg, *, mesh: Optional[Mesh] = None) -> tuple:
-    """Multi-token paged forward: S tokens per row in ONE dispatch.
-
-    The serving front door's verify-forward entry — it serves both
-    (a) **prefix-hit tail prefill**: a prompt whose head is already in
-    the pool (radix prefix cache) prefills only its tail while attending
-    over the cached prefix, and (b) **speculative-decode verify**:
-    the target model scores ``k + 1`` positions (last accepted token +
-    k draft tokens) in one forward so the accepted prefix falls out of a
-    single logits comparison.
-
-    tok [B, S] int32; positions [B, S] absolute positions per token;
-    valid [B, S] bool — False slots (right-padding, inactive verify
-    rows) route their cache writes to scratch block 0 so a padded slot
-    repeating a real position can never double-write a live (block,
-    offset); their logits are meaningless and must be ignored.
-    ``pools`` as :func:`decode_step_paged`'s; tables [B, n_cols] int32.
-
-    Each layer writes all S fresh entries first, then attends over the
-    table's logical window with the per-token causal mask ``pool_pos <=
-    positions[b, s]`` — so token s sees the cached prefix AND the
-    earlier tokens of this same call (their entries just landed in the
-    pool), exactly the visibility a monolithic prefill gives it.  Reads
-    go through the contiguous-gather path (GSPMD-shardable); the Pallas
-    decode kernels are single-query and do not apply here.  Returns
-    (logits [B, S, V] fp32, pools, stats) — donate the pools.  A model
-    with state slots refuses it (``check_servable``'s ``"extend"``)."""
-    model = model_of(cfg)
-    model.check_servable(cfg, mesh, path="extend")
-    BS = pools[0].shape[2]
-    T = tables.shape[1] * BS
-    mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]  # [B,S,T]
-    blk = jnp.where(valid,
-                    jnp.take_along_axis(tables, positions // BS, axis=1),
-                    0)                                             # [B,S]
-    off = jnp.where(valid, positions % BS, 0)
-    h, pools, _, stats = _serve_layers(
-        params, tok, positions, cfg, mesh,
-        model.paged_attend(cfg, mesh, tables, blk, off, mask), tuple(pools))
-    return (_head(params, h, cfg, ("batch", None, "vocab"), mesh,
-                  model.shard_rules(cfg, mesh)), pools, stats)
-
+# -- training: what models.steps asks a model for ---------------------------
 
 def _use_blockwise_ce(cfg: LlamaConfig, mesh: Optional[Mesh]) -> bool:
     if not cfg.blockwise_ce:
@@ -1294,38 +952,21 @@ def loss_fn(params: dict, batch: dict, cfg: LlamaConfig, *,
     return loss + cfg.moe_aux_weight * aux
 
 
-def _opt_shardings(tx, cfg, mesh: Mesh, model=None):
-    """Explicit shardings for the optimizer state: every param-shaped
-    subtree (adam mu/nu, momentum, ...) mirrors the parameter shardings,
-    anything else (step counters) replicates.
-
-    jit with donated arguments needs these spelled out: leaving the opt
-    state's shardings to inference lets the propagator pick layouts that
-    disagree with the donated inputs on tp/sp meshes, and XLA aliasing
-    fails at runtime with a sub-shape size mismatch."""
-    model = model or _THIS
-    pshard = model.param_shardings(cfg, mesh)
-    repl = NamedSharding(mesh, P())
-    params_aval = jax.eval_shape(partial(model.init_params, cfg),
-                                 jax.random.PRNGKey(0))
-    ptree = jax.tree.structure(params_aval)
-    state_aval = jax.eval_shape(tx.init, params_aval)
-
-    def is_param_subtree(x):
-        try:
-            return jax.tree.structure(x) == ptree
-        except Exception:  # pragma: no cover - exotic leaves
-            return False
-
-    return jax.tree.map(
-        lambda sub: pshard if is_param_subtree(sub)
-        else jax.tree.map(lambda _: repl, sub),
-        state_aval, is_leaf=is_param_subtree)
+def check_trainable(cfg: LlamaConfig) -> None:
+    """Raise, by name, for what of a :class:`LlamaConfig` has no
+    objective here: a looped stack."""
+    if cfg.loops > 1:
+        raise NotImplementedError(
+            f"loops={cfg.loops}: training a looped stack needs its own "
+            "objective, each pass's loss weighed by a learned exit "
+            "distribution with an entropy term, which is not here; plain "
+            "cross-entropy on the last pass would train another model")
 
 
-def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
-    """Training step for pp>1 meshes on the 1F1B schedule
-    (:func:`horovod_tpu.parallel.pipeline.pipeline_train_local`).
+def train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
+    """The training step for pp>1 meshes on the 1F1B schedule
+    (:func:`horovod_tpu.parallel.pipeline.pipeline_train_local`), for
+    ``models.steps.make_train_step`` to jit.
 
     Unlike the GPipe path (autodiff through the forward tick loop, all M
     microbatch activations live at the fwd/bwd boundary), this computes
@@ -1348,14 +989,13 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
     """
     from ..parallel.pipeline import pipeline_train_local
 
-    pp = mesh.shape["pp"]
+    if cfg.blockwise_ce:
+        raise NotImplementedError("blockwise CE requires a pp=1 mesh")
+
     data_axes = ("dp", "fsdp", "ep", "sp")
     n_data = 1
     for a in data_axes:
         n_data *= mesh.shape.get(a, 1)
-    pshard = param_shardings(cfg, mesh)
-    repl = NamedSharding(mesh, P())
-    batch_shard = NamedSharding(mesh, P(("dp", "fsdp")))
     head_dims = {"lm_head": param_logical_dims(cfg)["lm_head"],
                  "final_norm": param_logical_dims(cfg)["final_norm"]}
     head_specs = {k: shd.spec_for(d) for k, d in head_dims.items()}
@@ -1467,74 +1107,4 @@ def _make_train_step_1f1b(cfg: LlamaConfig, mesh: Mesh, tx):
             params = jax.tree.map(jnp.add, params, updates)
         return params, opt_state, loss + cfg.moe_aux_weight * aux
 
-    opt_shard = _opt_shardings(tx, cfg, mesh)
-    return jax.jit(step, in_shardings=(pshard, opt_shard, batch_shard),
-                   out_shardings=(pshard, opt_shard, repl),
-                   donate_argnums=(0, 1))
-
-
-def make_train_step(cfg, mesh: Mesh, tx, *,
-                    pipeline_schedule: str = "1f1b", model=None):
-    """Jitted full training step over the mesh (GSPMD collectives for
-    dp/fsdp/tp, explicit shard_map blocks for sp/ep; layer stack over pp).
-
-    ``model`` is the module the loss and the layout come from: it gives
-    ``loss_fn(params, batch, cfg, mesh=)``, ``param_shardings(cfg, mesh)``
-    and ``init_params(cfg, key)`` (for the optimizer state's shapes).
-    The default is this module with a :class:`LlamaConfig`;
-    :mod:`horovod_tpu.models.kimi_linear` is the other; a model whose
-    objective is not written refuses by its ``check_trainable(cfg)``
-    (:mod:`horovod_tpu.models.glm_moe_lite`).  Where the model
-    sets ``LOSS_HAS_AUX`` its loss returns ``(loss, aux)`` and so does
-    the step, as its third output.
-
-    On pp>1 meshes ``pipeline_schedule`` selects "1f1b" (default: explicit
-    interleaved fwd/bwd schedule, activation memory bounded by 2*(pp-1)
-    microbatches) or "gpipe" (autodiff through the fill-drain forward);
-    both are the Llama stack's."""
-    model = model or _THIS
-    if getattr(cfg, "loops", 1) > 1:
-        raise NotImplementedError(
-            f"loops={cfg.loops}: training a looped stack needs its own "
-            "objective, each pass's loss weighed by a learned exit "
-            "distribution with an entropy term, which is not here; plain "
-            "cross-entropy on the last pass would train another model")
-    if hasattr(model, "check_trainable"):
-        model.check_trainable(cfg)       # raises, naming what is missing
-    if mesh.shape.get("pp", 1) > 1 and model is not _THIS:
-        raise NotImplementedError(
-            f"{model.__name__} has no pipelined forward; use a pp=1 mesh")
-    if mesh.shape.get("pp", 1) > 1 and pipeline_schedule == "1f1b":
-        if cfg.blockwise_ce:
-            raise NotImplementedError("blockwise CE requires a pp=1 mesh")
-        return _make_train_step_1f1b(cfg, mesh, tx)
-    has_aux = getattr(model, "LOSS_HAS_AUX", False)
-    pshard = model.param_shardings(cfg, mesh)
-    repl = NamedSharding(mesh, P())
-    batch_shard = NamedSharding(mesh, P(("dp", "fsdp")))
-
-    multi_device = any(s > 1 for s in mesh.shape.values())
-
-    def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(
-            lambda p: model.loss_fn(p, batch, cfg, mesh=mesh),
-            has_aux=has_aux)(params)
-        # Pin gradients to the parameter shardings: the backward scan's
-        # per-layer dynamic-update-slice accumulators otherwise get
-        # propagation-derived shardings that force involuntary full
-        # rematerialization on the way into the optimizer update.  (On a
-        # single-device mesh the annotation is a no-op semantically and
-        # only an XLA fusion barrier, so it is skipped.)
-        if multi_device:
-            grads = jax.lax.with_sharding_constraint(grads, pshard)
-        with region("optim"):
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = jax.tree.map(jnp.add, params, updates)
-        return params, opt_state, loss
-
-    opt_shard = _opt_shardings(tx, cfg, mesh, model)
-    return jax.jit(
-        step,
-        in_shardings=(pshard, opt_shard, batch_shard),
-        out_shardings=(pshard, opt_shard, repl),
-        donate_argnums=(0, 1))
+    return step

@@ -8,7 +8,8 @@ What is GLM-4.7-Flash's is imported from
 leaves of attention and of the expert layers, the layer runs (dense
 layers first, ``first_k_dense_replace`` of them), the expert layer
 (``_moe_mlp``, ``moe_tile``), the latent mixer, both ``attend`` forms,
-the pool hooks and the embedding's lookup.  What differs:
+the pool hooks, the embedding's lookup and the refusals (no training
+objective, no mesh).  What differs:
 
 - **the frame** (:func:`horovod_tpu.models.layers.hc_block`): the
   residual is ``X [B, S, n, D]``, ``n = hc_mult`` streams, the token's
@@ -24,9 +25,8 @@ the pool hooks and the embedding's lookup.  What differs:
   and its ``mscale**2`` in the softmax scale of both attention forms.
 
 Served by :class:`horovod_tpu.serving.ServingEngine` through the steps of
-:mod:`horovod_tpu.models.llama`, which find this module by the
-configuration's class and ask it for its frame and its read-out.  Not
-trained here (:func:`check_trainable`), nor served over a mesh.
+:mod:`horovod_tpu.models.steps`, which find this module by the
+configuration's class and ask it for its frame and its read-out.
 """
 
 from __future__ import annotations
@@ -48,6 +48,8 @@ from .glm_moe_lite import (  # noqa: F401  (the serving hooks, re-exported)
     _mixer,
     _mlp,
     cache_rows,
+    check_servable,
+    check_trainable,
     layer_runs,
     leaf_shapes as _glm_leaf_shapes,
     moe_experts_scored,
@@ -60,8 +62,6 @@ from .glm_moe_lite import (  # noqa: F401  (the serving hooks, re-exported)
     serve_embed as _glm_embed,
     serve_runs,
     serve_stats,
-    shard_rules,
-    state_rows,
 )
 from .layers import (
     HyperConnections,
@@ -269,20 +269,3 @@ def forward(params: dict, tokens: jax.Array, cfg: Xing4Config, *,
             return h, stats
         logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
         return logits.astype(F32), stats
-
-
-def check_trainable(cfg: Xing4Config) -> None:
-    """What :func:`horovod_tpu.models.llama.make_train_step` asks first."""
-    raise NotImplementedError(
-        "xing4 has no training objective here: the published model trains "
-        "a multi-token prediction module (num_nextn_predict_layers) beside "
-        "the next-token loss, which is neither written nor loaded; plain "
-        "cross-entropy would train another model")
-
-
-def check_servable(cfg: Xing4Config, mesh: Optional[Mesh],
-                   engine_cfg=None, path: Optional[str] = None) -> None:
-    if mesh is not None and any(s > 1 for s in mesh.shape.values()):
-        raise NotImplementedError(
-            "xing4 is served whole on one chip: a mesh would split the "
-            "streams' maps, whose leaves carry no logical dims")

@@ -50,7 +50,7 @@ from typing import Any, Optional
 
 import numpy as np
 
-from ..models import llama
+from ..models import steps
 from .. import chaos
 from ..obs import REGISTRY as _obs
 from ..obs import trace as _trace
@@ -217,14 +217,15 @@ class ServingEngine:
                  engine_cfg: EngineConfig = EngineConfig(),
                  mesh=None, timeline=None,
                  draft_params: Any = None,
-                 draft_cfg: Optional[llama.LlamaConfig] = None) -> None:
+                 draft_cfg: Any = None) -> None:
         #: Timeline-v2 sink request traces render on (one lane per
         #: request with QUEUE->PREFILL->DECODE flow arrows); None keeps
         #: traces JSON/flight-recorder-only.
         self.timeline = timeline
-        #: the module of ``cfg``'s class: what the serving steps ask for
-        #: the layer runs, the cache's geometry and the attention paths
-        self.model = llama.model_of(cfg)
+        #: the model of ``cfg``'s class (:func:`steps.model_of`): what the
+        #: serving steps ask for the layer runs, the cache's geometry and
+        #: the attention paths
+        self.model = steps.model_of(cfg)
         self.model.check_servable(cfg, mesh, engine_cfg)
         self.params = params
         self.cfg = cfg
@@ -349,7 +350,7 @@ class ServingEngine:
             return self._jnp.argmax(logits, axis=-1).astype(self._jnp.int32)
 
     def _prefill_impl(self, params, tokens, last_pos):
-        logits, kept, stats = llama.prefill_step(
+        logits, kept, stats = steps.prefill_step(
             params, tokens, self.cfg, mesh=self.mesh, last_pos=last_pos)
         return (self._pick(logits), stats), kept
 
@@ -394,7 +395,7 @@ class ServingEngine:
         return out + (toks.at[row].set(pick[0]),)
 
     def _decode_impl(self, params, pools, tok, pos, tables, slots):
-        logits, pools, slots, stats = llama.decode_step_paged(
+        logits, pools, slots, stats = steps.decode_step_paged(
             params, tok, pos, pools, tables, self.cfg, mesh=self.mesh,
             use_flash=self._use_flash, interpret=self._interpret,
             slots=slots)
@@ -403,7 +404,7 @@ class ServingEngine:
     def _extend_impl(self, params, pools, tok, pos, valid, tables):
         """Multi-token paged forward ([B, S] at arbitrary positions):
         the prefix-hit tail prefill and the speculative verify step."""
-        logits, pools, stats = llama.extend_step_paged(
+        logits, pools, stats = steps.extend_step_paged(
             params, tok, pos, valid, pools, tables, self.cfg,
             mesh=self.mesh)
         return (self._pick(logits), stats), pools

@@ -8,7 +8,7 @@ once):
    as the target, so both models index the pool through the SAME block
    tables — one allocator, two pools);
 2. **verify** — ONE target forward
-   (:func:`horovod_tpu.models.llama.extend_step_paged`) over the
+   (:func:`horovod_tpu.models.steps.extend_step_paged`) over the
    ``k + 1`` tokens ``[t_last, d_1..d_k]`` at positions ``C..C+k``
    yields the target's greedy token ``g_j`` after every prefix;
 3. **accept** — the agreeing prefix ``d_1..d_m`` (``d_i == g_{i-1}``)
@@ -33,7 +33,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from ...models import llama
+from ...models import llama, steps
 from ...obs import REGISTRY as _obs
 from ..kv_pager import PagedKVCache
 from ..scheduler import RequestState
@@ -64,7 +64,7 @@ class SpecDecoder:
             raise NotImplementedError(
                 "the draft is a separate dense model of the Llama family "
                 f"(LlamaConfig), not a {type(draft_cfg).__name__}")
-        llama.check_servable(draft_cfg, None)
+        steps.model_of(draft_cfg).check_servable(draft_cfg, None)
         if draft_cfg.vocab_size != engine.cfg.vocab_size:
             raise ValueError(
                 f"draft vocab {draft_cfg.vocab_size} != target vocab "
@@ -80,7 +80,7 @@ class SpecDecoder:
             n_layers=draft_cfg.cache_layers,
             num_blocks=engine.cache.num_blocks,
             block_size=engine.cache.block_size,
-            rows=llama.cache_rows(draft_cfg))
+            rows=steps.model_of(draft_cfg).cache_rows(draft_cfg))
         # The draft pools stay replicated on a mesh: the draft is small
         # by design and its kv_heads need not divide tp.
         self.pools = tuple(jnp.zeros(shape, draft_cfg.dtype)
@@ -100,18 +100,18 @@ class SpecDecoder:
 
     # -- draft-model jitted bodies (target mesh rules do not apply) ------
     def _prefill_impl(self, params, tokens, last_pos):
-        _, kept, _ = llama.prefill_step(
+        _, kept, _ = steps.prefill_step(
             params, tokens, self.draft_cfg, mesh=None, last_pos=last_pos)
         return kept
 
     def _decode_impl(self, params, pools, tok, pos, tables):
-        logits, pools, _, _ = llama.decode_step_paged(
+        logits, pools, _, _ = steps.decode_step_paged(
             params, tok, pos, pools, tables, self.draft_cfg, mesh=None,
             use_flash=False)
         return self.eng._pick(logits), pools
 
     def _extend_impl(self, params, pools, tok, pos, valid, tables):
-        _, pools, _ = llama.extend_step_paged(
+        _, pools, _ = steps.extend_step_paged(
             params, tok, pos, valid, pools, tables, self.draft_cfg,
             mesh=None)
         return pools

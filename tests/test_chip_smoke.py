@@ -289,7 +289,7 @@ def test_the_mistral_train_step_fits_a_v5e_with_what_remat_keeps(
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
     import optax
-    from horovod_tpu.models import layers, llama
+    from horovod_tpu.models import layers, llama, steps
     from horovod_tpu.parallel import MeshConfig, build_mesh
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
@@ -311,10 +311,10 @@ def test_the_mistral_train_step_fits_a_v5e_with_what_remat_keeps(
     put = lambda tree, shard: jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         tree, shard)
-    compiled = llama.make_train_step(cfg, mesh, tx).lower(
+    compiled = steps.make_train_step(cfg, mesh, tx).lower(
         put(aval, llama.param_shardings(cfg, mesh)),
         put(jax.eval_shape(tx.init, aval),
-            llama._opt_shardings(tx, cfg, mesh)),
+            steps._opt_shardings(tx, cfg, mesh, steps.model_of(cfg))),
         {"tokens": jax.ShapeDtypeStruct(
             (4, 2049), jnp.int32,
             sharding=NamedSharding(mesh, P(("dp", "fsdp"))))}).compile()

@@ -28,7 +28,8 @@ from chipbench import weights_falcon_h1 as wts
 from horovod_tpu.models import falcon_h1 as F
 from horovod_tpu.models import glm_moe_lite as G
 from horovod_tpu.models import kimi_linear as KL
-from horovod_tpu.models import layers, llama
+from horovod_tpu.models import xing4 as X
+from horovod_tpu.models import layers, llama, steps
 from horovod_tpu.obs import REGISTRY
 from horovod_tpu.ops import ssd
 from horovod_tpu.serving import EngineConfig, ServingEngine
@@ -211,10 +212,10 @@ def test_bucketed_prefill_leaves_the_exact_prefills_row(params):
     the same from its 16-token bucket as from its exact length, and so
     is the next token's logit row."""
     toks = np.random.RandomState(8).randint(0, 256, 13).astype(np.int32)
-    exact = llama.prefill_step(params, jnp.asarray(toks)[None], CFG)
+    exact = steps.prefill_step(params, jnp.asarray(toks)[None], CFG)
     padded = np.zeros((1, 16), np.int32)
     padded[0, :13] = toks
-    bucket = llama.prefill_step(params, jnp.asarray(padded), CFG,
+    bucket = steps.prefill_step(params, jnp.asarray(padded), CFG,
                                 last_pos=jnp.asarray([12]))
     np.testing.assert_allclose(bucket[0], exact[0], rtol=1e-5, atol=1e-6)
     assert len(exact[1]) == 4                     # K, V, state, tail
@@ -233,10 +234,10 @@ def _serve(params, engine_cfg=ENGINE, prompts=PROMPTS, new=NEW,
     decode dispatches."""
     eng = ServingEngine(params, CFG, engine_cfg=engine_cfg)
     served: dict = {}
-    prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
+    prefill = jax.jit(lambda p, tok, last: steps.prefill_step(
         p, tok, CFG, last_pos=last))
     decode = jax.jit(lambda p, pools, tok, pos, tables, slots:
-                     llama.decode_step_paged(
+                     steps.decode_step_paged(
                          p, tok, pos, pools, tables, CFG,
                          use_flash=eng._use_flash, interpret=eng._interpret,
                          slots=slots))
@@ -340,7 +341,7 @@ REFUSALS = {
     "spec_k": lambda p: ServingEngine(
         p, CFG, engine_cfg=dataclasses.replace(ENGINE, spec_k=2),
         draft_params=p, draft_cfg=CFG),
-    "extend": lambda p: llama.extend_step_paged(
+    "extend": lambda p: steps.extend_step_paged(
         p, jnp.zeros((1, 4), jnp.int32), jnp.zeros((1, 4), jnp.int32),
         jnp.ones((1, 4), bool), _engine(p).pools,
         jnp.zeros((1, 4), jnp.int32), CFG),
@@ -373,7 +374,9 @@ def test_import_of_a_migrated_request_is_refused(params):
 #: multi-stream residual frame (a model in the plain frame compiles what
 #: it did); Falcon-H1 refuses the extend path.  GLM's interpreted tick
 #: runs its routed experts through the interpreted ``hvd_moe_decode``
-#: since the kernel was written (its XLA tick keeps the tile loop)
+#: since the kernel was written (its XLA tick keeps the tile loop);
+#: Xing4.0's as made from the tree before the steps every model goes
+#: through moved out of ``models/llama.py`` into ``models/steps.py``
 UNCHANGED = {
     "llama": {"prefill": "674b5f14b9ee", "scatter": "4f94cbfa9d52",
               "decode": "94e8a4ab1f82", "extend": "fef456b62af2",
@@ -386,6 +389,9 @@ UNCHANGED = {
             "decode.interpret": "53517f214909"},
     "falcon": {"prefill": "2134afb5fd09", "scatter": "0cb73678c692",
                "decode": "69d3b3057a43", "decode.interpret": "89ee5e9def1f"},
+    "xing4": {"prefill": "1cfd4abaea98", "scatter": "454bb854e887",
+              "decode": "385b783afa47", "extend": "58dc68d94254",
+              "decode.interpret": "b699af455faa"},
 }
 SERVED = {
     "llama": lambda: llama.LlamaConfig.tiny(n_kv_heads=4),
@@ -393,13 +399,14 @@ SERVED = {
                                            sandwich_norm=True),
     "glm": lambda: G.GlmMoeLiteConfig.tiny(),
     "falcon": lambda: CFG,
+    "xing4": lambda: X.Xing4Config.tiny(),
 }
 
 
 @pytest.mark.parametrize("kind", sorted(SERVED))
 def test_the_other_served_models_programs_are_unchanged(kind):
     cfg = SERVED[kind]()
-    model = llama.model_of(cfg)
+    model = steps.model_of(cfg)
     assert (model.state_rows(cfg) == ()) == (kind != "falcon")
     p = model.init_params(cfg, jax.random.PRNGKey(0))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)

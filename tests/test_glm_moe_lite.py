@@ -26,7 +26,7 @@ from chipbench import weights_glm as wts
 from horovod_tpu import serving
 from horovod_tpu.models import glm_moe_lite as G
 from horovod_tpu.models import kimi_linear as KL
-from horovod_tpu.models import layers, llama
+from horovod_tpu.models import layers, llama, steps
 from horovod_tpu.obs import REGISTRY
 from horovod_tpu.ops import flash_attention as FA
 from horovod_tpu.parallel import moe
@@ -134,10 +134,10 @@ def _serve(params, engine_cfg=ENGINE, ticks=None):
     was picked from; ``ticks`` counts the decode dispatches."""
     eng = ServingEngine(params, CFG, engine_cfg=engine_cfg)
     served: dict = {}
-    prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
+    prefill = jax.jit(lambda p, tok, last: steps.prefill_step(
         p, tok, CFG, last_pos=last))
     decode = jax.jit(lambda p, pools, tok, pos, tables, slots:
-                     llama.decode_step_paged(
+                     steps.decode_step_paged(
                          p, tok, pos, pools, tables, CFG,
                          use_flash=eng._use_flash, interpret=eng._interpret,
                          slots=slots))
@@ -678,16 +678,16 @@ def test_the_prefill_steps_tile_loop_writes_a_list_and_adds_no_rows(params):
     tile's rows into the list and holds no scatter-add of rows
     ``d_model`` wide; the tick's and the extend step's neither."""
     tok = jnp.asarray(PROMPTS[2])[None]
-    assert combine_ops(lambda p, t: llama.prefill_step(p, t, CFG)[0],
+    assert combine_ops(lambda p, t: steps.prefill_step(p, t, CFG)[0],
                        params, tok, width=CFG.d_model) == (1, 0)
     pool = jnp.zeros((3, 8, 4, 128))
     tables = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
     assert combine_ops(
-        lambda p, pool: llama.decode_step_paged(
+        lambda p, pool: steps.decode_step_paged(
             p, jnp.asarray([9, 7, 0]), jnp.asarray([9, 5, 0]), (pool,),
             tables, CFG)[0], params, pool, width=CFG.d_model) == (1, 0)
     assert combine_ops(
-        lambda p, pool: llama.extend_step_paged(
+        lambda p, pool: steps.extend_step_paged(
             p, jnp.zeros((3, 2), jnp.int32), jnp.zeros((3, 2), jnp.int32),
             jnp.ones((3, 2), bool), (pool,), tables, CFG)[0],
         params, pool, width=CFG.d_model) == (1, 0)
@@ -967,7 +967,7 @@ def test_training_is_refused_naming_the_missing_objective():
     import optax
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
     with pytest.raises(NotImplementedError, match="multi-token prediction"):
-        llama.make_train_step(CFG, mesh, optax.sgd(0.1), model=G)
+        steps.make_train_step(CFG, mesh, optax.sgd(0.1), model=G)
     assert not hasattr(G, "loss_fn")
 
 
@@ -988,20 +988,21 @@ def test_steps_return_each_expert_layers_counts(params):
     """The counts ride the step's own outputs: every row's k pairs, a
     layer a row of the result, for the prefill, the tick and extend."""
     tok = jnp.asarray(PROMPTS[1])[None]
-    _, kept, stats = llama.prefill_step(params, tok, CFG)
+    _, kept, stats = steps.prefill_step(params, tok, CFG)
     assert kept[0].shape == (3, 1, 9, 128)
     assert np.asarray(stats["expert_counts"]).sum(-1).tolist() == [18, 18]
     pool = jnp.zeros((3, 8, 4, 128))
     tables = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
     args = (jnp.asarray([9, 7, 0]), jnp.asarray([9, 5, 0]), (pool,), tables)
-    _, pools, _, stats = llama.decode_step_paged(params, *args, CFG)
+    _, pools, _, stats = steps.decode_step_paged(params, *args, CFG)
     assert len(pools) == 1
     assert np.asarray(stats["expert_counts"]).sum(-1).tolist() == [6, 6]
-    _, _, stats = llama.extend_step_paged(
+    _, _, stats = steps.extend_step_paged(
         params, jnp.zeros((3, 2), jnp.int32), jnp.zeros((3, 2), jnp.int32),
         jnp.ones((3, 2), bool), (pool,), tables, CFG)
     assert np.asarray(stats["expert_counts"]).shape == (2, 8)
-    assert llama.serve_stats(llama.LlamaConfig.tiny(), [None]) == {}
+    dense = llama.LlamaConfig.tiny()
+    assert steps.model_of(dense).serve_stats(dense, [None]) == {}
 
 
 # -- compiled for a v5e, no chip needed ---------------------------------------------
@@ -1041,7 +1042,7 @@ def test_the_cells_decode_tick_compiles_for_a_v5e(monkeypatch):
     pool = spec((7, 18432, 32, cfg.cache_row))
     i32 = lambda *shape: spec(shape, jnp.int32)
     step = jax.jit(
-        lambda p, tok, pos, pool, tables: llama.decode_step_paged(
+        lambda p, tok, pos, pool, tables: steps.decode_step_paged(
             p, tok, pos, (pool,), tables, cfg, use_flash=True),
         donate_argnums=(3,))
     compiled = step.lower(p, i32(64), i32(64), pool, i32(64, 512)).compile()
@@ -1095,7 +1096,7 @@ def test_the_cells_longest_prefill_compiles_for_a_v5e(monkeypatch):
         lambda: G.init_params(cfg, jax.random.PRNGKey(0))))
 
     def prefill(p, tok, last):
-        logits, kept, stats = llama.prefill_step(p, tok, cfg, last_pos=last)
+        logits, kept, stats = steps.prefill_step(p, tok, cfg, last_pos=last)
         return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
     compiled = jax.jit(prefill).lower(
         p, spec((1, P), jnp.int32), spec((1,), jnp.int32)).compile()

@@ -24,7 +24,7 @@ from chipbench import reference_kimi_linear as ref            # noqa: E402
 from chipbench import run as runmod                           # noqa: E402
 from chipbench import weights_kimi_linear as wts              # noqa: E402
 from chipbench.drivers.train_kimi_linear import kimi_config   # noqa: E402
-from horovod_tpu.models import kimi_linear as KL, layers, llama  # noqa: E402
+from horovod_tpu.models import kimi_linear as KL, layers, llama, steps  # noqa: E402
 from horovod_tpu.ops import kda                               # noqa: E402
 from horovod_tpu.parallel import moe                          # noqa: E402
 
@@ -450,7 +450,7 @@ def test_llama_step_lowers_to_the_program_it_lowered_to():
         return params, opt_state, loss
 
     pshard = llama.param_shardings(cfg, mesh)
-    opt_shard = llama._opt_shardings(tx, cfg, mesh)
+    opt_shard = steps._opt_shardings(tx, cfg, mesh, steps.model_of(cfg))
     repl = NamedSharding(mesh, P())
     before = jax.jit(
         step, in_shardings=(pshard, opt_shard,
@@ -459,7 +459,7 @@ def test_llama_step_lowers_to_the_program_it_lowered_to():
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     args = (params, tx.init(params),
             {"tokens": jnp.zeros((2, 17), jnp.int32)})
-    assert llama.make_train_step(cfg, mesh, tx).lower(*args).as_text() == \
+    assert steps.make_train_step(cfg, mesh, tx).lower(*args).as_text() == \
         before.lower(*args).as_text()
 
 
@@ -483,7 +483,7 @@ def test_the_train_step_lowers_as_before_the_tick_kernel(held):
     mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
     tx = KL.optimizer(optax.adamw(1e-3))
     params = wts.stacked(KEY, dims, jnp.float32)
-    text = llama.make_train_step(kcfg, mesh, tx, model=KL).lower(
+    text = steps.make_train_step(kcfg, mesh, tx, model=KL).lower(
         params, tx.init(params),
         {"tokens": jnp.zeros((2, 33), jnp.int32)}).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:12] == \

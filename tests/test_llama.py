@@ -15,7 +15,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.tree import leaves_with_path
 
-from horovod_tpu.models import llama
+from horovod_tpu.models import llama, steps
 from horovod_tpu.parallel import MeshConfig, build_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -70,7 +70,7 @@ def test_train_step_sharded(mesh_cfg):
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     tx = optax.adam(1e-2)
     opt_state = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
     batch = jax.device_put(_batch(cfg, B=8, S=32),
                            NamedSharding(mesh, P(("dp", "fsdp"))))
     losses = []
@@ -88,7 +88,7 @@ def test_train_step_moe_ep():
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     tx = optax.adam(1e-2)
     opt_state = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
     batch = jax.device_put(_batch(cfg, B=8, S=32),
                            NamedSharding(mesh, P(("dp", "fsdp"))))
     losses = []
@@ -123,7 +123,7 @@ def test_pp_1f1b_activation_memory_below_gpipe():
                            NamedSharding(mesh, P(("dp", "fsdp"))))
 
     def temp_bytes(schedule):
-        step = llama.make_train_step(cfg, mesh, tx,
+        step = steps.make_train_step(cfg, mesh, tx,
                                      pipeline_schedule=schedule)
         comp = step.lower(params, opt_state, batch).compile()
         return comp.memory_analysis().temp_size_in_bytes
@@ -144,7 +144,7 @@ def test_pp_pipeline_no_per_layer_param_gather():
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     tx = optax.adam(1e-2)
     opt_state = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
     batch = jax.device_put(_batch(cfg, B=8, S=32),
                            NamedSharding(mesh, P(("dp", "fsdp"))))
     txt = step.lower(params, opt_state, batch).compile().as_text()
@@ -280,7 +280,7 @@ def test_pp_ep_moe_trains():
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     tx = optax.adam(1e-2)
     opt_state = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
     batch = jax.device_put(_batch(cfg, B=8, S=32),
                            NamedSharding(mesh, P(("dp", "fsdp"))))
     losses = []
@@ -312,7 +312,7 @@ def test_pp_moe_1f1b_matches_gpipe(mesh_cfg):
     def run(schedule):
         params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
         opt_state = jax.jit(tx.init)(params)
-        step = llama.make_train_step(cfg, mesh, tx,
+        step = steps.make_train_step(cfg, mesh, tx,
                                      pipeline_schedule=schedule)
         losses = []
         for _ in range(3):
@@ -376,7 +376,7 @@ def _train_losses(mesh_cfg, n_steps=4, seed=0, schedule="1f1b", cfg=None):
     params = llama.init_params(cfg, jax.random.PRNGKey(seed), mesh)
     tx = optax.adam(1e-2)
     opt_state = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx,
+    step = steps.make_train_step(cfg, mesh, tx,
                                  pipeline_schedule=schedule)
     batch = jax.device_put(_batch(cfg, B=8, S=32, seed=seed),
                            NamedSharding(mesh, P(("dp", "fsdp"))))

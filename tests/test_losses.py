@@ -103,7 +103,7 @@ def test_llama_blockwise_ce_trains_on_fsdp_mesh():
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.models import llama
+    from horovod_tpu.models import llama, steps
     from horovod_tpu.parallel import MeshConfig, build_mesh
 
     def losses(blockwise):
@@ -113,7 +113,7 @@ def test_llama_blockwise_ce_trains_on_fsdp_mesh():
         params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
         tx = optax.adam(1e-2)
         opt_state = jax.jit(tx.init)(params)
-        step = llama.make_train_step(cfg, mesh, tx)
+        step = steps.make_train_step(cfg, mesh, tx)
         tokens = np.random.RandomState(0).randint(0, 512, size=(8, 17))
         batch = jax.device_put(
             {"tokens": jnp.asarray(tokens, jnp.int32)},

@@ -18,7 +18,7 @@ import pytest
 
 from horovod_tpu import serving
 from horovod_tpu.models import glm_moe_lite as G
-from horovod_tpu.models import llama
+from horovod_tpu.models import llama, steps
 from horovod_tpu.models import xing4 as X
 from horovod_tpu.ops import moe_decode as MD
 from horovod_tpu.parallel import moe
@@ -31,7 +31,7 @@ def _expert_run(cfg):
     """The expert layers' leaves of ``cfg``'s seeded weights: ``(layer
     0's leaves, the run's stack as the serving steps hand it over,
     layers)``."""
-    run = llama.model_of(cfg).init_params(cfg, jax.random.PRNGKey(45))[
+    run = steps.model_of(cfg).init_params(cfg, jax.random.PRNGKey(45))[
         "runs"][-1]
     n = run["router"].shape[0]
     stack = {k: run[leaf].reshape((-1,) + run[leaf].shape[2:])
@@ -205,7 +205,7 @@ def test_the_kernel_engages_on_a_tick_alone(step):
     pool = jnp.zeros((3, 8, 4, cfg.cache_row))
     tables = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
     tok, pos = jnp.asarray([9, 7, 0]), jnp.asarray([9, 5, 0])
-    tick = lambda p, flash: llama.decode_step_paged(
+    tick = lambda p, flash: steps.decode_step_paged(
         p, tok, pos, (pool,), tables, cfg, use_flash=flash,
         interpret=True)[0]
     lp, stack, _ = _expert_run(cfg)
@@ -216,9 +216,9 @@ def test_the_kernel_engages_on_a_tick_alone(step):
     run, arg = {
         "tick": (lambda p: tick(p, True), p),
         "tick-without-kernels": (lambda p: tick(p, False), p),
-        "prefill": (lambda p: llama.prefill_step(
+        "prefill": (lambda p: steps.prefill_step(
             p, jnp.zeros((1, 8), jnp.int32), cfg)[0], p),
-        "verify": (lambda p: llama.extend_step_paged(
+        "verify": (lambda p: steps.extend_step_paged(
             p, jnp.zeros((3, 2), jnp.int32), jnp.zeros((3, 2), jnp.int32),
             jnp.ones((3, 2), bool), (pool,), tables, cfg)[0], p),
         "the-layer-at-a-tick": (layer, x),

@@ -24,7 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from chipbench import reference_ouro as ref                    # noqa: E402
-from horovod_tpu.models import layers, llama                   # noqa: E402
+from horovod_tpu.models import layers, llama, steps            # noqa: E402
 from horovod_tpu.ops import flash_attention as FA              # noqa: E402
 from horovod_tpu.parallel import MeshConfig, build_mesh        # noqa: E402
 from horovod_tpu.serving.disagg import migration               # noqa: E402
@@ -75,10 +75,10 @@ def _serve(params, cfg=CFG, engine_cfg=ENGINE):
     after ``position`` from."""
     eng = ServingEngine(params, cfg, engine_cfg=engine_cfg)
     served: dict = {}
-    prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
+    prefill = jax.jit(lambda p, tok, last: steps.prefill_step(
         p, tok, cfg, last_pos=last))
     decode = jax.jit(lambda p, pools, tok, pos, tables, slots:
-                     llama.decode_step_paged(p, tok, pos, pools, tables,
+                     steps.decode_step_paged(p, tok, pos, pools, tables,
                                              cfg, slots=slots))
 
     def spy_prefill(p, tokens, last_pos):
@@ -149,9 +149,9 @@ def _shared_cache(monkeypatch):
 
 
 def _norm_after_last_pass_only(monkeypatch):
-    real = llama.looped
+    real = steps.looped
     monkeypatch.setattr(
-        llama, "looped", lambda layer, carry, stacked, loops, renorm,
+        steps, "looped", lambda layer, carry, stacked, loops, renorm,
         xs=None, unroll=1: real(layer, carry, stacked, loops, lambda h: h,
                                 xs, unroll))
 
@@ -217,9 +217,9 @@ def test_one_pass_without_post_norms_is_the_plain_decoder_bit_for_bit(
     args = (jnp.asarray([9, 77], jnp.int32), jnp.asarray([10, 5], jnp.int32),
             (pool, pool + 1), tables, cfg)
 
-    new = (llama.prefill_step(p, tok, cfg), llama.decode_step_paged(p, *args))
-    monkeypatch.setattr(llama, "_serve_layers", _plain_serve_layers)
-    old = (llama.prefill_step(p, tok, cfg), llama.decode_step_paged(p, *args))
+    new = (steps.prefill_step(p, tok, cfg), steps.decode_step_paged(p, *args))
+    monkeypatch.setattr(steps, "_serve_layers", _plain_serve_layers)
+    old = (steps.prefill_step(p, tok, cfg), steps.decode_step_paged(p, *args))
     for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(old)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
@@ -243,7 +243,7 @@ def test_paged_kernel_reads_a_cache_layer_past_the_weights_depth(params):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
     tok, pos = jnp.asarray([9, 77], jnp.int32), lengths - 1
-    outs = [llama.decode_step_paged(params, tok, pos, (kp, vp), tables, CFG,
+    outs = [steps.decode_step_paged(params, tok, pos, (kp, vp), tables, CFG,
                                     use_flash=flash, interpret=flash)
             for flash in (False, True)]
     for a, b in zip(*map(jax.tree.leaves, outs)):
@@ -308,5 +308,5 @@ def test_make_train_step_refuses_a_looped_stack():
     """(g) no objective for the passes' exits is here: refused by name."""
     mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
     with pytest.raises(NotImplementedError, match="exit distribution"):
-        llama.make_train_step(CFG, mesh, optax.adamw(1e-3))
-    llama.make_train_step(llama.LlamaConfig.tiny(), mesh, optax.adamw(1e-3))
+        steps.make_train_step(CFG, mesh, optax.adamw(1e-3))
+    steps.make_train_step(llama.LlamaConfig.tiny(), mesh, optax.adamw(1e-3))

@@ -21,7 +21,7 @@ import optax
 import pytest
 
 from horovod_tpu.models import falcon_h1 as FH, glm_moe_lite as G
-from horovod_tpu.models import kimi_linear as K, llama
+from horovod_tpu.models import kimi_linear as K, llama, steps
 from horovod_tpu.models import xing4 as X4
 from horovod_tpu.obs import trace as obs_trace
 from horovod_tpu.parallel import MeshConfig, build_mesh
@@ -38,7 +38,7 @@ WRITERS = ("horovod_tpu.models.layers", "horovod_tpu.models.llama",
            "horovod_tpu.models.kimi_linear",
            "horovod_tpu.models.glm_moe_lite",
            "horovod_tpu.models.falcon_h1", "horovod_tpu.models.xing4",
-           "horovod_tpu.parallel.moe",
+           "horovod_tpu.models.steps", "horovod_tpu.parallel.moe",
            "horovod_tpu.optim.distributed", "horovod_tpu.obs.trace")
 
 _INSTR = re.compile(
@@ -56,7 +56,7 @@ def _train_step(model, cfg):
     tx = optax.adamw(1e-3)
     if model is K:
         tx = K.optimizer(tx)
-    step = llama.make_train_step(cfg, mesh, tx, model=model)
+    step = steps.make_train_step(cfg, mesh, tx, model=model)
     params = jax.eval_shape(
         lambda: model.init_params(cfg, jax.random.PRNGKey(0)))
     state = jax.eval_shape(tx.init, params)

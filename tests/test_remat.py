@@ -11,7 +11,7 @@ import numpy as np
 import optax
 import pytest
 
-from horovod_tpu.models import layers, llama
+from horovod_tpu.models import layers, llama, steps
 from horovod_tpu.parallel import MeshConfig, build_mesh
 
 # What a v5e chip lends a program (``memory_stats()["bytes_limit"]``).
@@ -130,10 +130,34 @@ def test_the_gauge_after_a_traced_step(monkeypatch):
     mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
     tx = optax.adamw(1e-3)
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
     step.lower(params, tx.init(params),
                {"tokens": jnp.zeros((2, 17), jnp.int32)})
     plan = llama.remat_plan(cfg, 2, 16, _param_bytes(cfg), V5E_BYTES)
     assert tuple(plan) == layers.MIXER_RESIDUALS + layers.MLP_RESIDUALS
     for name, nbytes in plan.items():
         assert layers._m_saved.labels(name=name).value == nbytes > 0
+
+
+#: sha256 (first 12 hex digits) of the lowered text of the train step of a
+#: tiny Llama at B2 x S16 on a one-device mesh, remat on, as the chip's
+#: memory lets it keep every named residual ("v5e") and as the CPU keeps
+#: none ("cpu"): the text the step lowered to before the steps every model
+#: goes through moved to ``models/steps.py``
+TRAIN_STEP_LOWERED = {"cpu": "53851777151e", "v5e": "6337f284406c"}
+
+
+@pytest.mark.parametrize("device", sorted(TRAIN_STEP_LOWERED))
+def test_the_train_step_lowers_as_before(monkeypatch, device):
+    import hashlib
+    cfg = llama.LlamaConfig.tiny(remat=True)
+    monkeypatch.setattr(llama, "device_bytes",
+                        lambda: V5E_BYTES if device == "v5e" else None)
+    mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
+    tx = optax.adamw(1e-3)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
+    text = steps.make_train_step(cfg, mesh, tx).lower(
+        params, tx.init(params),
+        {"tokens": jnp.zeros((2, 17), jnp.int32)}).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:12] == \
+        TRAIN_STEP_LOWERED[device]

@@ -113,13 +113,13 @@ def _flagship_losses_on(mesh, batch, n_steps=4):
     multi-process worker (shipped by value) and the in-process oracle."""
     import jax
     import optax
-    from horovod_tpu.models import llama
+    from horovod_tpu.models import llama, steps
 
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg, jax.random.PRNGKey(0), mesh)
     tx = optax.adam(1e-2)
     opt = jax.jit(tx.init)(params)
-    step = llama.make_train_step(cfg, mesh, tx)
+    step = steps.make_train_step(cfg, mesh, tx)
     losses = []
     for _ in range(n_steps):
         params, opt, loss = step(params, opt, batch)

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu import serving
-from horovod_tpu.models import llama
+from horovod_tpu.models import llama, steps
 from horovod_tpu.parallel import MeshConfig, build_mesh
 from horovod_tpu.serving.kv_pager import (KVPager, OutOfBlocks,
                                           PagedKVCache, gather_blocks)
@@ -289,10 +289,10 @@ def test_decode_tick_is_extend_with_one_token_a_row(tiny, use_flash):
                          jnp.int32)
     tok = jnp.asarray(rng.randint(0, cfg.vocab_size, size=(B,)), jnp.int32)
     pos = jnp.asarray([5, 2, 9], jnp.int32)
-    logits_d, (kp_d, vp_d), _, _ = llama.decode_step_paged(
+    logits_d, (kp_d, vp_d), _, _ = steps.decode_step_paged(
         params, tok, pos, (kp, vp), tables, cfg, use_flash=use_flash,
         interpret=use_flash)
-    logits_e, (kp_e, vp_e), _ = llama.extend_step_paged(
+    logits_e, (kp_e, vp_e), _ = steps.extend_step_paged(
         params, tok[:, None], pos[:, None], jnp.ones((B, 1), bool), (kp, vp),
         tables, cfg)
     assert logits_d.shape == (B, cfg.vocab_size)
@@ -327,7 +327,7 @@ def test_decode_tick_skips_rows_with_no_stream(tiny):
     tok = np.where(live, rng.randint(0, cfg.vocab_size, size=B), 0)
     pos = np.where(live, [0, 6, 0, 0, 9], 0)
     args = [jnp.asarray(a, jnp.int32) for a in (tok, pos)]
-    step = lambda k, v, flash: llama.decode_step_paged(
+    step = lambda k, v, flash: steps.decode_step_paged(
         params, *args, (jnp.asarray(k), jnp.asarray(v)),
         jnp.asarray(tables), cfg, use_flash=flash, interpret=flash)
     logits_g, (kp_g, vp_g), _, _ = step(kp, vp, False)

@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu import serving
-from horovod_tpu.models import llama
+from horovod_tpu.models import llama, steps
 from horovod_tpu.obs import REGISTRY
 from horovod_tpu.obs import trace as obs_trace
 from horovod_tpu.ops import flash_attention as FA
@@ -387,7 +387,7 @@ def test_decode_step_compiles_to_one_paged_kernel_and_no_pool_copy(
     pool = spec((L, NB, BS, cfg.n_kv_heads, cfg.head_dim))
     i32 = lambda *shape: spec(shape, jnp.int32)
     step = jax.jit(
-        lambda p, tok, pos, kp, vp, tables: llama.decode_step_paged(
+        lambda p, tok, pos, kp, vp, tables: steps.decode_step_paged(
             p, tok, pos, (kp, vp), tables, cfg, use_flash=True),
         donate_argnums=(3, 4))
     compiled = step.lower(params, i32(R), i32(R), pool, pool,

@@ -25,8 +25,9 @@ import pytest
 
 from chipbench import reference_xing4 as ref
 from chipbench import weights_xing4 as wts
+from horovod_tpu.models import falcon_h1 as F
 from horovod_tpu.models import glm_moe_lite as G
-from horovod_tpu.models import layers, llama
+from horovod_tpu.models import layers, llama, steps
 from horovod_tpu.models import xing4 as X
 from horovod_tpu.obs import REGISTRY
 from horovod_tpu.serving import EngineConfig, ServingEngine
@@ -272,10 +273,10 @@ def _serve(params, engine_cfg=ENGINE, prompts=PROMPTS, new=NEW):
     was picked from."""
     eng = ServingEngine(params, CFG, engine_cfg=engine_cfg)
     served: dict = {}
-    prefill = jax.jit(lambda p, tok, last: llama.prefill_step(
+    prefill = jax.jit(lambda p, tok, last: steps.prefill_step(
         p, tok, CFG, last_pos=last))
     decode = jax.jit(lambda p, pools, tok, pos, tables, slots:
-                     llama.decode_step_paged(
+                     steps.decode_step_paged(
                          p, tok, pos, pools, tables, CFG,
                          use_flash=eng._use_flash, interpret=eng._interpret,
                          slots=slots))
@@ -334,7 +335,7 @@ def test_training_is_refused_by_name():
     import optax
     mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("dp",))
     with pytest.raises(NotImplementedError, match="multi-token prediction"):
-        llama.make_train_step(CFG, mesh, optax.sgd(0.1), model=X)
+        steps.make_train_step(CFG, mesh, optax.sgd(0.1), model=X)
 
 
 def test_a_mesh_is_refused_by_name(params):
@@ -385,12 +386,37 @@ def test_spans_carry_the_streams_and_the_gauge_says_how_many(params,
     assert REGISTRY.get("hvd_serving_residual_streams").value == 1
 
 
-def test_the_hooks_default_to_the_plain_frame():
-    assert llama.serve_hook(G, "serve_frame")(G.GlmMoeLiteConfig.tiny()) \
-        is layers.block
-    assert llama.serve_hook(llama, "serve_readout") is llama.serve_readout
-    assert llama.serve_hook(X, "serve_readout") is X.serve_readout
-    assert llama.serve_hook(X, "serve_frame")(CFG).func is layers.hc_block
+#: each served model, and the names with a default that it gives itself
+HOOKED = {
+    "llama": (llama, llama.LlamaConfig.tiny,
+              {"check_trainable", "train_step_1f1b", "shard_rules"}),
+    "glm": (G, G.GlmMoeLiteConfig.tiny, {"check_trainable", "serve_stats"}),
+    "falcon": (F, F.FalconH1Config.tiny, {"check_trainable", "state_rows"}),
+    "xing4": (X, X.Xing4Config.tiny, {"check_trainable", "serve_stats",
+                                      "serve_frame", "serve_readout"}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(steps.DEFAULTS))
+@pytest.mark.parametrize("kind", sorted(HOOKED))
+def test_the_hooks_default_to_the_plain_frame(kind, name):
+    """The one lookup: a model's own name where it gives one, else the
+    default, which the model does not write again."""
+    module, tiny, own = HOOKED[kind]
+    cfg = tiny()
+    got = getattr(steps.model_of(cfg), name)
+    if name in own:
+        assert got is getattr(module, name)
+    else:
+        assert not hasattr(module, name)
+        assert got is steps.DEFAULTS[name]
+    if name == "serve_frame":
+        frame = got(cfg)
+        assert frame.func is layers.hc_block if kind == "xing4" \
+            else frame is layers.block
+    if name == "serve_readout" and kind != "xing4":
+        h = jnp.ones((1, 2, cfg.d_model))
+        assert got(None, h, cfg) is h
 
 
 # -- (6) the cell's programs for a v5e ----------------------------------------
@@ -430,7 +456,7 @@ def test_the_cells_programs_fit_a_v5e(monkeypatch):
     pool = 7 * 15000 * 32 * cfg.cache_row * 2
 
     def prefill(p, tok, last):
-        logits, kept, stats = llama.prefill_step(p, tok, cfg, last_pos=last)
+        logits, kept, stats = steps.prefill_step(p, tok, cfg, last_pos=last)
         return (jnp.argmax(logits, -1).astype(jnp.int32), stats), kept
     mem = jax.jit(prefill).lower(
         p, spec((1, 8704), jnp.int32), spec((1,), jnp.int32)
@@ -439,7 +465,7 @@ def test_the_cells_programs_fit_a_v5e(monkeypatch):
     assert peak <= 15.45e9, (peak, mem)
     i32 = lambda *shape: spec(shape, jnp.int32)
     step = jax.jit(
-        lambda p, tok, pos, pool, tables: llama.decode_step_paged(
+        lambda p, tok, pos, pool, tables: steps.decode_step_paged(
             p, tok, pos, (pool,), tables, cfg, use_flash=True),
         donate_argnums=(3,))
     compiled = step.lower(p, i32(64), i32(64), spec((7, 15000, 32, 640)),
